@@ -89,7 +89,6 @@ class Reconciler:
         expectations: Optional[ControllerExpectations] = None,
         status_root: Optional[Path] = None,
         checkpoint_root: Optional[Path] = None,
-        cache_root: Optional[Path] = None,
         coordinator_host: str = "127.0.0.1",
         queue_slots: Optional[dict] = None,
         trace_root: Optional[Path] = None,
@@ -111,9 +110,6 @@ class Reconciler:
         # live under here; each serving replica gets a private spool
         # injected as TPUJOB_SPOOL_DIR. None = serve plane off.
         self.serve_root = Path(serve_root) if serve_root else None
-        # ONE cache for the whole state dir (not per-job): the win is a
-        # resubmitted job hitting the previous run's compiled executables.
-        self.cache_root = Path(cache_root) if cache_root else None
         self.coordinator_host = coordinator_host
         # Per-queue replica-slot caps (volcano queue analog): jobs name a
         # queue in scheduling_policy; admission is bounded by the queue's
@@ -865,10 +861,6 @@ class Reconciler:
             status_dir = self._status_dir(key)
             checkpoint_dir = self._checkpoint_dir(key)
             trace_dir = self._trace_dir(job, key)
-            cache_dir = None
-            if self.cache_root is not None:
-                self.cache_root.mkdir(parents=True, exist_ok=True)
-                cache_dir = str(self.cache_root)
             num_processes = sum(
                 self._desired_replicas(job, rt) for rt in job.spec.replica_specs
             )
@@ -943,7 +935,6 @@ class Reconciler:
                         coordinator_host=self.coordinator_host,
                         status_dir=status_dir,
                         checkpoint_dir=checkpoint_dir,
-                        compile_cache_dir=cache_dir,
                         trace_dir=trace_dir,
                         spool_dir=spool_dir,
                         rank=rank,

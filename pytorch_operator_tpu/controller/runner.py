@@ -709,8 +709,7 @@ class SubprocessRunner(ProcessRunner):
             # (worker-side faults fire inside the subprocess itself).
             faults.thread_env(full_env)
             # Replicas must import this package regardless of cwd, and the
-            # inherited PYTHONPATH must be PRESERVED (site customizations —
-            # e.g. the TPU PJRT plugin registration — live there).
+            # inherited PYTHONPATH is preserved (the user's own modules).
             pkg_root = str(Path(__file__).resolve().parents[2])
             parts = [p for p in full_env.get("PYTHONPATH", "").split(os.pathsep) if p]
             if pkg_root not in parts:

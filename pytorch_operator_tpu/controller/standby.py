@@ -1,17 +1,18 @@
 """Pre-warmed standby replicas — the schedule-to-first-step accelerator.
 
-BASELINE.md's latency breakdown puts a ~5s floor under even a warm
-(compile-cached) job start: process spawn + ``import jax`` (and friends)
-+ backend init, all paid serially before the workload's first line runs.
+Even a warm (compile-cached) job start pays process spawn + ``import
+jax`` (and friends) + backend init, serially, before the workload's first
+line runs.
 The reference has no analog (kubelet image pulls / container starts are
 its version of this cost, and it never attacks them); this is TPU-native
 performance work on the BASELINE.json:2 north-star metric.
 
 Design: the supervisor keeps N **standby** processes that have already
 paid the interpreter + heavy-import cost (jax/flax/optax/numpy — NO
-device client: standbys must not contend with live jobs for the TPU, per
-BASELINE.md's contention note; the client is acquired lazily after
-assignment). ``SubprocessRunner.create`` hands a job to a ready standby
+device client: a chip belongs to one process at a time, so a standby
+that opened it would take it from the live jobs; the client is acquired
+lazily after assignment). ``SubprocessRunner.create`` hands a job to a
+ready standby
 instead of spawning cold:
 
 1. runner writes ``<id>.assign.json`` (atomic tmp+rename) into the pool
@@ -96,11 +97,7 @@ def _run_assignment(spec: dict) -> int:
 
     for env_key, cfg_key in _JAX_ENV_CONFIG:
         if env.get(env_key):
-            try:
-                jax.config.update(cfg_key, _coerce(cfg_key, env[env_key]))
-            except Exception:
-                # invariant: waived — unknown option on this jax version; the env-var route still applies it
-                pass
+            jax.config.update(cfg_key, _coerce(cfg_key, env[env_key]))
     # Route all output to the replica's log file (kubectl-logs analog) —
     # fd-level dup2 so subprocesses and C extensions follow too.
     log_fd = os.open(

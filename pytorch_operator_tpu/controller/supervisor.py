@@ -190,7 +190,6 @@ class Supervisor:
             expectations=self.expectations,
             status_root=self.state_dir / "status",
             checkpoint_root=self.state_dir / "checkpoints",
-            cache_root=self.state_dir / "xla_cache",
             queue_slots=queue_slots,
             trace_root=self.state_dir / "trace",
             serve_root=self.state_dir / "serve",

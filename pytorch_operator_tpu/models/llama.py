@@ -76,8 +76,8 @@ class LlamaConfig:
     # Expert dispatch: "dense" (every device runs its local experts over
     # all tokens — exact, no drops, FLOPs ∝ local experts) or "sparse"
     # (GShard capacity-factor dispatch — FLOPs ∝ top_k·capacity_factor,
-    # over-capacity tokens dropped; measured 1.2-1.3x ideal vs dense's
-    # 2.1-4.9x at E=8-32, BASELINE.md). Prefer "sparse" from E >= 16.
+    # over-capacity tokens dropped). Dense's cost grows with E, sparse's
+    # does not: prefer "sparse" from E >= 16.
     moe_dispatch: str = "dense"
     moe_capacity_factor: float = 1.25
     # Switch-style load-balancing auxiliary loss weight (0 = off). With
@@ -103,7 +103,7 @@ class LlamaConfig:
     # dot's operand read — the cache is a scan CARRY, not a scan input,
     # so no materialization issue arises). Halves cache HBM: the lever
     # that fits long-context 8B serving on one chip next to the int8
-    # weights (BASELINE.md round-4). Independent of ``quantize``.
+    # weights. Independent of ``quantize``.
     kv_quantize: Optional[str] = None
     # Per-row decode offsets (decode only): False keeps the batch-uniform
     # contract (every row at the same position; cache writes are ONE
@@ -201,10 +201,9 @@ def llama3_8b(**over) -> LlamaConfig:
     """The real Llama-3-8B shape (BASELINE.json:10 target workload).
 
     Defaults to the pallas flash kernel: at this scale the S×S score
-    materialization dominates attention HBM traffic (3.5 ms vs 75 ms dense
-    fwd at S=8192 — BASELINE.md). flash_attention zero-pads unaligned
-    shapes to the kernel tiling and masks the padding (round 4; no dense
-    fallback cliff). Also defaults to
+    materialization dominates attention HBM traffic. flash_attention
+    zero-pads unaligned shapes to the kernel tiling and masks the padding
+    (round 4; no dense fallback cliff). Also defaults to
     the chunked-vocab loss: [B,S,128256] f32 logits would otherwise be the
     single largest activation in the step.
     """
@@ -216,8 +215,7 @@ def llama_0_3b(**over) -> LlamaConfig:
     largest config that trains comfortably on one v5e chip at long
     sequence lengths. Same architecture and kernel defaults as
     :func:`llama3_8b` (flash attention — head_dim stays 128, the kernel's
-    lane width — and chunked-vocab loss); the BASELINE.md "0.33B llama
-    variant" rows use this config.
+    lane width — and chunked-vocab loss).
     """
     return llama3_8b(
         **{
@@ -238,11 +236,9 @@ def llama_1b(**over) -> LlamaConfig:
     params + adafactor state + 'dots'-remat residuals fit one v5e chip
     (batch 2 × seq 4096; batch 4 needs 'full' remat and measures worse).
 
-    Role: the MFU-vs-scale evidence point. The 0.3b config's 63% MFU is
-    bounded by per-step elementwise/issue floors that amortize with
-    width — this config measures 76% of the sustained matmul rate on
-    the same chip (BASELINE.md round-4 "MFU vs scale"), showing the
-    framework's ceiling tracks the hardware, not the harness.
+    Role: the MFU-vs-scale evidence point. The 0.3b config is bounded
+    by per-step elementwise/issue floors that amortize with width; this
+    config shows how utilization moves with model size on the same chip.
     """
     return llama3_8b(
         **{
@@ -534,9 +530,7 @@ class Attention(nn.Module):
             # in a bf16 mantissa); the per-token scales fold into the
             # TINY score/prob tensors after the dots. A fused
             # convert+scale on the slab defeats operand fusion and
-            # materializes a full-precision copy per layer per step —
-            # measured -9% vs the fp cache at 1b/b8/L=4096, where this
-            # formulation measures +43% (BASELINE.md round-4).
+            # materializes a full-precision copy per layer per step.
             kc, vc = ck.value.astype(cfg.dtype), cv.value.astype(cfg.dtype)
         else:
             kc, vc = ck.value, cv.value
@@ -785,9 +779,8 @@ class Llama(nn.Module):
             split_rngs={"params": True},
             length=cfg.n_layers,
             metadata_params={nn.PARTITION_NAME: "layers"},
-            # Deliberately no unroll knob: lax.scan unroll=2/4 measured
-            # -13% on chip (BASELINE.md) — XLA pipelines the rolled scan
-            # better than merged bodies.
+            # Deliberately no unroll knob: an earlier round measured
+            # lax.scan unroll=2/4 slower than the rolled scan.
         )
         (x, _), _ = ScanBlocks(cfg, self.mesh, name="layers")((x, positions), None)
 
@@ -938,8 +931,8 @@ def decode_forward(
     Why this exists: under ``nn.scan(variable_axes={"cache": 0})`` every
     decode step dynamic-slices each layer's whole slab out of the
     stacked cache, rewrites it wholesale, and copies the stack — an
-    xplane profile at 1b/b8/L=4096 showed 16 of 22.3 ms/step going to
-    exactly that (copy 30% + DS/DUS fusions 43%; BASELINE.md round-4).
+    xplane profile at 1b/b8/L=4096 showed most of the step going to
+    exactly that (copies and dynamic-slice/update-slice fusions).
     Here each layer's slab is a plain carry leaf: the step reads it once
     (fused into the attention einsums) and writes ONE token slice in
     place. Quantized (``cfg.quantize``) trees are dequantized per layer

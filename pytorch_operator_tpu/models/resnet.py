@@ -10,7 +10,7 @@ TPU-first choices:
 - NHWC layout (XLA's native conv layout on TPU),
 - bfloat16 compute / float32 params and batch-norm statistics (MXU-friendly
   without accuracy loss; ``bn_f32_stats=False`` is an experimental knob that
-  drops BN stats AND BN scale/bias to bf16 — BASELINE.md A/B),
+  drops BN stats AND BN scale/bias to bf16),
 - no data-dependent control flow — the whole step is one XLA program.
 """
 
@@ -145,8 +145,8 @@ class ResNet(nn.Module):
     # for convergence runs. False computes the BN reductions in bf16 AND
     # (a flax constraint: stats are stored in param_dtype) downcasts the
     # learnable scale/bias to bf16 — so their SGD updates quantize to an
-    # 8-bit mantissa too. Measured throughput-neutral on this hardware
-    # (BASELINE.md A/B); kept as an experiment knob only.
+    # 8-bit mantissa too. An earlier round measured it throughput-
+    # neutral; kept as an experiment knob only.
     bn_f32_stats: bool = True
     # Compute the stem as a space-to-depth 4×4 conv (exact; see
     # SpaceToDepthStem). Same parameters/checkpoints either way.

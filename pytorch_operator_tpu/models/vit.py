@@ -54,7 +54,7 @@ class ViTConfig:
     # Remat policy when remat=True — same semantics as
     # LlamaConfig.remat_policy: "full" saves only block boundaries;
     # "dots" saves batch-dim-free GEMM outputs so backward skips
-    # recomputing the MXU-bound work (+8% on the 0.3b LM, BASELINE.md).
+    # recomputing the MXU-bound work.
     remat_policy: str = "full"
 
     @property

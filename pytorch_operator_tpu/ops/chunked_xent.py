@@ -118,28 +118,17 @@ def chunked_vocab_stats(hidden, w, labels, *, chunk: int = 8192, col_offset=0):
     return m, s, lab_logit
 
 
-def _aval(v):
-    """jax.typeof with a fallback for jax versions that predate it (the
-    vma machinery doesn't exist there either, so callers see no varying
-    axes and degrade to identity)."""
-    try:
-        return jax.typeof(v)
-    except AttributeError:
-        return jax.core.get_aval(v)
-
-
 def _match_vma(tree, ref):
     """pcast every leaf of ``tree`` to carry ``ref``'s varying manual
     axes (shard_map vma) — makes freshly-built scan carries type-stable
-    when this op runs inside a manual region. Identity elsewhere (and on
-    jax versions without vma/pcast, where types are never vma-annotated)."""
-    vma = getattr(_aval(ref), "vma", frozenset())
-    if not vma or not hasattr(jax.lax, "pcast"):
+    when this op runs inside a manual region. Identity elsewhere."""
+    vma = jax.typeof(ref).vma
+    if not vma:
         return tree
     return jax.tree.map(
         lambda v: (
             v
-            if set(getattr(_aval(v), "vma", frozenset())) >= set(vma)
+            if jax.typeof(v).vma >= vma
             else jax.lax.pcast(v, tuple(vma), to="varying")
         ),
         tree,

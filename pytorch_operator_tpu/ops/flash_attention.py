@@ -451,9 +451,9 @@ def flash_attention(
     S-padding is bounded by one extra block row/column, but D-padding
     MULTIPLIES the attention FLOPs and q/k/v/o bytes by D_pad/D (2x for
     D=64) — a win at long S where the kernel's O(S·D) HBM beats the
-    dense path's O(S^2) (measured 2.9x at S=5000, BASELINE.md), NOT for
-    short-S/thin-D models: ViT-B (S=197, D=64) measured 41% SLOWER
-    under the padded kernel than dense XLA and keeps its dense default.
+    dense path's O(S^2), NOT for short-S/thin-D models: an earlier round
+    measured ViT-B (S=197, D=64) slower under the padded kernel than
+    dense XLA, and it keeps its dense default.
 
     ``kv_len``: static TRUE sequence length when the caller's batch is
     already padded to S — keys/values at positions >= kv_len are masked
@@ -461,17 +461,21 @@ def flash_attention(
     an array operand; compose ragged batches with segment packing
     instead).
 
-    Default block sizes were swept on a TPU v5 lite chip. Round 2's
-    kernel-level sweep picked 512/1024 (matches or beats the in-tree
-    pallas kernel); round 3 re-swept END-TO-END in the 0.3b train step
-    (fwd+bwd under 'dots' remat), where 1024/1024 wins consistently —
-    +3.4% at S=4096 to +7.4% at S=16384 (BASELINE.md) — and stays
-    within VMEM with double buffering at D=128.
+    Default block sizes: an earlier round's end-to-end sweep in the 0.3b
+    train step settled on 1024/1024. Under libtpu 0.0.34 on a v5e all
+    three kernels compile at 1024/1024 with D=128 as they stand, with no
+    ``compiler_params`` (chip run, PR 21; chip_smoke.py keeps checking
+    them against ``_dense_reference`` at H=32, KH=8).
 
     ``mesh``: wrap in a partial-manual shard_map over the batch (dp, fsdp)
     and head (tp) mesh axes so the kernel composes with pjit sharding.
-    ``interpret``: force pallas interpret mode; default = auto (on for CPU
-    backends, where tests run; off on TPU).
+    ``interpret``: force pallas interpret mode; default = auto: on when
+    the default backend is the CPU, where the tests run, off on TPU. A
+    ``tpu_chips`` job cannot reach the interpreter by losing its chip:
+    the supervisor pins its platform (runtime/env.py), so with no chip
+    the replica fails at backend creation, before any model is built.
+    Nothing on the training path gives way to ``_dense_reference`` either;
+    that function is the tests' oracle only.
     """
     import jax
 
@@ -522,7 +526,7 @@ def flash_attention(
     if not manual:
         return core(q, k, v)
 
-    from ..jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     batch = tuple(batch_axes) or None
@@ -544,7 +548,8 @@ def flash_attention(
 
 
 def _dense_reference(q, k, v, *, causal: bool):
-    """XLA fallback — also the numerics oracle in tests."""
+    """Dense XLA attention: the numerics oracle for tests and for
+    chip_smoke.py. No code path falls back to it."""
     import jax
     import jax.numpy as jnp
 

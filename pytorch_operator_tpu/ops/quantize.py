@@ -2,9 +2,9 @@
 
 Reference analog: none — the reference is a training operator and any
 quantization lives in its user containers. The rebuild motivation is
-BASELINE.md's own decode analysis: at 0.3b scale the decode step is
-bound by a per-step issue floor (bf16 weights measured only +4% over
-f32), but the step becomes weight-STREAMING bound as the model grows —
+the decode step's own arithmetic: at 0.3b scale it is bound by a
+per-step issue floor (bf16 weights barely beat f32), but the step
+becomes weight-STREAMING bound as the model grows —
 and at 8B the bf16 weights alone (16 GB) exceed a v5e chip's HBM, so
 the flagship config cannot decode on one chip at all without shrinking
 the bytes. Symmetric per-channel int8 cuts the streamed weight bytes
@@ -22,8 +22,8 @@ TPU-first mechanics, and why this is NOT a "dequantize then run" wrapper:
 - Inside ``lax.scan`` decode loops the dequant is loop-invariant, but
   XLA's while-loop code motion declines to hoist size-inflating ops
   (a convert s8→f32 quadruples bytes), so the fusion — and the memory
-  win — survives the scan. Verified empirically by the 8B-on-one-chip
-  measurement in BASELINE.md (a hoisted dequant would OOM instantly).
+  win — survives the scan. Verified empirically by 8B decoding on one
+  chip at all (a hoisted dequant would OOM instantly).
 - Scales are per-OUTPUT-channel over each weight's contraction axis
   (the axis the matmul reduces), the standard accuracy/shape trade:
   one f32 per output column, broadcast along the reduction.

@@ -43,9 +43,7 @@ def ring_shift(x: Any, axis: str, *, shift: int = 1):
     shard to the next rank (rank i's output = rank i-1's input)."""
     import jax
 
-    from ..jaxcompat import axis_size as _axis_size
-
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis, perm)
 
@@ -57,6 +55,6 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    from ..jaxcompat import axis_size as _axis_size
+    import jax
 
-    return _axis_size(axis)
+    return jax.lax.axis_size(axis)

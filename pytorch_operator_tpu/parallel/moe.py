@@ -147,9 +147,8 @@ def moe_mlp_sparse(
     works here too. Tokens beyond an expert's per-group capacity are
     DROPPED — the standard capacity tradeoff; the dense-dispatch path
     (:func:`moe_mlp` / :func:`moe_mlp_reference`) stays the exact option.
-    BASELINE.md records the measured chip A/B (dense 2.1x/2.8x/4.9x the
-    top-k-FLOPs ideal at E=8/16/32; sparse 1.2-1.3x, flat in E): prefer
-    sparse from E >= 16.
+    Dense dispatch's cost over the top-k-FLOPs ideal grows with E while
+    sparse's stays flat: prefer sparse from E >= 16.
 
     With ``mesh``: experts shard over ``axis`` (ep) exactly like
     :func:`moe_mlp`; each device computes its local experts' capacity
@@ -187,7 +186,7 @@ def moe_mlp_sparse(
     ep = mesh.shape[axis]
     if n_exp % ep:
         raise ValueError(f"experts {n_exp} not divisible by ep={ep}")
-    from ..jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def per_shard(weights, dispatch_g, combine_g, xg_g):
@@ -235,7 +234,7 @@ def moe_mlp(
     (standard renormalized top-k routing); expert FFN is gelu.
     """
     import jax
-    from ..jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_exp, d_model, d_ff = params["w_in"].shape

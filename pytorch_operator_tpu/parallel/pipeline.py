@@ -62,7 +62,7 @@ def pipeline_apply(
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from ..jaxcompat import shard_map
+    from jax import shard_map
 
     n_stages = mesh.shape[axis]
     M = microbatches
@@ -255,7 +255,7 @@ def pipeline_value_and_grad(
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from ..jaxcompat import shard_map
+    from jax import shard_map
 
     if backward not in ("recompute", "stored"):
         raise ValueError(
@@ -360,12 +360,7 @@ def pipeline_value_and_grad(
         last = n_stages - 1
 
         def _varying(v):
-            # jax-version shim: no typeof/pcast (pre-vma jax) -> types
-            # are never vma-annotated, pcast neither exists nor matters.
-            typeof = getattr(jax, "typeof", None)
-            if typeof is None or not hasattr(jax.lax, "pcast"):
-                return v
-            if axis in getattr(typeof(v), "vma", ()):
+            if axis in jax.typeof(v).vma:
                 return v
             return jax.lax.pcast(v, (axis,), to="varying")
 

@@ -56,9 +56,7 @@ def ring_attention_shard(
     import jax.numpy as jnp
 
     B, Sq, K, G, D = q.shape
-    from ..jaxcompat import axis_size
-
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     scale = 1.0 / (D**0.5)
     neg = jnp.finfo(jnp.float32).min
 
@@ -94,9 +92,7 @@ def ring_attention_shard(
     # after the first block; mark them varying over the ring axis up front so
     # the fori_loop carry type is stable (shard_map VMA typing).
     def varying(x):
-        from ..jaxcompat import pcast_varying
-
-        return pcast_varying(x, axis_name)
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     m0 = varying(jnp.full((B, K, G, Sq), neg, jnp.float32))
     l0 = varying(jnp.zeros((B, K, G, Sq), jnp.float32))
@@ -145,7 +141,7 @@ def ring_self_attention(
     """
     import jax
 
-    from ..jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if (

@@ -86,7 +86,7 @@ def ulysses_self_attention(
     """
     import functools
 
-    from ..jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .ring import _single_shard

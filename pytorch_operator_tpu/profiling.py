@@ -5,8 +5,7 @@ SURVEY.md §5 "Tracing / profiling": the reference has none of its own
 workloads write ``jax.profiler`` traces via ``--profile-dir``. This
 module closes the loop WITHOUT tensorboard: it parses the trace's
 ``*.xplane.pb`` directly and prints where device time goes — per-step
-busy/idle split, op-category totals, and the top individual ops — the
-analysis used for the BASELINE.md bandwidth-wall findings, as a tool.
+busy/idle split, op-category totals, and the top individual ops.
 
 Usage::
 

@@ -206,10 +206,6 @@ def exit_for_resize(sig: ResizeSignal) -> None:
             "RANK": str(w.process_id),
             "MASTER_ADDR": host or "127.0.0.1",
             "MASTER_PORT": port,
-            "TPU_WORKER_ID": str(w.process_id),
-            "TPU_WORKER_HOSTNAMES": ",".join(
-                [host or "127.0.0.1"] * w.num_processes
-            ),
         }
     )
     report(
@@ -407,6 +403,17 @@ def _maybe_echo_probe() -> None:
         return
     _probe_echoed_seq = probe["seq"]
     report("clock_probe", probe_ts=probe["probe_ts"], seq=probe["seq"])
+
+
+def report_device() -> dict:
+    """Put this replica's :func:`~.backend.device_report` on the status
+    channel (a ``device`` record per replica — what a gang's processes
+    each saw) and return it for the workload's own result."""
+    from .backend import device_report
+
+    dev = device_report()
+    report("device", **dev)
+    return dev
 
 
 def report_first_step(step: int = 0) -> None:

@@ -213,7 +213,7 @@ def _loop(
         steps=steps,
         warmup=warmup,
         device_get=jax.device_get,
-        on_first_step=lambda: rendezvous.report_first_step(0),
+        on_first_step=lambda _loss, _s: rendezvous.report_first_step(0),
         log=lambda m: log(f"[bert] {m}"),
         profile_dir=profile_dir,
         progress=(

@@ -7,8 +7,7 @@ The build environment has no network (SURVEY.md §7 environment facts), so:
   reference's MNIST example (``examples/mnist``): real pixels, a real
   train/test generalization gap, and the >97% accuracy bar is meaningful.
 - ``synthetic_images``: procedurally generated image/label batches for
-  throughput benchmarking (isolates compute from input pipeline, the
-  BASELINE.md measurement methodology).
+  throughput benchmarking (isolates compute from input pipeline).
 """
 
 from __future__ import annotations

@@ -11,8 +11,8 @@ checkpoint dir and resumes from the latest step on restart — kill a worker
 mid-run and the restarted gang continues, not restarts.
 
 Data is a synthetic affine-bigram stream (token[t+1] = (a·token[t]+b) mod V)
-— structured enough that falling loss proves learning, with zero input-
-pipeline cost (the BASELINE.md synthetic-benchmark methodology).
+— structured enough that falling loss proves learning at a vocabulary a
+run can cover, with zero input-pipeline cost.
 """
 
 from __future__ import annotations
@@ -463,7 +463,10 @@ def run(
                 maybe_resize(step)
                 return put_global(host_batch(step), batch_sharding)
 
-        def on_first():
+        first = {}
+
+        def on_first(loss, seconds):
+            first.update(loss=float(loss), seconds=seconds)
             rendezvous.report_first_step(start_step)
 
         with mesh:
@@ -533,11 +536,15 @@ def run(
         "unit": "tokens/sec/chip",
         "config": config,
         "params_m": round(n_params / 1e6, 1),
+        "first_loss": round(first["loss"], 4),
+        "first_step_s": round(first["seconds"], 2),
         "final_loss": round(final_loss, 4),
+        "start_step": start_step,
         "end_step": end_step,
         "devices": n_dev,
         "n_layers": cfg.n_layers,
         "d_model": cfg.d_model,
+        **rendezvous.report_device(),
     }
     if cfg.n_experts > 1:
         # FLOPs-active parameter count for honest MoE MFU: sparse

@@ -273,6 +273,7 @@ def run(
         transport=transport,
         ring_recvs=spool.ring_recvs,
         ring_sends=spool.ring_sends,
+        **rendezvous.report_device(),
     )
     spool.close()
     if weight_bytes is not None:

@@ -55,7 +55,7 @@ def main() -> int:
 
     from functools import partial
 
-    from ..jaxcompat import shard_map
+    from jax import shard_map
 
     @jax.jit
     @partial(
@@ -91,7 +91,12 @@ def main() -> int:
         return 1
 
     rendezvous.report_first_step()
-    print(f"[smoke-dist] rank {world.process_id}: OK", flush=True)
+    dev = rendezvous.report_device()
+    print(
+        f"[smoke-dist] rank {world.process_id}: OK on {dev['platform']} "
+        f"device(s) {dev['local_device_ids']} of {dev['device_count']}",
+        flush=True,
+    )
     return 0
 
 

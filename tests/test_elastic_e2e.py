@@ -2,7 +2,7 @@
 jax.distributed world, real orbax checkpoints, deterministic fault
 injection.
 
-This is the BASELINE.md "Elastic job: preemption → in-place restart" row:
+The elastic job, preemption → in-place restart:
 a Worker dies mid-training with a retryable exit code; the supervisor
 gang-restarts the world (elastic re-rendezvous) and the restarted gang
 RESUMES from the latest checkpoint rather than restarting from step 0.

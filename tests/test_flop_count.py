@@ -40,7 +40,7 @@ class TestFlopCount:
     def test_shard_map_multiplies_by_manual_devices(self):
         import jax
         import jax.numpy as jnp
-        from pytorch_operator_tpu.jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from pytorch_operator_tpu.parallel import make_mesh

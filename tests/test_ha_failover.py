@@ -29,7 +29,7 @@ REPO_ROOT = str(Path(__file__).resolve().parents[1])
 def spawn_daemon(state_dir, log_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env["TPUJOB_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [
             sys.executable,

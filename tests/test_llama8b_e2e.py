@@ -13,8 +13,7 @@ activations are ~1 GiB while params+grads are ~32 GiB, so recompute
 would double step time to save nothing that matters here.
 
 Opt-in (TPUJOB_RUN_8B=1): one run takes tens of minutes and ~40+ GiB
-RSS — it must not ride the regular suite. BASELINE.md records the
-measured wall/RSS from the round-4 session.
+RSS — it must not ride the regular suite.
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 import tests.jaxenv  # noqa: F401  (forces CPU platform before jax use)
-from pytorch_operator_tpu.jaxcompat import shard_map
+from jax import shard_map
 from pytorch_operator_tpu.parallel import (
     collectives,
     fsdp_spec,

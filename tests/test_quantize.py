@@ -219,8 +219,8 @@ class TestQuantizedGenerate:
             qmodel.init(jax.random.key(0), toks)
 
     def test_run_quantized_smoke(self, tmp_path):
-        """The workload path end to end on CPU (the chip measurements in
-        BASELINE.md ride this exact entry)."""
+        """The workload path end to end on CPU (chip runs ride this exact
+        entry)."""
         from pytorch_operator_tpu.workloads import generate as gen_mod
 
         result = gen_mod.run(

@@ -378,7 +378,7 @@ def test_llama_max_steps_caps_work(tmp_path, monkeypatch):
 
 
 def test_llama_1b_plan_fits_one_v5e_chip():
-    """The MFU-vs-scale config (BASELINE.md round-4): ~1.14B params, and
+    """The MFU-vs-scale config: ~1.14B params, and
     its measured on-chip recipe — bf16 params + adafactor + batch 2 —
     must fit v5e HBM with the 'dots'-remat residuals. Abstract
     (eval_shape): no compile, no arrays."""
