@@ -1,0 +1,88 @@
+"""The traffic generator: the same seed gives the same schedule, every seed
+the same work (lengths and gaps as multisets) in another order, and the
+stated lengths and rate hold."""
+
+from __future__ import annotations
+
+import json
+from pathlib import Path
+
+import pytest
+
+from benchmark import traffic as T
+
+ROOT = Path(__file__).resolve().parents[2]
+MIXES = sorted(p.stem for p in (ROOT / "benchmark" / "traffic").glob("*.json"))
+SERVE_MIXES = [m for m in MIXES if "loop" in T.load(m)]
+
+
+@pytest.mark.parametrize("mix_name", SERVE_MIXES)
+def test_same_seed_same_schedule_other_seed_same_work(mix_name):
+    mix = T.load(mix_name)
+    a, b = T.schedule(mix, 12345, 20.0, 1000), T.schedule(mix, 12345, 20.0, 1000)
+    assert a == b
+    c = T.schedule(mix, 2**31 + 77, 20.0, 1000)  # the driver's seeds are large
+    assert [r["prompt"] for r in c] != [r["prompt"] for r in a]
+    pairs = lambda s: sorted((r["prompt_len"], r["max_new_tokens"]) for r in s)
+    assert pairs(a) == pairs(c)
+    assert all(len(r["prompt"]) == r["prompt_len"] and all(0 <= t < 1000 for t in r["prompt"]) for r in c)
+
+
+@pytest.mark.parametrize("mix_name", SERVE_MIXES)
+def test_lengths_are_the_tables_and_within_the_stated_range(mix_name):
+    mix = T.load(mix_name)
+    table = {tuple(p) for p in mix["lengths"]}
+    sched = T.schedule(mix, 3, 30.0, 50)
+    assert {(r["prompt_len"], r["max_new_tokens"]) for r in sched} <= table
+    longest = max(p + a for p, a in table)
+    assert longest <= mix["check_pad_to"] and longest < 4095  # fits the engine's cache and the check's padding
+
+
+def test_open_loop_rate_and_gaps():
+    mix = {"name": "m", "loop": "open", "rate_per_s": 5.0, "lengths": [[10, 3], [20, 4]]}
+    for seconds in (10.0, 50.0):
+        due = T.arrival_times(mix, 9, seconds)
+        assert len(due) == round(5.0 * seconds)
+        assert due == sorted(due) and 0 < due[0] and due[-1] < seconds
+    gaps = lambda d: sorted(round(y - x, 9) for x, y in zip([0.0] + d[:-1], d))
+    assert gaps(T.arrival_times(mix, 1, 50.0)) == gaps(T.arrival_times(mix, 2, 50.0))
+    assert T.arrival_times(mix, 1, 50.0) != T.arrival_times(mix, 2, 50.0)
+    # Exponential gaps: the coefficient of variation of a Poisson stream is 1.
+    g = gaps(T.arrival_times(mix, 1, 50.0))
+    mean = sum(g) / len(g)
+    cv = (sum((x - mean) ** 2 for x in g) / len(g)) ** 0.5 / mean
+    assert 0.85 < cv < 1.1
+
+
+def test_closed_loop_supply_and_unknown_loop():
+    mix = {"name": "m", "loop": "closed", "clients": 2, "supply": 40, "lengths": [[10, 3], [20, 4], [30, 5]]}
+    sched = T.schedule(mix, 5, 10.0, 100)
+    assert len(sched) == 40 and all(r["due"] is None for r in sched)
+    assert len({r["id"] for r in sched}) == 40
+    with pytest.raises(ValueError):
+        T.schedule({**mix, "loop": "spiral"}, 5, 10.0, 100)
+    with pytest.raises(FileNotFoundError):
+        T.load("no-such-mix")
+
+
+def test_chat_file_records_its_sweep():
+    mix = json.loads((ROOT / "benchmark" / "traffic" / "chat-poisson.json").read_text())
+    assert mix["swept"], "the swept rates and what each gave belong in the traffic file"
+    assert any(abs(row["rate_per_s"] * 0.8 - mix["rate_per_s"]) < 0.26 for row in mix["swept"] if row.get("knee"))
+
+
+def test_the_seed_enters_one_fixed_cycle():
+    """Which length meets which gap never changes: the seed only moves the
+    point at which the cycle is entered."""
+    mix = T.load("chat-poisson")
+    n = round(mix["rate_per_s"] * 50.0)
+
+    def cycle(seed):
+        s = T.schedule(mix, seed, 50.0, 100)
+        due = [r["due"] for r in s]
+        gaps = [round(b - a, 9) for a, b in zip([0.0] + due[:-1], due)]
+        return list(zip(gaps, [(r["prompt_len"], r["max_new_tokens"]) for r in s]))
+
+    a, b = cycle(0), cycle(2**31 + 12345)
+    k = (2**31 + 12345) % n
+    assert len(a) == n == 240 and b == a[k:] + a[:k] and b != a
