@@ -1,0 +1,4 @@
+"""head_share_pct.tpot: the head and sample scopes' part of the device's busy time in the traced window (span_reduce)."""
+from benchmark.span_readers import scope_share_pct
+
+read = scope_share_pct("head", "sample")

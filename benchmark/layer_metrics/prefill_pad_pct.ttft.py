@@ -1,0 +1,4 @@
+"""prefill_pad_pct.ttft: pad positions of each prompt's last chunk over the positions the prefill program ran (prompt + pad), from the engine's counters in the final record."""
+from benchmark.span_readers import final_value
+
+read = final_value("prefill_pad_pct")

@@ -631,7 +631,7 @@ def cmd_trace(args) -> int:
         estimate_job_offsets,
         offsets_for_trace_files,
     )
-    from pytorch_operator_tpu.obs.trace import span_files
+    from pytorch_operator_tpu.obs.trace import span_files, span_self_times
 
     state = _state_dir(args)
     key = _resolve_key(args)
@@ -680,6 +680,19 @@ def cmd_trace(args) -> int:
             Path(args.out).write_text(json.dumps(doc) + "\n")
             print(f"\nwrote {args.out}")
         return 0
+    # Where the time went, layer by layer: a span's self time is its
+    # duration less its children's (the spans naming it as `parent`).
+    rows = sorted(
+        span_self_times(doc["traceEvents"]).items(),
+        key=lambda kv: -kv[1]["self_ms"],
+    )
+    print("self time by span (ms): name count total self", file=sys.stderr)
+    for name, row in rows[:12]:
+        print(
+            f"  {name:28s} {row['count']:7d} {row['total_ms']:12.3f} "
+            f"{row['self_ms']:12.3f}",
+            file=sys.stderr,
+        )
     if args.out:
         Path(args.out).write_text(json.dumps(doc) + "\n")
         print(

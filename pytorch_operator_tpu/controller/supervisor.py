@@ -717,6 +717,7 @@ class Supervisor:
                 self._maybe_preempt(jobs, now)
         finally:
             queue_usage = self.reconciler.end_pass()
+            obs.flush()  # this pass's spans, for a live `tpujob trace`
         if fast_skips:
             self.metrics.steady_fast_skips.inc(fast_skips)
         self._update_gauges(jobs, queue_usage)

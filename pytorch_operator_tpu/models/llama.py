@@ -482,7 +482,8 @@ class Attention(nn.Module):
             if kv8:
                 from ..ops.quantize import quantize
 
-                kq, vq = quantize(k_in, axis=-1), quantize(v_in, axis=-1)
+                with jax.named_scope("kv_quantize"):
+                    kq, vq = quantize(k_in, axis=-1), quantize(v_in, axis=-1)
                 ck.value = write(ck.value, kq.q)
                 ks.value = write(ks.value, kq.scale)
                 cv.value = write(cv.value, vq.q)
@@ -531,7 +532,8 @@ class Attention(nn.Module):
             # TINY score/prob tensors after the dots. A fused
             # convert+scale on the slab defeats operand fusion and
             # materializes a full-precision copy per layer per step.
-            kc, vc = ck.value.astype(cfg.dtype), cv.value.astype(cfg.dtype)
+            with jax.named_scope("kv_dequantize"):
+                kc, vc = ck.value.astype(cfg.dtype), cv.value.astype(cfg.dtype)
         else:
             kc, vc = ck.value, cv.value
         scores = jnp.einsum(
@@ -540,7 +542,8 @@ class Attention(nn.Module):
         if kv8:
             # scores[b,k,g,s,t] · key_scale[b,k,t]: the K dequant, moved
             # past the dot (linear in K).
-            scores = scores * ks.value.squeeze(-1)[:, :, None, None, :]
+            with jax.named_scope("kv_dequantize"):
+                scores = scores * ks.value.squeeze(-1)[:, :, None, None, :]
         col = jnp.arange(L)[None, None, :]      # cache position [1,1,L]
         row = positions[:, :, None]             # query position [B,S,1]
         # Per-(row, token) validity: col <= row — the uniform generate
@@ -553,9 +556,10 @@ class Attention(nn.Module):
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         if kv8:
             # The V dequant, folded into probs (linear in V).
-            probs = (
-                probs * vs.value.squeeze(-1)[:, :, None, None, :]
-            ).astype(cfg.dtype)
+            with jax.named_scope("kv_dequantize"):
+                probs = (
+                    probs * vs.value.squeeze(-1)[:, :, None, None, :]
+                ).astype(cfg.dtype)
         return jnp.einsum("bkgst,bktd->bskgd", probs, vc)
 
 
@@ -957,14 +961,15 @@ def decode_forward(
     p = nn.meta.unbox(params)
 
     table = p["embed"]["embedding"]
-    if isinstance(table, QuantizedTensor):
-        # Gather rows first, dequantize the gathered rows (per-row
-        # scales) — never the whole table.
-        x = (
-            table.q[tokens].astype(jnp.float32) * table.scale[tokens]
-        ).astype(cfg.dtype)
-    else:
-        x = table.astype(cfg.dtype)[tokens]
+    with jax.named_scope("embed"):
+        if isinstance(table, QuantizedTensor):
+            # Gather rows first, dequantize the gathered rows (per-row
+            # scales) — never the whole table.
+            x = (
+                table.q[tokens].astype(jnp.float32) * table.scale[tokens]
+            ).astype(cfg.dtype)
+        else:
+            x = table.astype(cfg.dtype)[tokens]
 
     block = Block(cfg, model.mesh)
     new_cache = {}
@@ -986,8 +991,10 @@ def decode_forward(
     )
     if return_hidden:
         return x, new_cache
-    w = Llama.head_kernel(p)
-    return x.astype(jnp.float32) @ w.astype(jnp.float32), new_cache
+    with jax.named_scope("head"):
+        w = Llama.head_kernel(p)
+        logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
+    return logits, new_cache
 
 
 def forward_pp(

@@ -1,39 +1,57 @@
 """Span recording to per-process JSONL ring files + Chrome-trace merge.
 
-Write side: :class:`SpanRecorder` appends one JSON object per span —
-``{"name", "cat", "ph": "X", "ts", "dur", "pid", "tid", "args"}`` with
-``ts``/``dur`` in microseconds (the Chrome trace event format, so the
-merged output loads in Perfetto / ``chrome://tracing`` unmodified) — to
-``$TPUJOB_TRACE_DIR/<proc>-<pid>.trace.jsonl``. The file is a ring:
-past ``max_bytes`` it rotates once (``.1`` generation kept, older
-dropped), so a week-long daemon cannot fill the disk with spans.
+Write side: :class:`SpanRecorder` keeps one tuple per span in a bounded
+in-memory buffer and encodes + writes them in :meth:`~SpanRecorder.flush`
+(also ``close()``, ``atexit`` and when the buffer fills) — never inside a
+span's exit, which sits on the hot path. A record is
+``{"name", "cat", "ph": "X", "ts", "dur", "pid", "tid", "id", "parent",
+"args"}`` with ``ts``/``dur`` in microseconds (the Chrome trace event
+format, so the merged output loads in Perfetto / ``chrome://tracing``
+unmodified); ``id`` numbers the span within its process and ``parent``
+is the ``id`` of the span open around it on the same thread, so a
+layer's self time is its span minus its children. Files are
+``$TPUJOB_TRACE_DIR/<proc>-<pid>.trace.jsonl``, each a ring: past
+``max_bytes`` it rotates once (``.1`` generation kept, older dropped),
+so a week-long daemon cannot fill the disk with spans.
 
 Enablement is the ``TPUJOB_TRACE_DIR`` env knob, injected per replica
 by runtime/env.py and read once per process: with it unset,
 :func:`tracer` caches None and :func:`span` returns a shared
-nullcontext — no I/O, no allocation, one attribute check. The
-``bench_smoke`` lane pins that a tracing-disabled step loop emits ZERO
-span records.
+nullcontext — no I/O, no allocation. The ``bench_smoke`` lane pins that
+a tracing-disabled step loop emits ZERO span records.
 
-Timestamps are ``time.time()`` (wall clock — all replicas of a local
-world share it, and it is the same clock the progress heartbeats carry,
-so a future multi-host merger can align skewed hosts by matching each
+One clock with the device: :func:`span` also enters a
+``jax.profiler.TraceAnnotation`` of the same name and arguments while a
+``jax.profiler`` session is recording (a workload's ``--profile-dir``,
+the benchmark's ``--trace 1``), whatever ``TPUJOB_TRACE_DIR`` says — the
+profiler then places the program's spans and the device's operations on
+one axis. This module never imports JAX: it looks for it in
+``sys.modules``, so the supervisor and the CLI stay off the chip.
+
+File timestamps are ``time.time()`` (wall clock — all replicas of a
+local world share it, and it is the same clock the progress heartbeats
+carry, so the multi-host merger aligns skewed hosts by matching each
 replica's heartbeat ``ts`` against the supervisor's fold time). Each
 file opens with a ``clock_sync`` metadata record carrying both the wall
 clock and ``perf_counter`` so sub-ms skew is reconstructable.
 
 Read side: :func:`load_span_file` skips torn/foreign lines (a
-SIGKILLed writer tears its last line — the ring-file tests pin that the
-merger survives it); :func:`merge_trace_files` folds many span files
-into one ``{"traceEvents": [...]}`` document.
+SIGKILLed writer tears its last line and loses its buffered tail — the
+ring-file tests pin that the merger survives it);
+:func:`merge_trace_files` folds many span files into one
+``{"traceEvents": [...]}`` document; :func:`span_self_times` reads
+``id``/``parent`` for each span name's self time (``tpujob trace``
+prints it).
 """
 
 from __future__ import annotations
 
 import atexit
 import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from pathlib import Path
@@ -48,11 +66,12 @@ ENV_VAR = "TPUJOB_TRACE_DIR"
 DEFAULT_MAX_BYTES = 8 << 20
 RING_BYTES_ENV = "TPUJOB_TRACE_RING_BYTES"
 
-# Flush cadence: buffered records are cheap to lose only if a crash
-# tears them anyway; every FLUSH_EVERY records the buffer hits disk so
-# a live `tpujob trace` sees near-current spans. Overridable via
-# TPUJOB_TRACE_FLUSH_EVERY (spec.observability.trace_flush_every).
-FLUSH_EVERY = 32
+# Buffer bound: spans wait in memory, un-encoded, until flush() (the
+# serve loop's report, the trainer's heartbeat, the supervisor's pass)
+# or until this many have gathered, whichever is first; a crash loses
+# at most that tail. Overridable via TPUJOB_TRACE_FLUSH_EVERY
+# (spec.observability.trace_flush_every).
+FLUSH_EVERY = 256
 FLUSH_EVERY_ENV = "TPUJOB_TRACE_FLUSH_EVERY"
 
 
@@ -124,14 +143,33 @@ def reset_tracer() -> None:
         _TRACER, _RESOLVED = None, False
 
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once this process has JAX
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` if this process has imported JAX
+    by itself (looked up, never imported: the supervisor and the CLI use
+    this module and must not load JAX or touch the chip)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        _ANNOTATION = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    return _ANNOTATION
+
+
 def span(name: str, cat: str = "span", **args):
     """Context manager recording one complete span — THE call sites
-    sprinkle through the stack. Disabled: returns a shared nullcontext
-    (no allocation)."""
+    sprinkle through the stack. Goes to the process recorder when
+    ``TPUJOB_TRACE_DIR`` is set and, as a ``TraceAnnotation`` of the same
+    name and arguments, into a ``jax.profiler`` session when one is
+    recording. Neither: returns a shared nullcontext (no allocation)."""
     rec = tracer()
+    ann = _annotation()
+    if ann is not None and not ann.is_enabled():
+        ann = None
     if rec is None:
-        return _NULL
-    return rec.span(name, cat, **args)
+        return _NULL if ann is None else ann(name, **args)
+    return _Span(rec, name, cat, args, None if ann is None else ann(name, **args))
 
 
 def instant(name: str, cat: str = "span", **args) -> None:
@@ -139,6 +177,14 @@ def instant(name: str, cat: str = "span", **args) -> None:
     rec = tracer()
     if rec is not None:
         rec.emit(name, cat, time.time(), 0.0, **args)
+
+
+def flush() -> None:
+    """Write the process recorder's buffered spans out — for callers
+    that are paying a write anyway (a periodic report, a heartbeat)."""
+    rec = tracer()
+    if rec is not None:
+        rec.flush()
 
 
 def records_emitted() -> int:
@@ -170,14 +216,51 @@ def serve_span(name: str, ts: float, dur_s: float, **args) -> None:
         rec.emit(name, SERVE_CAT, ts, dur_s, **args)
 
 
-class SpanRecorder:
-    """Appends span records to one per-process JSONL ring file.
+# The ids of the spans open on this thread, innermost last.
+_OPEN = threading.local()
 
-    Lock-cheap by construction: the JSON line is formatted OUTSIDE the
-    lock; inside it there is an append + a size check, with a real
-    ``flush()`` only every :data:`FLUSH_EVERY` records (plus close).
-    A crash can therefore tear the buffered tail — the merge side
-    (:func:`load_span_file`) skips torn lines by contract.
+
+class _Span:
+    """One open span of :func:`span`: its id goes on the thread's stack
+    so that spans opened inside it name it as their parent."""
+
+    __slots__ = ("rec", "name", "cat", "args", "ann", "sid", "parent", "t_wall", "t0")
+
+    def __init__(self, rec, name, cat, args, ann=None):
+        self.rec, self.name, self.cat, self.args, self.ann = rec, name, cat, args, ann
+
+    def __enter__(self):
+        stack = _OPEN.__dict__.setdefault("ids", [])
+        self.parent = stack[-1] if stack else None
+        self.sid = next(self.rec._ids)
+        stack.append(self.sid)
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.t_wall = time.time()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        _OPEN.ids.pop()
+        self.rec._append(self.name, self.cat, self.t_wall, dur, self.sid, self.parent, self.args)
+        return False
+
+
+class SpanRecorder:
+    """Buffers span records and appends them to one per-process JSONL
+    ring file.
+
+    ``emit`` (a span's exit) appends one tuple to a list under the lock
+    and nothing else; the JSON encoding and the write happen in
+    ``flush()`` — which the callers that already pay a write call (the
+    serve loop's report, the trainer's heartbeat), as do ``close()`` and
+    ``atexit`` — or when ``flush_every`` records have gathered. A crash
+    therefore loses the buffered tail and can tear the last written line
+    — the merge side (:func:`load_span_file`) skips torn lines by
+    contract.
     """
 
     def __init__(
@@ -197,23 +280,27 @@ class SpanRecorder:
         self.records = 0
         self._lock = threading.Lock()
         self._f = open(self.path, "ab")
-        self._since_flush = 0
+        self._buf: list = []
+        self._ids = itertools.count(1)
         self._write_header()
-        # Normal process exit flushes the buffered tail; a SIGKILL tears
+        # Normal process exit writes the buffered tail; a SIGKILL loses
         # it, which the merge side tolerates by contract.
         atexit.register(self.close)
 
+    def _process_meta(self) -> dict:
+        return {
+            "ph": "M",
+            "name": "process_name",
+            "pid": self.pid,
+            "tid": 0,
+            "args": {"name": self.process_name},
+        }
+
     def _write_header(self) -> None:
         # Metadata the merger turns into Perfetto process names, plus
-        # the clock-sync pair for (future) cross-host alignment.
+        # the clock-sync pair for cross-host alignment.
         meta = [
-            {
-                "ph": "M",
-                "name": "process_name",
-                "pid": self.pid,
-                "tid": 0,
-                "args": {"name": self.process_name},
-            },
+            self._process_meta(),
             {
                 "ph": "M",
                 "name": "clock_sync",
@@ -234,32 +321,44 @@ class SpanRecorder:
     def emit(
         self, name: str, cat: str, ts: float, dur_s: float, **args
     ) -> None:
-        """Record one complete span; ``ts`` is wall-clock seconds of the
-        span START, ``dur_s`` its duration."""
+        """Record one complete span with explicit endpoints; ``ts`` is
+        wall-clock seconds of the span START, ``dur_s`` its duration."""
+        self._append(name, cat, ts, dur_s, next(self._ids), None, args)
+
+    def _append(self, name, cat, ts, dur_s, sid, parent, args) -> None:
         global _RECORDS
-        rec = {
-            "name": name,
-            "cat": cat,
-            "ph": "X",
-            "ts": round(ts * 1e6, 1),
-            "dur": round(dur_s * 1e6, 1),
-            "pid": self.pid,
-            "tid": threading.get_ident() & 0x7FFFFFFF,
-        }
-        if args:
-            rec["args"] = args
-        line = json.dumps(rec).encode() + b"\n"
+        item = (name, cat, ts, dur_s, threading.get_ident() & 0x7FFFFFFF, sid, parent, args)
         with self._lock:
             if self._f.closed:
                 return
-            self._maybe_rotate(len(line))
-            self._f.write(line)
+            self._buf.append(item)
             self.records += 1
             _RECORDS += 1
-            self._since_flush += 1
-            if self._since_flush >= self.flush_every:
-                self._f.flush()
-                self._since_flush = 0
+            if len(self._buf) >= self.flush_every:
+                self._drain()
+
+    def _drain(self) -> None:
+        """Encode and write the buffered records, under the held lock."""
+        buf, self._buf = self._buf, []
+        for name, cat, ts, dur_s, tid, sid, parent, args in buf:
+            rec = {
+                "name": name,
+                "cat": cat,
+                "ph": "X",
+                "ts": round(ts * 1e6, 1),
+                "dur": round(dur_s * 1e6, 1),
+                "pid": self.pid,
+                "tid": tid,
+                "id": sid,
+            }
+            if parent is not None:
+                rec["parent"] = parent
+            if args:
+                rec["args"] = args
+            line = json.dumps(rec).encode() + b"\n"
+            self._maybe_rotate(len(line))
+            self._f.write(line)
+        self._f.flush()
 
     def _maybe_rotate(self, incoming: int) -> None:
         """Ring rotation under the held lock: current generation moves
@@ -276,36 +375,20 @@ class SpanRecorder:
             if self._f.closed:
                 self._f = open(os.devnull, "ab")
         # Re-emit the header so the new generation is self-describing.
-        for m in (
-            {
-                "ph": "M",
-                "name": "process_name",
-                "pid": self.pid,
-                "tid": 0,
-                "args": {"name": self.process_name},
-            },
-        ):
-            self._f.write(json.dumps(m).encode() + b"\n")
+        self._f.write(json.dumps(self._process_meta()).encode() + b"\n")
 
-    @contextlib.contextmanager
     def span(self, name: str, cat: str = "span", **args):
-        t_wall = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.emit(name, cat, t_wall, time.perf_counter() - t0, **args)
+        return _Span(self, name, cat, args)
 
     def flush(self) -> None:
         with self._lock:
-            if not self._f.closed:
-                self._f.flush()
-                self._since_flush = 0
+            if not self._f.closed and self._buf:
+                self._drain()
 
     def close(self) -> None:
         with self._lock:
             if not self._f.closed:
-                self._f.flush()
+                self._drain()
                 self._f.close()
 
 
@@ -392,3 +475,25 @@ def merge_trace_files(paths: Iterable, clock_offsets: Optional[Dict] = None) -> 
             )
     events.sort(key=lambda r: r.get("ts", 0))
     return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+
+
+def span_self_times(events: Iterable[dict]) -> Dict[str, dict]:
+    """``{name: {"count", "total_ms", "self_ms"}}`` over the complete
+    spans of ``events``: a span's self time is its duration less that
+    of the spans naming it as ``parent`` (ids count per process, so the
+    key is ``(pid, id)``). Spans without an ``id`` (older files) and
+    the explicit-endpoint hops, which name no parent, count whole."""
+    spans = [e for e in events if e.get("ph") == "X"]
+    children: Dict[tuple, float] = {}
+    for e in spans:
+        if e.get("parent") is not None:
+            key = (e.get("pid"), e["parent"])
+            children[key] = children.get(key, 0.0) + e.get("dur", 0.0)
+    out: Dict[str, dict] = {}
+    for e in spans:
+        row = out.setdefault(e["name"], {"count": 0, "total_ms": 0.0, "self_ms": 0.0})
+        dur = e.get("dur", 0.0)
+        row["count"] += 1
+        row["total_ms"] += dur / 1e3
+        row["self_ms"] += max(0.0, dur - children.get((e.get("pid"), e.get("id")), 0.0)) / 1e3
+    return out

@@ -299,6 +299,7 @@ def _flash_fwd_call(q, k, v, cfg: _FlashCfg):
             pltpu.VMEM((cfg.block_q, 128), jnp.float32),
         ],
         interpret=cfg.interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -325,6 +326,7 @@ def _flash_bwd_call(q, k, v, o, lse, do, cfg: _FlashCfg):
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((cfg.block_q, D), jnp.float32)],
         interpret=cfg.interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     q_spec, kv_spec, row_spec = _specs(cfg, D, kv_from_j=False)
@@ -345,6 +347,7 @@ def _flash_bwd_call(q, k, v, o, lse, do, cfg: _FlashCfg):
             pltpu.VMEM((cfg.block_k, D), jnp.float32),
         ],
         interpret=cfg.interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     # Per-query-head dK/dV → per-KV-head (sum the G group members).

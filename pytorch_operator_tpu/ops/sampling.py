@@ -41,6 +41,7 @@ def make_sampler(
 
     validate_sampling(temperature, top_k, top_p)
 
+    @jax.named_scope("sample")
     def sample(logits, rng):
         if temperature == 0.0:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -3,21 +3,23 @@
 SURVEY.md §5 "Tracing / profiling": the reference has none of its own
 (training-side profiling is user-container business); the rebuild's
 workloads write ``jax.profiler`` traces via ``--profile-dir``. This
-module closes the loop WITHOUT tensorboard: it parses the trace's
-``*.xplane.pb`` directly and prints where device time goes — per-step
-busy/idle split, op-category totals, and the top individual ops.
+module closes the loop WITHOUT tensorboard: it reads the trace's
+``*.xplane.pb`` with ``jax.profiler.ProfileData`` (nothing but JAX) and
+prints where device time goes — per-step busy/idle split, op-category
+totals, and the top individual ops.
 
 Usage::
 
     python -m pytorch_operator_tpu.workloads.llama_train ... --profile-dir /tmp/prof
+    python -m pytorch_operator_tpu.workloads.serve ... --profile-dir /tmp/prof
     python -m pytorch_operator_tpu.profiling /tmp/prof [--top 12] [--json]
 
-The xplane schema is stable across the jax/tf profiler family: planes
-(one per device) → lines (Steps / XLA Ops / ...) → timed events whose
-metadata names the HLO op. Parsing needs the ``xplane_pb2`` proto, which
-ships inside the installed tensorflow (cpu) package; anything missing
-degrades to a clear error, never a crash, since this is a diagnostics
-path.
+The trace is planes (one per device, one for the host's threads) →
+lines (Steps / XLA Ops / a thread) → timed events named by the HLO
+operation, the runtime call or the program's own span (``obs.span``
+mirrors every span into the trace: ``--device host:CPU`` lists them).
+An unreadable trace is a clear error, never a crash: this is a
+diagnostics path.
 """
 
 from __future__ import annotations
@@ -30,25 +32,7 @@ from collections import defaultdict
 from pathlib import Path
 from typing import Optional
 
-_PS = 1e-12
-
-
-def _import_xplane_pb2():
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2  # type: ignore
-
-        return xplane_pb2
-    except ImportError:
-        pass
-    try:  # newer layouts
-        from tsl.profiler.protobuf import xplane_pb2  # type: ignore
-
-        return xplane_pb2
-    except ImportError as e:
-        raise RuntimeError(
-            "no xplane_pb2 proto available (needs the tensorflow package "
-            "that ships in this image) — cannot parse the trace"
-        ) from e
+_NS = 1e-9
 
 
 def find_xplane(profile_dir) -> Path:
@@ -61,13 +45,19 @@ def find_xplane(profile_dir) -> Path:
     return paths[-1]
 
 
+def _display(name: str) -> str:
+    """A device event is named by its HLO text, ``%fusion.12 = bf16[...]
+    fusion(...)``: the operation's own name is what stands before ``=``."""
+    return name.split(" = ")[0].lstrip("%$") or name
+
+
 def _category(display_name: str) -> str:
     """HLO op display names carry a ``kind.N`` suffix — strip the serial
     to get the category (fusion, copy, all-reduce, custom-call, ...)."""
     return re.sub(r"[.\-]?\d+$", "", display_name) or display_name
 
 
-def _aggregate_self_times(line, meta, by_cat, by_op) -> float:
+def _aggregate_self_times(events, by_cat, by_op) -> float:
     """Charge each event its SELF time (duration minus enclosed children)
     into the aggregates; returns the line's total busy seconds.
 
@@ -78,28 +68,27 @@ def _aggregate_self_times(line, meta, by_cat, by_op) -> float:
     the tree.
     """
     busy = 0.0
-    stack: list = []  # [end_ps, metadata_id, start_ps, child_ps]
+    stack: list = []  # [end_ns, name, start_ns, child_ns]
 
-    def pop(ev_start_ps) -> None:
+    def pop(ev_start_ns) -> None:
         nonlocal busy
-        while stack and (ev_start_ps is None or stack[-1][0] <= ev_start_ps):
-            end, mid, start, child = stack.pop()
+        while stack and (ev_start_ns is None or stack[-1][0] <= ev_start_ns):
+            end, name, start, child = stack.pop()
             dur = end - start
             if stack:
                 stack[-1][3] += dur
-            dt = (dur - child) * _PS
+            dt = (dur - child) * _NS
             busy += dt
-            m = meta.get(mid)
-            name = (m.display_name or m.name) if m is not None else f"op{mid}"
+            name = _display(name)
             by_cat[_category(name)] += dt
             by_op[name] += dt
 
     # Outer intervals must be pushed before children that share their
     # start timestamp — longest-first at ties keeps the nesting upright
     # (child-first would charge the child a negative self time).
-    for e in sorted(line.events, key=lambda e: (e.offset_ps, -e.duration_ps)):
-        pop(e.offset_ps)
-        stack.append([e.offset_ps + e.duration_ps, e.metadata_id, e.offset_ps, 0])
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        pop(start)
+        stack.append([start + dur, name, start, 0])
     pop(None)
     return busy
 
@@ -110,22 +99,27 @@ def device_report(profile_dir, device_substr: str = "TPU") -> Optional[dict]:
     Returns None when the trace has no matching device plane (e.g. a
     CPU-only run asked for TPU).
     """
-    xplane_pb2 = _import_xplane_pb2()
-    xs = xplane_pb2.XSpace()
-    xs.ParseFromString(find_xplane(profile_dir).read_bytes())
+    from jax.profiler import ProfileData
 
-    plane = next(
-        (p for p in xs.planes if device_substr in p.name and p.lines), None
-    )
-    if plane is None:
+    data = ProfileData.from_file(str(find_xplane(profile_dir)))
+    lines: dict = {}
+    report: dict = {}
+    for plane in data.planes:
+        if device_substr not in plane.name:
+            continue
+        lines = {
+            line.name: [(e.name, e.start_ns, e.duration_ns) for e in line.events]
+            for line in plane.lines
+        }
+        if lines:
+            report["device"] = plane.name
+            break
+    if not lines:
         return None
 
-    lines = {l.name: l for l in plane.lines}
-    report: dict = {"device": plane.name}
-
     steps = lines.get("Steps")
-    if steps is not None and steps.events:
-        durs = [e.duration_ps * _PS for e in steps.events]
+    if steps:
+        durs = [dur * _NS for _, _, dur in steps]
         report["steps"] = len(durs)
         report["mean_step_s"] = sum(durs) / len(durs)
         report["span_s"] = sum(durs)
@@ -137,16 +131,15 @@ def device_report(profile_dir, device_substr: str = "TPU") -> Optional[dict]:
         op_lines = [lines["XLA Ops"]]
     else:
         op_lines = [
-            l for l in plane.lines
-            if l.events and l.name not in ("Steps", "XLA Modules")
+            events for name, events in lines.items()
+            if events and name not in ("Steps", "XLA Modules")
         ]
-    if any(l.events for l in op_lines):
-        meta = plane.event_metadata
+    if any(op_lines):
         by_cat: dict = defaultdict(float)
         by_op: dict = defaultdict(float)
         busy = 0.0
-        for line in op_lines:
-            busy += _aggregate_self_times(line, meta, by_cat, by_op)
+        for events in op_lines:
+            busy += _aggregate_self_times(events, by_cat, by_op)
         if busy <= 0:
             # All-zero-duration events (truncated capture, instant
             # markers): no meaningful breakdown — report what exists
@@ -216,7 +209,7 @@ def main(argv=None) -> int:
     except (RuntimeError, FileNotFoundError) as e:
         print(f"error: {e}", file=sys.stderr)
         return 1
-    except Exception as e:  # corrupt/truncated trace (protobuf DecodeError)
+    except Exception as e:  # corrupt/truncated trace (the reader's own error)
         print(f"error: unreadable trace: {e!r}", file=sys.stderr)
         return 1
     if report is None:

@@ -64,6 +64,13 @@ def setup_backend(platform: Optional[str] = None) -> str:
         jax.config.update("jax_enable_compilation_cache", False)
     else:
         jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+        # The names on the device's work (``jax.named_scope``, Flax's module
+        # names) reach a profile through the compiled program's metadata.
+        # JAX leaves metadata out of the cache's key by default, so a hit
+        # would hand back a program compiled before a scope was named — or
+        # by another checkout without it — and the trace would read stale
+        # names. With it in the key, a renamed scope compiles again.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return platform
 
 

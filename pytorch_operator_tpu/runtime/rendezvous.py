@@ -443,8 +443,11 @@ def report_progress(
     # ``drop_heartbeat`` injection site: an armed fault plan can
     # suppress heartbeats to trip the supervisor's hung-world detector
     # (controller/reconciler.py). No-op without a plan.
-    from .. import faults
+    from .. import faults, obs
 
+    # The beat is a write already: the spans buffered since the last one
+    # go out with it (obs/trace.py buffers; a kill loses only this tail).
+    obs.flush()
     if faults.heartbeat_dropped():
         return
     fields = {}

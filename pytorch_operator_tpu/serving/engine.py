@@ -15,7 +15,8 @@ TPU-first shape (everything static):
   live stream by the col <= row validity mask). Admission happens at
   block boundaries: ``block`` trades slot-idle time (a finished row
   idles at most block-1 steps) against one host round trip per
-  dispatch. No chip cell has judged the trade on this install yet.
+  dispatch. Two chip cells judge the trade (``BENCHMARK.json``'s chat
+  and longprompt cells); the counters below say which side pays.
 - ONE prefill program: fixed-size chunks through a
   ``prefill_mode="cache"`` model (chunked prefill), last chunk padded
   — the pad tokens write cache slots past the prompt that every later
@@ -29,10 +30,30 @@ TPU-first shape (everything static):
   no live stream ever attends a parked write.
 
 Latency accounting: TTFT per request (submit -> first sampled token,
-measured on the host around the real dispatches); per-token latency
-samples at block granularity (block wall / tokens in block) — what a
-client experiences when tokens arrive a block at a time, and the source
-for the p50/p99 the bench reports.
+measured on the host around the real dispatches) and its three parts
+(claim wait, slot wait, prefill); per-token latency samples at block
+granularity (block wall / tokens in block) — what a client experiences
+when tokens arrive a block at a time, and the source for the p50/p99
+the bench reports.
+
+Measurement lives where the work happens, always on (PERF.md section 3
+lists every name beside the metric that reads it):
+
+- spans through ``obs.span`` (file records under ``TPUJOB_TRACE_DIR``,
+  ``TraceAnnotation`` s on the device trace's clock while a
+  ``jax.profiler`` session records): ``engine.step`` around
+  ``engine.admit`` (with ``engine.prefill_dispatch`` per chunk and
+  ``engine.first_token``, the fence on the logits),
+  ``engine.decode_dispatch``, ``engine.decode_fence``, ``engine.accept``
+  and ``engine.harvest``; the per-request hop spans ``slot_wait`` and
+  ``decode`` from the engine's own timestamps;
+- counters (integers and ``perf_counter`` sums, O(1) per iteration):
+  blocks, occupied rows, row-steps and accepted tokens (slot occupancy,
+  decode yield), prefill chunks and pad tokens, admissions, and one
+  clock that charges every second of the serving thread to a segment
+  (:data:`GAP_SEGMENTS` while the device waits for the host,
+  :data:`FENCE_SEGMENTS` while the host waits for the device,
+  ``dispatch``, ``idle``).
 """
 
 from __future__ import annotations
@@ -44,6 +65,34 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs
+from ..obs.trace import serve_span
+
+# Segments of the serving thread's time (``ServingEngine.host_lap``).
+# From a fence's return to the next dispatch the device has nothing to do
+# and the host is what it waits for; those seconds are the host gap, by
+# what the host did (``admit_prep``: a prompt padded, the arguments moved
+# to the device; ``accept``: tokens taken, the row's state set):
+GAP_SEGMENTS = ("accept", "harvest", "respond", "report", "poll", "submit", "admit_prep")
+# ... while the host blocks on the device's result:
+FENCE_SEGMENTS = ("first_token", "decode_fence")
+# ... ``dispatch`` in the dispatch calls and while it queues a prompt's later
+# chunks behind the first, and ``idle`` while there is no request anywhere
+# (the serve loop's sleep).
+SEGMENTS = GAP_SEGMENTS + FENCE_SEGMENTS + ("dispatch", "idle")
+
+
+def host_key(segment: str) -> str:
+    """The key of a segment's seconds in ``ServingEngine.stats()``."""
+    return f"host_gap_{segment}_s" if segment in GAP_SEGMENTS else f"host_{segment}_s"
+
+
+_COUNTERS = (
+    "decode_blocks", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
+    "prefill_chunks", "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "admitted",
+)
+SPAN_CAT = "engine"
+
 
 @dataclasses.dataclass
 class Request:
@@ -51,6 +100,7 @@ class Request:
     prompt: np.ndarray  # [p] int32 token ids
     max_new_tokens: int
     submit_time: float  # client wall clock (time.time())
+    claim_time: float = 0.0  # stamped by ServingEngine.submit
 
 
 @dataclasses.dataclass
@@ -62,6 +112,10 @@ class RequestResult:
     admit_wait_s: float  # submit -> admission (queueing component)
     tpot_s: Optional[float]  # (finish - first token) / (n - 1)
     finish_time: float
+    # ttft_s in its three parts (they sum to it):
+    claim_wait_s: float = 0.0  # client's submit -> handed to the engine
+    slot_wait_s: float = 0.0  # handed to the engine -> admitted to a slot
+    prefill_s: float = 0.0  # admitted -> first token sampled
 
 
 @dataclasses.dataclass
@@ -162,9 +216,10 @@ class ServingEngine:
                 cache,
                 row,
             )
-            h = jax.lax.dynamic_slice_in_dim(hidden, last_idx, 1, axis=1)
-            w = llama_lib.Llama.head_kernel(params)
-            logits = h[:, 0].astype(jnp.float32) @ w.astype(jnp.float32)
+            with jax.named_scope("head"):
+                h = jax.lax.dynamic_slice_in_dim(hidden, last_idx, 1, axis=1)
+                w = llama_lib.Llama.head_kernel(params)
+                logits = h[:, 0].astype(jnp.float32) @ w.astype(jnp.float32)
             return logits[0], cache  # [V]
 
         @functools.partial(jax.jit, donate_argnums=(1,))
@@ -214,8 +269,23 @@ class ServingEngine:
         # Latency/throughput accounting.
         self.completed: list[RequestResult] = []
         self._tpot_samples: list[float] = []
-        self._decode_tokens = 0
         self._decode_wall = 0.0
+        self._n = dict.fromkeys(_COUNTERS, 0)
+        self._host_s = dict.fromkeys(SEGMENTS, 0.0)
+        self._mark = time.perf_counter()
+
+    # ---- the serving thread's clock ----
+
+    def host_lap(self, segment: str) -> None:
+        """Charge the serving thread's time since the previous lap to
+        ``segment`` (one of :data:`SEGMENTS`). The engine laps its own
+        phases; the serve loop calls this after each of its own
+        (``poll``, ``submit``, ``respond``, ``report``, and ``idle`` for
+        a sleep or a poll that found an idle engine nothing), so there
+        is one clock and every second goes to one segment."""
+        now = time.perf_counter()
+        self._host_s[segment] += now - self._mark
+        self._mark = now
 
     # ---- admission ----
 
@@ -240,6 +310,9 @@ class ServingEngine:
                 f"{request.max_new_tokens} exceeds the cache budget "
                 f"(max_decode_len {L}, 1 slot reserved)"
             )
+        if not self.busy:
+            self.host_lap("idle")  # work arrives: the idle stretch ends here
+        request.claim_time = time.time()
         self._queue.append(request)
 
     def _free_slots(self) -> list[int]:
@@ -249,10 +322,14 @@ class ServingEngine:
         """Sample the request's first token from the prefill's [V]
         logits: greedy on the host, else the one-dispatch compiled
         sampler (same T/top-k/top-p semantics as the decode blocks)."""
-        if self._temperature == 0.0:
-            return int(np.argmax(np.asarray(logits)))
-        tok, self._first_key = self._first_token(logits, self._first_key)
-        return int(tok)
+        with obs.span("engine.first_token", SPAN_CAT):
+            if self._temperature == 0.0:
+                tok = np.argmax(np.asarray(logits))
+            else:
+                tok, self._first_key = self._first_token(logits, self._first_key)
+            tok = int(tok)  # the fence: every chunk of the prompt has run
+        self.host_lap("first_token")
+        return tok
 
     def _admit(self, request: Request, slot: int) -> None:
         jnp = self._jnp
@@ -264,16 +341,29 @@ class ServingEngine:
         buf[:p] = prompt
         logits = None
         last_valid = (p - 1) % self.chunk  # index within the FINAL chunk
+        self._n["admitted"] += 1
+        self._n["prefill_chunks"] += padded // self.chunk
+        self._n["prefill_tokens"] += p
+        self._n["prefill_pad_tokens"] += padded - p
         for start in range(0, padded, self.chunk):
             final = start + self.chunk >= padded
-            chunk_toks = jnp.asarray(buf[None, start : start + self.chunk])
-            logits, self._cache = self._prefill_chunk(
-                self._params, self._cache, jnp.int32(slot), chunk_toks,
-                jnp.int32(start),
-                # Only the final chunk's last VALID position (not the
-                # padded tail) feeds the first token.
-                jnp.int32(last_valid if final else 0),
-            )
+            with obs.span("engine.prefill_dispatch", SPAN_CAT, start=start):
+                args = (
+                    jnp.int32(slot),
+                    jnp.asarray(buf[None, start : start + self.chunk]),
+                    jnp.int32(start),
+                    # Only the final chunk's last VALID position (not the
+                    # padded tail) feeds the first token.
+                    jnp.int32(last_valid if final else 0),
+                )
+                if start == 0:
+                    # Up to this dispatch the device waited for the host;
+                    # from here the host queues chunks behind chunks.
+                    self.host_lap("admit_prep")
+                logits, self._cache = self._prefill_chunk(
+                    self._params, self._cache, *args
+                )
+        self.host_lap("dispatch")
         first = self._sample_first(logits)
         first_time = time.time()
         st = _Slot(
@@ -292,6 +382,7 @@ class ServingEngine:
         # first scan step does.
         self._tok = self._tok.at[slot].set(first)
         self._pos = self._pos.at[slot].set(st.pos)
+        self.host_lap("accept")
 
     def _accept_token(self, st: _Slot, slot: int, token: int) -> None:
         st.tokens.append(int(token))
@@ -314,12 +405,25 @@ class ServingEngine:
         # this iteration raise InjectedFault — the serve loop's recovery
         # (abort_in_flight + error responses) is what chaos tests pin.
         faults.engine_step_check()
+        with obs.span("engine.step", SPAN_CAT):
+            return self._step()
+
+    def _step(self) -> list[RequestResult]:
         jnp = self._jnp
         # 1. Admission.
+        admitted = 0
         for slot in self._free_slots():
             if not self._queue:
                 break
-            self._admit(self._queue.popleft(), slot)
+            request = self._queue.popleft()
+            p = len(request.prompt)
+            with obs.span(
+                "engine.admit", SPAN_CAT, rid=request.id, slot=slot,
+                prompt_len=p, chunks=-(-p // self.chunk),
+            ):
+                self._admit(request, slot)
+            admitted += 1
+        self._n["admit_rounds"] += bool(admitted)
         # Harvest single-token requests that finished inside prefill.
         finished = self._harvest()
         active_rows = [
@@ -331,60 +435,87 @@ class ServingEngine:
         active = np.zeros((self.slots,), bool)
         active[active_rows] = True
         t0 = time.time()
-        toks, self._cache, self._tok, self._pos, self._rng = (
-            self._decode_block(
-                self._params, self._cache, self._tok, self._pos,
-                jnp.asarray(active), self._rng,
+        with obs.span("engine.decode_dispatch", SPAN_CAT, rows=len(active_rows)):
+            active = jnp.asarray(active)
+            self.host_lap("admit_prep")  # up to this dispatch the device waited
+            toks, self._cache, self._tok, self._pos, self._rng = (
+                self._decode_block(
+                    self._params, self._cache, self._tok, self._pos,
+                    active, self._rng,
+                )
             )
-        )
-        toks = np.asarray(toks)  # device fence: the block is the unit
+        self.host_lap("dispatch")
+        with obs.span("engine.decode_fence", SPAN_CAT):
+            toks = np.asarray(toks)  # device fence: the block is the unit
+        self.host_lap("decode_fence")
         wall = time.time() - t0
         live = 0
-        for i in active_rows:
-            st = self._slots[i]
-            accepted = 0
-            for t in toks[i]:
-                if st.done:
-                    break
-                self._accept_token(st, i, t)
-                accepted += 1
-            if accepted:
-                # Per-REQUEST experienced latency: every occupied slot
-                # waited the whole block wall for its `accepted` tokens
-                # (concurrent slots don't divide a request's wait —
-                # aggregating wall/total_tokens would understate tpot by
-                # the concurrency factor).
-                self._tpot_samples.append(wall / accepted)
-            live += accepted
+        with obs.span("engine.accept", SPAN_CAT):
+            for i in active_rows:
+                st = self._slots[i]
+                accepted = 0
+                for t in toks[i]:
+                    if st.done:
+                        break
+                    self._accept_token(st, i, t)
+                    accepted += 1
+                if accepted:
+                    # Per-REQUEST experienced latency: every occupied slot
+                    # waited the whole block wall for its `accepted` tokens
+                    # (concurrent slots don't divide a request's wait —
+                    # aggregating wall/total_tokens would understate tpot by
+                    # the concurrency factor).
+                    self._tpot_samples.append(wall / accepted)
+                live += accepted
+        self._n["decode_blocks"] += 1
+        self._n["slot_blocks_occupied"] += len(active_rows)
+        self._n["decode_row_steps"] += len(active_rows) * self.block
         if live:
-            self._decode_tokens += live
+            self._n["decode_tokens"] += live
             self._decode_wall += wall
+        self.host_lap("accept")
         return finished + self._harvest()
 
     def _harvest(self) -> list[RequestResult]:
         out = []
-        for i, st in enumerate(self._slots):
-            if st is None or not st.done:
-                continue
-            now = time.time()
-            n = len(st.tokens)
-            out.append(
-                RequestResult(
-                    id=st.request.id,
-                    prompt_len=int(np.asarray(st.request.prompt).shape[0]),
-                    tokens=st.tokens,
-                    ttft_s=st.first_token_time - st.request.submit_time,
-                    admit_wait_s=st.admit_time - st.request.submit_time,
-                    tpot_s=(
-                        (now - st.first_token_time) / (n - 1)
-                        if n > 1
-                        else None
-                    ),
-                    finish_time=now,
+        with obs.span("engine.harvest", SPAN_CAT):
+            for i, st in enumerate(self._slots):
+                if st is None or not st.done:
+                    continue
+                now = time.time()
+                n = len(st.tokens)
+                req = st.request
+                out.append(
+                    RequestResult(
+                        id=req.id,
+                        prompt_len=int(np.asarray(req.prompt).shape[0]),
+                        tokens=st.tokens,
+                        ttft_s=st.first_token_time - req.submit_time,
+                        admit_wait_s=st.admit_time - req.submit_time,
+                        tpot_s=(
+                            (now - st.first_token_time) / (n - 1)
+                            if n > 1
+                            else None
+                        ),
+                        finish_time=now,
+                        claim_wait_s=req.claim_time - req.submit_time,
+                        slot_wait_s=st.admit_time - req.claim_time,
+                        prefill_s=st.first_token_time - st.admit_time,
+                    )
                 )
-            )
-            self._slots[i] = None  # the slot is free for the next admit
+                # The request's hops, for `tpujob why` and `tpujob trace
+                # --request` (file spans only: explicit endpoints).
+                serve_span(
+                    "slot_wait", req.claim_time,
+                    st.admit_time - req.claim_time, rid=req.id,
+                )
+                serve_span(
+                    "decode", st.admit_time, now - st.admit_time,
+                    rid=req.id, tokens=n,
+                )
+                self._slots[i] = None  # the slot is free for the next admit
         self.completed.extend(out)
+        self.host_lap("harvest")
         return out
 
     def abort_in_flight(self) -> list[str]:
@@ -435,8 +566,10 @@ class ServingEngine:
         state, not XLA compilation)."""
         self.completed.clear()
         self._tpot_samples.clear()
-        self._decode_tokens = 0
         self._decode_wall = 0.0
+        self._n = dict.fromkeys(_COUNTERS, 0)
+        self._host_s = dict.fromkeys(SEGMENTS, 0.0)
+        self._mark = time.perf_counter()
 
     def stats(self) -> dict:
         """Aggregate latency/throughput record (the bench block)."""
@@ -450,11 +583,16 @@ class ServingEngine:
             i = min(len(xs) - 1, int(round(q * (len(xs) - 1))))
             return round(1000 * xs[i], 3)
 
+        n, host = self._n, self._host_s
+
+        def share(part, whole):
+            return round(100.0 * part / whole, 3) if whole else None
+
         return {
             "requests": len(done),
             "generated_tokens": sum(len(r.tokens) for r in done),
             "decode_tokens_per_sec": round(
-                self._decode_tokens / self._decode_wall, 1
+                n["decode_tokens"] / self._decode_wall, 1
             )
             if self._decode_wall
             else None,
@@ -465,4 +603,23 @@ class ServingEngine:
             "slots": self.slots,
             "block": self.block,
             "chunk": self.chunk,
+            **n,
+            # Rows that held a request, of the rows the blocks ran; and
+            # tokens accepted, of the steps those rows ran.
+            "slot_occupancy_pct": share(
+                n["slot_blocks_occupied"], n["decode_blocks"] * self.slots
+            ),
+            "decode_yield_pct": share(n["decode_tokens"], n["decode_row_steps"]),
+            # Pad positions of each prompt's last chunk, of the positions
+            # the prefill program ran.
+            "prefill_pad_pct": share(
+                n["prefill_pad_tokens"],
+                n["prefill_tokens"] + n["prefill_pad_tokens"],
+            ),
+            # The serving thread's seconds by segment; the host gap is
+            # the time the device waited for the host with work queued.
+            # ``host_gap_<segment>_s`` are its parts, so a reader finds
+            # them by the key and keeps no list of its own.
+            "host_gap_s": sum(host[k] for k in GAP_SEGMENTS),
+            **{host_key(k): v for k, v in host.items()},
         }

@@ -373,9 +373,16 @@ class Spool:
                 continue  # lost a race with another claimer
             self.io.renames += 1
             try:
-                out.append(json.loads(dst.read_text()))
+                rec = json.loads(dst.read_text())
                 self.io.reads += 1
-            except (OSError, json.JSONDecodeError):
+                if not isinstance(rec, dict):
+                    raise ValueError("not a request record")
+                # The file's name is the id the response is written and
+                # the claim released under: a hand-dropped record that
+                # states none is answered there, not under a made-up id.
+                rec.setdefault("id", path.stem)
+                out.append(rec)
+            except (OSError, ValueError):
                 # Torn request (a foreign client wrote requests/<id>.json
                 # without the tmp+rename discipline and died mid-write).
                 # Leaving the claim in place would WEDGE admission: the

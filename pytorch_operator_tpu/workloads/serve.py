@@ -25,9 +25,10 @@ import json
 import sys
 import time
 
-from .. import faults
-from ..obs.trace import serve_span, tracer as _span_tracer
+from .. import faults, obs
+from ..obs.trace import serve_span
 from ..runtime import rendezvous
+from .trainer import maybe_profile
 
 
 def run(
@@ -52,16 +53,24 @@ def run(
     report_every: float = 5.0,
     transport: str = "spool",
     seed: int = 0,
+    profile_dir: str | None = None,
     log=print,
 ) -> dict:
     """The serving loop. ``max_requests``/``idle_timeout`` bound the run
     for tests and benches; both 0 means serve forever (the production
-    daemon shape — the supervisor owns the lifecycle)."""
+    daemon shape — the supervisor owns the lifecycle).
+
+    The loop's phases are spans (``serve.poll``, ``serve.submit``,
+    ``serve.respond``, ``serve.idle`` around the engine's own) and laps
+    of the engine's one clock (``ServingEngine.host_lap``); with
+    ``profile_dir`` the whole loop runs under a ``jax.profiler`` trace,
+    where those spans land beside the device's operations."""
     import jax
     import numpy as np
 
     from ..models import llama as llama_lib
     from ..serving import Request, ServingEngine
+    from ..serving.engine import SPAN_CAT
     from ..serving.shmring import EngineTransport
     from .generate import load_params
     from .llama_train import CONFIGS
@@ -102,11 +111,6 @@ def run(
     rejected = 0
     last_activity = time.time()
     last_report = 0.0
-    synth_rng = np.random.default_rng(seed)
-    # Engine-claim wall times by rid, for the slot_wait/decode hop
-    # spans (populated only while tracing is enabled — with it off the
-    # dict stays empty and the serve path allocates nothing extra).
-    claims: dict = {}
 
     def to_request(rec: dict) -> Request:
         if rec.get("prompt") is not None:
@@ -132,137 +136,150 @@ def run(
 
     def finish(res) -> None:
         nonlocal served, last_activity
-        traced = _span_tracer() is not None
-        t_resp = time.time() if traced else 0.0
-        spool.respond(
-            res.id,
-            {
-                "id": res.id,
-                "tokens": res.tokens,
-                "prompt_len": res.prompt_len,
-                "ttft_ms": round(1000 * res.ttft_s, 3),
-                "admit_wait_ms": round(1000 * res.admit_wait_s, 3),
-                "tpot_ms": (
-                    round(1000 * res.tpot_s, 3)
-                    if res.tpot_s is not None
-                    else None
-                ),
-            },
-        )
-        if traced:
-            info = claims.pop(res.id, None)
-            if info is not None:
-                claim_ts, submit = info
-                # The engine's own latency record anchors the hops:
-                # admit_wait_s / ttft_s are measured from the client's
-                # submit_time, which is wall clock — same axis.
-                admit_t = submit + res.admit_wait_s
-                serve_span(
-                    "slot_wait", claim_ts,
-                    max(0.0, admit_t - claim_ts), rid=res.id,
-                )
-                serve_span(
-                    "decode", admit_t,
-                    max(0.0, res.finish_time - admit_t),
-                    rid=res.id, tokens=len(res.tokens),
-                )
-                serve_span("respond", t_resp, time.time() - t_resp,
-                           rid=res.id)
-        served += 1
+        t_resp = time.time()
+        with obs.span("serve.respond", SPAN_CAT, rid=res.id):
+            spool.respond(
+                res.id,
+                {
+                    "id": res.id,
+                    "tokens": res.tokens,
+                    "prompt_len": res.prompt_len,
+                    "ttft_ms": round(1000 * res.ttft_s, 3),
+                    "admit_wait_ms": round(1000 * res.admit_wait_s, 3),
+                    # ttft_ms in its three parts: waiting to be claimed
+                    # from the spool, waiting for a slot, being prefilled.
+                    "claim_wait_ms": round(1000 * res.claim_wait_s, 3),
+                    "slot_wait_ms": round(1000 * res.slot_wait_s, 3),
+                    "prefill_ms": round(1000 * res.prefill_s, 3),
+                    "tpot_ms": (
+                        round(1000 * res.tpot_s, 3)
+                        if res.tpot_s is not None
+                        else None
+                    ),
+                },
+            )
         last_activity = time.time()
+        # The request's last hop (slot_wait and decode are the engine's).
+        serve_span("respond", t_resp, last_activity - t_resp, rid=res.id)
+        served += 1
 
-    while True:
-        # Admission feed: claim enough to keep the slots fed one
-        # iteration ahead (ring tier first, then the file spool).
-        polled, _ = spool.poll_requests(2 * slots - engine.queued)
+    def submit_polled(polled) -> None:
+        nonlocal rejected, last_activity
         for rec in polled:
             try:
-                req = to_request(rec)
-                if _span_tracer() is not None:
-                    claims[req.id] = (time.time(), req.submit_time)
-                engine.submit(req)
+                engine.submit(to_request(rec))
                 last_activity = time.time()
             except (ValueError, KeyError, TypeError) as e:
                 rejected += 1
-                claims.pop(rec.get("id"), None)
-                spool.respond(rec.get("id", "unknown"), {"error": str(e)})
-        if engine.busy:
-            try:
-                results = engine.step()
-            except faults.InjectedFault as e:
-                # Failure-path hardening: a faulted iteration must not
-                # strand its in-flight requests (a client would block
-                # its full timeout on a response nothing will write).
-                # Abort the occupied slots and answer each with an
-                # error — exactly-once responses, queued requests
-                # untouched, the engine keeps serving.
-                aborted = engine.abort_in_flight()
-                for rid in aborted:
-                    claims.pop(rid, None)
-                    spool.respond(rid, {"id": rid, "error": f"engine fault: {e}"})
-                rejected += len(aborted)
-                log(
-                    f"[serve] engine step fault ({e}); aborted "
-                    f"{len(aborted)} in-flight request(s) with error "
-                    "responses"
-                )
-                results = []
-            for res in results:
-                finish(res)
-        else:
-            time.sleep(poll_interval)
-        now = time.time()
-        if now - last_report > report_every:
-            last_report = now
-            s = engine.stats()
-            rendezvous.report_metrics(
-                served,
-                serve_requests=served,
-                serve_pending=spool.pending_count(),
-                serve_decode_tokens_per_sec=s["decode_tokens_per_sec"],
-                serve_ttft_ms_p50=s["ttft_ms_p50"],
-                serve_tpot_ms_p50=s["tpot_ms_p50"],
+                rid = rec.get("id")
+                if rid is None:
+                    # Nobody to answer. The spool names a single-file
+                    # request by its file (serving/spool.py), so only a
+                    # foreign batch frame can come without an id.
+                    log(f"[serve] dropped a request with no id: {e}")
+                else:
+                    spool.respond(rid, {"id": rid, "error": str(e)})
+
+    def step_and_respond() -> None:
+        nonlocal rejected
+        try:
+            results = engine.step()
+        except faults.InjectedFault as e:
+            # Failure-path hardening: a faulted iteration must not
+            # strand its in-flight requests (a client would block
+            # its full timeout on a response nothing will write).
+            # Abort the occupied slots and answer each with an
+            # error — exactly-once responses, queued requests
+            # untouched, the engine keeps serving.
+            aborted = engine.abort_in_flight()
+            for rid in aborted:
+                spool.respond(rid, {"id": rid, "error": f"engine fault: {e}"})
+            rejected += len(aborted)
+            log(
+                f"[serve] engine step fault ({e}); aborted "
+                f"{len(aborted)} in-flight request(s) with error "
+                "responses"
             )
-            # Serve-plane load beat: the router's least-loaded dispatch
-            # and the queue_growth/batch_size_collapse detectors read
-            # this replica-side occupancy stream (serving/router.py).
-            rendezvous.report_serve(
-                served,
-                slots=slots,
-                slots_free=engine.slots_free,
-                queued=engine.queued,
-                pending=spool.pending_count(),
-                ttft_ms_p50=s["ttft_ms_p50"],
-                ttft_ms_p99=s["ttft_ms_p99"],
-                tpot_ms_p50=s["tpot_ms_p50"],
-                tpot_ms_p99=s["tpot_ms_p99"],
-                # Decode-block phase for the router's batch-fill
-                # tie-break: a busy engine frees its next slot one
-                # block's worth of per-token time away.
-                block_ms=(
-                    (s["tpot_ms_p50"] or 0.0) * block
-                    if engine.busy
-                    else 0.0
-                ),
-            )
-            # The LIVE operator surface (`tpujob describe` Training
-            # block + per-job gauges) folds only progress records —
-            # report through it like training workloads do, with
-            # served requests as the step counter.
-            rendezvous.report_progress(
-                served,
-                throughput=s["decode_tokens_per_sec"] or 0.0,
-                unit="tok/s",
-            )
-        if max_requests and served >= max_requests and not engine.busy:
-            break
-        if (
-            idle_timeout
-            and not engine.busy
-            and now - last_activity > idle_timeout
-        ):
-            log(f"[serve] idle for {idle_timeout}s, exiting")
-            break
+            results = []
+        for res in results:
+            finish(res)
+        engine.host_lap("respond")
+
+    def report() -> None:
+        s = engine.stats()
+        rendezvous.report_metrics(
+            served,
+            serve_requests=served,
+            serve_pending=spool.pending_count(),
+            serve_decode_tokens_per_sec=s["decode_tokens_per_sec"],
+            serve_ttft_ms_p50=s["ttft_ms_p50"],
+            serve_tpot_ms_p50=s["tpot_ms_p50"],
+        )
+        # Serve-plane load beat: the router's least-loaded dispatch
+        # and the queue_growth/batch_size_collapse detectors read
+        # this replica-side occupancy stream (serving/router.py).
+        rendezvous.report_serve(
+            served,
+            slots=slots,
+            slots_free=engine.slots_free,
+            queued=engine.queued,
+            pending=spool.pending_count(),
+            ttft_ms_p50=s["ttft_ms_p50"],
+            ttft_ms_p99=s["ttft_ms_p99"],
+            tpot_ms_p50=s["tpot_ms_p50"],
+            tpot_ms_p99=s["tpot_ms_p99"],
+            # Decode-block phase for the router's batch-fill
+            # tie-break: a busy engine frees its next slot one
+            # block's worth of per-token time away.
+            block_ms=(
+                (s["tpot_ms_p50"] or 0.0) * block
+                if engine.busy
+                else 0.0
+            ),
+        )
+        # The LIVE operator surface (`tpujob describe` Training
+        # block + per-job gauges) folds only progress records —
+        # report through it like training workloads do, with
+        # served requests as the step counter. (The beat also writes
+        # the buffered spans out: rendezvous.report_progress.)
+        rendezvous.report_progress(
+            served,
+            throughput=s["decode_tokens_per_sec"] or 0.0,
+            unit="tok/s",
+        )
+        engine.host_lap("report")
+
+    with maybe_profile(profile_dir, log):
+        while True:
+            # Admission feed: claim enough to keep the slots fed one
+            # iteration ahead (ring tier first, then the file spool).
+            with obs.span("serve.poll", SPAN_CAT):
+                polled, _ = spool.poll_requests(2 * slots - engine.queued)
+            # A poll that finds an idle engine nothing is part of being idle.
+            engine.host_lap("poll" if polled or engine.busy else "idle")
+            if polled:
+                with obs.span("serve.submit", SPAN_CAT, n=len(polled)):
+                    submit_polled(polled)
+                engine.host_lap("submit")
+            if engine.busy:
+                step_and_respond()
+            else:
+                with obs.span("serve.idle", SPAN_CAT):
+                    time.sleep(poll_interval)
+                engine.host_lap("idle")
+            now = time.time()
+            if now - last_report > report_every:
+                last_report = now
+                report()
+            if max_requests and served >= max_requests and not engine.busy:
+                break
+            if (
+                idle_timeout
+                and not engine.busy
+                and now - last_activity > idle_timeout
+            ):
+                log(f"[serve] idle for {idle_timeout}s, exiting")
+                break
 
     stats = engine.stats()
     stats.update(
@@ -345,6 +362,12 @@ def main(argv=None) -> int:
         "injected TPUJOB_SERVE_TRANSPORT (spec.serving.transport)",
     )
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument(
+        "--profile-dir", default=None,
+        help="write a jax.profiler trace of the serving loop here (the "
+        "loop's and the engine's spans land in it beside the device's "
+        "operations; read it with python -m pytorch_operator_tpu.profiling)",
+    )
     p.add_argument("--json", action="store_true")
     args = p.parse_args(argv)
     if not args.spool:
@@ -373,6 +396,7 @@ def main(argv=None) -> int:
         report_every=args.report_every,
         transport=args.transport,
         seed=args.seed,
+        profile_dir=args.profile_dir,
         log=lambda msg: print(msg, flush=True),
     )
     if args.json and world.process_id == 0:

@@ -424,15 +424,17 @@ def make_lm_loss_fn(model, mesh, microbatches=None, include_aux=True):
             with activation_rules(mesh):
                 hidden, aux = forward(params, tokens, True)
             # Head access goes through the model (it owns its param naming).
-            w = model.head_kernel(params)
-            h = hidden[:, :-1].reshape(-1, hidden.shape[-1])
-            xent = chunked_softmax_xent(h, w, tokens[:, 1:].reshape(-1)).mean()
+            with jax.named_scope("loss"):
+                w = model.head_kernel(params)
+                h = hidden[:, :-1].reshape(-1, hidden.shape[-1])
+                xent = chunked_softmax_xent(h, w, tokens[:, 1:].reshape(-1)).mean()
             return xent + aux_w * aux
         with activation_rules(mesh):
             logits, aux = forward(params, tokens, False)
-        xent = optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :-1], tokens[:, 1:]
-        ).mean()
+        with jax.named_scope("loss"):
+            xent = optax.softmax_cross_entropy_with_integer_labels(
+                logits[:, :-1], tokens[:, 1:]
+            ).mean()
         return xent + aux_w * aux
 
     return loss_fn
@@ -506,10 +508,11 @@ def make_lm_train_step(
             loss, grads = model.pp_value_and_grad(
                 state["params"], tokens, mesh=mesh, microbatches=mb
             )
-            updates, opt_state = tx.update(
-                grads, state["opt_state"], state["params"]
-            )
-            params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(
+                    grads, state["opt_state"], state["params"]
+                )
+                params = optax.apply_updates(state["params"], updates)
             return {"params": params, "opt_state": opt_state}, loss
 
         return train_step_1f1b
@@ -553,8 +556,9 @@ def make_lm_train_step(
                 grad_sum,
                 state["params"],
             )
-        updates, opt_state = tx.update(grads, state["opt_state"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state["opt_state"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
         return {"params": params, "opt_state": opt_state}, loss
 
     return train_step

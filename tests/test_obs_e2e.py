@@ -134,7 +134,10 @@ def test_trace_export_covers_all_layers(tmp_path, capsys):
     assert main(
         ["--state-dir", str(state), "trace", "traced-e2e", "--out", str(out)]
     ) == 0
-    assert "perfetto" in capsys.readouterr().out.lower()
+    said = capsys.readouterr()
+    assert "perfetto" in said.out.lower()
+    # The self time of each layer's spans, from their `parent` links.
+    assert "self time by span (ms)" in said.err and " step " in said.err
     doc = json.loads(out.read_text())
     spans = _validate_chrome_trace(doc)
 

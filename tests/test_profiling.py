@@ -65,3 +65,19 @@ def test_cli_human_and_json(trace_dir):
 def test_missing_dir_errors_cleanly(tmp_path):
     rc = profiling.main([str(tmp_path / "nope")])
     assert rc == 1
+
+
+def test_report_needs_no_tensorflow(trace_dir):
+    """The reader is ``jax.profiler.ProfileData``: the report comes out with
+    TensorFlow made unimportable, and lists the program's own spans."""
+    code = (
+        "import sys; sys.modules['tensorflow'] = None; sys.modules['tsl'] = None\n"
+        "from pytorch_operator_tpu import profiling\n"
+        f"sys.exit(profiling.main([{str(trace_dir)!r}, '--device', 'host:CPU', '--json', '--top', '400']))\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True)
+    assert out.returncode == 0, out.stderr
+    import json
+
+    ops = {r["op"] for r in json.loads(out.stdout)["top_ops"]}
+    assert "step" in ops  # obs.span("step") of the trainer, mirrored into the trace
