@@ -555,8 +555,8 @@ def run(argv=None) -> dict:
                 eng_cfg, config="1b", quantize="int8",
                 log=lambda m: log(f"[bench] {m}"), tag="bench-serve",
             )
-            # block=64 is the value an earlier round's sweep on this
-            # stream settled on; a chip cell has yet to judge it.
+            # block=64 is the most steps one dispatch may run; the
+            # engine sizes each dispatch itself (serving/engine.py).
             eng = ServingEngine(
                 eng_cfg, eparams, slots=8, chunk=128, block=64,
             )

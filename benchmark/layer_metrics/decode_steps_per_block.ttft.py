@@ -1,0 +1,4 @@
+"""decode_steps_per_block.ttft: decode steps run over the decode dispatches made (the mean length the engine chose for a dispatch; an arrival waits for the current one's end), from the engine's counters in the final record."""
+from benchmark.span_readers import final_value
+
+read = final_value("decode_steps_per_block")

@@ -8,15 +8,20 @@ request at its own depth, refilled the moment its occupant finishes.
 
 TPU-first shape (everything static):
 
-- ONE decode program: ``decode_block`` scans ``block`` single-token
-  steps over the full [slots] batch through a ``decode_per_row=True``
-  model (models/llama.py) — every row at its own position, finished/
-  empty rows parked (they re-write their own slot, masked from every
-  live stream by the col <= row validity mask). Admission happens at
-  block boundaries: ``block`` trades slot-idle time (a finished row
-  idles at most block-1 steps) against one host round trip per
-  dispatch. Two chip cells judge the trade (``BENCHMARK.json``'s chat
-  and longprompt cells); the counters below say which side pays.
+- ONE decode program: ``decode_block`` loops ``steps`` single-token
+  steps (a traced count, so every length is the same compiled program)
+  over the full [slots] batch through a ``decode_per_row=True`` model
+  (models/llama.py) — every row at its own position, finished/empty
+  rows parked (they re-write their own slot, masked from every live
+  stream by the col <= row validity mask). Everything the engine
+  decides (admit, harvest, and the serve loop's poll and responses)
+  happens between dispatches, so the engine chooses each dispatch's
+  length from what it holds on the host (:func:`decode_steps`): to the
+  step at which the next slot frees when all are taken, a quantum
+  (:data:`QUANTUM`) while a slot is free for an arrival, never past the
+  last row's budget, never more than ``block``, the most steps one
+  dispatch may run. The counters below say how often each rule sized a
+  dispatch (``BENCHMARK.json``'s chat and longprompt cells judge it).
 - ONE prefill program: fixed-size chunks through a
   ``prefill_mode="cache"`` model (chunked prefill), last chunk padded
   — the pad tokens write cache slots past the prompt that every later
@@ -31,10 +36,11 @@ TPU-first shape (everything static):
 
 Latency accounting: TTFT per request (submit -> first sampled token,
 measured on the host around the real dispatches) and its three parts
-(claim wait, slot wait, prefill); per-token latency samples at block
-granularity (block wall / tokens in block) — what a client experiences
-when tokens arrive a block at a time, and the source for the p50/p99
-the bench reports.
+(claim wait, slot wait, prefill); per-token latency samples at dispatch
+granularity (dispatch wall / tokens accepted in it) — what a client
+experiences when tokens arrive a dispatch at a time, and the source for
+the p50/p99 the bench reports. Greedy tokens are a function of the
+model and the prompt, never of where the dispatches were cut.
 
 Measurement lives where the work happens, always on (PERF.md section 3
 lists every name beside the metric that reads it):
@@ -48,9 +54,10 @@ lists every name beside the metric that reads it):
   and ``engine.harvest``; the per-request hop spans ``slot_wait`` and
   ``decode`` from the engine's own timestamps;
 - counters (integers and ``perf_counter`` sums, O(1) per iteration):
-  blocks, occupied rows, row-steps and accepted tokens (slot occupancy,
-  decode yield), prefill chunks and pad tokens, admissions, and one
-  clock that charges every second of the serving thread to a segment
+  blocks (dispatches), their steps and what sized them, occupied rows,
+  row-steps and accepted tokens (slot occupancy, decode yield), prefill
+  chunks and pad tokens, admissions, and one clock that charges every
+  second of the serving thread to a segment
   (:data:`GAP_SEGMENTS` while the device waits for the host,
   :data:`FENCE_SEGMENTS` while the host waits for the device,
   ``dispatch``, ``idle``).
@@ -87,11 +94,50 @@ def host_key(segment: str) -> str:
     return f"host_gap_{segment}_s" if segment in GAP_SEGMENTS else f"host_{segment}_s"
 
 
+# What sized a decode dispatch (:func:`decode_steps`), each a counter
+# ``decode_sized_by_<reason>`` in ``ServingEngine.stats()``.
+SIZED_BY = ("budget", "quantum", "ceiling")
 _COUNTERS = (
-    "decode_blocks", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
+    "decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
+    *(f"decode_sized_by_{reason}" for reason in SIZED_BY),
     "prefill_chunks", "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "admitted",
 )
 SPAN_CAT = "engine"
+
+# Decode steps per dispatch while a slot is free for an arrival, and the
+# fewest a full batch runs to its next finishing row. Chosen from a chip
+# sweep of 8 / 16 / 32 in both serving cells (PERF.md section 6, PR 25):
+# at 7.4 ms a step on the v5e a boundary costs the host 2-3 ms, and 8 gave
+# the shortest time to first token and the most tokens a second at the
+# same time per output token as 16.
+QUANTUM = 8
+
+
+def decode_steps(remaining, free_slots: int, block: int) -> tuple[int, str]:
+    """How many steps the next decode dispatch runs, and which rule sized
+    it (one of :data:`SIZED_BY`). ``remaining`` are the active rows'
+    remaining budgets (each >= 1; an upper bound on the row's life where
+    an EOS token can end it sooner), ``free_slots`` the slots that hold no
+    request — after admission, so a free slot means nothing is queued.
+
+    - a slot free: an arrival could be admitted at the next boundary, so
+      at most a quantum;
+    - all slots taken, whether or not a request is queued: to the
+      shortest budget, the step at which the next slot frees and its
+      answer can go out — but at least a quantum, so rows that finish a
+      step apart do not make one-step dispatches;
+    - never past the longest budget (a step no row can use), never more
+      than ``block``.
+    """
+    shortest, longest = min(remaining), max(remaining)
+    steps, sized_by = QUANTUM, "quantum"
+    if not free_slots and shortest > QUANTUM:
+        steps, sized_by = shortest, "budget"
+    if longest < steps:
+        steps, sized_by = longest, "budget"
+    if block < steps:
+        steps, sized_by = block, "ceiling"
+    return steps, sized_by
 
 
 @dataclasses.dataclass
@@ -223,14 +269,16 @@ class ServingEngine:
             return logits[0], cache  # [V]
 
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def decode_block(params, cache, tok, pos, active, rng):
-            """``block`` decode steps over all slots: tok/pos [slots]
-            are each row's last accepted token and its position; parked
-            rows (active=False) hold position and re-write their own
-            slot. Returns the sampled tokens [slots, block]."""
+        def decode_block(params, cache, tok, pos, active, rng, steps):
+            """``steps`` (a traced int32, at most ``block``) decode steps
+            over all slots: tok/pos [slots] are each row's last accepted
+            token and its position; parked rows (active=False) hold
+            position and re-write their own slot. Returns the sampled
+            tokens [slots, block], of which the first ``steps`` columns
+            are written."""
 
-            def step(carry, _):
-                cache, tok, pos, rng = carry
+            def step(i, carry):
+                cache, tok, pos, rng, toks = carry
                 logits, cache = decode_forward(
                     decode_model, params, cache, tok[:, None], pos[:, None],
                     return_hidden=False,
@@ -241,10 +289,12 @@ class ServingEngine:
                 pos = jnp.where(
                     active, jnp.minimum(pos + 1, L - 1), pos
                 )
-                return (cache, nxt, pos, rng), nxt
+                toks = jax.lax.dynamic_update_index_in_dim(toks, nxt, i, 0)
+                return cache, nxt, pos, rng, toks
 
-            (cache, tok, pos, rng), toks = jax.lax.scan(
-                step, (cache, tok, pos, rng), None, length=self.block
+            toks = jnp.zeros((self.block, slots), tok.dtype)
+            cache, tok, pos, rng, toks = jax.lax.fori_loop(
+                0, steps, step, (cache, tok, pos, rng, toks)
             )
             return toks.swapaxes(0, 1), cache, tok, pos, rng
 
@@ -266,6 +316,7 @@ class ServingEngine:
         self._pos = jnp.zeros((slots,), jnp.int32)
         self._slots: list[Optional[_Slot]] = [None] * slots
         self._queue: deque[Request] = deque()
+        self.last_steps = 0  # steps of the newest decode dispatch
         # Latency/throughput accounting.
         self.completed: list[RequestResult] = []
         self._tpot_samples: list[float] = []
@@ -396,8 +447,8 @@ class ServingEngine:
     # ---- the engine iteration ----
 
     def step(self) -> list[RequestResult]:
-        """One engine iteration: admit into free slots at this block
-        boundary, run one decode block, harvest finished requests.
+        """One engine iteration: admit into free slots at this dispatch
+        boundary, run one decode dispatch, harvest finished requests.
         Returns the requests completed this iteration."""
         from .. import faults
 
@@ -431,22 +482,30 @@ class ServingEngine:
         ]
         if not active_rows:
             return finished
-        # 2. One decode block over the full slot batch.
+        # 2. One decode dispatch over the full slot batch, as long as the
+        # rows' budgets and the free slots say (decode_steps).
         active = np.zeros((self.slots,), bool)
         active[active_rows] = True
+        steps, sized_by = decode_steps(
+            [self._slots[i].remaining for i in active_rows],
+            self.slots - len(active_rows), self.block,
+        )
         t0 = time.time()
-        with obs.span("engine.decode_dispatch", SPAN_CAT, rows=len(active_rows)):
+        with obs.span(
+            "engine.decode_dispatch", SPAN_CAT, rows=len(active_rows), steps=steps
+        ):
             active = jnp.asarray(active)
             self.host_lap("admit_prep")  # up to this dispatch the device waited
             toks, self._cache, self._tok, self._pos, self._rng = (
                 self._decode_block(
                     self._params, self._cache, self._tok, self._pos,
-                    active, self._rng,
+                    active, self._rng, np.int32(steps),
                 )
             )
         self.host_lap("dispatch")
         with obs.span("engine.decode_fence", SPAN_CAT):
-            toks = np.asarray(toks)  # device fence: the block is the unit
+            # Device fence: the dispatch is the unit.
+            toks = np.asarray(toks)[:, :steps]
         self.host_lap("decode_fence")
         wall = time.time() - t0
         live = 0
@@ -461,15 +520,18 @@ class ServingEngine:
                     accepted += 1
                 if accepted:
                     # Per-REQUEST experienced latency: every occupied slot
-                    # waited the whole block wall for its `accepted` tokens
-                    # (concurrent slots don't divide a request's wait —
-                    # aggregating wall/total_tokens would understate tpot by
-                    # the concurrency factor).
+                    # waited the whole dispatch's wall for its `accepted`
+                    # tokens (concurrent slots don't divide a request's
+                    # wait — aggregating wall/total_tokens would understate
+                    # tpot by the concurrency factor).
                     self._tpot_samples.append(wall / accepted)
                 live += accepted
+        self.last_steps = steps
         self._n["decode_blocks"] += 1
+        self._n["decode_steps"] += steps
+        self._n[f"decode_sized_by_{sized_by}"] += 1
         self._n["slot_blocks_occupied"] += len(active_rows)
-        self._n["decode_row_steps"] += len(active_rows) * self.block
+        self._n["decode_row_steps"] += len(active_rows) * steps
         if live:
             self._n["decode_tokens"] += live
             self._decode_wall += wall
@@ -604,12 +666,16 @@ class ServingEngine:
             "block": self.block,
             "chunk": self.chunk,
             **n,
-            # Rows that held a request, of the rows the blocks ran; and
-            # tokens accepted, of the steps those rows ran.
+            # Rows that held a request, of the rows the dispatches ran;
+            # tokens accepted, of the steps those rows ran; and the mean
+            # length of a dispatch.
             "slot_occupancy_pct": share(
                 n["slot_blocks_occupied"], n["decode_blocks"] * self.slots
             ),
             "decode_yield_pct": share(n["decode_tokens"], n["decode_row_steps"]),
+            "decode_steps_per_block": round(n["decode_steps"] / n["decode_blocks"], 3)
+            if n["decode_blocks"]
+            else None,
             # Pad positions of each prompt's last chunk, of the positions
             # the prefill program ran.
             "prefill_pad_pct": share(

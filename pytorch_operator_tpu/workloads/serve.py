@@ -7,9 +7,10 @@ Where ``workloads/generate.py`` decodes ONE fixed batch and exits (the
 benchmark shape), this runs indefinitely: clients drop requests into a
 spool directory (serving/spool.py — this environment's Service
 substrate), the engine (serving/engine.py) admits them into cache slots
-at decode-block boundaries, finished requests free their slot for the
-next arrival, and responses carry the per-request latency record (TTFT,
-per-token). Progress/metrics flow through the same rendezvous surface
+between decode dispatches (it picks each one's length, so the loop below
+polls, harvests and answers as often as that), finished requests free
+their slot for the next arrival, and responses carry the per-request
+latency record (TTFT, per-token). Progress/metrics flow through the same rendezvous surface
 training workloads use, so ``tpujob describe`` shows a serving job's
 live throughput exactly like a training job's.
 
@@ -228,11 +229,11 @@ def run(
             ttft_ms_p99=s["ttft_ms_p99"],
             tpot_ms_p50=s["tpot_ms_p50"],
             tpot_ms_p99=s["tpot_ms_p99"],
-            # Decode-block phase for the router's batch-fill
+            # Decode-dispatch phase for the router's batch-fill
             # tie-break: a busy engine frees its next slot one
-            # block's worth of per-token time away.
+            # dispatch away, as long as the steps it last ran.
             block_ms=(
-                (s["tpot_ms_p50"] or 0.0) * block
+                (s["tpot_ms_p50"] or 0.0) * engine.last_steps
                 if engine.busy
                 else 0.0
             ),
@@ -330,8 +331,10 @@ def main(argv=None) -> int:
     p.add_argument("--chunk", type=int, default=64,
                    help="prefill chunk length (bounds prefill memory)")
     p.add_argument("--block", type=int, default=16,
-                   help="decode steps per dispatch; admission happens "
-                   "at block boundaries")
+                   help="the most decode steps one dispatch may run; the "
+                   "engine picks each dispatch's length (to the next slot "
+                   "that frees, a short quantum while one is free) and "
+                   "admits between dispatches")
     p.add_argument("--max-decode-len", type=int, default=2048)
     p.add_argument("--temperature", type=float, default=0.0)
     p.add_argument("--top-k", type=int, default=0)

@@ -22,10 +22,11 @@ import tests.jaxenv  # noqa: F401
 from pytorch_operator_tpu.models import llama as llama_lib
 from pytorch_operator_tpu.obs import trace as obs_trace
 from pytorch_operator_tpu.serving import Request, ServingEngine
-from pytorch_operator_tpu.serving.engine import FENCE_SEGMENTS, GAP_SEGMENTS, SEGMENTS, host_key
+from pytorch_operator_tpu.serving.engine import FENCE_SEGMENTS, GAP_SEGMENTS, SEGMENTS, SIZED_BY, host_key
 
 SHAPES = [(5, 7), (13, 9), (8, 1), (21, 5), (3, 12)]  # (prompt, new tokens); one finishes inside prefill
-COUNTERS = ("decode_blocks", "slot_blocks_occupied", "decode_row_steps", "decode_tokens", "prefill_chunks",
+COUNTERS = ("decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
+            "decode_sized_by_budget", "decode_sized_by_quantum", "decode_sized_by_ceiling", "prefill_chunks",
             "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "admitted")
 
 
@@ -63,24 +64,38 @@ def test_counters_repeat_exactly_and_say_what_they_count(model):
     for _ in range(2):
         eng = _engine(model)
         _submit_all(eng)
-        results = eng.run_until_drained()
-        runs.append((_counts(eng.stats()), {r.id: r.tokens for r in results}))
+        results, row_steps, before = [], 0, _counts(eng.stats())
+        while eng.busy:
+            results += eng.step()
+            now = _counts(eng.stats())
+            assert now["decode_blocks"] - before["decode_blocks"] <= 1  # a step is one dispatch at most
+            assert now["decode_steps"] - before["decode_steps"] == eng.last_steps * (
+                now["decode_blocks"] - before["decode_blocks"])
+            row_steps += (now["slot_blocks_occupied"] - before["slot_blocks_occupied"]) * (
+                now["decode_steps"] - before["decode_steps"])
+            before = now
+        runs.append((before, {r.id: r.tokens for r in results}, row_steps))
     assert runs[0] == runs[1]
-    n = runs[0][0]
+    n, _, row_steps = runs[0]
     assert n["admitted"] == len(SHAPES)
     assert n["prefill_tokens"] == sum(p for p, _ in SHAPES)
     assert n["prefill_chunks"] == sum(-(-p // 8) for p, _ in SHAPES)
     assert n["prefill_pad_tokens"] == n["prefill_chunks"] * 8 - n["prefill_tokens"]
     # The first token of each request comes out of prefill; the blocks yield the rest.
     assert n["decode_tokens"] == sum(new - 1 for _, new in SHAPES)
-    assert n["decode_row_steps"] == 4 * n["slot_blocks_occupied"]
+    # Row-steps are the sum over dispatches of rows x the steps that dispatch ran (not rows x block).
+    assert n["decode_row_steps"] == row_steps <= 4 * n["slot_blocks_occupied"]
+    assert n["decode_blocks"] <= n["decode_steps"] <= 4 * n["decode_blocks"]
+    assert sum(n[f"decode_sized_by_{reason}"] for reason in SIZED_BY) == n["decode_blocks"]
+    assert n["decode_sized_by_ceiling"] > 0 and n["decode_sized_by_budget"] > 0  # block 4 cuts; so do last tokens
     assert n["decode_blocks"] <= n["slot_blocks_occupied"] <= 3 * n["decode_blocks"]
     assert 1 <= n["admit_rounds"] <= n["admitted"]
     stats = eng.stats()
     assert stats["slot_occupancy_pct"] == pytest.approx(
         100 * n["slot_blocks_occupied"] / (3 * n["decode_blocks"]), abs=1e-3)
     assert stats["decode_yield_pct"] == pytest.approx(100 * n["decode_tokens"] / n["decode_row_steps"], abs=1e-3)
-    assert 0 < stats["decode_yield_pct"] < 100  # rows finish inside a block: some steps yield nothing
+    assert 0 < stats["decode_yield_pct"] < 100  # rows finish inside a dispatch: some steps yield nothing
+    assert stats["decode_steps_per_block"] == pytest.approx(n["decode_steps"] / n["decode_blocks"], abs=1e-3)
     assert stats["prefill_pad_pct"] == pytest.approx(
         100 * n["prefill_pad_tokens"] / (n["prefill_chunks"] * 8), abs=1e-3) and 0 < stats["prefill_pad_pct"] < 100
 
@@ -95,7 +110,7 @@ def test_reset_clears_the_counters_and_the_clock(model):
     assert all(stats[k] == 0 for k in COUNTERS)
     assert all(stats[host_key(k)] == 0.0 for k in SEGMENTS) and stats["host_gap_s"] == 0.0
     assert stats["slot_occupancy_pct"] is None and stats["decode_yield_pct"] is None
-    assert stats["prefill_pad_pct"] is None
+    assert stats["prefill_pad_pct"] is None and stats["decode_steps_per_block"] is None
     assert stats["requests"] == 0
 
 

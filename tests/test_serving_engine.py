@@ -18,6 +18,7 @@ import pytest
 import tests.jaxenv  # noqa: F401
 from pytorch_operator_tpu.models import llama as llama_lib
 from pytorch_operator_tpu.serving import Request, ServingEngine, Spool
+from pytorch_operator_tpu.serving.engine import QUANTUM, SIZED_BY, decode_steps
 
 
 def _cfg_params(max_decode_len=48, **over):
@@ -57,6 +58,110 @@ def _req(rid, prompt, new):
     return Request(
         id=rid, prompt=prompt, max_new_tokens=new, submit_time=time.time()
     )
+
+
+Q = QUANTUM
+
+
+@pytest.mark.parametrize(
+    "remaining, free_slots, block, want",
+    [
+        # A slot free: a quantum, so an arrival waits that long at most ...
+        ([40, 100], 1, 64, (Q, "quantum")),
+        ([40, 100], 6, 256, (Q, "quantum")),
+        # ... but never past the last row's budget,
+        ([3, Q - 1], 1, 64, (Q - 1, "budget")),
+        ([1], 7, 64, (1, "budget")),
+        # and the ceiling cuts a quantum like anything else.
+        ([40, 100], 1, 4, (4, "ceiling")),
+        ([40, 100], 1, 1, (1, "ceiling")),
+        # All slots taken: to the step at which the next one frees,
+        ([40, 100, 57], 0, 64, (40, "budget")),
+        ([Q + 1, 300], 0, 64, (Q + 1, "budget")),
+        # under the ceiling,
+        ([100, 200], 0, 64, (64, "ceiling")),
+        ([64, 200], 0, 64, (64, "budget")),
+        # with a floor of a quantum (rows a step apart do not make one-step dispatches),
+        ([1, 2, 3, 90], 0, 64, (Q, "quantum")),
+        ([Q, 90], 0, 64, (Q, "quantum")),
+        # which the longest budget still cuts.
+        ([1, 2, 3], 0, 64, (3, "budget")),
+        ([5], 0, 64, (5, "budget")),
+        ([5], 0, 4, (4, "ceiling")),
+    ],
+)
+def test_decode_steps_rule(remaining, free_slots, block, want):
+    steps, sized_by = decode_steps(remaining, free_slots, block)
+    assert (steps, sized_by) == want and sized_by in SIZED_BY
+    assert 1 <= steps <= min(block, max(remaining))  # no step that no row can use
+
+
+PARITY_SHAPES = [(5, 20), (13, 30), (8, 3), (9, 18)]  # (prompt, new tokens): budgets on both sides of a quantum
+
+
+@pytest.fixture(scope="module")
+def parity_model():
+    cfg, params = _cfg_params(max_decode_len=64)
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, 256, (p,)).astype(np.int32) for p, _ in PARITY_SHAPES]
+    want = [_reference_rollout(cfg, params, prompt, n) for prompt, (_, n) in zip(prompts, PARITY_SHAPES)]
+    return cfg, params, prompts, want
+
+
+@pytest.mark.parametrize("block", [1, 4, 64])
+@pytest.mark.parametrize("slots, occupancy", [(5, "free"), (4, "full"), (2, "queued")])
+def test_greedy_tokens_do_not_depend_on_where_dispatches_are_cut(parity_model, block, slots, occupancy):
+    """The same four requests with a slot always free, with every slot
+    taken, and with a queue waiting for slots, under ceilings of 1, 4 and 64
+    steps: token for token ``make_generate``'s single-stream rollout."""
+    cfg, params, prompts, want = parity_model
+    eng = ServingEngine(cfg, params, slots=slots, chunk=8, block=block)
+    for i, (prompt, (_, n)) in enumerate(zip(prompts, PARITY_SHAPES)):
+        eng.submit(_req(f"r{i}", prompt, n))
+    results, sizes = {}, []
+    while eng.busy:
+        for r in eng.step():
+            results[r.id] = r.tokens
+        sizes.append((eng.last_steps, eng.queued))  # the dispatch's steps, and who still waited for a slot
+    assert [results[f"r{i}"] for i in range(len(want))] == want
+    assert eng._decode_block._cache_size() == 1  # every length ran the one compiled program
+    n = eng.stats()
+    assert all(1 <= steps <= block for steps, _ in sizes)
+    assert n["decode_steps"] <= block * n["decode_blocks"]
+    assert sum(n[f"decode_sized_by_{reason}"] for reason in SIZED_BY) == n["decode_blocks"]
+    assert n["decode_tokens"] == sum(new - 1 for _, new in PARITY_SHAPES)
+    if block < Q:
+        assert n["decode_sized_by_ceiling"] > 0 and n["decode_sized_by_quantum"] == 0
+    elif occupancy == "free":
+        # First dispatch: budgets 19, 29, 2, 17 and a slot free -> a quantum.
+        assert sizes[0][0] == Q and n["decode_sized_by_quantum"] > 0 and n["decode_sized_by_ceiling"] == 0
+    elif occupancy == "full":
+        # All four slots taken, shortest budget 2 -> the floor of a quantum; nothing ever exceeds it here.
+        assert sizes[0][0] == Q and max(steps for steps, _ in sizes) == Q
+    else:
+        # Two slots, two requests queued: 19 and 29 remain -> run to 19, when the next slot frees.
+        assert sizes[0] == (19, 2) and n["decode_sized_by_budget"] > 0
+
+
+def test_an_eos_row_is_harvested_at_the_next_boundary(parity_model):
+    """A budget is an upper bound on a row's life where an EOS token can end
+    it: the dispatch is sized by the budget, the row ends inside it, and the
+    same ``step()`` hands the answer out and frees the slot."""
+    cfg, params, prompts, want = parity_model
+    full = want[1]  # 30 tokens
+    eos = full[3]
+    cut = full.index(eos) + 1
+    eng = ServingEngine(cfg, params, slots=1, chunk=8, block=64, eos_token=eos)
+    eng.submit(_req("e0", prompts[1], 30))
+    eng.submit(_req("e1", prompts[2], 3))
+    (res,) = eng.step()
+    assert res.id == "e0" and res.tokens == full[:cut]
+    n = eng.stats()
+    assert eng.last_steps == 29 and n["decode_sized_by_budget"] == 1  # one slot, all taken: to its budget
+    assert n["decode_row_steps"] == 29 and n["decode_tokens"] == cut - 1
+    assert eng.slots_free == 1 and eng.queued == 1  # the next boundary admits the one that waited
+    (nxt,) = eng.run_until_drained()
+    assert nxt.id == "e1" and nxt.tokens == want[2]
 
 
 @pytest.mark.slow
