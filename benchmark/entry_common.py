@@ -1,7 +1,7 @@
 """What the benchmark's two entry modules share. They run inside the
-replica, the process that holds the chip: they state the cell's model to
-the program, give it seeded weights, and afterwards report the allocator's
-peak and the reduced trace. Everything else is the program's own ``main``.
+replica, the process that holds the chip: they have the configuration's
+family state the cell's model to the program and give it seeded weights,
+and afterwards report the allocator's peak and the reduced trace. Everything else is the program's own ``main``.
 """
 
 from __future__ import annotations
@@ -16,45 +16,21 @@ def bench_args(argv):
     p = argparse.ArgumentParser(add_help=False)
     p.add_argument("--bench-config", required=True)
     p.add_argument("--bench-state", required=True)
+    p.add_argument("--bench-dir", default=str(Path(__file__).resolve().parent))
     p.add_argument("--bench-seconds", type=float, default=0.0)
     p.add_argument("--bench-trace-s", type=float, default=0.0)
     return p.parse_known_args(argv)
 
 
-def install(config_path: str) -> dict:
-    """Register the configuration file's model as the ``bench`` preset of
-    the workloads' ``--config`` and make ``Llama.init`` return the
-    benchmark's seeded weights (same tree, same sharding metadata)."""
-    import flax.linen as nn
+def install(args):
+    """Read the configuration and hand it to its family's ``install.py``,
+    which states the model to the program and gives it seeded weights.
+    Returns the configuration and the family's ``weights`` module."""
+    from . import family
 
-    from pytorch_operator_tpu.models import llama as llama_lib
-    from pytorch_operator_tpu.workloads import llama_train
-
-    from . import weights as W
-
-    model = json.loads(Path(config_path).read_text())
-    d = W.dims(model)
-
-    def bench_config(**over):
-        return llama_lib.llama3_8b(**{
-            "vocab_size": d["V"], "d_model": d["D"], "n_layers": d["L"],
-            "n_heads": d["H"], "n_kv_heads": d["K"], "head_dim": d["hd"],
-            "d_ff": d["F"], "rope_theta": d["theta"], "rms_eps": d["eps"], **over,
-        })
-
-    llama_lib.bench_config = bench_config
-    llama_train.CONFIGS["bench"] = "bench_config"
-    flax_init = llama_lib.Llama.init
-
-    def seeded_init(self, rngs, *args, **kwargs):
-        variables = flax_init(self, rngs, *args, **kwargs)
-        key = rngs["params"] if isinstance(rngs, dict) else rngs
-        mine = W.make_params(W.dims(model | {"num_hidden_layers": self.cfg.n_layers}),
-                             key, self.cfg.param_dtype)
-        return {**variables, "params": nn.meta.replace_boxed(variables["params"], mine)}
-
-    llama_lib.Llama.init = seeded_init
-    return model
+    model = json.loads(Path(args.bench_config).read_text())
+    family.of(model, "install", args.bench_dir).install(model)
+    return model, family.of(model, "weights", args.bench_dir)
 
 
 def trace_in_background(trace_dir, delay_s: float, seconds: float):

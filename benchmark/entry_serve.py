@@ -21,7 +21,7 @@ TRACE_DELAY_S = 5.0
 def main(argv=None) -> int:
     args, rest = bench_args(sys.argv[1:] if argv is None else argv)
     state = Path(args.bench_state)
-    install(args.bench_config)
+    install(args)
 
     from pytorch_operator_tpu.serving.engine import ServingEngine
     from pytorch_operator_tpu.workloads import serve

@@ -64,18 +64,17 @@ def grad_squares(opt_state, params):
     return out
 
 
-def delta_squares(params, d, key):
-    """Per leaf, the squared norm of (params - the seeded start), one leaf
-    to a program so that only one leaf's start is alive at a time."""
+def delta_squares(params, make_start, key):
+    """Per leaf, the squared norm of (params - the seeded start, which
+    ``make_start(key, dtype)`` gives), one leaf to a program so that only
+    one leaf's start is alive at a time."""
     import jax
     import jax.numpy as jnp
-
-    from . import weights as W
 
     out = {}
     for path, leaf in leaf_paths(params):
         def diff(p, key, path=path):  # the key an argument: one program for every seed
-            start = W.make_params(d, key, p.dtype)
+            start = make_start(key, p.dtype)
             for name in path.split("/"):
                 start = start[name]
             return jnp.sum((p.astype(jnp.float32) - start.astype(jnp.float32)) ** 2)
@@ -86,12 +85,10 @@ def delta_squares(params, d, key):
 def main(argv=None) -> int:
     args, rest = bench_args(sys.argv[1:] if argv is None else argv)
     state_dir = Path(args.bench_state)
-    model = install(args.bench_config)
+    model, W = install(args)
     seed = int(os.environ.get("TPUJOB_SEED", "0"))
 
     from pytorch_operator_tpu.workloads import llama_train, trainer
-
-    from . import weights as W
 
     llama_train.synthetic_bigram_batch = (
         lambda batch, seq_len, vocab, step: seeded_batch(seed, step, batch, seq_len, vocab)
@@ -115,7 +112,8 @@ def main(argv=None) -> int:
             if seen["calls"] == 1:
                 seen["grad_sq"] = jax.jit(grad_squares)(state["opt_state"], params)
             if seen["calls"] == CHECK_STEPS:
-                seen["delta_sq"] = delta_squares(params, W.dims(model), jax.random.key(seed))
+                seen["delta_sq"] = delta_squares(
+                    params, lambda key, dtype: W.make_params(W.dims(model), key, dtype), jax.random.key(seed))
             return state, loss
 
         state, _, rate, end = trainer_loop(

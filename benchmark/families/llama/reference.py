@@ -1,10 +1,11 @@
-"""The plain reference: a Llama-shaped block (RMSNorm, rotate-half RoPE,
-grouped-query causal attention, SwiGLU) in straightforward float32
-``jax.numpy``, one file for every configuration of the benchmark.
+"""The plain reference of the ``llama`` family: a Llama-shaped block
+(RMSNorm, rotate-half RoPE, grouped-query causal attention, SwiGLU) in
+straightforward float32 ``jax.numpy``, one file for every configuration of
+the family.
 
 It imports nothing of the program under test and takes nothing the
-program made: weights come from ``benchmark.weights`` and the seed, one
-layer at a time, so a model whose float32 weights exceed the chip still
+program made: weights come from the family's ``weights.py`` and the seed,
+one layer at a time, so a model whose float32 weights exceed the chip still
 fits. Matrix products run at ``jax.default_matmul_precision("highest")``;
 callers wrap their calls in :func:`highest`.
 
@@ -18,6 +19,10 @@ Two drivers sit on the block:
 
 ``lower`` selects the control: the same mathematics with its matrix
 products' operands rounded to the next precision down.
+
+:func:`serve_check` and :func:`train_check` are what ``reference_run.py``
+calls (the contract is stated there): they read the harness's
+``check_in.json`` and hand its sizes to the two drivers.
 """
 
 from __future__ import annotations
@@ -27,16 +32,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import weights as W
+from benchmark.families._common import (  # noqa: F401  (``highest`` is what callers wrap their calls in)
+    adafactor_scaled, bf16, fake_int, fp8_round, highest, identity, rms_norm, zero_stats,
+)
 
-highest = functools.partial(jax.default_matmul_precision, "highest")
+from . import weights as W
 
 
 # ---- the block ----
-
-
-def rms_norm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
 def rope(x, positions, theta):
@@ -49,11 +52,7 @@ def rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _identity(a):
-    return a
-
-
-def attention(x, w, d, rnd=_identity):
+def attention(x, w, d, rnd=identity):
     """Causal grouped-query attention; one KV head's group at a time so the
     [S, S] scores of a long sequence stay small."""
     B, S, _ = x.shape
@@ -81,27 +80,18 @@ def attention(x, w, d, rnd=_identity):
     return rnd(out) @ rnd(w["o_proj"]["kernel"])
 
 
-def mlp(x, w, rnd=_identity):
+def mlp(x, w, rnd=identity):
     x = rnd(x)
     h = jax.nn.silu(x @ rnd(w["gate_proj"]["kernel"])) * (x @ rnd(w["up_proj"]["kernel"]))
     return rnd(h) @ rnd(w["down_proj"]["kernel"])
 
 
-def block(x, w, d, rnd=_identity):
+def block(x, w, d, rnd=identity):
     x = x + attention(rms_norm(x, w["attn_norm"]["scale"], d["eps"]), w["attn"], d, rnd)
     return x + mlp(rms_norm(x, w["mlp_norm"]["scale"], d["eps"]), w["mlp"], rnd)
 
 
-# ---- stated precisions, and the next one down ----
-
-
-def fake_int(w, axis, levels):
-    """Symmetric per-channel integer rounding over ``axis`` (the axis the
-    product contracts): scale = max|w| / levels, values in [-levels, levels].
-    levels 127 is the int8 the serving configuration states; 7 is int4."""
-    amax = jnp.max(jnp.abs(w), axis=axis, keepdims=True)
-    scale = jnp.maximum(amax, jnp.finfo(jnp.float32).tiny) / levels
-    return jnp.clip(jnp.round(w / scale), -levels, levels) * scale
+# ---- the weights as the serving configuration states them ----
 
 
 def quantise_layer(w, levels):
@@ -110,26 +100,6 @@ def quantise_layer(w, levels):
         for name, leaf in w[part].items():
             out[part][name] = {"kernel": fake_int(leaf["kernel"], 0, levels)}
     return out
-
-
-def _scaled_round(a, dtype, top):
-    s = jnp.maximum(jnp.max(jnp.abs(a)), 1e-30) / top
-    return (a / s).astype(dtype).astype(jnp.float32) * s
-
-
-@jax.custom_vjp
-def fp8_round(a):
-    """A product's operand in per-tensor scaled float8, as fp8 training does
-    it: e4m3 on the way forward, and the gradient that comes back through it
-    in e5m2. The next precision below the bfloat16 the training
-    configuration states."""
-    return _scaled_round(a, jnp.float8_e4m3fn, 448.0)
-
-
-fp8_round.defvjp(
-    lambda a: (fp8_round(a), None),
-    lambda _, ct: (_scaled_round(ct, jnp.float8_e5m2, 57344.0),),
-)
 
 
 # ---- serving: the gap of each served token ----
@@ -193,49 +163,6 @@ def serve_gaps(d, key, tokens, first, count, width, *, levels=127, control_level
 # ---- training: loss, gradient norms, parameter change ----
 
 
-def _factored_axes(shape):
-    """The two largest axes, as optax's Adafactor picks them (stable order;
-    the larger is averaged away in the row statistic), or None."""
-    if len(shape) < 2:
-        return None
-    order = sorted(range(len(shape)), key=lambda i: shape[i])
-    if shape[order[-2]] < 128:
-        return None
-    return order[-2], order[-1]
-
-
-def _adafactor_scaled(g, v, step):
-    """g over the factored estimate of its root mean square (Shazeer & Stern
-    2018 as the training configuration states it: decay 1 - t^-0.8, eps
-    1e-30, factored where two axes reach 128). Returns (u, new statistics)."""
-    decay = 1.0 - (step + 1.0) ** -0.8
-    g2 = g * g + 1e-30
-    axes = _factored_axes(g.shape)
-    if axes is None:
-        nv = decay * v["v"] + (1 - decay) * g2
-        return g * nv ** -0.5, {"v": nv}
-    d1, d0 = axes
-    row = decay * v["row"] + (1 - decay) * jnp.mean(g2, axis=d0)
-    col = decay * v["col"] + (1 - decay) * jnp.mean(g2, axis=d1)
-    rd1 = d1 - 1 if d1 > d0 else d1
-    rfac = (row / jnp.mean(row, axis=rd1, keepdims=True)) ** -0.5
-    u = g * jnp.expand_dims(rfac, d0) * jnp.expand_dims(col ** -0.5, d1)
-    return u, {"row": row, "col": col}
-
-
-def _zero_stats(shape):
-    axes = _factored_axes(shape)
-    if axes is None:
-        return {"v": jnp.zeros(shape, jnp.float32)}
-    d1, d0 = axes
-    drop = lambda ax: tuple(s for i, s in enumerate(shape) if i != ax)
-    return {"row": jnp.zeros(drop(d0), jnp.float32), "col": jnp.zeros(drop(d1), jnp.float32)}
-
-
-def _bf16(a):
-    return a.astype(jnp.bfloat16).astype(jnp.float32)
-
-
 def train_steps(d, key, batches, *, lr, lower=False):
     """Follow the trainer's first ``len(batches)`` steps on ``batches[i]
     [B, S]``: next-token cross-entropy, Adafactor at learning rate ``lr``
@@ -249,7 +176,7 @@ def train_steps(d, key, batches, *, lr, lower=False):
     backward pass for the per-leaf statistics (the clip needs the whole
     stacked leaf), and a second backward pass that applies the update.
     """
-    rnd = fp8_round if lower else _identity
+    rnd = fp8_round if lower else identity
     L = d["L"]
     # Parameters are held as the configuration states them, in bfloat16 (the
     # update rounds to it, so nothing is lost), and widened where used. The
@@ -258,8 +185,8 @@ def train_steps(d, key, batches, *, lr, lower=False):
     start_layer = jax.jit(lambda k, l: W.make_layer(d, k, l, jnp.bfloat16))
     layers = [start_layer(key, jnp.int32(l)) for l in range(L)]
     outer = start_outer = jax.jit(lambda k: W.make_outer(d, k, jnp.bfloat16))(key)
-    stats_l = [jax.tree.map(lambda a: _zero_stats(a.shape), w) for w in layers]
-    stats_o = jax.tree.map(lambda a: _zero_stats(a.shape), outer)
+    stats_l = [jax.tree.map(lambda a: zero_stats(a.shape), w) for w in layers]
+    stats_o = jax.tree.map(lambda a: zero_stats(a.shape), outer)
     is_stats = lambda n: isinstance(n, dict) and ("v" in n or "row" in n)
 
     fwd = jax.jit(lambda x, w: block(x, f32(w), d, rnd))
@@ -281,14 +208,14 @@ def train_steps(d, key, batches, *, lr, lower=False):
     def sums(g, v, step):
         """Per-leaf sum of g^2 and of u^2 for one layer's (or the outer) tree."""
         def one(g_, v_):
-            u, _ = _adafactor_scaled(_bf16(g_), v_, step)
-            return jnp.stack([jnp.sum(_bf16(g_) ** 2), jnp.sum(u * u), jnp.float32(u.size)])
+            u, _ = adafactor_scaled(bf16(g_), v_, step)
+            return jnp.stack([jnp.sum(bf16(g_) ** 2), jnp.sum(u * u), jnp.float32(u.size)])
         return jax.tree.map(one, g, v, is_leaf=lambda n: is_stats(n))
 
     @jax.jit
     def apply(w, g, v, step, clip, scale):
         def one(w_, g_, v_, clip_, scale_):
-            u, nv = _adafactor_scaled(_bf16(g_), v_, step)
+            u, nv = adafactor_scaled(bf16(g_), v_, step)
             return (w_.astype(jnp.float32) - lr * scale_ * u / clip_).astype(jnp.bfloat16), nv
         pairs = jax.tree.map(one, w, g, v, clip, scale, is_leaf=lambda n: is_stats(n))
         is_pair = lambda n: isinstance(n, tuple)
@@ -359,3 +286,50 @@ def train_steps(d, key, batches, *, lr, lower=False):
             **jax.tree.map(lambda s: float(jnp.sqrt(s)), dsq(outer, start_outer)),
         }
     return {"losses": losses, "grad_norm": grad_norm, "delta_norm": delta_norm}
+
+
+# ---- what reference_run.py calls ----
+
+
+def serve_check(check: dict, control: bool) -> dict:
+    import numpy as np
+
+    d = W.dims(check["config"])
+    reqs = check["requests"]
+    pad_to = int(check["pad_to"])
+    tokens = np.zeros((len(reqs), pad_to), np.int32)
+    first, count = [], []
+    for i, r in enumerate(reqs):
+        seq = list(r["prompt"]) + list(r["tokens"])
+        if len(seq) > pad_to:
+            raise SystemExit(f"request of {len(seq)} tokens exceeds the mix's check_pad_to {pad_to}")
+        tokens[i, : len(seq)] = seq
+        first.append(len(r["prompt"]) - 1)
+        count.append(len(r["tokens"]))
+    res = serve_gaps(
+        d, jax.random.key(check["seed"]), jnp.asarray(tokens), jnp.asarray(first),
+        jnp.asarray(count), int(check["width"]), control_levels=7 if control else None,
+    )
+    valid = np.asarray(res["valid"])
+    gaps = np.asarray(res["gap"])[valid].tolist()
+    agree = int(np.asarray(res["agree"])[valid].sum())
+    cgaps = np.asarray(res["control_gap"])[valid].tolist() if control else []
+    out = {}
+    out.update(requests=len(reqs), positions=len(gaps), agree=agree, gap_max=max(gaps),
+               gap_mean=sum(gaps) / len(gaps))
+    if control:
+        out.update(control_gap_max=max(cgaps), control_gap_mean=sum(cgaps) / len(cgaps))
+    return out
+
+
+def train_check(check: dict, control: bool) -> dict:
+    from benchmark.entry_train import seeded_batch
+
+    d = W.dims(check["config"])
+    batches = [seeded_batch(check["seed"], s, check["batch"], check["seq_len"], d["V"])
+               for s in range(check["steps"])]
+    key = jax.random.key(check["seed"])
+    out = train_steps(d, key, batches, lr=check["lr"])
+    if control:
+        out["control"] = train_steps(d, key, batches, lr=check["lr"], lower=True)
+    return out

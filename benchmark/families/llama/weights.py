@@ -17,10 +17,10 @@ ln V + 1/2.
 
 from __future__ import annotations
 
-import zlib
-
 import jax
 import jax.numpy as jnp
+
+from benchmark.families._common import draw, leaf_key, nest
 
 
 def dims(model: dict) -> dict:
@@ -64,39 +64,17 @@ def outer_leaves(d: dict) -> dict:
     }
 
 
-def _leaf_key(key, path):
-    return jax.random.fold_in(key, zlib.crc32("/".join(path).encode()) & 0x7FFFFFFF)
-
-
-def _draw(key, shape, fan_in, dtype):
-    if fan_in is None:
-        return jnp.ones(shape, dtype)
-    w = jax.random.normal(key, shape, jnp.float32) * (fan_in ** -0.5)
-    return w.astype(dtype)
-
-
-def _nest(flat: dict) -> dict:
-    out: dict = {}
-    for path, leaf in flat.items():
-        node = out
-        for name in path[:-1]:
-            node = node.setdefault(name, {})
-        node[path[-1]] = leaf
-    return out
-
-
 def make_layer(d: dict, key, layer, dtype=jnp.float32) -> dict:
     """One layer's leaves (nested dict), for a traced or concrete index."""
-    return _nest({
-        path: _draw(jax.random.fold_in(_leaf_key(key, ("layers",) + path), layer),
-                    shape, fan_in, dtype)
+    return nest({
+        path: draw(jax.random.fold_in(leaf_key(key, ("layers",) + path), layer), shape, fan_in, dtype)
         for path, (shape, fan_in) in layer_leaves(d).items()
     })
 
 
 def make_outer(d: dict, key, dtype=jnp.float32, only=None) -> dict:
-    return _nest({
-        path: _draw(_leaf_key(key, path), shape, fan_in, dtype)
+    return nest({
+        path: draw(leaf_key(key, path), shape, fan_in, dtype)
         for path, (shape, fan_in) in outer_leaves(d).items()
         if only is None or path[0] in only
     })

@@ -5,6 +5,7 @@ nothing to read; the harness then leaves the metric out of the line."""
 
 from __future__ import annotations
 
+from benchmark import family
 from benchmark import metrics as M
 
 
@@ -48,6 +49,24 @@ def answers_stat(field, stat):
     return read
 
 
+def generator_late_ms(ctx):
+    """How late the benchmark's own load generator sent, mean over the
+    window's requests (sent - due, open loop): a stalled generator shows here,
+    and is not read as a slow server."""
+    late = (ctx.get("load") or {}).get("lateness")
+    return 1e3 * M.mean(late) if late else None  # the maximum is on run.py's own "generator lateness" line
+
+
+def generator_stall_ms_per_s(ctx):
+    """Milliseconds by which the load generator's sleeps overran (each by
+    ``run.STALL_S`` or more), per second of the window: pauses of the whole
+    machine, in which the server stands still too. 0.0 where none did."""
+    stalls = (ctx.get("load") or {}).get("stalls")
+    if stalls is None or not ctx.get("seconds"):
+        return None
+    return 1e3 * sum(stalls) / ctx["seconds"]
+
+
 def engine_decode_tok_s(ctx):
     return ctx["final"].get("decode_tokens_per_sec")
 
@@ -75,4 +94,6 @@ def train_mfu_pct(ctx):
     rate = ctx["e2e"].get("train_tokens_per_s_chip")
     if rate is None:
         return None
-    return M.mfu_pct(ctx["config"], int(ctx["traffic"]["seq_len"]), rate, ctx["device"]["device_kind"])
+    flops = family.of(ctx["config"], "flops", ctx["bench"])
+    return M.mfu_pct(flops.train_flops_per_token(ctx["config"], int(ctx["traffic"]["seq_len"])), rate,
+                     ctx["device"]["device_kind"])

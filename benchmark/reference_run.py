@@ -1,10 +1,38 @@
 """The check's reference side, as a process of its own:
-``python -m benchmark.reference_run CHECK_IN.json CHECK_OUT.json [--control]``.
+``python -m benchmark.reference_run CHECK_IN.json CHECK_OUT.json [--control]
+[--bench DIR]``.
 
 Reads what the harness wrote about a finished run (the configuration, the
-seed, and for serving a sample of prompts with the tokens served), runs
-the plain reference and writes its numbers. ``--control`` adds the same
+seed, and for serving a sample of prompts with the tokens served), calls
+the reference of the configuration's family (``family.py``: the file
+``<DIR>/families/<bench.family>/reference.py``) and writes its numbers
+beside how long it took and on what platform. ``--control`` adds the same
 reading in the next precision down, which the limits were set against.
+
+**The contract a family's ``reference.py`` meets.** Two functions over the
+parsed ``check_in.json`` (``check``) and the ``--control`` flag, each
+returning a dict of plain numbers that ``json`` can write:
+
+- ``serve_check(check, control)``, for ``check["kind"] == "serve"``.
+  ``check`` has ``config`` (the configuration file), ``seed``, ``pad_to``
+  and ``width`` (the mix's longest prompt + answer, and longest answer) and
+  ``requests``, each a ``prompt`` with the ``tokens`` served. Follow every
+  request teacher-forced through the plain reference, weights from the seed
+  in the precision the configuration states. Returns ``requests``,
+  ``positions`` (served tokens compared), ``agree`` (of them, the
+  reference's own first choice), ``gap_max`` and ``gap_mean`` (the gap by
+  which a served token's logit lies below the reference's best) and, with
+  ``control``, ``control_gap_max`` and ``control_gap_mean`` (the same gap of
+  the token that the next precision down puts first).
+- ``train_check(check, control)``, for ``"train"``. ``check`` has
+  ``config``, ``seed``, ``steps``, ``batch``, ``seq_len`` and ``lr``; step
+  ``s``'s tokens are ``entry_train.seeded_batch(seed, s, batch, seq_len,
+  vocab_size)``. Returns ``losses`` (one a step), ``grad_norm`` (the first
+  step's gradient norm as the optimizer gets it) and ``delta_norm`` (the
+  parameters' change over the steps), both nested dicts with a number for
+  each leaf of the program's parameter tree, per-layer leaves stacked; and,
+  with ``control``, the same three under ``control``, computed in the next
+  precision down.
 """
 
 from __future__ import annotations
@@ -14,68 +42,24 @@ import sys
 import time
 from pathlib import Path
 
+from . import family
 
-def serve_check(check: dict, control: bool) -> dict:
-    import jax
-    import numpy as np
-
-    from . import reference as R
-    from . import weights as W
-
-    d = W.dims(check["config"])
-    reqs = check["requests"]
-    pad_to = int(check["pad_to"])
-    tokens = np.zeros((len(reqs), pad_to), np.int32)
-    first, count = [], []
-    for i, r in enumerate(reqs):
-        seq = list(r["prompt"]) + list(r["tokens"])
-        if len(seq) > pad_to:
-            raise SystemExit(f"request of {len(seq)} tokens exceeds the mix's check_pad_to {pad_to}")
-        tokens[i, : len(seq)] = seq
-        first.append(len(r["prompt"]) - 1)
-        count.append(len(r["tokens"]))
-    res = R.serve_gaps(
-        d, jax.random.key(check["seed"]), jax.numpy.asarray(tokens), jax.numpy.asarray(first),
-        jax.numpy.asarray(count), int(check["width"]), control_levels=7 if control else None,
-    )
-    valid = np.asarray(res["valid"])
-    gaps = np.asarray(res["gap"])[valid].tolist()
-    agree = int(np.asarray(res["agree"])[valid].sum())
-    cgaps = np.asarray(res["control_gap"])[valid].tolist() if control else []
-    out = {}
-    out.update(requests=len(reqs), positions=len(gaps), agree=agree, gap_max=max(gaps),
-               gap_mean=sum(gaps) / len(gaps))
-    if control:
-        out.update(control_gap_max=max(cgaps), control_gap_mean=sum(cgaps) / len(cgaps))
-    return out
-
-
-def train_check(check: dict, control: bool) -> dict:
-    import jax
-
-    from . import reference as R
-    from . import weights as W
-    from .entry_train import seeded_batch
-
-    d = W.dims(check["config"])
-    batches = [seeded_batch(check["seed"], s, check["batch"], check["seq_len"], d["V"])
-               for s in range(check["steps"])]
-    key = jax.random.key(check["seed"])
-    out = R.train_steps(d, key, batches, lr=check["lr"])
-    if control:
-        out["control"] = R.train_steps(d, key, batches, lr=check["lr"], lower=True)
-    return out
+SERVE_KEYS = ("requests", "positions", "agree", "gap_max", "gap_mean")
+SERVE_CONTROL_KEYS = ("control_gap_max", "control_gap_mean")
+TRAIN_KEYS = ("losses", "grad_norm", "delta_norm")
 
 
 def main(argv=None) -> int:
-    argv = sys.argv[1:] if argv is None else argv
+    argv = list(sys.argv[1:] if argv is None else argv)
     control = "--control" in argv
+    bench = Path(argv.pop(argv.index("--bench") + 1)) if "--bench" in argv else family.BENCH
     src, dst = [a for a in argv if not a.startswith("--")]
     check = json.loads(Path(src).read_text())
     import jax  # its compile cache is where JAX_COMPILATION_CACHE_DIR says; the harness sets it
 
+    reference = family.of(check["config"], "reference", bench)
     t0 = time.time()
-    out = (serve_check if check["kind"] == "serve" else train_check)(check, control)
+    out = (reference.serve_check if check["kind"] == "serve" else reference.train_check)(check, control)
     out["seconds"] = time.time() - t0
     out["platform"] = jax.devices()[0].platform
     Path(dst).write_text(json.dumps(out))

@@ -12,7 +12,9 @@ prints one JSON object as the last line of its standard output.
 This process never imports JAX: the replica holds the chip. Everything
 that belongs to one configuration, one traffic mix or one per-layer metric
 is a file found by its name in BENCHMARK.json (``configs/``, ``traffic/``,
-``limits/``, ``layer_metrics/``); adding a cell adds files, not code.
+``limits/``, ``layer_metrics/``), and what knows the shape of a model's block
+by the configuration file's ``bench.family`` (``families/<family>/``, see
+``family.py``); adding a cell, of a new family too, adds files, not code.
 """
 
 from __future__ import annotations
@@ -40,6 +42,8 @@ DRAIN_S = 45.0        # after the window: answers still owed are waited for this
 STARTUP_S = 1100.0    # a first run compiles
 TRACE_S = 4.0         # seconds of the window a traced run records
 CHECK_SAMPLE = 4      # finished requests the reference follows, the longest among them
+POLL_S = 0.02         # the load generator looks for its answers this often
+STALL_S = 0.05        # a sleep of the generator that overruns by this much: the machine stood still
 PROGRAM_SEED_MOD = 2147483629  # the program's seeds are signed 32-bit
 
 
@@ -148,7 +152,11 @@ def drive_serve(spool: Path, job: subprocess.Popen, mix: dict, schedule: list,
                 seconds: float, warm: dict) -> dict:
     """Offer the schedule to the spool and collect the answers. One thread:
     it sleeps to the next due time, writes the request whole (temporary
-    name, then rename), and looks for new response files in between."""
+    name, then rename), and in between looks, by name, for the answers it
+    waits for. Not a scan of ``responses/``: the directory grows to some
+    hundreds of files while the server renames into it, and scanning it every
+    5 ms cost the saturated cell 0.3% of its rate and the harness half of its
+    CPU time (PERF.md, PR 26)."""
     requests_dir, responses_dir = spool / "requests", spool / "responses"
     deadline = time.time() + STARTUP_S
     while not requests_dir.is_dir():
@@ -157,6 +165,19 @@ def drive_serve(spool: Path, job: subprocess.Popen, mix: dict, schedule: list,
         time.sleep(0.05)
     answers: dict = {}
     seen_at: dict = {}
+    waiting: set = set()
+    stalls: list = []
+
+    def nap(limit: float) -> None:
+        """Sleep, and keep by how much the sleep overran where that is
+        ``STALL_S`` or more: a process that only sleeps sees every pause of
+        the whole machine, and the server stands still in the same pauses
+        (PERF.md, PR 26)."""
+        t = time.time()
+        time.sleep(limit)
+        over = time.time() - t - limit
+        if over >= STALL_S:
+            stalls.append(over)
 
     def send(rec: dict, submit_time: float) -> None:
         body = {"id": rec["id"], "prompt": rec["prompt"], "prompt_len": None,
@@ -165,20 +186,24 @@ def drive_serve(spool: Path, job: subprocess.Popen, mix: dict, schedule: list,
         tmp.write_text(json.dumps(body))
         os.rename(tmp, requests_dir / f"{rec['id']}.json")
         rec["submit_time"] = submit_time
+        waiting.add(rec["id"])
 
     def collect() -> None:
-        for entry in os.scandir(responses_dir):
-            rid = entry.name[:-5]
-            if entry.name.endswith(".json") and not entry.name.startswith(".") and rid not in answers:
-                answers[rid] = json.loads(Path(entry.path).read_text())
-                seen_at[rid] = time.time()
+        for rid in list(waiting):
+            try:
+                text = (responses_dir / f"{rid}.json").read_text()  # the server renames it into place whole
+            except FileNotFoundError:
+                continue
+            answers[rid] = json.loads(text)
+            seen_at[rid] = time.time()
+            waiting.discard(rid)
 
     def wait_for(ids, until: float) -> None:
         while time.time() < until and not all(i in answers for i in ids):
             if job.poll() is not None:
                 raise BenchFailure("the serving job ended before its answers were read")
             collect()
-            time.sleep(0.005)
+            time.sleep(POLL_S)
 
     # Warm-up: one request through both of the engine's programs.
     send(warm, time.time())
@@ -195,7 +220,7 @@ def drive_serve(spool: Path, job: subprocess.Popen, mix: dict, schedule: list,
             due = t0 + rec["due"]
             while time.time() < due:
                 collect()
-                time.sleep(min(0.005, max(0.0, due - time.time())))
+                nap(min(POLL_S, max(0.0, due - time.time())))
             send(rec, due)
             lateness.append(time.time() - due)
             sent.append(rec)
@@ -212,10 +237,10 @@ def drive_serve(spool: Path, job: subprocess.Popen, mix: dict, schedule: list,
                 in_flight.add(rec["id"])
             collect()
             in_flight -= set(answers)
-            time.sleep(0.005)
+            nap(POLL_S)
     wait_for([r["id"] for r in sent], end + DRAIN_S)
     return {"t0": t0, "end": end, "sent": sent, "answers": answers, "seen_at": seen_at,
-            "lateness": lateness}
+            "lateness": lateness, "stalls": stalls}
 
 
 def judge_answers(load: dict) -> dict:
@@ -233,13 +258,13 @@ def judge_answers(load: dict) -> dict:
 # ---- the check ----
 
 
-def run_reference(state: Path, env: dict, control: bool = False) -> dict:
+def run_reference(state: Path, env: dict, bench: Path, control: bool = False) -> dict:
     """The plain reference, in a process of its own once the program's
     replicas have ended (the chip is free, and ``memory_peak_bytes`` stays
     the program's)."""
     proc = subprocess.run(
         [sys.executable, "-m", "benchmark.reference_run", str(state / "check_in.json"),
-         str(state / "check_out.json"), *(["--control"] if control else [])],
+         str(state / "check_out.json"), "--bench", str(bench), *(["--control"] if control else [])],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=900,
     )
     if proc.returncode != 0:
@@ -303,7 +328,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     pseed = seed % PROGRAM_SEED_MOD
     trace_s = min(TRACE_S, seconds / 2) if trace else 0.0
     name = "bench"
-    args = ["--bench-config", spec["config_path"], "--bench-state", state,
+    args = ["--bench-config", spec["config_path"], "--bench-dir", bench, "--bench-state", state,
             "--bench-seconds", seconds, "--bench-trace-s", trace_s, *config["bench"]["args"]]
     load = None
     if role == "serve":
@@ -353,7 +378,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     replicas = [json.loads(p.read_text()) for p in sorted((state / "tpujob" / "replicas").glob("*.json"))]
     finals = [r for r in records if r.get("event") == "metrics"]
     ctx = {
-        "cell": cell, "config": config, "traffic": mix, "chips": chips, "seconds": seconds,
+        "cell": cell, "config": config, "traffic": mix, "bench": bench, "chips": chips, "seconds": seconds,
         "device": dev, "records": records, "reports": reports, "replicas": replicas, "t_submit": t_submit,
         "final": finals[-1] if finals else {},
     }
@@ -367,9 +392,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         ctx.update(answers=good, load=load)
         attempted, failed = len(load["sent"]), len(verdict["failed"])
         if load["lateness"]:
-            say(f"generator lateness ms: mean {1e3 * M.mean(load['lateness']):.3f} "
-                f"max {1e3 * max(load['lateness']):.3f} over {len(load['lateness'])} requests")
-        e2e["ttft_mean_ms"] = M.mean(a["ttft_ms"] for a in good)
+            say(f"generator lateness ms: mean {1e3 * M.mean(load['lateness']):.3f} max {1e3 * max(load['lateness']):.3f}, "
+                f"over 10 ms {sum(x > 0.01 for x in load['lateness'])} of {len(load['lateness'])} requests")
+        # A window in which the whole machine stood still reads as a slow server.
+        say(f"generator sleeps that overran by {1e3 * STALL_S:g} ms or more: {len(load['stalls'])}, "
+            f"{sum(load['stalls']):.3f} s in all, the longest {max(load['stalls'], default=0.0):.3f} s")
+        ttft = [a["ttft_ms"] for a in good]
+        e2e["ttft_mean_ms"] = M.mean(ttft)
+        say(f"time to first token ms over {len(ttft)} requests: mean {e2e['ttft_mean_ms']} "
+            f"p50 {M.percentile(ttft, 50)} p90 {M.percentile(ttft, 90)}")
         e2e["tpot_p50_ms"] = M.percentile([a["tpot_ms"] for a in good if a["tpot_ms"] is not None], 50)
         # Work done inside the window, credited from the engine's record of
         # each request; whole answers seen by its end go on an earlier line
@@ -398,14 +429,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     ctx["e2e"] = e2e
 
     (state / "check_in.json").write_text(json.dumps(check_in))
-    ref = run_reference(state, env, control)
+    ref = run_reference(state, env, bench, control)
     numbers = check_numbers(role, ref, ctx)
     if control:
         say("control " + json.dumps(control_numbers(role, ref)))
-    correct = compare(numbers, spec["limits"].get("limits", {})) and failed == 0
+    limits = spec["limits"].get("limits", {})
+    correct = compare(numbers, limits) and failed == 0
+    compared = {name: {"value": value, "limit": limits[name]["limit"]} for name, value in numbers.items()}
     if role == "serve":
         say(f"compared requests answered in full = {attempted - failed} of {attempted} limit {attempted} "
             f"{'ok' if failed == 0 else 'NOT CORRECT'}")
+        compared["requests_answered_in_full"] = {"value": attempted - failed, "limit": attempted}
 
     # ---- the result line ----
     group = "per_layer" if trace else "end_to_end"
@@ -433,6 +467,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         device["window_s"] = M.mean(t["window_s"] for t in traces)
         say(f"collective operations s per chip in the traced window: {M.mean(t['collective_s'] for t in traces)}")
         result["breakdown"] = {"device_ops": traces[0]["device_ops"], "idle_gaps": traces[0]["idle_gaps"]}
+    result["compared"] = compared  # last on the line: what a record of a run that is not correct keeps
     return result
 
 
@@ -513,6 +548,9 @@ def main(argv=None) -> int:
     if "jax" in sys.modules:
         print("benchmark: the harness imported JAX", file=sys.stderr)
         return 1
+    # Each number compared beside its limit: the last lines of standard error, and last in the result's line.
+    for name, c in result["compared"].items():
+        print(f"compared {name} = {c['value']} limit {c['limit']}", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -46,37 +46,38 @@ def trace_reduction(ctx) -> dict:
     return json.loads(kept.read_text())
 
 
-def host_gap_ms_per_block(ctx):
-    """Host seconds between a fence's return and the next dispatch, while the
-    engine had work, over the decode blocks run. Its segments, the cross-check
-    against the trace's idle time and the table of idle gaps by program span
-    go on earlier lines."""
+def host_gap_ms_per_s(ctx):
+    """Host milliseconds between a fence's return and the next dispatch, while
+    the engine had work, per second of the window (PR 25 let the engine choose
+    how many dispatches a run makes, so a quotient over them compares across no
+    PR). Its segments, the cross-check against the trace's idle time and the
+    table of idle gaps by program span go on earlier lines."""
     final = ctx.get("final", {})
-    gap, blocks = final.get("host_gap_s"), final.get("decode_blocks")
-    if gap is None or not blocks:
+    gap, seconds = final.get("host_gap_s"), ctx.get("seconds")
+    if gap is None or not seconds:
         return None
-    per_block = 1e3 * gap / blocks
+    per_s = 1e3 * gap / seconds
     # The gap's parts are the record's ``host_gap_<segment>_s``; its other ``host_<segment>_s`` are the
     # rest of the serving thread's time (blocked in a fence, dispatching, idle).
-    parts = {k[9:-2]: round(1e3 * v / blocks, 3) for k, v in final.items()
+    parts = {k[9:-2]: round(1e3 * v / seconds, 3) for k, v in final.items()
              if k.startswith("host_gap_") and k.endswith("_s") and k != "host_gap_s"}
-    rest = {k[5:-2]: round(1e3 * v / blocks, 3) for k, v in final.items()
+    rest = {k[5:-2]: round(1e3 * v / seconds, 3) for k, v in final.items()
             if k.startswith("host_") and k.endswith("_s") and not k.startswith("host_gap_")}
-    print(f"host gap ms per block over {blocks} blocks: {json.dumps(parts)}; the rest of the serving thread's time, "
-          f"ms per block: {json.dumps(rest)}", flush=True)
+    blocks = final.get("decode_blocks")
+    print(f"host gap ms per s of a {seconds:g} s window, {blocks} blocks: {json.dumps(parts)}; the rest of the serving "
+          f"thread's time, ms per s: {json.dumps(rest)}", flush=True)
     rounds, admitted = final.get("admit_rounds"), final.get("admitted")
-    if rounds and admitted:
+    if rounds and admitted and blocks:
         print(f"admissions: {admitted} in {rounds} rounds ({admitted / rounds:.3f} a round, {rounds / blocks:.3f} rounds "
               f"a block); prefill chunks {final.get('prefill_chunks', 0)} ({final.get('prefill_chunks', 0) / admitted:.3f} "
               f"an admission)", flush=True)
     red = trace_reduction(ctx)
     if red:
-        traced = red["spans_in_window"].get("engine.decode_fence", 0)
-        print(f"two clocks: host gap {per_block:.3f} ms x {traced} blocks in the traced window = "
-              f"{per_block * traced:.3f} ms; the trace's idle time {1e3 * (red['window_s'] - red['busy_s']):.3f} ms",
+        print(f"two clocks: host gap {per_s:.3f} ms/s x {red['window_s']:.3f} s of traced window = "
+              f"{per_s * red['window_s']:.3f} ms; the trace's idle time {1e3 * (red['window_s'] - red['busy_s']):.3f} ms",
               flush=True)
         print(span_reduce.table(red), flush=True)
-    return per_block
+    return per_s
 
 
 def scope_share_pct(*scopes):

@@ -4,7 +4,7 @@ Not part of a run: ``benchmark/run.py`` never imports this file.
 
     python benchmark/tools/measure.py sets   WORKLOAD SECONDS SEED [SEED ...]
     python benchmark/tools/measure.py limits WORKLOAD SECONDS CONTROLS SEED [SEED ...]
-    python benchmark/tools/measure.py sweep  WORKLOAD SECONDS RATE [RATE ...]
+    python benchmark/tools/measure.py sweep  WORKLOAD SECONDS SEED[,SEED...] RATE [RATE ...]
 
 ``sets``: the contract's two sets of runs of the committed command, the
 same seeds in both, each run a new process; for each metric the medians and
@@ -18,10 +18,12 @@ in the next precision down. A limit lies above the sound runs' largest and
 below the control's smallest.
 
 ``sweep``: an open-loop cell's knee, once: the cell at each arrival rate (a
-copy of its traffic file with another ``rate_per_s``), with the time to
-first token over the window's thirds and the backlog at its end. The
-highest rate whose thirds do not climb is the knee; the cell runs at four
-fifths of it, written into the traffic file with these lines.
+copy of its traffic file with another ``rate_per_s``), one window a seed,
+with the time to first token over the window's thirds and the backlog at
+its end. A window climbs where its last third's mean lies above both
+earlier thirds' and at least 1.3 times the first's; the knee is the highest
+rate up to which no window of any rate climbs. The cell runs at four fifths
+of it, written into the traffic file with these lines.
 """
 
 from __future__ import annotations
@@ -69,8 +71,11 @@ def sets(workload: str, seconds: str, seeds: list) -> None:
             res = json.loads(lines[-1])
             show(set=k, seed=seed, correct=res["correct"], attempted=res["attempted"], failed=res["failed"],
                  memory_peak_bytes=res["device"]["memory_peak_bytes"], **values(res),
-                 lines=[ln for ln in lines if ln.startswith(("compared", "generator", "answers seen"))])
+                 lines=[ln for ln in lines if ln.startswith(("compared", "generator", "answers seen", "time to first"))])
             rows.append(values(res))
+            answers = sorted((ROOT / ".benchrun" / workload / "spool" / "responses").glob("r*.json"))
+            if answers:  # a serving cell: each request's time to first token, as the run's spool still holds it
+                show(set=k, seed=seed, ttft_ms=[json.loads(p.read_text()).get("ttft_ms") for p in answers])
         per_set.append(rows)
     for name in sorted(per_set[0][0]) if per_set[0] else []:
         xs = [[r[name] for r in rows if name in r] for rows in per_set]
@@ -95,34 +100,47 @@ def limits(workload: str, seconds: str, controls: str, seeds: list) -> None:
         show(number=n, sound_largest=max(s), sound_all=s, control_smallest=min(c) if c else None, control_all=c)
 
 
-def sweep(workload: str, seconds: str, rates: list) -> None:
+def climbs(thirds) -> bool:
+    return thirds[2] > max(thirds[0], thirds[1]) and thirds[2] >= 1.3 * thirds[0]
+
+
+def sweep(workload: str, seconds: str, seeds: str, rates: list) -> None:
     copy = ROOT / ".benchrun" / "sweep-copy"
-    for k, rate in enumerate(rates):
+    climbed = {}
+    for rate in rates:
         shutil.rmtree(copy, ignore_errors=True)
         shutil.copytree(ROOT / "benchmark", copy / "benchmark", ignore=shutil.ignore_patterns("__pycache__"))
         shutil.copy(ROOT / "BENCHMARK.json", copy / "BENCHMARK.json")
         cell = run.load_cell(workload, copy / "benchmark")
         mix_path = copy / "benchmark" / "traffic" / f"{cell['cell']['traffic']}.json"
         mix_path.write_text(json.dumps({**cell["traffic"], "rate_per_s": float(rate)}))
-        seen = {}
-        judge = run.judge_answers
-        run.judge_answers = lambda load: seen.update(load=load) or judge(load)
-        try:
-            res = run.run_cell(workload, 9000 + k, float(seconds), False, bench=copy / "benchmark")
-        finally:
-            run.judge_answers = judge
-        load = seen["load"]
-        rows = sorted((r["due"], load["answers"][r["id"]]) for r in load["sent"] if r["id"] in load["answers"])
-        third = len(rows) // 3 or 1
-        show(rate_per_s=float(rate), requests=len(load["sent"]),
-             ttft_mean_ms_by_third=[M.mean(a["ttft_ms"] for _, a in rows[i * third:(i + 1) * third]) for i in range(3)],
-             unanswered_at_window_end=sum(1 for r in load["sent"] if load["seen_at"].get(r["id"], 1e18) > load["end"]),
-             correct=res["correct"], failed=res["failed"], **values(res))
+        for seed in seeds.split(","):
+            seen = {}
+            judge = run.judge_answers
+            run.judge_answers = lambda load: seen.update(load=load) or judge(load)
+            try:
+                res = run.run_cell(workload, int(seed), float(seconds), False, bench=copy / "benchmark")
+            except run.BenchFailure as e:
+                print(f"rate {rate} seed {seed}: no result: {e}", flush=True)
+                continue
+            finally:
+                run.judge_answers = judge
+            load = seen["load"]
+            rows = sorted((r["due"], load["answers"][r["id"]]) for r in load["sent"] if r["id"] in load["answers"])
+            third = len(rows) // 3 or 1
+            thirds = [M.mean(a["ttft_ms"] for _, a in rows[i * third:(i + 1) * third]) for i in range(3)]
+            climbed[float(rate)] = climbed.get(float(rate), False) or climbs(thirds)
+            show(rate_per_s=float(rate), seed=seed, requests=len(load["sent"]), ttft_mean_ms_by_third=thirds,
+                 climbs=climbs(thirds), ttft_mean_ms=M.mean(a["ttft_ms"] for _, a in rows),
+                 unanswered_at_window_end=sum(1 for r in load["sent"] if load["seen_at"].get(r["id"], 1e18) > load["end"]),
+                 stalls_s=sum(load["stalls"]), correct=res["correct"], failed=res["failed"], **values(res))
+    flat = [r for r in sorted(climbed) if not any(climbed[q] for q in climbed if q <= r)]
+    show(knee=max(flat) if flat else None, climbed=climbed)
 
 
 def main(argv) -> int:
     modes = {"sets": lambda a: sets(a[0], a[1], a[2:]), "limits": lambda a: limits(a[0], a[1], a[2], a[3:]),
-             "sweep": lambda a: sweep(a[0], a[1], a[2:])}
+             "sweep": lambda a: sweep(a[0], a[1], a[2], a[3:])}
     if len(argv) < 4 or argv[0] not in modes:
         print(__doc__, file=sys.stderr)
         return 2

@@ -8,7 +8,11 @@ file — as one cycle, which the seed enters at another point. So runs differ
 by where the cycle starts and by token values, never by how much there is
 to do or by which long prompt meets which burst: on a server that admits
 in lumps, that pairing alone moved the mean time to first token by a third
-(PERF.md, PR 23).
+(PERF.md, PR 23). A file whose ``cycle_entry`` is a number has every seed
+enter the cycle at that row, and the seed chooses token values alone: for a
+mix whose repeats of one seed lie together and whose seeds lie apart, which
+requests meet the empty engine at the window's start being what differs
+(PERF.md, PR 26).
 """
 
 from __future__ import annotations
@@ -28,8 +32,9 @@ def load(name: str, traffic_dir: Path = TRAFFIC_DIR) -> dict:
     return json.loads(path.read_text())
 
 
-def rotate(items: list, seed: int) -> list:
-    k = seed % len(items)
+def rotate(items: list, seed: int, mix: dict) -> list:
+    entry = mix.get("cycle_entry", "seed")
+    k = (seed if entry == "seed" else int(entry)) % len(items)
     return items[k:] + items[:k]
 
 
@@ -37,7 +42,7 @@ def lengths_in_order(mix: dict, seed: int, count: int) -> list:
     """``count`` (prompt, answer) pairs: the table's first ``count`` entries
     (cycled where the table is shorter), entered at the seed's point."""
     table = [tuple(p) for p in mix["lengths"]]
-    return rotate([table[i % len(table)] for i in range(count)], seed)
+    return rotate([table[i % len(table)] for i in range(count)], seed, mix)
 
 
 def arrival_times(mix: dict, seed: int, seconds: float) -> list:
@@ -51,7 +56,7 @@ def arrival_times(mix: dict, seed: int, seconds: float) -> list:
     scale = seconds / (sum(gaps) + 1.0)  # one mean gap's room: the last arrival lies before the end
     random.Random(0).shuffle(gaps)  # the one fixed order of every run
     times, t = [], 0.0
-    for g in rotate(gaps, seed):
+    for g in rotate(gaps, seed, mix):
         t += g * scale
         times.append(t)
     return times

@@ -1,6 +1,9 @@
 """Tiny made-up cells for the benchmark's tests, added the way a later PR
 adds a cell: new files beside the benchmark's and new entries in the
-manifest, in a temporary copy, with no file of the benchmark edited.
+manifest, in a temporary copy, with no file of the benchmark edited. Two of
+them are of a second, made-up family (``data/families/tiny-moe``: a layer of
+experts, the trainer's ``MoEMLP``, standing on no other family), which comes as files too: the
+rehearsal of a PR that brings a model of another shape.
 
 As a program (``python -m tests.zz_benchmark.benchcells COPY CELL SECONDS
 [ENTRY_MODULE]``) it drives one run of such a cell on the CPU — the whole
@@ -16,6 +19,10 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).resolve().parent / "data" / "cells"
+FAMILIES = Path(__file__).resolve().parent / "data" / "families"
+
+MOE_LIMITS = {"loss_gap_step1": 0.01, "loss_gap_step2": 0.01, "loss_gap_step3": 0.01,
+              "grad_norm_gap_worst_leaf": 0.02, "delta_norm_gap_worst_leaf": 0.1}
 
 # name -> (configuration, traffic mix, the real cell whose metrics it reports, limits)
 CELLS = {
@@ -24,6 +31,9 @@ CELLS = {
     "tiny-pre": ("tiny-train", "tiny-pretrain", "train-mistral7b-1chip", {
         "loss_gap_step1": 0.01, "loss_gap_step2": 0.01, "loss_gap_step3": 0.01,
         "grad_norm_gap_worst_leaf": 0.004, "delta_norm_gap_worst_leaf": 0.1}),
+    "tiny-moe": ("tiny-moe-train", "tiny-pretrain", "train-mistral7b-1chip", MOE_LIMITS),
+    # The same program beside a reference whose router keeps one expert a token.
+    "tiny-top1": ("tiny-moe-top1", "tiny-pretrain", "train-mistral7b-1chip", MOE_LIMITS),
 }
 
 
@@ -34,6 +44,8 @@ def make_copy(copy: Path, cells=CELLS, suffix: str = "") -> Path:
     shutil.copytree(ROOT / "benchmark", bench, ignore=shutil.ignore_patterns("__pycache__"))
     manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
     before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    for family in FAMILIES.iterdir():
+        shutil.copytree(family, bench / "families" / family.name, ignore=shutil.ignore_patterns("__pycache__"))
     for name, (config, mix, like, limits) in cells.items():
         name += suffix
         for kind, item, folder in (("config", config, "configs"), ("traffic", mix, "traffic")):

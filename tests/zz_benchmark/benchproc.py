@@ -52,8 +52,8 @@ def tiny_cell(tmp_path, cell, seconds=3.0, module=None, timeout=300, core=-1):
 
 
 def control(kind, core):
-    """The tiny cell's control (the reference in the next precision down),
-    on one large seed."""
+    """One reading of ``controls.py`` (the tiny cell's control, the reference
+    in the next precision down; or the drivers' contract), on one large seed."""
     rc, out = run(["-m", "tests.zz_benchmark.controls", kind, str(2**31 + 5)], timeout=240,
                   env={"JAX_PLATFORMS": "cpu"}, core=core)
     assert rc == 0, out[-2000:]

@@ -54,7 +54,7 @@ def test_names_units_and_keys(group):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cells_files_are_found_by_name(cell):
-    from benchmark import run
+    from benchmark import family, run
 
     spec = run.load_cell(cell)
     w = spec["cell"]
@@ -66,6 +66,8 @@ def test_every_cells_files_are_found_by_name(cell):
     assert sorted(entry["reduced"]) == sorted(cfg["reduced"]) and entry["source"] == cfg["source"]
     assert str(entry["file"]).startswith(tuple(MANIFEST["paths"]))
     assert spec["limits"].get("limits"), "the cell's limits file is missing or empty"
+    family_dir = ROOT / "benchmark" / "families" / cfg["bench"]["family"]
+    assert all((family_dir / f"{part}.py").is_file() for part in family.PARTS), f"{family_dir}: not a family"
     reported = [m for m in run.metrics_of(MANIFEST, "end_to_end", cell)]
     assert "setup_s" in [m["name"] for m in reported] and len(reported) >= 2
     layer = run.metrics_of(MANIFEST, "per_layer", cell)

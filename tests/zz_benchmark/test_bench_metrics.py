@@ -8,7 +8,10 @@ import json
 import numpy as np
 import pytest
 
+from benchmark import family
 from benchmark import metrics as M
+
+F = family.load("llama", "flops")
 
 TINY = dict(hidden_size=8, intermediate_size=16, num_hidden_layers=2, num_attention_heads=2,
             num_key_value_heads=1, head_dim=4, vocab_size=32)
@@ -57,11 +60,11 @@ def test_tokens_processed_moves_smoothly_and_sums_requests():
 def test_flops_leave_out_the_input_embedding_hand_count():
     # By hand: q 8*2*4=64, k and v 8*1*4=32 each, o 8*8=64, feed-forward 3*8*16=384 -> 576 a layer;
     # two layers 1152; the output head 8*32=256. The input embedding (256 more) is a lookup.
-    assert M.matmul_params(TINY) == 1152 + 256
+    assert F.matmul_params(TINY) == 1152 + 256
     # Causal attention at S=10: 6 * L * S * (H*hd) = 6*2*10*8 = 960.
-    assert M.train_flops_per_token(TINY, 10) == 6 * 1408 + 960
+    assert F.train_flops_per_token(TINY, 10) == 6 * 1408 + 960
     all_params = 1408 + 32 * 8 + 2 * 2 * 8 + 8  # + embedding + norm scales
-    assert M.train_flops_per_token(TINY, 10) < 6 * all_params + 960  # bench.py's 6*N over everything
+    assert F.train_flops_per_token(TINY, 10) < 6 * all_params + 960  # bench.py's 6*N over everything
 
 
 def test_peaks_table_known_and_unknown(tmp_path):
@@ -71,12 +74,12 @@ def test_peaks_table_known_and_unknown(tmp_path):
     with pytest.raises(KeyError, match="no published peaks"):
         M.peaks("cpu")
     with pytest.raises(KeyError):
-        M.mfu_pct(TINY, 10, 100.0, "TPU v9 imaginary")
+        M.mfu_pct(F.train_flops_per_token(TINY, 10), 100.0, "TPU v9 imaginary")
 
 
 def test_mfu_is_a_share_of_the_peak():
     mistral8 = dict(hidden_size=4096, intermediate_size=14336, num_hidden_layers=8, num_attention_heads=32,
                     num_key_value_heads=8, vocab_size=32000)
     # 1.876 B matrix parameters; 12.06 GFLOP a token at 4096; 10,041 tokens/s is 61.5% of 197 TFLOP/s.
-    assert M.matmul_params(mistral8) == 1_875_902_464
-    assert M.mfu_pct(mistral8, 4096, 10041.0, "TPU v5 lite") == pytest.approx(61.47, abs=0.05)
+    assert F.matmul_params(mistral8) == 1_875_902_464
+    assert M.mfu_pct(F.train_flops_per_token(mistral8, 4096), 10041.0, "TPU v5 lite") == pytest.approx(61.47, abs=0.05)

@@ -25,7 +25,10 @@ def test_made_up_open_loop_cell_runs_and_is_correct(tmp_path):
     assert set(res["metrics"]) == {"ttft_mean_ms", "tpot_p50_ms", "setup_s"}
     assert all(v["value"] > 0 for v in res["metrics"].values())
     assert res["device"]["platform"] == "cpu" and res["device"]["count"] == 1
-    assert "compared served_logit_gap_max" in out and "generator lateness" in out
+    # Each number compared stands beside its limit, last on the line.
+    assert list(res)[-1] == "compared" and res["compared"]["served_logit_gap_max"]["limit"] == 0.05
+    assert res["compared"]["requests_answered_in_full"] == {"value": 12, "limit": 12}
+    assert "compared served_logit_gap_max" in out and "generator lateness" in out and "generator sleeps that overran" in out
 
 
 def test_altered_tokens_are_not_correct_and_the_closed_loop_reports_tokens_per_s(tmp_path):

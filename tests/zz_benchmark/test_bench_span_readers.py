@@ -18,7 +18,7 @@ DATA = Path(__file__).resolve().parent / "data"
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
 NEW = ["claim_wait_mean_ms", "slot_wait_mean_ms", "prefill_mean_ms", "slot_occupancy_pct.tpot",
        "slot_occupancy_pct.serve_tps", "decode_yield_pct.tpot", "decode_yield_pct.serve_tps",
-       "host_gap_ms_per_block.ttft", "host_gap_ms_per_block.serve_tps", "attn_share_pct.train",
+       "host_gap_ms_per_s.ttft", "host_gap_ms_per_s.serve_tps", "attn_share_pct.train",
        "mlp_share_pct.train", "head_share_pct.tpot", "prefill_pad_pct.ttft", "prefill_pad_pct.serve_tps"]
 DECLARED = [m["name"] for m in MANIFEST["per_layer"] if m["name"] in NEW]
 
@@ -76,15 +76,15 @@ def test_the_chat_cells_readers_on_a_recorded_context(with_trace, capsys):
         100 * final["slot_blocks_occupied"] / (final["decode_blocks"] * final["slots"]), abs=1e-3)
     assert read("decode_yield_pct.tpot") == pytest.approx(
         100 * final["decode_tokens"] / final["decode_row_steps"], abs=1e-3)
-    gap = read("host_gap_ms_per_block.ttft")
-    assert gap == pytest.approx(1e3 * final["host_gap_s"] / final["decode_blocks"])
+    gap = read("host_gap_ms_per_s.ttft")
+    assert gap == pytest.approx(1e3 * final["host_gap_s"] / ctx["seconds"])
     out = capsys.readouterr().out
-    assert "host gap ms per block over" in out and "two clocks:" in out and "idle gaps >= 0.5 ms:" in out
+    assert "host gap ms per s of a" in out and "two clocks:" in out and "idle gaps >= 0.5 ms:" in out
     # The parts printed are the record's own ``host_gap_<segment>_s``, and sum to the whole.
     printed = json.loads(out.split("blocks: ", 1)[1].split("; the rest", 1)[0])
     assert len(printed) >= 6 and sum(printed.values()) == pytest.approx(gap, abs=0.01)
     assert sum(final[f"host_gap_{k}_s"] for k in printed) == pytest.approx(final["host_gap_s"], abs=1e-9)
-    rest = json.loads(out.split("ms per block: ", 1)[1].splitlines()[0])
+    rest = json.loads(out.split("time, ms per s: ", 1)[1].splitlines()[0])
     assert {"first_token", "decode_fence", "dispatch", "idle"} <= set(rest) and not set(rest) & set(printed)
     # The admission and prefill counters are read on the line after it.
     assert f"admissions: {final['admitted']} in {final['admit_rounds']} rounds" in out
@@ -103,7 +103,7 @@ def test_the_longprompt_cells_readers_on_a_recorded_context():
     final = ctx["final"]
     assert read("slot_occupancy_pct.serve_tps") == final["slot_occupancy_pct"]
     assert read("decode_yield_pct.serve_tps") == final["decode_yield_pct"] and 0 < final["decode_yield_pct"] < 100
-    assert read("host_gap_ms_per_block.serve_tps") == pytest.approx(1e3 * final["host_gap_s"] / final["decode_blocks"])
+    assert read("host_gap_ms_per_s.serve_tps") == pytest.approx(1e3 * final["host_gap_s"] / ctx["seconds"])
     assert read("prefill_pad_pct.serve_tps") == final["prefill_pad_pct"] and 0 < final["prefill_pad_pct"] < 10
 
 

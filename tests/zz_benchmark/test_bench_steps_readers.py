@@ -29,7 +29,9 @@ def test_declared_beside_the_yield_of_the_same_cell(name):
         "ttft": "claim_wait_mean_ms", "serve_tps": "decode_yield_pct.serve_tps"}[suffix]]
     assert entry["workloads"] == [CELL_OF[name]] == beside["workloads"]
     assert (entry["moves"], entry["layer"], entry["source"]) == (beside["moves"], beside["layer"], "program_counter")
-    assert MANIFEST["per_layer"].index(entry) >= len(MANIFEST["per_layer"]) - 2  # appended, nothing moved
+    # Appended by PR 25, and nothing moved since: only what later PRs appended follows (PR 26: the load generator's).
+    later = [m["name"] for m in MANIFEST["per_layer"][MANIFEST["per_layer"].index(entry) + 1:]]
+    assert all(n in CELL_OF or n.startswith("generator_") for n in later), later
 
 
 @pytest.mark.parametrize("name", sorted(CELL_OF))
@@ -61,3 +63,21 @@ def test_reads_the_engines_own_final_record(name, final_record):
     got = run.read_layer_metric(name, {"cell": {"name": "a-cell"}, "final": final_record})
     assert got == pytest.approx(final_record["decode_steps"] / final_record["decode_blocks"], abs=1e-3)
     assert 1 <= got <= 32 and final_record["decode_row_steps"] <= 2 * final_record["decode_steps"]
+
+
+def test_generator_lateness_is_the_mean_of_the_open_loops_own_list(capsys):
+    """``generator_late_ms.ttft`` (PR 26): sent - due of each request, as ``drive_serve`` keeps it."""
+    late = [0.001, 0.002, 0.0015, 0.0305]
+    assert run.read_layer_metric("generator_late_ms.ttft", {"load": {"lateness": late}}) == pytest.approx(8.75)
+    assert capsys.readouterr().out == ""  # the maximum is on run.py's own line, once
+    assert run.read_layer_metric("generator_late_ms.ttft", {"load": {"lateness": []}}) is None  # a closed loop has no due time
+    assert run.read_layer_metric("generator_late_ms.ttft", {}) is None
+
+
+@pytest.mark.parametrize("name", ["generator_stall_ms_per_s.ttft", "generator_stall_ms_per_s.serve_tps"])
+def test_generator_stalls_are_the_overruns_per_second_of_window(name):
+    """PR 26: a sleep of the generator that overran is a pause of the whole machine."""
+    ctx = {"seconds": 50.0, "load": {"stalls": [0.115, 0.735, 0.12]}}
+    assert run.read_layer_metric(name, ctx) == pytest.approx(19.4)
+    assert run.read_layer_metric(name, {"seconds": 50.0, "load": {"stalls": []}}) == 0.0  # a window without a pause
+    assert run.read_layer_metric(name, {"seconds": 50.0}) is None  # a training cell has no generator

@@ -71,18 +71,27 @@ def test_chat_file_records_its_sweep():
     assert any(abs(row["rate_per_s"] * 0.8 - mix["rate_per_s"]) < 0.26 for row in mix["swept"] if row.get("knee"))
 
 
-def test_the_seed_enters_one_fixed_cycle():
+def _cycle(mix, seed):
+    s = T.schedule(mix, seed, 50.0, 100)
+    due = [r["due"] for r in s]
+    gaps = [round(b - a, 9) for a, b in zip([0.0] + due[:-1], due)]
+    return list(zip(gaps, [(r["prompt_len"], r["max_new_tokens"]) for r in s])), [r["prompt"] for r in s]
+
+
+@pytest.mark.parametrize("entry", ["seed", 0, 7])
+def test_the_seed_enters_one_fixed_cycle(entry):
     """Which length meets which gap never changes: the seed only moves the
-    point at which the cycle is entered."""
-    mix = T.load("chat-poisson")
+    point at which the cycle is entered, or, in a file whose ``cycle_entry``
+    is a number, nothing but the token values."""
+    mix = {**T.load("chat-poisson"), "cycle_entry": entry}
     n = round(mix["rate_per_s"] * 50.0)
+    base, _ = _cycle({**mix, "cycle_entry": 0}, 0)
+    (a, tokens_a), (b, tokens_b) = _cycle(mix, 0), _cycle(mix, 2**31 + 12345)
+    k = (2**31 + 12345) % n if entry == "seed" else entry
+    assert len(a) == n and b == base[k:] + base[:k] and tokens_a != tokens_b
+    assert (b != a) == (entry == "seed")
 
-    def cycle(seed):
-        s = T.schedule(mix, seed, 50.0, 100)
-        due = [r["due"] for r in s]
-        gaps = [round(b - a, 9) for a, b in zip([0.0] + due[:-1], due)]
-        return list(zip(gaps, [(r["prompt_len"], r["max_new_tokens"]) for r in s]))
 
-    a, b = cycle(0), cycle(2**31 + 12345)
-    k = (2**31 + 12345) % n
-    assert len(a) == n == 240 and b == a[k:] + a[:k] and b != a
+def test_the_chat_cell_enters_its_cycle_at_one_row():
+    """PR 26: its seeds lay apart and its repeats together (PERF.md), so the seed chooses token values alone."""
+    assert T.load("chat-poisson")["cycle_entry"] == 0 and "cycle_entry" not in T.load("longprompt-closed")
