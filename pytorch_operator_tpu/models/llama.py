@@ -196,6 +196,10 @@ class LlamaConfig:
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
+    def serving_model(self):
+        """What the serving engine talks to (models/serving.py)."""
+        return serving_model(self)
+
 
 def llama3_8b(**over) -> LlamaConfig:
     """The real Llama-3-8B shape (BASELINE.json:10 target workload).
@@ -995,6 +999,56 @@ def decode_forward(
         w = Llama.head_kernel(p)
         logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
     return logits, new_cache
+
+
+def serving_model(cfg: LlamaConfig):
+    """This family behind ``models.serving.ServingModel``: a per-row decode
+    model and a chunked-prefill (``prefill_mode="cache"``) model over one
+    :func:`init_decode_cache`, both through :func:`decode_forward`; the
+    seeded init is the training model's (float32, the whole tree in one
+    program: ``workloads.generate.load_params`` quantises or commits it)."""
+    from .serving import ServingModel
+
+    if not cfg.decode:
+        raise ValueError("serving needs a decode=True config")
+    cfg = dataclasses.replace(cfg, decode_per_row=False, prefill_mode="self")
+    decode_model = Llama(dataclasses.replace(cfg, decode_per_row=True))
+    prefill_model = Llama(dataclasses.replace(cfg, prefill_mode="cache"))
+    train_cfg = dataclasses.replace(cfg, decode=False, quantize=None)
+
+    def init_params(key):
+        # Looked up at call time: a caller that brings its own seeded
+        # leaves (the benchmark) wraps ``Llama.init``.
+        return nn.meta.unbox(
+            jax.jit(
+                lambda k: Llama(train_cfg).init(k, jnp.zeros((1, 8), jnp.int32))["params"]
+            )(key)
+        )
+
+    def prefill(params, row, tokens, positions):
+        hidden, row = decode_forward(
+            prefill_model, params, row, tokens, positions, return_hidden=True
+        )
+        return hidden, row, {}
+
+    def decode(params, cache, tok, pos):
+        logits, cache = decode_forward(
+            decode_model, params, cache, tok, pos, return_hidden=False
+        )
+        return logits[:, -1], cache, {}
+
+    def logits(params, hidden):
+        w = Llama.head_kernel(params)
+        return hidden.astype(jnp.float32) @ w.astype(jnp.float32)
+
+    return ServingModel(
+        cfg=cfg,
+        init_params=init_params,
+        init_cache=lambda slots, chunk: init_decode_cache(cfg, slots),
+        prefill=prefill,
+        decode=decode,
+        logits=logits,
+    )
 
 
 def forward_pp(

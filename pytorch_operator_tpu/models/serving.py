@@ -1,0 +1,73 @@
+"""What the serving stack asks of a model, and the presets by name.
+
+``serving/engine.py``, ``workloads/serve.py`` and
+``workloads/generate.load_params`` talk to a model through
+:class:`ServingModel` alone: its config, its seeded init, its cache
+constructor and its two forwards. A config class states its family by
+having ``serving_model()`` (``models.llama.LlamaConfig``,
+``models.mimo_v2.MiMoV2Config``); a job's ``--config`` names a preset, and
+:func:`preset` finds the family that has it. Nothing else selects a path.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable
+
+
+def _nothing(*_):
+    return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class ServingModel:
+    """A model as the engine sees it. ``cache`` is the model's own state
+    object: any pytree whose every leaf leads with the slot axis (the
+    engine slices one slot's row out for a prefill chunk and writes it
+    back; what the leaves are is the model's business). ``counts`` are
+    the model's own device counters at zero (a pytree of int32 arrays, ``{}``
+    for none): each forward returns what one call adds, the engine's two
+    programs sum them on the device, and ``ServingEngine.stats()`` brings
+    them back."""
+
+    cfg: Any  # vocab_size, max_decode_len, decode
+    # key -> the serving parameter tree, on the device in the serving dtype.
+    init_params: Callable
+    # (slots, chunk) -> cache.
+    init_cache: Callable
+    # (params, one slot's row of the cache, tokens [1, chunk], positions
+    # [1, chunk]) -> (final-norm hidden [1, chunk, D], row, counts).
+    prefill: Callable
+    # (params, cache, tokens [slots, 1], positions [slots, 1]), every row
+    # at its own position -> (float32 logits [slots, V], cache, counts).
+    decode: Callable
+    # (params, hidden [n, D]) -> float32 logits [n, V].
+    logits: Callable
+    counts: Any = dataclasses.field(default_factory=dict)
+    # cache -> {gauge: number}, read once (shapes, not values).
+    gauges: Callable = _nothing
+    # {counter: host total} -> {derived statistic: number}.
+    derive: Callable = _nothing
+
+
+def families() -> dict:
+    """``preset name -> (model module, name of the function that makes its
+    config)``, read at call time: a family's table is a module dict that a
+    caller may add a preset to (the benchmark's ``bench``)."""
+    from ..workloads.llama_train import CONFIGS as llama_presets
+    from . import llama, mimo_v2
+
+    return {
+        **{name: (llama, fn) for name, fn in llama_presets.items()},
+        **{name: (mimo_v2, fn) for name, fn in mimo_v2.CONFIGS.items()},
+    }
+
+
+def preset(name: str, **over):
+    """The config of preset ``name`` with ``over`` (the server's
+    ``decode=True``, ``max_decode_len`` ...)."""
+    table = families()
+    if name not in table:
+        raise ValueError(f"no preset {name!r} (has {sorted(table)})")
+    module, fn = table[name]
+    return getattr(module, fn)(**over)

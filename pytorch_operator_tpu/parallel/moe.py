@@ -303,3 +303,80 @@ def moe_mlp(
         in_specs=(weight_spec, tok_spec, tok_spec),
         out_specs=tok_spec,
     )({"w_in": params["w_in"], "w_out": params["w_out"]}, gates, x)
+
+
+# ---- a chip's share of a sigmoid-routed SwiGLU expert layer ----
+#
+# What expert parallelism asks of one chip, without its exchange: the
+# router keeps its published width (every expert of the layer), the chip
+# holds the weights of ``experts_held = (first id, count)`` and computes
+# their part of each token's result. Exact: no capacity, no dropped token,
+# whatever the imbalance. The parts of all the shares add up to the whole
+# layer (tests/test_mimo_v2.py pins that against the uncut reference).
+
+
+def route_sigmoid_topk(router, e_bias, x, top_k: int):
+    """Sigmoid scores over every expert of the layer, the ``top_k`` picked
+    by score + ``e_bias`` (the bias selects and does not weigh), weights
+    the picked scores renormalised to sum to one. ``router [D, E]``,
+    ``e_bias [E]`` float32, ``x [N, D]``; scores in float32.
+    Returns (idx [N, top_k] int32, weights [N, top_k] float32)."""
+    import jax
+    import jax.numpy as jnp
+
+    scores = jax.nn.sigmoid(
+        jnp.dot(x, router, preferred_element_type=jnp.float32)
+    )
+    _, idx = jax.lax.top_k(scores + e_bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+def moe_swiglu_held(params, x, *, top_k: int, experts_held: tuple[int, int]):
+    """This chip's part of the expert layer for ``x [N, D]``: routing over
+    all ``router_width`` experts, the sum over the selected experts that
+    lie in ``experts_held``; the weights are renormalised over all
+    ``top_k`` selected, held or not, and a token none of whose experts is
+    held gets zero.
+
+    ``params``: ``router [D, E]``, ``e_bias [E]``, and the held experts'
+    ``w_gate``/``w_up [n, D, F]``, ``w_down [n, F, D]``.
+
+    Every held expert runs over all N tokens with the others' gates at
+    zero, and the gates are folded in before the one down-projection that
+    contracts over (expert, F). At the sizes the engine calls it with (64
+    rows a decode step, 128 a prefill chunk, 16 experts of 3 x 4096 x 2048)
+    the layer is bound by reading its weights, which this reads once each:
+    PERF.md section 6 (PR 28) has the chip's numbers beside a ragged
+    grouped product's.
+
+    Returns ``(y [N, D], counts)``; ``counts`` are int32 sums over this
+    call: ``moe_tokens`` (N), ``moe_local_pairs`` (selected experts that
+    were held), ``moe_expert_tokens [n]`` (tokens each held expert got) and
+    ``moe_experts_touched`` (held experts that got any)."""
+    import jax
+    import jax.numpy as jnp
+
+    first, n = experts_held
+    if params["w_gate"].shape[0] != n:
+        raise ValueError(
+            f"experts_held {experts_held} but weights of "
+            f"{params['w_gate'].shape[0]} experts"
+        )
+    with jax.named_scope("moe_router"):
+        idx, w = route_sigmoid_topk(params["router"], params["e_bias"], x, top_k)
+        local = idx - first  # [N, k]; held where 0 <= local < n
+        onehot = local[:, :, None] == jnp.arange(n)[None, None, :]  # [N, k, n]
+        gates = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1)  # [N, n]
+        per_expert = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)
+        counts = {
+            "moe_tokens": jnp.int32(x.shape[0]),
+            "moe_local_pairs": jnp.sum(per_expert),
+            "moe_expert_tokens": per_expert,
+            "moe_experts_touched": jnp.sum(per_expert > 0, dtype=jnp.int32),
+        }
+    h = jax.nn.silu(
+        jnp.einsum("nd,edf->nef", x, params["w_gate"])
+    ) * jnp.einsum("nd,edf->nef", x, params["w_up"])
+    h = h * gates.astype(h.dtype)[:, :, None]
+    return jnp.einsum("nef,efd->nd", h, params["w_down"]), counts

@@ -8,10 +8,13 @@ request at its own depth, refilled the moment its occupant finishes.
 
 TPU-first shape (everything static):
 
+- The model is whatever ``cfg.serving_model()`` returns
+  (models/serving.py: its cache constructor, its two forwards, its own
+  device counters); the engine names no model family.
 - ONE decode program: ``decode_block`` loops ``steps`` single-token
   steps (a traced count, so every length is the same compiled program)
-  over the full [slots] batch through a ``decode_per_row=True`` model
-  (models/llama.py) — every row at its own position, finished/empty
+  over the full [slots] batch through the model's per-row decode
+  forward — every row at its own position, finished/empty
   rows parked (they re-write their own slot, masked from every live
   stream by the col <= row validity mask). Everything the engine
   decides (admit, harvest, and the serve loop's poll and responses)
@@ -22,8 +25,8 @@ TPU-first shape (everything static):
   last row's budget, never more than ``block``, the most steps one
   dispatch may run. The counters below say how often each rule sized a
   dispatch (``BENCHMARK.json``'s chat and longprompt cells judge it).
-- ONE prefill program: fixed-size chunks through a
-  ``prefill_mode="cache"`` model (chunked prefill), last chunk padded
+- ONE prefill program: fixed-size chunks through the model's
+  chunked-prefill forward over one slot's row of the cache, last chunk padded
   — the pad tokens write cache slots past the prompt that every later
   read either masks (col <= row) or overwrites (the next decode token
   lands exactly on the first padded slot before anything attends it).
@@ -55,8 +58,10 @@ lists every name beside the metric that reads it):
   ``decode`` from the engine's own timestamps;
 - counters (integers and ``perf_counter`` sums, O(1) per iteration):
   blocks (dispatches), their steps and what sized them, occupied rows,
-  row-steps and accepted tokens (slot occupancy, decode yield), prefill
-  chunks and pad tokens, admissions, and one clock that charges every
+  row-steps and accepted tokens (slot occupancy, decode yield), the cache
+  positions those tokens had live, prefill chunks and pad tokens,
+  admissions, the model's own counters (summed on the device inside the
+  two programs, brought back at ``stats()``), and one clock that charges every
   second of the serving thread to a segment
   (:data:`GAP_SEGMENTS` while the device waits for the host,
   :data:`FENCE_SEGMENTS` while the host waits for the device,
@@ -99,6 +104,7 @@ def host_key(segment: str) -> str:
 SIZED_BY = ("budget", "quantum", "ceiling")
 _COUNTERS = (
     "decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
+    "decode_live_positions",
     *(f"decode_sized_by_{reason}" for reason in SIZED_BY),
     "prefill_chunks", "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "admitted",
 )
@@ -176,11 +182,12 @@ class _Slot:
 
 
 class ServingEngine:
-    """Slot-based continuous batching over the llama decode stack.
+    """Slot-based continuous batching over a model's decode stack.
 
-    ``cfg`` must be a decode config (``decode=True``); ``params`` may be
-    a quantized tree (ops/quantize.py). The engine builds its own
-    per-row decode and chunked-prefill model variants from ``cfg``.
+    ``cfg`` must be a decode config (``decode=True``) of a family that
+    serves (``cfg.serving_model()``, models/serving.py); ``params`` is that
+    model's parameter tree (for the llama family possibly a quantized one,
+    ops/quantize.py).
     """
 
     def __init__(
@@ -202,12 +209,11 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models import llama as llama_lib
-        from ..models.llama import decode_forward, init_decode_cache
         from ..ops.sampling import make_sampler, validate_sampling
 
         if not cfg.decode:
             raise ValueError("ServingEngine needs a decode=True config")
+        model = cfg.serving_model()
         if chunk < 1 or block < 1 or slots < 1:
             raise ValueError("slots, chunk and block must be >= 1")
         if cfg.max_decode_len < chunk + 1:
@@ -216,9 +222,8 @@ class ServingEngine:
                 f"chunk {chunk} (+1 parking slot)"
             )
         validate_sampling(temperature, top_k, top_p)
-        self.cfg = dataclasses.replace(
-            cfg, decode_per_row=False, prefill_mode="self"
-        )
+        self.model = model
+        self.cfg = model.cfg
         self.slots = slots
         self.chunk = chunk
         self.block = block
@@ -230,31 +235,24 @@ class ServingEngine:
         self._first_key = jax.random.key(seed + 1)
         L = cfg.max_decode_len
 
-        decode_model = llama_lib.Llama(
-            dataclasses.replace(self.cfg, decode_per_row=True)
-        )
-        prefill_model = llama_lib.Llama(
-            dataclasses.replace(self.cfg, prefill_mode="cache")
-        )
         sample = make_sampler(temperature, top_k, top_p)
+        add = functools.partial(jax.tree.map, jnp.add)
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def prefill_chunk(params, cache, slot, chunk_toks, start, last_idx):
+        @functools.partial(jax.jit, donate_argnums=(1, 2))
+        def prefill_chunk(params, cache, counts, slot, chunk_toks, start, last_idx):
             """One [1, chunk] prefill chunk into row ``slot`` of the
             batch cache (slot/start/last_idx are traced scalars — one
             program). Returns the head logits [V] of position
             ``last_idx`` ONLY: the full [chunk, V] head matmul costs as
             much as several transformer layers and all but one row
             would be discarded (intermediate chunks pass 0 and ignore
-            the result)."""
+            the result). ``counts`` are the model's counters so far, to
+            which this call's are added."""
             row = jax.tree.map(
                 lambda s: jax.lax.dynamic_slice_in_dim(s, slot, 1, 0), cache
             )
             pos = (start + jnp.arange(self.chunk, dtype=jnp.int32))[None, :]
-            hidden, row = decode_forward(
-                prefill_model, params, row, chunk_toks, pos,
-                return_hidden=True,
-            )
+            hidden, row, added = model.prefill(params, row, chunk_toks, pos)
             cache = jax.tree.map(
                 lambda s, r: jax.lax.dynamic_update_slice_in_dim(
                     s, r, slot, 0
@@ -264,12 +262,11 @@ class ServingEngine:
             )
             with jax.named_scope("head"):
                 h = jax.lax.dynamic_slice_in_dim(hidden, last_idx, 1, axis=1)
-                w = llama_lib.Llama.head_kernel(params)
-                logits = h[:, 0].astype(jnp.float32) @ w.astype(jnp.float32)
-            return logits[0], cache  # [V]
+                logits = model.logits(params, h[:, 0])
+            return logits[0], cache, add(counts, added)  # [V]
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def decode_block(params, cache, tok, pos, active, rng, steps):
+        @functools.partial(jax.jit, donate_argnums=(1, 2))
+        def decode_block(params, cache, counts, tok, pos, active, rng, steps):
             """``steps`` (a traced int32, at most ``block``) decode steps
             over all slots: tok/pos [slots] are each row's last accepted
             token and its position; parked rows (active=False) hold
@@ -278,25 +275,24 @@ class ServingEngine:
             are written."""
 
             def step(i, carry):
-                cache, tok, pos, rng, toks = carry
-                logits, cache = decode_forward(
-                    decode_model, params, cache, tok[:, None], pos[:, None],
-                    return_hidden=False,
+                cache, counts, tok, pos, rng, toks = carry
+                logits, cache, added = model.decode(
+                    params, cache, tok[:, None], pos[:, None]
                 )
                 rng, k = jax.random.split(rng)
-                nxt = sample(logits[:, -1], k)
+                nxt = sample(logits, k)
                 nxt = jnp.where(active, nxt, tok)
                 pos = jnp.where(
                     active, jnp.minimum(pos + 1, L - 1), pos
                 )
                 toks = jax.lax.dynamic_update_index_in_dim(toks, nxt, i, 0)
-                return cache, nxt, pos, rng, toks
+                return cache, add(counts, added), nxt, pos, rng, toks
 
             toks = jnp.zeros((self.block, slots), tok.dtype)
-            cache, tok, pos, rng, toks = jax.lax.fori_loop(
-                0, steps, step, (cache, tok, pos, rng, toks)
+            cache, counts, tok, pos, rng, toks = jax.lax.fori_loop(
+                0, steps, step, (cache, counts, tok, pos, rng, toks)
             )
-            return toks.swapaxes(0, 1), cache, tok, pos, rng
+            return toks.swapaxes(0, 1), cache, counts, tok, pos, rng
 
         @jax.jit
         def first_token(logits, key):
@@ -311,7 +307,12 @@ class ServingEngine:
         self._decode_block = decode_block
         self._jnp = jnp
         self._jax = jax
-        self._cache = init_decode_cache(self.cfg, slots)
+        self._cache = model.init_cache(slots, chunk)
+        self._gauges = model.gauges(self._cache)
+        # The model's own counters: one running sum a program on the
+        # device, drained to the host's totals at ``stats()``.
+        self._counts = self._zero_counts()
+        self._model_n = jax.tree.map(lambda a: np.zeros(a.shape, np.int64), self._counts)
         self._tok = jnp.zeros((slots,), jnp.int32)
         self._pos = jnp.zeros((slots,), jnp.int32)
         self._slots: list[Optional[_Slot]] = [None] * slots
@@ -411,8 +412,8 @@ class ServingEngine:
                     # Up to this dispatch the device waited for the host;
                     # from here the host queues chunks behind chunks.
                     self.host_lap("admit_prep")
-                logits, self._cache = self._prefill_chunk(
-                    self._params, self._cache, *args
+                logits, self._cache, self._counts["prefill"] = self._prefill_chunk(
+                    self._params, self._cache, self._counts["prefill"], *args
                 )
         self.host_lap("dispatch")
         first = self._sample_first(logits)
@@ -496,11 +497,10 @@ class ServingEngine:
         ):
             active = jnp.asarray(active)
             self.host_lap("admit_prep")  # up to this dispatch the device waited
-            toks, self._cache, self._tok, self._pos, self._rng = (
-                self._decode_block(
-                    self._params, self._cache, self._tok, self._pos,
-                    active, self._rng, np.int32(steps),
-                )
+            (toks, self._cache, self._counts["decode"], self._tok, self._pos,
+             self._rng) = self._decode_block(
+                self._params, self._cache, self._counts["decode"], self._tok,
+                self._pos, active, self._rng, np.int32(steps),
             )
         self.host_lap("dispatch")
         with obs.span("engine.decode_fence", SPAN_CAT):
@@ -512,7 +512,7 @@ class ServingEngine:
         with obs.span("engine.accept", SPAN_CAT):
             for i in active_rows:
                 st = self._slots[i]
-                accepted = 0
+                accepted, live0 = 0, st.pos + 1  # cache positions live at the row's first step
                 for t in toks[i]:
                     if st.done:
                         break
@@ -525,6 +525,9 @@ class ServingEngine:
                     # wait — aggregating wall/total_tokens would understate
                     # tpot by the concurrency factor).
                     self._tpot_samples.append(wall / accepted)
+                    self._n["decode_live_positions"] += (
+                        accepted * live0 + accepted * (accepted - 1) // 2
+                    )
                 live += accepted
         self.last_steps = steps
         self._n["decode_blocks"] += 1
@@ -622,10 +625,29 @@ class ServingEngine:
             out.extend(self.step())
         raise RuntimeError("engine did not drain")
 
+    def _zero_counts(self) -> dict:
+        zeros = lambda: self._jax.tree.map(self._jnp.zeros_like, self.model.counts)
+        return {"prefill": zeros(), "decode": zeros()}
+
+    def _drain_counts(self) -> None:
+        """Move the model's device counters into the host's totals and
+        start them again at zero (so the int32 sums on the device only
+        ever hold what one reporting interval added). A model without
+        counters costs nothing here."""
+        if not self.model.counts:
+            return
+        got = self._jax.device_get(self._counts)
+        self._model_n = self._jax.tree.map(
+            lambda total, new: total + np.asarray(new, np.int64), self._model_n, got
+        )
+        self._counts = self._zero_counts()
+
     def reset_stats(self) -> None:
         """Clear the latency/throughput accumulators (benches call this
         after compile-warmup requests so percentiles reflect steady
         state, not XLA compilation)."""
+        self._drain_counts()
+        self._model_n = self._jax.tree.map(np.zeros_like, self._model_n)
         self.completed.clear()
         self._tpot_samples.clear()
         self._decode_wall = 0.0
@@ -646,6 +668,9 @@ class ServingEngine:
             return round(1000 * xs[i], 3)
 
         n, host = self._n, self._host_s
+        self._drain_counts()
+        plain = lambda v: int(v) if np.ndim(v) == 0 else [int(x) for x in v]
+        model_n = self._jax.tree.map(np.add, self._model_n["prefill"], self._model_n["decode"])
 
         def share(part, whole):
             return round(100.0 * part / whole, 3) if whole else None
@@ -688,4 +713,10 @@ class ServingEngine:
             # them by the key and keeps no list of its own.
             "host_gap_s": sum(host[k] for k in GAP_SEGMENTS),
             **{host_key(k): v for k, v in host.items()},
+            # The model's own: its gauges, its counters over both programs
+            # and over the decode program alone, and what it derives.
+            **self._gauges,
+            **{k: plain(v) for k, v in model_n.items()},
+            **{f"decode_{k}": plain(v) for k, v in self._model_n["decode"].items()},
+            **(self.model.derive(model_n) if self.model.counts else {}),
         }

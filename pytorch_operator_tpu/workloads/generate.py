@@ -147,12 +147,8 @@ def load_params(
     (only when ``compare_unquantized``)."""
     import contextlib
 
-    import flax.linen as nn
     import jax
-    import jax.numpy as jnp
     import numpy as np
-
-    from ..models import llama as llama_lib
 
     if init_host and not quantize:
         # Host init exists exactly for models whose full-precision tree
@@ -162,11 +158,11 @@ def load_params(
         # so every caller (generate, serve, bench) gets the guard.
         raise ValueError("init_host requires quantize='int8'")
 
-    def make_params(key):
-        train_cfg = dataclasses.replace(cfg, decode=False, quantize=None)
-        return llama_lib.Llama(train_cfg).init(
-            key, jnp.zeros((1, 8), jnp.int32)
-        )["params"]
+    # The model's own seeded init (models/serving.py): the llama family's
+    # is the training model's float32 tree in one program, which the
+    # quantisation below then shrinks; a family that serves its weights as
+    # they are makes them in the serving dtype, a layer at a time.
+    make_params = dataclasses.replace(cfg, decode=True).serving_model().init_params
 
     restored_step = None
     if restore is not None:
@@ -193,9 +189,7 @@ def load_params(
         # Shapes only — a bf16-trained checkpoint must still serve.
         import jax.tree_util as jtu
 
-        expected = nn.meta.unbox(
-            jax.eval_shape(make_params, jax.random.key(0))
-        )
+        expected = jax.eval_shape(make_params, jax.random.key(0))
         exp = {
             jtu.keystr(p): tuple(l.shape)
             for p, l in jtu.tree_flatten_with_path(expected)[0]
@@ -227,7 +221,7 @@ def load_params(
             else contextlib.nullcontext()
         )
         with init_ctx:
-            params = nn.meta.unbox(jax.jit(make_params)(jax.random.key(seed)))
+            params = make_params(jax.random.key(seed))
     n_params = sum(p.size for p in jax.tree.leaves(params))
     src = (
         f"trained checkpoint, step {restored_step}"

@@ -69,14 +69,16 @@ def run(
     import jax
     import numpy as np
 
-    from ..models import llama as llama_lib
+    from ..models.serving import preset
     from ..serving import Request, ServingEngine
     from ..serving.engine import SPAN_CAT
     from ..serving.shmring import EngineTransport
     from .generate import load_params
-    from .llama_train import CONFIGS
 
-    cfg = getattr(llama_lib, CONFIGS[config])(
+    # The preset states the model's family; everything a model decides
+    # (its cache, its forwards, how its weights are made) comes with it.
+    cfg = preset(
+        config,
         decode=True,
         max_decode_len=max_decode_len,
         quantize=quantize,
@@ -312,12 +314,12 @@ def run(
 
 
 def main(argv=None) -> int:
-    from .llama_train import CONFIGS
+    from ..models.serving import families
 
     import os
 
     p = argparse.ArgumentParser()
-    p.add_argument("--config", choices=sorted(CONFIGS), default="tiny")
+    p.add_argument("--config", choices=sorted(families()), default="tiny")
     p.add_argument(
         "--spool",
         default=os.environ.get("TPUJOB_SPOOL_DIR") or None,
