@@ -122,10 +122,11 @@ class LlamaConfig:
     # incoming tokens alone IS the full attention, so the flash kernel
     # applies and no [B,K,G,S,L] scores materialize. "cache" = a CHUNK
     # of a partially prefilled stream (positions [start, start+S)): the
-    # chunk is written to the cache, then attends against the full cache
-    # with the position-validity mask — intra-chunk causality and the
-    # prefix both fall out of col <= row. Memory is O(S·L) scores, so
-    # chunked prefill picks S (the chunk) to bound it; that bound is the
+    # chunk is written to the cache, then attends against the cache's
+    # filled prefix (ops/cache_attention.py) with the position-validity
+    # mask — intra-chunk causality and the prefix both fall out of
+    # col <= row. Memory is at most O(S·L) scores, so chunked prefill
+    # picks S (the chunk) to bound it; that bound is the
     # point (one-shot 8B long prompts exceed one program's activation
     # budget).
     prefill_mode: str = "self"
@@ -414,9 +415,11 @@ class Attention(nn.Module):
 
         Cache: ``cached_key``/``cached_value`` [B, K, max_decode_len, D]
         (heads-major) in the flax "cache" collection, written in place
-        at the current positions; scores run q against the FULL cache with a
-        position-validity mask (col_pos <= row_pos), so the program shape
-        is static no matter how much of the cache is filled.
+        at the current positions; scores run q against the cache's filled
+        prefix with a position-validity mask (col_pos <= row_pos). The
+        prefix is walked in static blocks with a trip count found inside
+        the program (``_cache_attend``), so the program's shapes stay
+        static however much of the cache is filled.
 
         CONTRACT (``cfg.decode_per_row=False``): positions must be
         batch-uniform (every row at the same offsets — the standard
@@ -512,59 +515,27 @@ class Attention(nn.Module):
         else:
             # Single-token decode steps, and (prefill_mode="cache")
             # chunks of a partially prefilled stream: attend against
-            # the full cache — the chunk's own tokens were written
-            # above at their true positions, so intra-chunk causality
-            # and the prefix both fall out of the col <= row mask.
+            # the cache's filled prefix — the chunk's own tokens were
+            # written above at their true positions, so intra-chunk
+            # causality and the prefix both fall out of the col <= row
+            # mask.
             out = self._cache_attend(q, positions, ck, cv, ks, vs)
         out = out.reshape(B, S, K * G * D)
         out = nn.with_logical_constraint(out, ("batch", "seq", None))
         return self._o_proj(out)
 
     def _cache_attend(self, q, positions, ck, cv, ks, vs):
-        """q against the FULL cache with a per-(row, token) position-
-        validity mask — static shapes however much of the cache is
-        filled. Serves single-token decode steps (S=1, possibly at
-        per-row depths) and chunked-prefill continuations (S>1,
-        prefill_mode="cache")."""
-        cfg = self.cfg
-        B, S, K, G, D = q.shape
-        L = cfg.max_decode_len
-        kv8 = cfg.kv_quantize == "int8"
-        if kv8:
-            # Convert-ONLY on the big slabs (int8 -> 256 levels is exact
-            # in a bf16 mantissa); the per-token scales fold into the
-            # TINY score/prob tensors after the dots. A fused
-            # convert+scale on the slab defeats operand fusion and
-            # materializes a full-precision copy per layer per step.
-            with jax.named_scope("kv_dequantize"):
-                kc, vc = ck.value.astype(cfg.dtype), cv.value.astype(cfg.dtype)
-        else:
-            kc, vc = ck.value, cv.value
-        scores = jnp.einsum(
-            "bskgd,bktd->bkgst", q, kc, preferred_element_type=jnp.float32
-        ) / jnp.sqrt(D).astype(jnp.float32)
-        if kv8:
-            # scores[b,k,g,s,t] · key_scale[b,k,t]: the K dequant, moved
-            # past the dot (linear in K).
-            with jax.named_scope("kv_dequantize"):
-                scores = scores * ks.value.squeeze(-1)[:, :, None, None, :]
-        col = jnp.arange(L)[None, None, :]      # cache position [1,1,L]
-        row = positions[:, :, None]             # query position [B,S,1]
-        # Per-(row, token) validity: col <= row — the uniform generate
-        # loop is just the special case where the B rows agree.
-        scores = jnp.where(
-            (col <= row)[:, None, None, :, :],  # [B,1,1,S,L]
-            scores,
-            jnp.finfo(jnp.float32).min,
-        )
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        if kv8:
-            # The V dequant, folded into probs (linear in V).
-            with jax.named_scope("kv_dequantize"):
-                probs = (
-                    probs * vs.value.squeeze(-1)[:, :, None, None, :]
-                ).astype(cfg.dtype)
-        return jnp.einsum("bkgst,bktd->bskgd", probs, vc)
+        """q against the cache's filled prefix under a per-(row, token)
+        position-validity mask (ops/cache_attention.py: the slab is read
+        in blocks up to the one that holds the deepest query's position,
+        found inside the program from ``positions``). Serves
+        single-token decode steps (S=1, possibly at per-row depths) and
+        chunked-prefill continuations (S>1, prefill_mode="cache"). An
+        int8 cache's scales fold into the scores and the probabilities."""
+        from ..ops.cache_attention import cache_attention
+
+        scales = (ks.value, vs.value) if ks is not None else ()
+        return cache_attention(q, positions, ck.value, cv.value, *scales)
 
 
 class MLP(nn.Module):

@@ -24,9 +24,10 @@ The layer (pre-norm residual, RMSNorm, no biases, untied head):
 - feed-forward: SwiGLU of width ``d_ff``, or ``moe_swiglu_held``.
 
 TPU-first shape: everything static. A full layer's cache is a
-``[slots, Hk, max_decode_len, d]`` slab read whole under a position mask;
-a window layer's is a ring of ``window + chunk`` positions addressed by
-``position % ring`` that also records WHICH position each entry holds, so
+``[slots, Hk, max_decode_len, d]`` slab of which attention reads the filled
+prefix, in static blocks up to the deepest query (ops/cache_attention.py),
+under a position mask; a window layer's is a ring of ``window + chunk`` positions
+addressed by ``position % ring`` that also records WHICH position each entry holds, so
 an entry is live iff its recorded position passes the same mask: a slot
 taken by a new request never sees the last one's keys (their recorded
 positions lie ahead of every query until they are overwritten), and a
@@ -345,8 +346,9 @@ def _write(slab, vals, positions):
 def attention(cfg: MiMoV2Config, attn_kind: str, w: dict, state: dict, x, positions):
     """One layer's attention for ``x [B, S, D]`` at ``positions [B, S]``
     (contiguous in a row) through its cache ``state``: the incoming keys
-    and values are written first, then the queries attend the whole slab or
-    ring under the position mask. Returns (out [B, S, D], new state)."""
+    and values are written first, then the queries attend a full layer's
+    filled prefix (ops/cache_attention.py) or a window layer's whole ring
+    under the position mask. Returns (out [B, S, D], new state)."""
     B, S, _ = x.shape
     H, Hk = cfg.n_heads, cfg.n_kv_heads(attn_kind)
     theta = cfg.rope_theta if attn_kind == FULL else cfg.window_rope_theta
@@ -360,31 +362,34 @@ def attention(cfg: MiMoV2Config, attn_kind: str, w: dict, state: dict, x, positi
         "k": _write(state["k"], k.swapaxes(1, 2).astype(cfg.dtype), positions),
         "v": _write(state["v"], v.swapaxes(1, 2).astype(cfg.dtype), positions),
     }
-    row = positions[:, :, None]  # [B, S, 1]
+    q = q.reshape(B, S, Hk, H // Hk, cfg.qk_head_dim)
     if attn_kind == FULL:
-        col = jnp.arange(new["k"].shape[2])[None, None, :]
-        visible = col <= row
+        from ..ops.cache_attention import cache_attention
+
+        out = cache_attention(q, positions, new["k"], new["v"])
     else:
         new["pos"] = _write(state["pos"], positions, positions)
-        held = new["pos"][:, None, :]  # [B, 1, R]
-        visible = (held >= 0) & (held <= row) & (row - held < cfg.window)
+        out = _ring_attend(cfg, q, positions, new, w["sink"])
+    return out.reshape(B, S, H * cfg.v_head_dim) @ w["o_proj"], new
 
-    q = q.reshape(B, S, Hk, H // Hk, cfg.qk_head_dim)
+
+def _ring_attend(cfg: MiMoV2Config, q, positions, ring: dict, sink):
+    """Queries ``[B, S, Hk, G, dqk]`` against a window layer's whole ring:
+    an entry is visible iff the position it records passes the causal and
+    the window test; the head's sink logit is one more column of the
+    softmax that carries no value."""
+    row = positions[:, :, None]  # [B, S, 1]
+    held = ring["pos"][:, None, :]  # [B, 1, R]
+    visible = (held >= 0) & (held <= row) & (row - held < cfg.window)
     scores = jnp.einsum(
-        "bskge,bkte->bkgst", q, new["k"], preferred_element_type=jnp.float32
+        "bskge,bkte->bkgst", q, ring["k"], preferred_element_type=jnp.float32
     ) / jnp.sqrt(jnp.float32(cfg.qk_head_dim))
     scores = jnp.where(visible[:, None, None, :, :], scores, jnp.finfo(jnp.float32).min)
-    if "sink" in w:
-        # The sink as one more column of the softmax that carries no value.
-        sink = jnp.broadcast_to(
-            w["sink"].astype(jnp.float32).reshape(1, Hk, H // Hk, 1, 1),
-            scores.shape[:-1] + (1,),
-        )
-        probs = jax.nn.softmax(jnp.concatenate([scores, sink], axis=-1), axis=-1)[..., :-1]
-    else:
-        probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgst,bkte->bskge", probs.astype(cfg.dtype), new["v"])
-    return out.reshape(B, S, H * cfg.v_head_dim) @ w["o_proj"], new
+    sink = jnp.broadcast_to(
+        sink.astype(jnp.float32).reshape(1, *q.shape[2:4], 1, 1), scores.shape[:-1] + (1,)
+    )
+    probs = jax.nn.softmax(jnp.concatenate([scores, sink], axis=-1), axis=-1)[..., :-1]
+    return jnp.einsum("bkgst,bkte->bskge", probs.astype(cfg.dtype), ring["v"])
 
 
 def dense_mlp(w: dict, x):

@@ -6,7 +6,7 @@ keeps a desired world running; this engine keeps a desired BATCH
 decoding: a fixed set of cache slots, each slot independently holding a
 request at its own depth, refilled the moment its occupant finishes.
 
-TPU-first shape (everything static):
+TPU-first shape (every program's shapes static):
 
 - The model is whatever ``cfg.serving_model()`` returns
   (models/serving.py: its cache constructor, its two forwards, its own
@@ -14,9 +14,14 @@ TPU-first shape (everything static):
 - ONE decode program: ``decode_block`` loops ``steps`` single-token
   steps (a traced count, so every length is the same compiled program)
   over the full [slots] batch through the model's per-row decode
-  forward — every row at its own position, finished/empty
-  rows parked (they re-write their own slot, masked from every live
-  stream by the col <= row validity mask). Everything the engine
+  forward — every row at its own position. Attention reads each
+  layer's cache in blocks up to the one that holds the deepest row's
+  position, a trip count found inside the program
+  (ops/cache_attention.py), so a step costs what the batch holds and not
+  what the slabs reserve. Empty rows are parked at position 0 (they
+  re-write position 0 of their own empty row, which the next admission's
+  first chunk overwrites), so a slot's last occupant never holds the
+  bound up; rows never see each other's cache. Everything the engine
   decides (admit, harvest, and the serve loop's poll and responses)
   happens between dispatches, so the engine chooses each dispatch's
   length from what it holds on the host (:func:`decode_steps`): to the
@@ -30,9 +35,10 @@ TPU-first shape (everything static):
   — the pad tokens write cache slots past the prompt that every later
   read either masks (col <= row) or overwrites (the next decode token
   lands exactly on the first padded slot before anything attends it).
-  Arbitrary prompt lengths therefore hit exactly two compiled
-  programs, and a prompt longer than one program's activation budget
-  prefills in bounded O(chunk · L) score memory.
+  A chunk attends the row's prefix up to its own last position, by the
+  same bounded attention. Arbitrary prompt lengths therefore hit exactly
+  two compiled programs, and a prompt longer than one program's
+  activation budget prefills in bounded O(chunk · L) score memory.
 - Slot L-1 of every row is a parking slot: rows that exhaust their
   budget clamp there, so admission requires prompt + new <= L-1 and
   no live stream ever attends a parked write.
@@ -59,7 +65,11 @@ lists every name beside the metric that reads it):
 - counters (integers and ``perf_counter`` sums, O(1) per iteration):
   blocks (dispatches), their steps and what sized them, occupied rows,
   row-steps and accepted tokens (slot occupancy, decode yield), the cache
-  positions those tokens had live, prefill chunks and pad tokens,
+  positions those tokens had live beside the positions attention read for
+  them (``decode_attended_positions`` over ``decode_row_steps`` x
+  ``max_decode_len`` is the share of the slabs still read; the same for
+  ``prefill_attended_positions`` over the chunks), prefill chunks and pad
+  tokens,
   admissions, the model's own counters (summed on the device inside the
   two programs, brought back at ``stats()``), and one clock that charges every
   second of the serving thread to a segment
@@ -104,7 +114,7 @@ def host_key(segment: str) -> str:
 SIZED_BY = ("budget", "quantum", "ceiling")
 _COUNTERS = (
     "decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
-    "decode_live_positions",
+    "decode_live_positions", "decode_attended_positions", "prefill_attended_positions",
     *(f"decode_sized_by_{reason}" for reason in SIZED_BY),
     "prefill_chunks", "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "admitted",
 )
@@ -209,6 +219,7 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
+        from ..ops.cache_attention import attended
         from ..ops.sampling import make_sampler, validate_sampling
 
         if not cfg.decode:
@@ -269,10 +280,13 @@ class ServingEngine:
         def decode_block(params, cache, counts, tok, pos, active, rng, steps):
             """``steps`` (a traced int32, at most ``block``) decode steps
             over all slots: tok/pos [slots] are each row's last accepted
-            token and its position; parked rows (active=False) hold
-            position and re-write their own slot. Returns the sampled
-            tokens [slots, block], of which the first ``steps`` columns
-            are written."""
+            token and its position; parked rows (active=False) stand at
+            position 0 whatever their last occupant left, so they do not
+            hold up the bound of the model's cache attention, and
+            re-write position 0 of their own empty row. Returns the
+            sampled tokens [slots, block], of which the first ``steps``
+            columns are written."""
+            pos = jnp.where(active, pos, 0)
 
             def step(i, carry):
                 cache, counts, tok, pos, rng, toks = carry
@@ -307,6 +321,7 @@ class ServingEngine:
         self._decode_block = decode_block
         self._jnp = jnp
         self._jax = jax
+        self._attended = attended  # the cache attention's own rounding, for the counters
         self._cache = model.init_cache(slots, chunk)
         self._gauges = model.gauges(self._cache)
         # The model's own counters: one running sum a program on the
@@ -384,7 +399,7 @@ class ServingEngine:
         return tok
 
     def _admit(self, request: Request, slot: int) -> None:
-        jnp = self._jnp
+        jnp, L = self._jnp, self.cfg.max_decode_len
         admit_time = time.time()
         prompt = np.asarray(request.prompt, np.int32)
         p = prompt.shape[0]
@@ -397,6 +412,10 @@ class ServingEngine:
         self._n["prefill_chunks"] += padded // self.chunk
         self._n["prefill_tokens"] += p
         self._n["prefill_pad_tokens"] += padded - p
+        # What the chunks' attention reads of the row: each up to its own end.
+        self._n["prefill_attended_positions"] += int(
+            self._attended(np.arange(self.chunk, padded + 1, self.chunk), L).sum()
+        )
         for start in range(0, padded, self.chunk):
             final = start + self.chunk >= padded
             with obs.span("engine.prefill_dispatch", SPAN_CAT, start=start):
@@ -490,6 +509,13 @@ class ServingEngine:
         steps, sized_by = decode_steps(
             [self._slots[i].remaining for i in active_rows],
             self.slots - len(active_rows), self.block,
+        )
+        # What each step's attention reads of every row: up to the deepest
+        # active row, which the program finds from the same positions.
+        L = self.cfg.max_decode_len
+        deepest = max(self._slots[i].pos for i in active_rows)
+        self._n["decode_attended_positions"] += len(active_rows) * int(
+            self._attended(np.minimum(deepest + np.arange(steps), L - 1) + 1, L).sum()
         )
         t0 = time.time()
         with obs.span(

@@ -11,6 +11,8 @@ given this file may.
 
 from __future__ import annotations
 
+import re
+
 import pytest
 
 import tests.jaxenv  # noqa: F401
@@ -69,6 +71,10 @@ def test_a_decode_step_over_64_slots_compiles_and_fits(described, one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 10.7e9  # 9.05 GB of weights + 1.76 GB of cache
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
+    # Each of the two full layers' attention is one loop over the slab's blocks, its trip count traced
+    # (the per-row cache writes are loops of the compiler's own: scatters).
+    loops = [l for l in re.findall(r" while\(.*", compiled.as_text()) if 'attn_full/while"' in l]
+    assert len(loops) == 2 and not any("known_trip_count" in l for l in loops), loops
 
 
 def test_a_prefill_chunk_into_one_slots_row_compiles_and_fits(described, one_chip):
