@@ -46,6 +46,10 @@ Project-wide rules (subclass :class:`ProjectRule`, see also
                            adopt pair, fleet actuations outside the
                            post-commit effectors, and cross-module
                            calls of engine-private decision internals.
+- ``layer-direction``      a module of the compute layer (models/, ops/,
+                           parallel/) importing from a layer that drives
+                           it (workloads/, serving/, controller/,
+                           client/), function-level imports included.
 """
 
 from __future__ import annotations
@@ -633,6 +637,55 @@ class RemediationDiscipline(ProjectRule):
             )
 
 
+# ---------------------------------------------------------------------------
+# layer-direction
+
+_COMPUTE_LAYERS = ("models/", "ops/", "parallel/")
+_DRIVING_LAYERS = frozenset({"workloads", "serving", "controller", "client"})
+
+
+class LayerDirection(ProjectRule):
+    id = "layer-direction"
+    summary = (
+        "the compute layer (models/, ops/, parallel/) is imported by "
+        "workloads/, serving/, controller/ and client/ and imports "
+        "none of them"
+    )
+
+    def run(self, mods) -> Iterator[tuple]:
+        for mod in mods:
+            if not mod.relpath.startswith(_COMPUTE_LAYERS):
+                continue
+            here = mod.relpath.split("/")[:-1]
+            package = mod.path.parents[len(here)].name
+            for node in ast.walk(mod.tree):
+                if isinstance(node, ast.Import):
+                    dotted = [a.name.split(".") for a in node.names]
+                    targets = [d[1:] for d in dotted if d[0] == package]
+                elif isinstance(node, ast.ImportFrom):
+                    path = node.module.split(".") if node.module else []
+                    if node.level:
+                        base = here[: len(here) - (node.level - 1)] + path
+                    elif path[:1] == [package]:
+                        base = path[1:]
+                    else:
+                        continue
+                    # ``from .. import workloads`` names the layer in
+                    # the imported names, not in the module path.
+                    targets = [base + [a.name] for a in node.names]
+                else:
+                    continue
+                for target in targets:
+                    if target and target[0] in _DRIVING_LAYERS:
+                        yield mod, RawFinding(
+                            node.lineno,
+                            f"{mod.relpath} imports {'.'.join(target)}: "
+                            "the compute layer must not know what drives "
+                            "it — move the shared piece down, or pass it "
+                            "in",
+                        )
+
+
 def module_rules() -> List[Rule]:
     return [
         AtomicStateWrite(),
@@ -645,4 +698,9 @@ def module_rules() -> List[Rule]:
 def project_rules() -> List[ProjectRule]:
     from .locks import LockOrder
 
-    return [FencedStoreWrite(), LockOrder(), RemediationDiscipline()]
+    return [
+        FencedStoreWrite(),
+        LockOrder(),
+        RemediationDiscipline(),
+        LayerDirection(),
+    ]

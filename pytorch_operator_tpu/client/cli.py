@@ -1996,7 +1996,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     sp.add_argument(
         "--out", default=None,
-        help="write the full artifact here (e.g. BENCH_ctrlplane.json)",
+        help="write the full artifact to this file",
     )
     sp.set_defaults(func=cmd_bench_control_plane)
 
@@ -2025,7 +2025,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     sp.add_argument(
         "--out", default=None,
-        help="write the full artifact here (e.g. BENCH_dataplane.json)",
+        help="write the full artifact to this file",
     )
     sp.set_defaults(func=cmd_bench_data_plane)
 
@@ -2056,7 +2056,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     sp.add_argument(
         "--out", default=None,
-        help="write the full artifact here (e.g. BENCH_serveplane.json)",
+        help="write the full artifact to this file",
     )
     sp.set_defaults(func=cmd_bench_serve_plane)
 
@@ -2086,7 +2086,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     sp.add_argument(
         "--out", default=None,
-        help="write the full artifact here (e.g. BENCH_elastic.json)",
+        help="write the full artifact to this file",
     )
     sp.set_defaults(func=cmd_bench_elastic)
 
@@ -2094,7 +2094,8 @@ def build_parser() -> argparse.ArgumentParser:
         "verify-invariants",
         help="run the static invariant checker (atomic-state-write, "
         "fenced-store-write, lock-order, swallowed-exception, "
-        "retry-discipline, clock-discipline) over the package; exit 1 "
+        "retry-discipline, clock-discipline, remediation-discipline, "
+        "layer-direction) over the package; exit 1 "
         "on any unsuppressed finding",
     )
     sp.add_argument(

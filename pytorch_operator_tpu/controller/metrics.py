@@ -401,8 +401,8 @@ class MetricsRegistry:
             "1.0 = spending budget exactly as fast as the SLO earns it)",
         )
         # Live mirrors of the bench-only I/O instrumentation: idle-I/O
-        # regressions become visible in production, not just in
-        # BENCH_ctrlplane.json (store deltas folded once per pass).
+        # regressions become visible in production, not just in the
+        # control-plane bench (store deltas folded once per pass).
         self.store_io = {
             k: self.counter(
                 f"tpujob_store_{k}_total",

@@ -1030,7 +1030,7 @@ class Supervisor:
         """Mirror the bench-only I/O instrumentation (StoreIOCounters,
         ProgressTailer fold stats) onto live registry counters, once per
         pass, as deltas — an idle-I/O regression shows on /metrics in
-        production, not just in BENCH_ctrlplane.json."""
+        production, not just in the control-plane bench."""
         m = self.metrics
         cur = self.store.io.snapshot()
         for k, counter in m.store_io.items():

@@ -6,7 +6,7 @@ from __future__ import annotations
 def remat_policy(cfg):
     """Resolve ``cfg.remat_policy`` to a jax.checkpoint policy (None =
     save nothing beyond block boundaries, i.e. full remat). Duck-typed:
-    any config with a ``remat_policy`` field (LlamaConfig, ViTConfig).
+    any config with a ``remat_policy`` field (LlamaConfig).
 
     ``"dots"`` saves outputs of batch-dim-free dot_generals — the
     projection and MLP GEMMs — so backward recomputes only the cheap

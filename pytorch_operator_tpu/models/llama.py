@@ -25,7 +25,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .common import remat_policy  # shared with ViT (models/common.py)
+from .common import remat_policy
 
 Dtype = Any
 
@@ -1300,3 +1300,14 @@ def train_value_and_grad_pp(
         grads_unboxed,
         is_leaf=lambda v: isinstance(v, nn.meta.Partitioned),
     )
+
+
+# Preset name -> the function above that builds its config. A caller may add
+# one (the benchmark's ``bench``); ``workloads.llama_train`` re-exports this
+# same dict, and ``models.serving.families`` reads it at call time.
+CONFIGS = {
+    "8b": "llama3_8b",
+    "1b": "llama_1b",
+    "0.3b": "llama_0_3b",
+    "tiny": "llama_tiny",
+}

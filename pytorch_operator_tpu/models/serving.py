@@ -54,11 +54,10 @@ def families() -> dict:
     """``preset name -> (model module, name of the function that makes its
     config)``, read at call time: a family's table is a module dict that a
     caller may add a preset to (the benchmark's ``bench``)."""
-    from ..workloads.llama_train import CONFIGS as llama_presets
     from . import llama, mimo_v2
 
     return {
-        **{name: (llama, fn) for name, fn in llama_presets.items()},
+        **{name: (llama, fn) for name, fn in llama.CONFIGS.items()},
         **{name: (mimo_v2, fn) for name, fn in mimo_v2.CONFIGS.items()},
     }
 

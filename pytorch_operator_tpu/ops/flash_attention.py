@@ -27,7 +27,7 @@ Design notes:
 - Unaligned shapes (S not divisible by the blocks; D not lane-aligned)
   are zero-padded to the tiling and masked via a static ``kv_len``
   (padded key columns score -inf; padded query rows are sliced off), so
-  e.g. ViT's S=197/D=64 runs the O(S·D) kernel instead of falling back
+  e.g. S=197/D=64 runs the O(S·D) kernel instead of falling back
   to a dense O(S^2) path (round 4).
 - Multi-device: pass ``mesh`` — the call is wrapped in a partial-manual
   ``shard_map`` over the dp/fsdp (batch) and tp (heads) axes, composing
@@ -455,8 +455,8 @@ def flash_attention(
     MULTIPLIES the attention FLOPs and q/k/v/o bytes by D_pad/D (2x for
     D=64) — a win at long S where the kernel's O(S·D) HBM beats the
     dense path's O(S^2), NOT for short-S/thin-D models: an earlier round
-    measured ViT-B (S=197, D=64) slower under the padded kernel than
-    dense XLA, and it keeps its dense default.
+    measured a ViT-B shape (S=197, D=64) slower under the padded
+    kernel than dense XLA.
 
     ``kv_len``: static TRUE sequence length when the caller's batch is
     already padded to S — keys/values at positions >= kv_len are masked

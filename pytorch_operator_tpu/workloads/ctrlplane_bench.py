@@ -26,7 +26,7 @@ Three harnesses share the artifact:
 
 Each pass runs the daemon loop body (rescan + the four marker scans +
 sync_once), so the numbers measure what ``tpujob supervisor`` actually
-pays. Emitted artifact (``BENCH_ctrlplane.json``): per cell, pass-
+pays. Emitted artifact (``--out``): per cell, pass-
 latency p50/p99 (ms) and per-pass store I/O, autoscaler pool bounds,
 churn throughput, and the multi-supervisor flatness acceptance (idle
 p50 at N=10000 with 2 supervisors vs the 63 ms N=1000 single-supervisor
@@ -34,7 +34,7 @@ baseline the PR-2 artifact pinned).
 
 Usage:
     python -m pytorch_operator_tpu.workloads.ctrlplane_bench \
-        [--jobs 10,100,1000] [--passes 30] [--out BENCH_ctrlplane.json] \
+        [--jobs 10,100,1000] [--passes 30] [--out ctrlplane.json] \
         [--sharded-cells 10000:1,10000:2,10000:4] \
         [--gang-cells 500x16:2] [--churn-cells 2000:2]
     tpujob bench-control-plane ...
@@ -499,8 +499,8 @@ def _parse_cells(spec: Optional[str]) -> List[dict]:
 
 
 # The pinned single-supervisor baseline this artifact's flatness
-# acceptance is judged against: idle pass p50 at N=1000, from the PR-2
-# artifact (BENCH_ctrlplane.json at the time the 10k target was set).
+# acceptance is judged against: idle pass p50 at N=1000 on the CPU box
+# of PR 2, when the 10k target was set (a host-plane figure, no chip's).
 BASELINE_N1000_P50_MS = 63.0
 ACCEPTANCE_RATIO = 1.5
 

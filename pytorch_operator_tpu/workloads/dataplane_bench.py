@@ -39,9 +39,13 @@ Transfer accounting pins the pipeline invariants per cell:
   bench itself performs (one per save + the final read) — the "chunked
   hand-off budget". Staged cells pin ZERO gathers beyond it (the
   per-leaf state gather happens on the snapshot-stage thread); eager
-  async cells show the per-leaf snapshot cost on the step thread.
+  async cells show the per-leaf snapshot cost on the step thread;
+- ``step_thread_commits`` vs ``background_commits`` — which thread wrote
+  each timed save's checksum sidecar, the last write of a commit: a
+  blocking cell commits every save on the step thread, async and
+  staged cells none.
 
-Emitted artifact (``BENCH_dataplane.json``): per checkpoint cell,
+Emitted artifact (``--out``): per checkpoint cell,
 steps/s (stalls included — that is the point), checkpoint-stall
 p50/p99/total, drain time, transfer accounting, and the verification
 result; per feed cell, steps/s, rolling/total stall, and the depth the
@@ -49,7 +53,7 @@ autotuner settled on (pinned ≤ depth_max); plus cross-cell comparisons.
 
 Usage:
     python -m pytorch_operator_tpu.workloads.dataplane_bench \
-        [--steps 40] [--checkpoint-every 5] [--dim 256] [--out BENCH_dataplane.json]
+        [--steps 40] [--checkpoint-every 5] [--dim 256] [--out dataplane.json]
     tpujob bench-data-plane ...
 """
 
@@ -116,19 +120,27 @@ def _build_model(dim: int, batch: int, seed: int = 0):
 
 
 class _TransferMeter:
-    """Patches ``jax.device_get`` for the duration of a cell, counting
-    calls issued from the step thread — the zero-inline-gather pin's
-    instrument. (``device_put`` is metered by routing every feed through
-    a counting ``put``; ``device_get`` has no such seam, hence the
-    patch.)"""
+    """Patches ``jax.device_get`` and ``integrity.write_sidecar`` for the
+    duration of a cell, counting by calling thread: gathers issued from
+    the step thread (the zero-inline-gather pin's instrument) and, since
+    the sidecar is the last write of every commit, the commits that ran
+    on the step thread beside those that ran behind it. (``device_put``
+    is metered by routing every feed through a counting ``put``; these
+    two have no such seam, hence the patches.)"""
 
     def __init__(self, step_tid: int):
         import jax
 
+        from ..checkpoint import integrity
+
         self._jax = jax
         self._real = jax.device_get
+        self._integrity = integrity
+        self._real_sidecar = integrity.write_sidecar
         self.step_tid = step_tid
         self.step_thread_gets = 0
+        self.step_thread_commits = 0
+        self.background_commits = 0
 
     def __enter__(self):
         meter = self
@@ -138,11 +150,22 @@ class _TransferMeter:
                 meter.step_thread_gets += 1
             return meter._real(x)
 
+        def counting_sidecar(root, step):
+            # One writer at a time: blocking saves commit on the step
+            # thread, async ones on the writer's single commit thread.
+            if threading.get_ident() == meter.step_tid:
+                meter.step_thread_commits += 1
+            else:
+                meter.background_commits += 1
+            return meter._real_sidecar(root, step)
+
         self._jax.device_get = counting_get
+        self._integrity.write_sidecar = counting_sidecar
         return self
 
     def __exit__(self, *exc):
         self._jax.device_get = self._real
+        self._integrity.write_sidecar = self._real_sidecar
 
 
 def bench_cell(
@@ -267,6 +290,10 @@ def bench_cell(
         "step_thread_gets_beyond_budget": max(
             gets.step_thread_gets - device_get_budget, 0
         ),
+        # Where each timed save's commit ran: a blocking save pays it on
+        # the step thread, an async or staged one behind the steps.
+        "step_thread_commits": gets.step_thread_commits,
+        "background_commits": gets.background_commits,
         "last_saved_step": last_saved,
         "last_verified_step": last_verified,
         "all_saves_verified": last_verified == last_saved,

@@ -30,14 +30,14 @@ the pre-death frontier), the post-resize rank assignment (pinned
 unique AND dense in [0, world)), and the count of post-kill cold
 starts (pinned 0 for resize cells — shrink must not respawn anyone).
 
-Emitted artifact (``BENCH_elastic.json``): per-cell numbers plus the
+Emitted artifact (``--out``): per-cell numbers plus the
 acceptance block — resize recovery strictly faster than restart
 recovery for every gang size, and zero duplicate ranks ever observed.
 
 Usage:
     python -m pytorch_operator_tpu.workloads.elastic_bench \
         [--gangs 2,4,8] [--pre-steps 5] [--step-time 0.02] \
-        [--timeout 120] [--out BENCH_elastic.json]
+        [--timeout 120] [--out elastic.json]
     tpujob bench-elastic ...
 """
 

@@ -295,7 +295,6 @@ def run(
     import numpy as np
 
     from ..models import llama as llama_lib
-    from .llama_train import CONFIGS
 
     if quantize not in (None, "int8"):
         raise ValueError(f"quantize={quantize!r} not in (None, 'int8')")
@@ -306,7 +305,7 @@ def run(
             "compare_unquantized requires quantize and not init_host"
         )
 
-    cfg = getattr(llama_lib, CONFIGS[config])(
+    cfg = getattr(llama_lib, llama_lib.CONFIGS[config])(
         decode=True,
         # The cache is statically sized by max_decode_len; overriding it
         # beyond prompt+new measures serving at a context budget without
@@ -413,7 +412,7 @@ def run(
 
 
 def main(argv=None) -> int:
-    from .llama_train import CONFIGS
+    from ..models.llama import CONFIGS
 
     p = argparse.ArgumentParser()
     p.add_argument("--config", choices=sorted(CONFIGS), default="tiny")

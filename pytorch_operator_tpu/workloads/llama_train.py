@@ -24,6 +24,9 @@ import os
 import sys
 import time
 
+# The llama family's preset table, the same dict as models.llama.CONFIGS:
+# the benchmark adds its preset `bench` through this name.
+from ..models.llama import CONFIGS
 from ..runtime import rendezvous
 
 
@@ -37,14 +40,6 @@ def synthetic_bigram_batch(batch: int, seq_len: int, vocab: int, step: int):
     for _ in range(seq_len - 1):
         toks.append((toks[-1] * 5 + 3) % vocab)
     return np.concatenate(toks, axis=1).astype(np.int32)
-
-
-CONFIGS = {
-    "8b": "llama3_8b",
-    "1b": "llama_1b",
-    "0.3b": "llama_0_3b",
-    "tiny": "llama_tiny",
-}
 
 
 def run(
@@ -145,7 +140,7 @@ def run(
         # Silently measuring the no-remat path while the user believes
         # the selective policy is active is a benchmarking trap ('full'
         # is the inert default, so passing it without --remat measures
-        # exactly what it says and is allowed — vit_bench agrees).
+        # exactly what it says and is allowed).
         raise ValueError(
             f"--remat-policy {remat_policy} has no effect without --remat"
         )

@@ -122,7 +122,6 @@ def run(
     from ..models import llama as llama_lib
     from ..ops.quantize import quantize_tree
     from .generate import load_params, make_generate
-    from .llama_train import CONFIGS
 
     # Held-out sequences from the packed eval file (same format the
     # trainer's --eval-file takes).
@@ -146,7 +145,7 @@ def run(
     S = eval_tokens.shape[1]
     L = max(S, drift_prompt + drift_tokens)
 
-    base = getattr(llama_lib, CONFIGS[config])(
+    base = getattr(llama_lib, llama_lib.CONFIGS[config])(
         decode=True, max_decode_len=L
     )
     params_fp, _, n_params, _, restored_step = load_params(
@@ -237,7 +236,7 @@ def run(
 
 
 def main(argv=None) -> int:
-    from .llama_train import CONFIGS
+    from ..models.llama import CONFIGS
 
     p = argparse.ArgumentParser()
     p.add_argument("--config", choices=sorted(CONFIGS), default="tiny")

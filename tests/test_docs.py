@@ -1,8 +1,8 @@
 """Docs-drift guard: user-facing docs must reference real code.
 
-MIGRATION.md and README.md are the user-switch surface — every
-backticked repo path or ``pytorch_operator_tpu.*`` module they name must
-exist, or the docs rot silently as code moves (the same cannot-drift
+MIGRATION.md and README.md are the user-switch surface, ARCHITECTURE.md
+the description of the system — every backticked repo path or
+``pytorch_operator_tpu.*`` module they name must exist, or the docs rot silently as code moves (the same cannot-drift
 principle the CRD generator applies to the API schema).
 """
 
@@ -43,7 +43,7 @@ def _resolves(path_str: str) -> bool:
     return False
 
 
-@pytest.mark.parametrize("doc", ["MIGRATION.md", "README.md"])
+@pytest.mark.parametrize("doc", ["MIGRATION.md", "README.md", "ARCHITECTURE.md"])
 def test_doc_paths_exist(doc):
     text = (REPO / doc).read_text()
     missing = []
@@ -56,7 +56,7 @@ def test_doc_paths_exist(doc):
     assert missing == [], f"{doc} references nonexistent paths: {missing}"
 
 
-@pytest.mark.parametrize("doc", ["MIGRATION.md", "README.md"])
+@pytest.mark.parametrize("doc", ["MIGRATION.md", "README.md", "ARCHITECTURE.md"])
 def test_doc_modules_importable(doc):
     text = (REPO / doc).read_text()
     missing = []

@@ -13,8 +13,8 @@ tentpole's quantitative claims:
   incarnations in the resize cell), while the restart cell respawns
   the entire gang.
 
-The full {2,4,8}-gang artifact is BENCH_elastic.json; this lane keeps
-the 2-worker cells honest inside the tier-1 budget.
+The full {2,4,8}-gang artifact is ``tpujob bench-elastic``'s; this lane
+keeps the 2-worker cells honest inside the tier-1 budget.
 """
 
 from __future__ import annotations

@@ -310,6 +310,19 @@ def test_presets_name_their_family_and_the_server_finds_both():
         preset("no-such-model")
 
 
+def test_the_llama_presets_are_one_table_wherever_they_are_named(monkeypatch):
+    """The table lives with the model; the trainer's name for it is the same
+    dict, so the preset the benchmark adds through ``llama_train.CONFIGS`` is
+    the server's too."""
+    from pytorch_operator_tpu.models import llama
+    from pytorch_operator_tpu.workloads import llama_train
+
+    assert llama_train.CONFIGS is llama.CONFIGS
+    monkeypatch.setitem(llama_train.CONFIGS, "bench", "llama_tiny")
+    assert families()["bench"] == (llama, "llama_tiny")
+    assert preset("bench", decode=True).n_layers == llama.llama_tiny().n_layers
+
+
 @pytest.mark.parametrize("knob", ["quantize", "kv_quantize"])
 def test_the_family_refuses_what_it_does_not_serve(knob):
     with pytest.raises(ValueError, match="unquantised"):

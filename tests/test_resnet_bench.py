@@ -82,69 +82,6 @@ class TestResNetModel:
         assert max(jax.tree.leaves(sdiffs)) > 0
 
 
-class TestBench:
-    @pytest.mark.slow
-    def test_bench_smoke_emits_schema(self, capsys):
-        import bench
-
-        result = bench.run(["--smoke", "--steps", "2", "--warmup", "1"])
-        # Round-4 shape: the artifact LEADS with the flagship LM (the
-        # MFU carrier); ResNet rides as the continuity sub-block.
-        # No "mfu": a CPU run has no published peak to be measured against.
-        assert set(result) == {
-            "metric",
-            "value",
-            "unit",
-            "config",
-            "seq_len",
-            "final_loss",
-            "resnet",
-            "schedule_to_first_step_s",
-            "device",
-            "failed_legs",
-        }
-        assert result["value"] > 0
-        assert result["unit"] == "tokens/sec/chip"
-        assert result["device"]["platform"] == "cpu"
-        assert result["device"]["count"] >= 1
-        assert result["failed_legs"] == []
-        rn = result["resnet"]
-        assert rn["unit"] == "images/sec/chip" and rn["value"] > 0
-        assert rn["vs_baseline"] > 0
-        # The latency probe runs REAL supervisor jobs even in smoke mode
-        # (with a pre-warmed standby, the production daemon config);
-        # both phases must come back measured, not None.
-        lat = result["schedule_to_first_step_s"]
-        assert lat["cold"] > 0 and lat["warm"] > 0
-
-    @pytest.mark.slow
-    def test_bench_smoke_no_latency_flag(self):
-        import bench
-
-        result = bench.run(
-            ["--smoke", "--steps", "2", "--warmup", "1", "--no-latency"]
-        )
-        assert set(result) == {
-            "metric", "value", "unit", "config", "seq_len",
-            "final_loss", "resnet", "device", "failed_legs",
-        }
-
-    def test_mfu_math(self):
-        import bench
-
-        # MFU is against the published peak of the device JAX reports;
-        # a device that is not in the table is an error, not a default.
-        peak = bench.peak_flops("TPU v5 lite")
-        assert peak == 197e12
-        m = bench.mfu(98.5e12, peak)
-        assert m == {"model_tflops_per_sec": 98.5, "vs_peak_pct": 50.0}
-        with pytest.raises(KeyError, match="no published peak"):
-            bench.peak_flops("cpu")
-        # The LM formula: 6N dominates at short S.
-        f = bench.lm_train_flops_per_token(1e9, 16, 1024, 64)
-        assert abs(f - (6e9 + 6 * 16 * 64 * 1024)) < 1
-
-
 class TestDataFileMode:
     @pytest.mark.slow
     def test_trains_from_packed_file(self, tmp_path):
@@ -300,235 +237,3 @@ class TestGraftEntry:
         out = capsys.readouterr().out
         assert "[dryrun] ok" in out and "dp=1,fsdp=2,sp=2,tp=2" in out
         assert "attn=ring" in out
-
-
-class TestBenchArtifactContract:
-    """Round-5 driver-artifact contract (VERDICT r4 Weak #1): the FINAL
-    stdout line must be a compact JSON summary that survives the
-    driver's bounded tail window. Round 4's 4.3 KB single line did not,
-    and the round's headline numbers were lost to the record."""
-
-    # Worst-case full-detail dict: every block present, floats at full
-    # precision, all round-5 serving fields populated.
-    FULL = {
-        "metric": "llama_train_tokens_per_sec_per_chip",
-        "value": 44983.123456789,
-        "unit": "tokens/sec/chip",
-        "vs_baseline": 1.1085123,
-        "config": "0.3b",
-        "seq_len": 4096,
-        "final_loss": 5.84321098765,
-        "device": {"platform": "tpu", "device_kind": "TPU v5 lite", "count": 1},
-        "failed_legs": ["vit"],
-        "mfu": {
-            "model_tflops_per_sec": 103.4,
-            "vs_peak_pct": 52.5,
-        },
-        "resnet": {
-            "metric": "resnet50_images_per_sec_per_chip",
-            "value": 2706.987654321,
-            "unit": "images/sec/chip",
-            "vs_baseline": 1.0149876,
-            "mfu": {
-                "model_tflops_per_sec": 33.3,
-                "vs_peak_pct": 16.9,
-            },
-        },
-        "llama_real_data": {
-            "metric": "llama_train_real_data_tokens_per_sec_per_chip",
-            "value": 56969.123,
-            "unit": "tokens/sec/chip",
-            "data": "repo source+docs, byte-level, 90/10 held-out split",
-            "final_loss": 2.123456789,
-            "eval_loss": 2.4123456789,
-            "chance_loss": 5.545,
-            "learned": True,
-        },
-        "llama_1b_scale": {
-            "metric": "scale_llama_train_tokens_per_sec_per_chip",
-            "value": 16256.123,
-            "unit": "tokens/sec/chip",
-            "config": "1b",
-            "params_m": 1100.123,
-            "seq_len": 4096,
-            "mfu": {
-                "model_tflops_per_sec": 124.0,
-                "vs_peak_pct": 63.0,
-            },
-        },
-        "moe": {
-            "metric": "moe_llama_train_tokens_per_sec_per_chip",
-            "value": 52642.9,
-            "unit": "tokens/sec/chip",
-            "n_experts": 8,
-            "moe_dispatch": "sparse",
-            "moe_top_k": 2,
-            "params_m": 1500.1,
-            "active_params_m": 500.2,
-            "final_loss": 6.1234,
-            "mfu": {
-                "model_tflops_per_sec": 76.4,
-                "vs_peak_pct": 38.8,
-            },
-        },
-        "serving_decode": {
-            "metric": "serving_decode_tokens_per_sec_per_chip",
-            "value": 2141.62345,
-            "unit": "tokens/sec/chip",
-            "config": "1b",
-            "batch": 8,
-            "max_decode_len": 4096,
-            "weight_mb": 1234.5,
-            "quantize": "int8 weights + int8 kv",
-            "fp_tokens_per_sec_per_chip": 969.1234,
-            "int8_stack_speedup": 2.2098765,
-            "vs_baseline": 0.9956789,
-            "quality": {"fp_eval_loss": 2.41, "int8_eval_loss": 2.43},
-            "ttft_ms_p50": 181.234567,
-            "ttft_ms_p99": 423.456789,
-            "tpot_ms_p50": 3.73456789,
-            "tpot_ms_p99": 5.91234567,
-        },
-        "bert": {
-            "metric": "bert_base_seqs_per_sec_per_chip",
-            "value": 1250.123,
-            "unit": "seqs/sec/chip",
-            "mfu": {
-                "model_tflops_per_sec": 107.0,
-                "vs_peak_pct": 54.3,
-            },
-        },
-        "vit": {
-            "metric": "vit_b16_images_per_sec_per_chip",
-            "value": 882.123,
-            "unit": "images/sec/chip",
-            "mfu": {
-                "model_tflops_per_sec": 46.6,
-                "vs_peak_pct": 23.6,
-            },
-        },
-        "schedule_to_first_step_s": {
-            "cold": 11.234,
-            "warm": 1.297,
-            "cold_phases": {
-                "submit_to_launch_s": 0.123,
-                "launch_to_main_s": 0.456,
-                "rendezvous_s": 0.01,
-                "import_jax_s": 2.1,
-                "client_init_s": 3.2,
-                "compile_s": 4.5,
-                "first_exec_s": 0.9,
-            },
-            "warm_phases": {
-                "submit_to_launch_s": 0.1,
-                "launch_to_main_s": 0.4,
-                "rendezvous_s": 0.01,
-                "import_jax_s": 0.3,
-                "client_init_s": 0.15,
-                "compile_s": 0.3,
-                "first_exec_s": 0.05,
-            },
-        },
-    }
-
-    def test_compact_worst_case_fits_tail_window(self):
-        import json
-
-        import bench
-
-        line = json.dumps(bench.compact(self.FULL))
-        assert len(line.encode()) <= bench.COMPACT_MAX_BYTES, len(line)
-        c = json.loads(line)
-        # The round-over-round trackers must survive compaction.
-        assert c["value"] == pytest.approx(44983.1235)
-        assert c["vs_baseline"] == pytest.approx(1.1085)
-        assert c["mfu_pct"] == pytest.approx(52.5)
-        assert c["resnet"]["vs_baseline"] == pytest.approx(1.015)
-        assert c["serving"]["vs_baseline"] == pytest.approx(0.9957)
-        assert c["serving"]["int8_stack_speedup"] == pytest.approx(2.2099)
-        assert c["serving"]["ttft_ms_p50"] == pytest.approx(181.2346)
-        assert c["serving"]["tpot_ms_p99"] == pytest.approx(5.9123)
-        assert c["serving"]["quality"] == {
-            "fp_eval_loss": 2.41, "int8_eval_loss": 2.43,
-        }
-        assert c["real_data"]["learned"] is True
-        assert c["real_data"]["eval_loss"] == pytest.approx(2.4123)
-        assert c["scale_1b"]["mfu_pct"] == pytest.approx(63.0)
-        assert c["moe"]["mfu_pct"] == pytest.approx(38.8)
-        assert c["schedule_to_first_step_s"] == {"cold": 11.234, "warm": 1.297}
-        assert c["detail"] == "BENCH_DETAIL.json"
-        assert c["device"]["device_kind"] == "TPU v5 lite"
-        assert c["failed_legs"] == ["vit"]
-        # Phase breakdowns are detail, not trackers — they must NOT ride.
-        assert "cold_phases" not in json.dumps(c)
-
-    def test_compact_resnet_led_fallback(self):
-        """If the LM leg failed, the artifact is resnet-led; compact
-        must still produce a valid tracked line."""
-        import json
-
-        import bench
-
-        out = {
-            "metric": "resnet50_images_per_sec_per_chip",
-            "value": 2706.9,
-            "unit": "images/sec/chip",
-            "vs_baseline": 1.015,
-        }
-        c = bench.compact(out)
-        assert c["value"] == 2706.9 and c["vs_baseline"] == 1.015
-        assert len(json.dumps(c).encode()) <= bench.COMPACT_MAX_BYTES
-
-    def test_compact_degrades_on_pathological_values(self):
-        """A huge leaked string can't break the line: the CORRUPT block
-        drops first (largest-first eviction), the cap holds, and every
-        healthy tracker survives — even when the corruption lands in an
-        early-inserted block like resnet."""
-        import json
-
-        import bench
-
-        for victim in ("vit", "resnet"):
-            out = dict(self.FULL)
-            out[victim] = dict(out[victim], unit="x" * 5000)
-            c = bench.compact(out)
-            assert len(json.dumps(c).encode()) <= bench.COMPACT_MAX_BYTES
-            assert c["value"] == pytest.approx(44983.1235)
-            assert victim not in c  # the culprit was evicted...
-            # ...and the healthy trackers were not.
-            assert c["serving"]["vs_baseline"] == pytest.approx(0.9957)
-            assert c["schedule_to_first_step_s"]["warm"] == 1.297
-
-    @pytest.mark.slow
-    def test_main_final_stdout_line_is_compact(self, tmp_path):
-        """End-to-end: `python bench.py --smoke` must end stdout with a
-        parseable line under the cap, and write the detail sidecar."""
-        import json
-        import os
-        import subprocess
-        import sys
-        from pathlib import Path
-
-        root = Path(__file__).resolve().parent.parent
-        detail = tmp_path / "detail.json"
-        env = dict(os.environ, TPUJOB_BENCH_DETAIL=str(detail))
-        proc = subprocess.run(
-            [
-                sys.executable, str(root / "bench.py"), "--smoke",
-                "--steps", "2", "--warmup", "1", "--no-latency",
-            ],
-            cwd=root, env=env, capture_output=True, text=True, timeout=600,
-        )
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        last = proc.stdout.strip().splitlines()[-1]
-        assert len(last.encode()) <= 2000  # the driver's tail window
-        c = json.loads(last)
-        assert c["unit"] == "tokens/sec/chip" and c["value"] > 0
-        assert c["resnet"]["value"] > 0
-        # The line names the device it ran on and the legs that raised.
-        assert c["device"]["platform"] == "cpu" and c["failed_legs"] == []
-        # The sidecar holds the full detail, including what compaction
-        # dropped (final_loss, ...).
-        full = json.loads(detail.read_text())
-        assert full["metric"] == c["metric"]
-        assert "final_loss" in full and "final_loss" not in c

@@ -442,6 +442,68 @@ class TestRemediationDiscipline:
 
 
 # ---------------------------------------------------------------------------
+# rule 8: layer-direction
+
+
+class TestLayerDirection:
+    def test_upward_imports_from_the_compute_layer_fire(self, tmp_path):
+        rep = analyze_fixture(tmp_path, {
+            "models/serving.py": """
+                import fix.controller.store
+
+                def families():
+                    # function-level imports count
+                    from ..workloads.llama_train import CONFIGS
+                    return CONFIGS
+            """,
+            "ops/attend.py": """
+                from .. import serving
+            """,
+            "parallel/nested/mesh.py": """
+                from fix.client import cli
+            """,
+        })
+        got = rule_findings(rep, "layer-direction")
+        msgs = " | ".join(f.message for f in got)
+        assert len(got) == 4, msgs
+        for target in (
+            "controller.store", "workloads.llama_train.CONFIGS",
+            "imports serving", "client.cli",
+        ):
+            assert target in msgs
+
+    def test_downward_and_sideways_imports_are_clean(self, tmp_path):
+        rep = analyze_fixture(tmp_path, {
+            # the compute layer among itself, and a module of it that
+            # merely shares a driving layer's name
+            "models/serving.py": """
+                import jax
+                import workloads
+
+                from . import llama
+                from ..ops import attend
+                from ..parallel.moe import moe_swiglu_held
+                from fix.runtime import backend
+            """,
+            # what drives the compute layer imports it freely
+            "workloads/llama_train.py": """
+                from ..models.llama import CONFIGS
+                from ..serving import engine
+            """,
+            "serving/engine.py": """
+                from ..models import serving
+            """,
+        })
+        assert rule_findings(rep, "layer-direction") == []
+
+    def test_the_repo_compute_layer_imports_nothing_that_drives_it(
+        self, repo_report
+    ):
+        got = [f for f in repo_report.findings if f.rule == "layer-direction"]
+        assert got == [], repo_report.render_text()
+
+
+# ---------------------------------------------------------------------------
 # waiver syntax
 
 
