@@ -53,18 +53,7 @@ from typing import Dict, Optional, Tuple
 _JAX_ENV_CONFIG = (
     ("JAX_COMPILATION_CACHE_DIR", "jax_compilation_cache_dir"),
     ("JAX_PLATFORMS", "jax_platforms"),
-    (
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-        "jax_persistent_cache_min_compile_time_secs",
-    ),
 )
-
-
-def _coerce(cfg_key: str, raw: str):
-    """jax.config options are typed; env vars are strings."""
-    if cfg_key == "jax_persistent_cache_min_compile_time_secs":
-        return float(raw)
-    return raw
 
 
 # ---- the standby process ----
@@ -97,7 +86,7 @@ def _run_assignment(spec: dict) -> int:
 
     for env_key, cfg_key in _JAX_ENV_CONFIG:
         if env.get(env_key):
-            jax.config.update(cfg_key, _coerce(cfg_key, env[env_key]))
+            jax.config.update(cfg_key, env[env_key])
     # Route all output to the replica's log file (kubectl-logs analog) —
     # fd-level dup2 so subprocesses and C extensions follow too.
     log_fd = os.open(

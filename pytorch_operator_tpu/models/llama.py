@@ -304,6 +304,24 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+class Dense(nn.DenseGeneral):
+    """``nn.DenseGeneral`` whose kernel may arrive as an int8
+    :class:`ops.quantize.QuantizedTensor` (the serving forward hands each
+    layer's leaves on as they are held): the product then reads the int8
+    payload and the scale follows it (``QuantizedTensor.project``). Every
+    projection of a block is one of these, so one rule serves all seven
+    weights; a plain kernel is ``nn.DenseGeneral``'s own business (same
+    parameter, same init, same product)."""
+
+    def __call__(self, x):
+        from ..ops.quantize import QuantizedTensor
+
+        kernel = None if self.is_initializing() else self.get_variable("params", "kernel")
+        if isinstance(kernel, QuantizedTensor):
+            return kernel.project(x.astype(self.dtype))
+        return super().__call__(x)
+
+
 class Attention(nn.Module):
     """Grouped-query attention with RoPE and a causal mask.
 
@@ -316,12 +334,12 @@ class Attention(nn.Module):
     mesh: Any = None
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, slot=None):
         cfg = self.cfg
         B, S, _ = x.shape
         H, K, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-        q = nn.DenseGeneral(
+        q = Dense(
             (H, D), use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), ("embed", "heads", "head_dim")
@@ -331,11 +349,11 @@ class Attention(nn.Module):
         kv_kernel = nn.with_logical_partitioning(
             nn.initializers.lecun_normal(), ("embed", "kv_heads", "head_dim")
         )
-        k = nn.DenseGeneral(
+        k = Dense(
             (K, D), use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=kv_kernel, name="k_proj",
         )(x)
-        v = nn.DenseGeneral(
+        v = Dense(
             (K, D), use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=kv_kernel, name="v_proj",
         )(x)
@@ -347,7 +365,7 @@ class Attention(nn.Module):
         G = cfg.q_per_kv
         q = q.reshape(B, S, K, G, D)
         if cfg.decode:
-            return self._decode_attend(q, k, v, positions)
+            return self._decode_attend(q, k, v, positions, slot)
         if cfg.attn_impl == "ring":
             if self.mesh is None:
                 raise ValueError(
@@ -401,7 +419,7 @@ class Attention(nn.Module):
 
     def _o_proj(self, out):
         cfg = self.cfg
-        return nn.DenseGeneral(
+        return Dense(
             cfg.d_model, axis=-1, use_bias=False,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=nn.with_logical_partitioning(
@@ -410,7 +428,7 @@ class Attention(nn.Module):
             name="o_proj",
         )(out)
 
-    def _decode_attend(self, q, k, v, positions):
+    def _decode_attend(self, q, k, v, positions, slot=None):
         """KV-cache attention (prefill AND single-token decode steps).
 
         Cache: ``cached_key``/``cached_value`` [B, K, max_decode_len, D]
@@ -431,6 +449,13 @@ class Attention(nn.Module):
         silently wrong (not an error), ``TPUJOB_DEBUG_CHECKS=1``
         installs a host-callback assert at the model top level (see
         ``Llama.__call__`` — once per step, not per layer).
+
+        ``slot`` (a traced scalar; the serving engine's prefill chunk):
+        the batch is ONE row that lives at row ``slot`` of slabs holding
+        every slot. Its keys, values and scales are written at
+        ``(slot, :, start:start + S, :)`` of those slabs and its attention
+        reads that row's blocks where it cuts them: no row-sized copy
+        leaves the slabs or returns to them.
         """
         cfg = self.cfg
         B, S, K, G, D = q.shape
@@ -462,7 +487,7 @@ class Attention(nn.Module):
             # The incoming S tokens sit at contiguous positions starting
             # at positions[:, 0] (prefill: the prompt or a chunk of it;
             # decode: one token at the current index).
-            if cfg.decode_per_row:
+            if cfg.decode_per_row and slot is None:
                 # Per-row write offsets: a batched update-slice (XLA
                 # lowers the vmapped DUS to a scatter). Only the serving
                 # engine's mixed-depth batches pay this; the uniform
@@ -477,11 +502,13 @@ class Attention(nn.Module):
                     )(slab, vals, starts)
 
             else:
-                start = positions[0, 0]
+                # One update-slice: over rows [0, B), or at row ``slot``
+                # of slabs that hold every slot.
+                row, start = 0 if slot is None else slot, positions[0, 0]
 
                 def write(slab, vals):
                     return jax.lax.dynamic_update_slice(
-                        slab, vals, (0, 0, start, 0)
+                        slab, vals, (row, 0, start, 0)
                     )
 
             k_in = k.swapaxes(1, 2)  # [B, K, S, D]
@@ -519,12 +546,12 @@ class Attention(nn.Module):
             # written above at their true positions, so intra-chunk
             # causality and the prefix both fall out of the col <= row
             # mask.
-            out = self._cache_attend(q, positions, ck, cv, ks, vs)
+            out = self._cache_attend(q, positions, ck, cv, ks, vs, slot)
         out = out.reshape(B, S, K * G * D)
         out = nn.with_logical_constraint(out, ("batch", "seq", None))
         return self._o_proj(out)
 
-    def _cache_attend(self, q, positions, ck, cv, ks, vs):
+    def _cache_attend(self, q, positions, ck, cv, ks, vs, slot=None):
         """q against the cache's filled prefix under a per-(row, token)
         position-validity mask (ops/cache_attention.py: the slab is read
         in blocks up to the one that holds the deepest query's position,
@@ -535,7 +562,7 @@ class Attention(nn.Module):
         from ..ops.cache_attention import cache_attention
 
         scales = (ks.value, vs.value) if ks is not None else ()
-        return cache_attention(q, positions, ck.value, cv.value, *scales)
+        return cache_attention(q, positions, ck.value, cv.value, *scales, slot=slot)
 
 
 class MLP(nn.Module):
@@ -546,7 +573,7 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        proj = lambda name: nn.DenseGeneral(  # noqa: E731
+        proj = lambda name: Dense(  # noqa: E731
             cfg.d_ff, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), ("embed", "mlp")
@@ -555,7 +582,7 @@ class MLP(nn.Module):
         )
         h = nn.silu(proj("gate_proj")(x)) * proj("up_proj")(x)
         h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
-        return nn.DenseGeneral(
+        return Dense(
             cfg.d_model, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), ("mlp", "embed")
@@ -605,6 +632,10 @@ class MoEMLP(nn.Module):
             (E, F, D),
             cfg.param_dtype,
         )
+        # The serving forward hands quantized banks on as they are held.
+        from ..ops.quantize import dequantize_tree
+
+        w_in, w_out = dequantize_tree((w_in, w_out), cfg.dtype)
         params = {
             "gate": gate,
             "w_in": w_in.astype(cfg.dtype),
@@ -647,17 +678,21 @@ class MoEMLP(nn.Module):
 
 
 class Block(nn.Module):
-    """Pre-norm decoder block; carries (hidden, positions) through scan."""
+    """Pre-norm decoder block; carries (hidden, positions) through scan.
+    The second argument is scan's per-layer input, which the model has
+    none of; the serving forward, which applies one block at a time,
+    hands the cache row its one-row batch lives in (``slot``,
+    :meth:`Attention._decode_attend`) through it."""
 
     cfg: LlamaConfig
     mesh: Any = None
 
     @nn.compact
-    def __call__(self, carry, _):
+    def __call__(self, carry, slot=None):
         x, positions = carry
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         x = x + Attention(self.cfg, self.mesh, name="attn")(
-            RMSNorm(self.cfg.rms_eps, name="attn_norm")(x), positions
+            RMSNorm(self.cfg.rms_eps, name="attn_norm")(x), positions, slot
         )
         if self.cfg.n_experts > 0:
             mlp = MoEMLP(self.cfg, self.mesh, name="moe_mlp")
@@ -900,6 +935,7 @@ def decode_forward(
     positions=None,
     *,
     return_hidden: bool = True,
+    slot=None,
 ):
     """The SERVING forward: numerically identical to
     ``Llama(decode=True).apply`` (pinned by test), but with the layer
@@ -914,9 +950,16 @@ def decode_forward(
     exactly that (copies and dynamic-slice/update-slice fusions).
     Here each layer's slab is a plain carry leaf: the step reads it once
     (fused into the attention einsums) and writes ONE token slice in
-    place. Quantized (``cfg.quantize``) trees are dequantized per layer
-    at the use site — python-unrolled, so there is no scan-input
-    materialization hazard and no map_variables hook is needed.
+    place. Quantized (``cfg.quantize``) trees reach each layer's modules
+    as they are held: a projection multiplies by the int8 payload and
+    scales the result (:class:`Dense`), so no dequantised copy of a
+    weight is ever an array of its own — python-unrolled, so there is no
+    scan-input materialization hazard and no map_variables hook is needed.
+
+    ``slot`` (a traced scalar): ``tokens`` is one row, whose cache is row
+    ``slot`` of ``cache``'s slabs; it is written and read in place
+    (:meth:`Attention._decode_attend`). Without it row ``b`` of the batch
+    owns row ``b`` of the cache.
 
     Returns ``(hidden_or_logits, new_cache)``.
     """
@@ -947,16 +990,19 @@ def decode_forward(
             x = table.astype(cfg.dtype)[tokens]
 
     block = Block(cfg, model.mesh)
+    layers = p["layers"]
     new_cache = {}
     for i in range(cfg.n_layers):
-        # Static per-layer slice; QuantizedTensor is a pytree node, so
-        # its q/scale fields are sliced like any other stacked leaf.
-        lp = dequantize_tree(jax.tree.map(lambda a: a[i], p["layers"]))
+        # A layer's own tree where the parameters are held per layer
+        # (:func:`per_layer_params`, the serving arrangement); else a static
+        # slice of the scan-stacked leaves (QuantizedTensor is a pytree
+        # node, so its q/scale fields are sliced like any other leaf).
+        lp = layers[i] if isinstance(layers, list) else jax.tree.map(lambda a: a[i], layers)
         with nn.logical_axis_rules(()):
             ((x, _pos), _), upd = block.apply(
                 {"params": lp, "cache": cache[f"layer_{i}"]},
                 (x, positions),
-                None,
+                slot,
                 mutable=["cache"],
             )
         new_cache[f"layer_{i}"] = upd["cache"]
@@ -972,12 +1018,33 @@ def decode_forward(
     return logits, new_cache
 
 
+def per_layer_params(params):
+    """``params`` with its scan-stacked ``layers`` (every leaf leading
+    with the layer axis: what ``Llama.init`` makes and the trainer saves)
+    as a list of per-layer trees, which is how the serving forward reads
+    them fastest: sliced out of a stacked parameter inside a program, a
+    layer's int8 q/k/v kernels were copied to an array of their own at
+    every prefill chunk and every layer's weights read slower at every
+    decode step (PERF.md section 6, PR 31: 4.31 -> 3.68 ms a chunk, 8.23
+    -> 7.13 ms a step on the v5e). Plain indexing: views of a host
+    checkpoint, slices inside the program that makes or quantises the
+    weights (where it costs nothing: that program writes each layer's
+    leaves instead of the stack). A tree already held per layer passes
+    through."""
+    layers = params.get("layers")
+    if not layers or isinstance(layers, list):  # nothing to arrange (a caller's shape check says what is missing)
+        return params
+    n = jax.tree.leaves(layers)[0].shape[0]
+    return {**params, "layers": [jax.tree.map(lambda a: a[i], layers) for i in range(n)]}
+
+
 def serving_model(cfg: LlamaConfig):
     """This family behind ``models.serving.ServingModel``: a per-row decode
     model and a chunked-prefill (``prefill_mode="cache"``) model over one
     :func:`init_decode_cache`, both through :func:`decode_forward`; the
     seeded init is the training model's (float32, the whole tree in one
-    program: ``workloads.generate.load_params`` quantises or commits it)."""
+    program: ``workloads.generate.load_params`` quantises or commits it),
+    with the layers a tree each, as ``arrange`` makes a checkpoint's."""
     from .serving import ServingModel
 
     if not cfg.decode:
@@ -989,18 +1056,19 @@ def serving_model(cfg: LlamaConfig):
 
     def init_params(key):
         # Looked up at call time: a caller that brings its own seeded
-        # leaves (the benchmark) wraps ``Llama.init``.
-        return nn.meta.unbox(
-            jax.jit(
-                lambda k: Llama(train_cfg).init(k, jnp.zeros((1, 8), jnp.int32))["params"]
-            )(key)
-        )
+        # leaves (the benchmark) wraps ``Llama.init``. The one program
+        # writes the layers a tree each (:func:`per_layer_params`).
+        return jax.jit(
+            lambda k: per_layer_params(
+                nn.meta.unbox(Llama(train_cfg).init(k, jnp.zeros((1, 8), jnp.int32))["params"])
+            )
+        )(key)
 
-    def prefill(params, row, tokens, positions):
-        hidden, row = decode_forward(
-            prefill_model, params, row, tokens, positions, return_hidden=True
+    def prefill(params, cache, slot, tokens, positions):
+        hidden, cache = decode_forward(
+            prefill_model, params, cache, tokens, positions, return_hidden=True, slot=slot
         )
-        return hidden, row, {}
+        return hidden, cache, {}
 
     def decode(params, cache, tok, pos):
         logits, cache = decode_forward(
@@ -1019,6 +1087,7 @@ def serving_model(cfg: LlamaConfig):
         prefill=prefill,
         decode=decode,
         logits=logits,
+        arrange=per_layer_params,
     )
 
 

@@ -22,9 +22,14 @@ def _nothing(*_):
 @dataclasses.dataclass(frozen=True)
 class ServingModel:
     """A model as the engine sees it. ``cache`` is the model's own state
-    object: any pytree whose every leaf leads with the slot axis (the
-    engine slices one slot's row out for a prefill chunk and writes it
-    back; what the leaves are is the model's business). ``counts`` are
+    object: any pytree whose every leaf leads with the slot axis; what the
+    leaves are is the model's business. The engine donates the whole of it
+    to both forwards and never takes a row out itself: ``prefill`` is told
+    which slot its one row of tokens lives in and leaves every other
+    slot's leaves as they were (the llama family writes the chunk's keys
+    and values in place and reads that row's filled prefix where it lies;
+    the layer-pattern family slices its small row out and writes it back,
+    inside its own forward). ``counts`` are
     the model's own device counters at zero (a pytree of int32 arrays, ``{}``
     for none): each forward returns what one call adds, the engine's two
     programs sum them on the device, and ``ServingEngine.stats()`` brings
@@ -35,14 +40,20 @@ class ServingModel:
     init_params: Callable
     # (slots, chunk) -> cache.
     init_cache: Callable
-    # (params, one slot's row of the cache, tokens [1, chunk], positions
-    # [1, chunk]) -> (final-norm hidden [1, chunk, D], row, counts).
+    # (params, cache, slot (a traced int32 scalar), tokens [1, chunk],
+    # positions [1, chunk]) -> (final-norm hidden [1, chunk, D], cache,
+    # counts): one call shape for every family.
     prefill: Callable
     # (params, cache, tokens [slots, 1], positions [slots, 1]), every row
     # at its own position -> (float32 logits [slots, V], cache, counts).
     decode: Callable
     # (params, hidden [n, D]) -> float32 logits [n, V].
     logits: Callable
+    # a checkpoint's parameter tree (the trainer's form, host arrays) ->
+    # the same leaves as ``init_params`` arranges them, which is how the
+    # forwards read them fastest (``workloads.generate.load_params`` calls
+    # it on what it restores). Plain indexing, no copy on the host.
+    arrange: Callable = lambda params: params
     counts: Any = dataclasses.field(default_factory=dict)
     # cache -> {gauge: number}, read once (shapes, not values).
     gauges: Callable = _nothing

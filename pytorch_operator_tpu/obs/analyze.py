@@ -492,6 +492,17 @@ def analyze(
         for replica, rs in sorted(tl.progress.items())
     }
 
+    # What each replica compiled and what it found in the compile cache
+    # (runtime/backend.py counts; the first_step and metrics records carry
+    # the totals so far): a warm start compiles nothing.
+    compiled: Dict[str, dict] = {}
+    for kind, at in (("first_step", "to_first_step"), ("metrics", "in_all")):
+        for rec in tl.records.get(kind, []):  # sorted by time: the last one stays
+            if "programs_compiled" in rec:
+                compiled.setdefault(rec["replica"], {})[at] = [
+                    rec["programs_compiled"], rec.get("programs_from_cache", 0)
+                ]
+
     return {
         "job": key,
         "generated_at": _time.time() if now is None else now,
@@ -500,6 +511,7 @@ def analyze(
         "restarts": restarts,
         "clock": {r: est.to_dict() for r, est in sorted(tl.clock.items())},
         "replicas": replicas,
+        "programs_compiled": compiled,
         "events": len(tl.events),
         "spans": len(tl.spans),
         "exemplars": exemplars,
@@ -569,6 +581,14 @@ def render_report(report: dict) -> str:
             for r, e in clock.items()
         ]
         lines.append("clock:    " + "; ".join(parts))
+    for replica, counts in sorted(report.get("programs_compiled", {}).items()):
+        lines.append(
+            f"compiled: {replica} "
+            + ", ".join(
+                f"{n} program(s) compiled and {hits} from the cache {at.replace('_', ' ')}"
+                for at, (n, hits) in counts.items()
+            )
+        )
     alerts = report.get("alerts", [])
     findings = report.get("findings", [])
     ttft = report.get("ttft_attribution")

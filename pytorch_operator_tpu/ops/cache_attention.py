@@ -54,7 +54,7 @@ def attended(needed, L: int):
     return blocks_needed(np.asarray(needed), L) * block(L)
 
 
-def cache_attention(q, positions, k, v, k_scale=None, v_scale=None):
+def cache_attention(q, positions, k, v, k_scale=None, v_scale=None, *, slot=None):
     """Grouped-query attention of ``q [B, S, K, G, dk]``, whose queries
     stand at ``positions [B, S]``, against a cache ``k [B, K, L, dk]`` /
     ``v [B, K, L, dv]`` that already holds the queries' own keys and values:
@@ -62,6 +62,11 @@ def cache_attention(q, positions, k, v, k_scale=None, v_scale=None):
     ``k_scale`` / ``v_scale`` ``[B, K, L, 1]`` float32 are an int8 cache's
     per-position scales. Float32 scores and softmax; the result
     ``[B, S, K, G, dv]`` in the queries' dtype.
+
+    With ``slot`` (a traced scalar) the queries are one row (``B == 1``)
+    whose cache is row ``slot`` of slabs ``[slots, K, L, d]``: each block
+    is cut out of that row where it lies, so the row is never an array of
+    its own (a prefill chunk in the serving engine).
 
     Only the blocks up to the deepest query's position are read."""
     B, S, K, G, dk = q.shape
@@ -71,7 +76,9 @@ def cache_attention(q, positions, k, v, k_scale=None, v_scale=None):
     lowest = jnp.finfo(jnp.float32).min
 
     def cut(slab, i):
-        return jax.lax.dynamic_slice_in_dim(slab, i * T, T, axis=2)
+        if slot is None:
+            return jax.lax.dynamic_slice_in_dim(slab, i * T, T, axis=2)
+        return jax.lax.dynamic_slice(slab, (slot, 0, i * T, 0), (1, K, T, slab.shape[-1]))
 
     def per_position(scale, i):  # [B, K, T, 1] -> over scores [B, K, G, S, T]
         return cut(scale, i).squeeze(-1)[:, :, None, None, :]

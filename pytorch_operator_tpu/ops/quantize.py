@@ -12,13 +12,28 @@ the bytes. Symmetric per-channel int8 cuts the streamed weight bytes
 
 TPU-first mechanics, and why this is NOT a "dequantize then run" wrapper:
 
-- Quantized leaves stay **int8 in HBM**. ``dequantize_tree`` is traced
-  *inside* the jitted decode step, so the emitted HLO is
-  ``convert(s8) * scale`` feeding each matmul — XLA fuses that
-  elementwise chain into the dot's operand read (the same fusion this
-  tree already leans on for its f32-param → bf16-compute casts
-  everywhere), so no full-size bf16/f32 copy of the weights ever
-  materializes; the per-step HBM traffic is the int8 bytes.
+- Quantized leaves stay **int8 in HBM**, and a product reads them there.
+  The serving forward (``models.llama.decode_forward``) hands each layer's
+  :class:`QuantizedTensor` leaves to the modules that multiply by them,
+  and those call :meth:`QuantizedTensor.project`: the int8 payload is
+  converted (exactly) inside the product's own fusion, the product
+  accumulates in float32, and the per-channel scale multiplies the small
+  result. The other order, ``dequantize()`` then product, this module used
+  to claim "always fuses into the product's operand read". It did for the
+  2-D kernels and in the decode loop; in the serving engine's prefill
+  chunk (128 rows a product) the compiler wrote a bfloat16 copy of every
+  layer's 3-D ``q_proj`` / ``k_proj`` / ``v_proj`` kernel to HBM at every
+  call, 403 MB out for 201 MB in, and read that instead (PERF.md section
+  6, PR 31: found in the compiled program and timed in the chip's trace).
+  With the scale behind the product no dequantised weight exists as a
+  value that could be written anywhere. What the compiler may still copy
+  is the int8 payload itself, where a layer's kernel has to be sliced out
+  of a scan-stacked parameter first: the serving stack therefore holds
+  its layers a tree each (``models.llama.per_layer_params``), and a
+  kernel is then an argument of the program as it lies.
+  ``dequantize_tree`` remains for the consumers that want plain arrays
+  (``Llama.__call__``'s scan under ``map_variables``, the expert banks,
+  tests).
 - Inside ``lax.scan`` decode loops the dequant is loop-invariant, but
   XLA's while-loop code motion declines to hoist size-inflating ops
   (a convert s8→f32 quadruples bytes), so the fusion — and the memory
@@ -64,6 +79,29 @@ class QuantizedTensor:
 
     def dequantize(self, dtype=jnp.float32) -> jax.Array:
         return (self.q.astype(jnp.float32) * self.scale).astype(dtype)
+
+    def project(self, x: jax.Array) -> jax.Array:
+        """``x [..., in] @ w [in, *out]`` for a weight quantized over its
+        leading (contracted) axis, the scale BEHIND the product:
+        ``(x @ q) * scale``, in ``x``'s dtype. One scale per output channel
+        is constant along the contraction, so this is the same mathematics
+        as ``x @ dequantize()``; the int8 -> ``x.dtype`` convert is exact
+        (256 levels fit a bf16 mantissa), the product accumulates in
+        float32 and the result is rounded once, where
+        ``x @ dequantize(bf16)`` rounds the weight and then the product.
+        The product's only large operand is the int8 payload as it lies
+        in HBM (module docstring: what the compiler made of the other
+        order)."""
+        if self.scale.shape[0] != 1 or self.scale.shape[1:] != self.q.shape[1:]:
+            raise ValueError(
+                f"project needs one scale per output channel over the leading "
+                f"axis; q {self.q.shape}, scale {self.scale.shape}"
+            )
+        y = jax.lax.dot_general(
+            x, self.q.astype(x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return (y * self.scale[0]).astype(x.dtype)
 
 
 def quantize(w: jax.Array, axis: int) -> QuantizedTensor:
@@ -118,6 +156,8 @@ def quantize_tree(params, *, rule=contract_axis):
             return type(node)(
                 {k: walk(v, path + (k,)) for k, v in node.items()}
             )
+        if isinstance(node, list):  # layers held one tree each: the names are the layer's own
+            return [walk(v, path) for v in node]
         axis = rule(path, node)
         return node if axis is None else quantize(node, axis)
 
@@ -126,9 +166,10 @@ def quantize_tree(params, *, rule=contract_axis):
 
 def dequantize_tree(tree, dtype=jnp.float32):
     """Map :class:`QuantizedTensor` leaves back to arrays (identity on
-    plain trees). Call this INSIDE the jitted consumer — see module
-    docstring — so the dequant fuses into the matmul operand reads
-    instead of materializing a full-precision weight copy."""
+    plain trees). Call this INSIDE the jitted consumer, so that the
+    compiler may fuse the dequantisation into the product's operand read;
+    where it must not write a copy, use :meth:`QuantizedTensor.project`
+    (module docstring)."""
     return jax.tree.map(
         lambda leaf: (
             leaf.dequantize(dtype) if isinstance(leaf, QuantizedTensor) else leaf

@@ -30,12 +30,40 @@ def compile_cache_dir() -> str:
     )
 
 
+# What JAX's compilation cache told this process so far, by the names the
+# status records carry (:func:`compile_counts`). A miss is recorded where a
+# freshly compiled program is written to the cache, which with the
+# threshold at 0 (:func:`setup_backend`) is every program compiled through
+# it; one with a host callback in it is never written, and never counted.
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_misses": "programs_compiled",
+    "/jax/compilation_cache/cache_hits": "programs_from_cache",
+}
+_compile_counts: Optional[dict] = None  # None until setup_backend places the cache
+
+
+def _count_cache_event(event: str, **_kwargs) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        _compile_counts[name] += 1
+
+
+def compile_counts() -> dict:
+    """``programs_compiled`` and ``programs_from_cache`` of this process up
+    to now, for a status record: a warm start reads 0 compiled. Empty where
+    :func:`setup_backend` placed no cache (never called, or the gloo world
+    that runs without one). Imports no JAX."""
+    return dict(_compile_counts or {})
+
+
 def setup_backend(platform: Optional[str] = None) -> str:
-    """Pin the JAX platform, enable gloo for multi-process CPU worlds and
-    place the compilation cache (:func:`compile_cache_dir`).
+    """Pin the JAX platform, enable gloo for multi-process CPU worlds,
+    place the compilation cache (:func:`compile_cache_dir`) and count what
+    it is asked for (:func:`compile_counts`).
 
     Must be called before any JAX computation or device query. Returns
     the pinned platform string (``""`` = JAX's own default)."""
+    global _compile_counts
     import jax
 
     platform = platform or os.environ.get("JAX_PLATFORMS", "")
@@ -64,6 +92,16 @@ def setup_backend(platform: Optional[str] = None) -> str:
         jax.config.update("jax_enable_compilation_cache", False)
     else:
         jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+        # Every compiled program is kept, however fast it compiled. JAX's
+        # default keeps only those that took a second or more, so a run's
+        # many small programs (a weight leaf's quantisation, a cache's
+        # zeros, a sampler) compiled again on every start, and one that
+        # takes about a second was kept or not by what the machine was
+        # doing on the checkout's first run (PERF.md section 6, PR 32).
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        if _compile_counts is None:
+            _compile_counts = dict.fromkeys(_CACHE_EVENTS.values(), 0)
+            jax.monitoring.register_event_listener(_count_cache_event)
         # The names on the device's work (``jax.named_scope``, Flax's module
         # names) reach a profile through the compiled program's metadata.
         # JAX leaves metadata out of the cache's key by default, so a hit

@@ -27,6 +27,8 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import Optional
 
+from .backend import compile_counts
+
 
 @dataclass
 class WorldInfo:
@@ -417,11 +419,14 @@ def report_device() -> dict:
 
 
 def report_first_step(step: int = 0) -> None:
-    report("first_step", step=step)
+    """The replica is up; with it, how many programs it had to compile to
+    get there and how many the compile cache held (a warm start: 0 and
+    all of them; ``tpujob why`` prints both)."""
+    report("first_step", step=step, **compile_counts())
 
 
 def report_metrics(step: int, **metrics) -> None:
-    report("metrics", step=step, **metrics)
+    report("metrics", step=step, **compile_counts(), **metrics)
 
 
 def report_progress(

@@ -30,15 +30,21 @@ TPU-first shape (every program's shapes static):
   last row's budget, never more than ``block``, the most steps one
   dispatch may run. The counters below say how often each rule sized a
   dispatch (``BENCHMARK.json``'s chat and longprompt cells judge it).
-- ONE prefill program: fixed-size chunks through the model's
-  chunked-prefill forward over one slot's row of the cache, last chunk padded
-  — the pad tokens write cache slots past the prompt that every later
-  read either masks (col <= row) or overwrites (the next decode token
-  lands exactly on the first padded slot before anything attends it).
-  A chunk attends the row's prefix up to its own last position, by the
-  same bounded attention. Arbitrary prompt lengths therefore hit exactly
-  two compiled programs, and a prompt longer than one program's
-  activation budget prefills in bounded O(chunk · L) score memory.
+- ONE prefill program, ``prefill_chunk``: fixed-size chunks through the
+  model's chunked-prefill forward into one slot's row of the donated
+  cache, in place (the model is told the slot; nothing row-sized is
+  sliced out or written back), last chunk padded — the pad tokens write
+  cache slots past the prompt that every later read either masks
+  (col <= row) or overwrites (the next decode token lands exactly on the
+  first padded slot before anything attends it). A chunk attends the
+  row's prefix up to its own last position, by the same bounded
+  attention, and runs no head: a small program of its own,
+  ``prefill_chunk_head``, takes the last chunk's hidden states to the
+  logits of the prompt's last position, once a prompt
+  (``prefill_head_chunks`` counts it). Arbitrary prompt lengths
+  therefore hit exactly these compiled programs, and a prompt longer
+  than one program's activation budget prefills in bounded
+  O(chunk · L) score memory.
 - Slot L-1 of every row is a parking slot: rows that exhaust their
   budget clamp there, so admission requires prompt + new <= L-1 and
   no live stream ever attends a parked write.
@@ -68,8 +74,8 @@ lists every name beside the metric that reads it):
   positions those tokens had live beside the positions attention read for
   them (``decode_attended_positions`` over ``decode_row_steps`` x
   ``max_decode_len`` is the share of the slabs still read; the same for
-  ``prefill_attended_positions`` over the chunks), prefill chunks and pad
-  tokens,
+  ``prefill_attended_positions`` over the chunks), prefill chunks, those
+  of them that ran the head, and pad tokens,
   admissions, the model's own counters (summed on the device inside the
   two programs, brought back at ``stats()``), and one clock that charges every
   second of the serving thread to a segment
@@ -81,9 +87,10 @@ lists every name beside the metric that reads it):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 
@@ -116,7 +123,8 @@ _COUNTERS = (
     "decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
     "decode_live_positions", "decode_attended_positions", "prefill_attended_positions",
     *(f"decode_sized_by_{reason}" for reason in SIZED_BY),
-    "prefill_chunks", "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "admitted",
+    "prefill_chunks", "prefill_head_chunks", "prefill_tokens", "prefill_pad_tokens",
+    "admit_rounds", "admitted",
 )
 SPAN_CAT = "engine"
 
@@ -191,6 +199,98 @@ class _Slot:
     done: bool = False
 
 
+class Programs(NamedTuple):
+    """The engine's compiled programs (:func:`programs`)."""
+
+    prefill_chunk: Callable
+    prefill_chunk_head: Callable
+    decode_block: Callable
+    first_token: Callable
+
+
+def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
+    """The engine's compiled programs over ``model`` (a
+    ``models.serving.ServingModel``). ``sample`` maps (float32 logits
+    [rows, V], key) to tokens [rows] (ops/sampling.py). A function of its
+    own so that a test can compile the very programs the engine runs, for
+    a chip that is described and not attached, from shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    L = model.cfg.max_decode_len
+    add = functools.partial(jax.tree.map, jnp.add)
+
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def prefill_chunk(params, cache, counts, slot, chunk_toks, start):
+        """One [1, chunk] prefill chunk into row ``slot`` of the batch
+        cache (slot/start are traced scalars: one program). The model
+        writes the chunk's keys and values into the donated slabs where
+        they belong and reads the row's filled prefix where it lies:
+        nothing row-sized is copied out or back. Returns the final-norm
+        hidden states [1, chunk, D] and runs no head: nobody reads the
+        logits of a prompt's earlier chunks (:func:`prefill_chunk_head`
+        is for its last). ``counts`` are the model's counters so far, to
+        which this call's are added."""
+        pos = (start + jnp.arange(chunk, dtype=jnp.int32))[None, :]
+        hidden, cache, added = model.prefill(params, cache, slot, chunk_toks, pos)
+        return hidden, cache, add(counts, added)
+
+    @jax.jit
+    def prefill_chunk_head(params, hidden, last_idx):
+        """The head on position ``last_idx`` ONLY of a prompt's last
+        chunk (the full [chunk, V] product costs as much as several
+        transformer layers): float32 logits [V]. A program of its own
+        and not a second form of the chunk's: a second copy of the whole
+        chunk program cost every run 2.3 s of set-up to load (PERF.md
+        section 6, PR 31). Its name keeps ``prefill_chunk`` in it, by
+        which the benchmark finds the prefill's programs."""
+        with jax.named_scope("head"):
+            h = jax.lax.dynamic_slice_in_dim(hidden, last_idx, 1, axis=1)
+            return model.logits(params, h[:, 0])[0]
+
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def decode_block(params, cache, counts, tok, pos, active, rng, steps):
+        """``steps`` (a traced int32, at most ``block``) decode steps
+        over all slots: tok/pos [slots] are each row's last accepted
+        token and its position; parked rows (active=False) stand at
+        position 0 whatever their last occupant left, so they do not
+        hold up the bound of the model's cache attention, and
+        re-write position 0 of their own empty row. Returns the
+        sampled tokens [slots, block], of which the first ``steps``
+        columns are written."""
+        pos = jnp.where(active, pos, 0)
+
+        def step(i, carry):
+            cache, counts, tok, pos, rng, toks = carry
+            logits, cache, added = model.decode(
+                params, cache, tok[:, None], pos[:, None]
+            )
+            rng, k = jax.random.split(rng)
+            nxt = sample(logits, k)
+            nxt = jnp.where(active, nxt, tok)
+            pos = jnp.where(
+                active, jnp.minimum(pos + 1, L - 1), pos
+            )
+            toks = jax.lax.dynamic_update_index_in_dim(toks, nxt, i, 0)
+            return cache, add(counts, added), nxt, pos, rng, toks
+
+        toks = jnp.zeros((block, slots), tok.dtype)
+        cache, counts, tok, pos, rng, toks = jax.lax.fori_loop(
+            0, steps, step, (cache, counts, tok, pos, rng, toks)
+        )
+        return toks.swapaxes(0, 1), cache, counts, tok, pos, rng
+
+    @jax.jit
+    def first_token(logits, key):
+        """First-token sampling as ONE compiled dispatch (eager
+        sort/softmax/categorical would each be a dispatch, billed to
+        every request's TTFT)."""
+        key, sub = jax.random.split(key)
+        return sample(logits[None, :], sub)[0], key
+
+    return Programs(prefill_chunk, prefill_chunk_head, decode_block, first_token)
+
+
 class ServingEngine:
     """Slot-based continuous batching over a model's decode stack.
 
@@ -214,8 +314,6 @@ class ServingEngine:
         eos_token: Optional[int] = None,
         seed: int = 0,
     ):
-        import functools
-
         import jax
         import jax.numpy as jnp
 
@@ -244,81 +342,11 @@ class ServingEngine:
         self._params = params
         self._rng = jax.random.key(seed)
         self._first_key = jax.random.key(seed + 1)
-        L = cfg.max_decode_len
-
-        sample = make_sampler(temperature, top_k, top_p)
-        add = functools.partial(jax.tree.map, jnp.add)
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def prefill_chunk(params, cache, counts, slot, chunk_toks, start, last_idx):
-            """One [1, chunk] prefill chunk into row ``slot`` of the
-            batch cache (slot/start/last_idx are traced scalars — one
-            program). Returns the head logits [V] of position
-            ``last_idx`` ONLY: the full [chunk, V] head matmul costs as
-            much as several transformer layers and all but one row
-            would be discarded (intermediate chunks pass 0 and ignore
-            the result). ``counts`` are the model's counters so far, to
-            which this call's are added."""
-            row = jax.tree.map(
-                lambda s: jax.lax.dynamic_slice_in_dim(s, slot, 1, 0), cache
-            )
-            pos = (start + jnp.arange(self.chunk, dtype=jnp.int32))[None, :]
-            hidden, row, added = model.prefill(params, row, chunk_toks, pos)
-            cache = jax.tree.map(
-                lambda s, r: jax.lax.dynamic_update_slice_in_dim(
-                    s, r, slot, 0
-                ),
-                cache,
-                row,
-            )
-            with jax.named_scope("head"):
-                h = jax.lax.dynamic_slice_in_dim(hidden, last_idx, 1, axis=1)
-                logits = model.logits(params, h[:, 0])
-            return logits[0], cache, add(counts, added)  # [V]
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def decode_block(params, cache, counts, tok, pos, active, rng, steps):
-            """``steps`` (a traced int32, at most ``block``) decode steps
-            over all slots: tok/pos [slots] are each row's last accepted
-            token and its position; parked rows (active=False) stand at
-            position 0 whatever their last occupant left, so they do not
-            hold up the bound of the model's cache attention, and
-            re-write position 0 of their own empty row. Returns the
-            sampled tokens [slots, block], of which the first ``steps``
-            columns are written."""
-            pos = jnp.where(active, pos, 0)
-
-            def step(i, carry):
-                cache, counts, tok, pos, rng, toks = carry
-                logits, cache, added = model.decode(
-                    params, cache, tok[:, None], pos[:, None]
-                )
-                rng, k = jax.random.split(rng)
-                nxt = sample(logits, k)
-                nxt = jnp.where(active, nxt, tok)
-                pos = jnp.where(
-                    active, jnp.minimum(pos + 1, L - 1), pos
-                )
-                toks = jax.lax.dynamic_update_index_in_dim(toks, nxt, i, 0)
-                return cache, add(counts, added), nxt, pos, rng, toks
-
-            toks = jnp.zeros((self.block, slots), tok.dtype)
-            cache, counts, tok, pos, rng, toks = jax.lax.fori_loop(
-                0, steps, step, (cache, counts, tok, pos, rng, toks)
-            )
-            return toks.swapaxes(0, 1), cache, counts, tok, pos, rng
-
-        @jax.jit
-        def first_token(logits, key):
-            """First-token sampling as ONE compiled dispatch (eager
-            sort/softmax/categorical would each be a dispatch, billed to
-            every request's TTFT)."""
-            key, sub = jax.random.split(key)
-            return sample(logits[None, :], sub)[0], key
-
-        self._first_token = first_token
-        self._prefill_chunk = prefill_chunk
-        self._decode_block = decode_block
+        (self._prefill_chunk, self._prefill_chunk_head, self._decode_block,
+         self._first_token) = programs(
+            model, slots=slots, chunk=chunk, block=block,
+            sample=make_sampler(temperature, top_k, top_p),
+        )
         self._jnp = jnp
         self._jax = jax
         self._attended = attended  # the cache attention's own rounding, for the counters
@@ -406,7 +434,6 @@ class ServingEngine:
         padded = -(-p // self.chunk) * self.chunk
         buf = np.zeros((padded,), np.int32)
         buf[:p] = prompt
-        logits = None
         last_valid = (p - 1) % self.chunk  # index within the FINAL chunk
         self._n["admitted"] += 1
         self._n["prefill_chunks"] += padded // self.chunk
@@ -417,23 +444,23 @@ class ServingEngine:
             self._attended(np.arange(self.chunk, padded + 1, self.chunk), L).sum()
         )
         for start in range(0, padded, self.chunk):
-            final = start + self.chunk >= padded
             with obs.span("engine.prefill_dispatch", SPAN_CAT, start=start):
                 args = (
                     jnp.int32(slot),
                     jnp.asarray(buf[None, start : start + self.chunk]),
                     jnp.int32(start),
-                    # Only the final chunk's last VALID position (not the
-                    # padded tail) feeds the first token.
-                    jnp.int32(last_valid if final else 0),
                 )
                 if start == 0:
                     # Up to this dispatch the device waited for the host;
                     # from here the host queues chunks behind chunks.
                     self.host_lap("admit_prep")
-                logits, self._cache, self._counts["prefill"] = self._prefill_chunk(
+                hidden, self._cache, self._counts["prefill"] = self._prefill_chunk(
                     self._params, self._cache, self._counts["prefill"], *args
                 )
+        # The last chunk's last VALID position (not the padded tail) feeds
+        # the first token: the head runs once a prompt.
+        logits = self._prefill_chunk_head(self._params, hidden, jnp.int32(last_valid))
+        self._n["prefill_head_chunks"] += 1
         self.host_lap("dispatch")
         first = self._sample_first(logits)
         first_time = time.time()

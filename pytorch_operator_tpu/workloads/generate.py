@@ -162,7 +162,8 @@ def load_params(
     # is the training model's float32 tree in one program, which the
     # quantisation below then shrinks; a family that serves its weights as
     # they are makes them in the serving dtype, a layer at a time.
-    make_params = dataclasses.replace(cfg, decode=True).serving_model().init_params
+    model = dataclasses.replace(cfg, decode=True).serving_model()
+    make_params = model.init_params
 
     restored_step = None
     if restore is not None:
@@ -183,6 +184,9 @@ def load_params(
                 raise ValueError(
                     f"checkpoint under {restore} has no 'params': {e}"
                 ) from None
+        # The trainer's tree as the model's own init arranges it (the llama
+        # family: its layers a tree each; views of the host arrays).
+        params = model.arrange(params)
         # Config check against the FULL expected structure (ADVICE r4):
         # an embedding-only check lets a wrong-n_layers/d_ff/n_heads
         # checkpoint through to an opaque stacked-param tracing error.

@@ -36,9 +36,6 @@ def test_rehearsal_passes_and_cache_follows_the_environment(tmp_path):
         env=_env(
             JAX_PLATFORMS="cpu",
             JAX_COMPILATION_CACHE_DIR=str(cache),
-            # Persist every program, so the count below cannot depend on
-            # how long this machine takes to compile a tiny model.
-            JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
         ),
         capture_output=True, text=True, timeout=600,
     )

@@ -110,7 +110,7 @@ def test_chunked_prefill_then_decode_logits_equal_the_full_forward(prompt_len):
     got = []
     for start in range(0, padded, chunk):
         pos = (start + jnp.arange(chunk, dtype=jnp.int32))[None]
-        hidden, cache, _ = model.prefill(params, cache, jnp.asarray(buf[None, start : start + chunk]), pos)
+        hidden, cache, _ = model.prefill(params, cache, jnp.int32(0), jnp.asarray(buf[None, start : start + chunk]), pos)
         got.append(np.asarray(model.logits(params, hidden[0])))
     got = np.concatenate(got)[:prompt_len]
     assert np.abs(got - ref[:prompt_len]).max() <= TOL

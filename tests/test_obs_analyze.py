@@ -474,6 +474,28 @@ class TestWhyCLI:
             "heartbeat_silence"
         ]
 
+    def test_why_prints_what_each_replica_compiled(self, tmp_path, capsys):
+        """The first_step and metrics records carry the compile cache's
+        counts (runtime/backend.py); `why` prints the last of each."""
+        from pytorch_operator_tpu.client.cli import main
+
+        state = tmp_path / "state"
+        _write_status(state, "default/pm", "master-0", _beats(100.0, 3, 0.5) + [
+            {"event": "first_step", "ts": 100.2, "step": 0,
+             "programs_compiled": 0, "programs_from_cache": 41},
+            {"event": "metrics", "ts": 100.9, "step": 1,
+             "programs_compiled": 9, "programs_from_cache": 41},
+            {"event": "metrics", "ts": 101.4, "step": 3,
+             "programs_compiled": 2, "programs_from_cache": 57},
+        ])
+        out = tmp_path / "report.json"
+        assert main(["--state-dir", str(state), "why", "pm", "--out", str(out)]) == 0
+        assert ("compiled: master-0 0 program(s) compiled and 41 from the cache to first step, "
+                "2 program(s) compiled and 57 from the cache in all") in capsys.readouterr().out
+        assert json.loads(out.read_text())["programs_compiled"] == {
+            "master-0": {"to_first_step": [0, 41], "in_all": [2, 57]}
+        }
+
     def test_why_errors_with_no_artifacts(self, tmp_path, capsys):
         from pytorch_operator_tpu.client.cli import main
 

@@ -463,7 +463,10 @@ class TestKVQuantize:
         ref, _ = decode_forward(
             model, dequantize_tree(qparams), init_decode_cache(cfg, 2), toks
         )
-        np.testing.assert_array_equal(np.asarray(got_q), np.asarray(ref))
+        # The attention's projections scale behind their product since PR
+        # 31 (float32 throughout here), the banks dequantize first: equal
+        # to rounding, no longer to the bit.
+        np.testing.assert_allclose(np.asarray(got_q), np.asarray(ref), rtol=1e-4, atol=1e-5)
 
     @pytest.mark.slow
     def test_decode_forward_tp_sharded_matches_unsharded(self):
