@@ -1,0 +1,134 @@
+"""What the families that serve an explicit LIST of layers share
+(``models/mimo_v2.py``, ``models/nemotron_h.py``): seeded leaves drawn a
+layer at a time in the serving dtype, RMSNorm, the write of a step's or a
+chunk's keys and values into a slab, the head's product, and the plumbing of
+the expert layers' device counters. What a layer computes, and what its
+cache holds, is each family's own.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+# ---- parameters: made in the serving dtype, a layer at a time ----
+
+
+def draw(key, shapes: dict) -> dict:
+    """``path -> (shape, fan_in, dtype)`` as a nested dict of seeded leaves.
+    ``fan_in`` None = ones (a norm scale); 0 = zeros (what a freshly made
+    model has of a sink logit or a selection bias); else normal with
+    variance 1 / fan_in."""
+    tree: dict = {}
+    for i, (path, (shape, fan_in, dtype)) in enumerate(sorted(shapes.items())):
+        if fan_in is None:
+            leaf = jnp.ones(shape, dtype)
+        elif fan_in == 0:
+            leaf = jnp.zeros(shape, dtype)
+        else:
+            leaf = (
+                jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+                * fan_in ** -0.5
+            ).astype(dtype)
+        node = tree
+        for name in path[:-1]:
+            node = node.setdefault(name, {})
+        node[path[-1]] = leaf
+    return tree
+
+
+def outer_shapes(cfg) -> dict:
+    """Embedding, final norm and untied head of ``cfg`` (``vocab_size``,
+    ``d_model``, ``param_dtype``)."""
+    D, V = cfg.d_model, cfg.vocab_size
+    return {
+        ("embed", "embedding"): ((V, D), 1, cfg.param_dtype),
+        ("final_norm", "scale"): ((D,), None, jnp.float32),
+        ("lm_head", "kernel"): ((D, V), D, cfg.param_dtype),
+    }
+
+
+def init_params(cfg, key, kinds, init_outer, init_layer) -> dict:
+    """The serving tree ``{embed, layers: [per-layer dict], final_norm,
+    lm_head}``, each layer made by a program of its own in the serving
+    dtype: no float32 copy of the whole tree ever sits on the device (the
+    largest transient is one leaf's float32 draw). ``kinds`` is the layers'
+    kinds in order (hashable: layers of one kind share a compiled program,
+    the layer's index traced); ``init_outer(cfg, key)`` and
+    ``init_layer(cfg, kind, key, layer)`` make the leaves."""
+    outer = jax.jit(lambda k: init_outer(cfg, k))(key)
+    make = jax.jit(
+        lambda kind, k, l: init_layer(cfg, kind, k, l), static_argnums=(0,)
+    )
+    layers = [make(kind, key, jnp.int32(l)) for l, kind in enumerate(kinds)]
+    return {**outer, "layers": layers}
+
+
+# ---- the forward's shared pieces ----
+
+
+def rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def write_positions(slab, vals, positions):
+    """Write ``vals [B, Hk, S, d]`` (or ``[B, S]`` for a ring's recorded
+    positions) at ``positions [B, S] % length`` of ``slab``'s position
+    axis. A single position a row (a decode step) is one update-slice a
+    row; a chunk is a scatter, since a ring may wrap inside it."""
+    T = slab.shape[-2] if slab.ndim == 4 else slab.shape[-1]
+    idx = positions % T
+    if slab.ndim == 2:
+        return jax.vmap(lambda c, u, i: c.at[i].set(u))(slab, vals, idx)
+    if idx.shape[1] == 1:
+        return jax.vmap(
+            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (0, i, 0))
+        )(slab, vals, idx[:, 0])
+    return jax.vmap(lambda c, u, i: c.at[:, i].set(u))(slab, vals, idx)
+
+
+def logits(params: dict, hidden):
+    """Float32 logits of ``hidden [..., D]``: the head's product accumulates
+    in float32 from the operands as they are held."""
+    return jnp.dot(hidden, params["lm_head"]["kernel"], preferred_element_type=jnp.float32)
+
+
+# ---- the expert layers' device counters (parallel/moe.py makes them) ----
+
+
+def zero_moe_counts(cfg) -> dict:
+    """The counters a forward adds to, at zero (int32; the engine drains
+    them to the host at every ``stats()``)."""
+    return {
+        "moe_tokens": jnp.zeros((), jnp.int32),
+        "moe_local_pairs": jnp.zeros((), jnp.int32),
+        "moe_expert_tokens": jnp.zeros((cfg.experts_held[1],), jnp.int32),
+        "moe_experts_touched": jnp.zeros((), jnp.int32),
+    }
+
+
+def derived_moe_stats(cfg, n: dict) -> dict:
+    """What ``ServingEngine.stats()`` adds from those counters: of the
+    experts the tokens selected, the share held here (100 x held / router's
+    width where the router really routes over all of them), and the busiest
+    held expert's tokens over the mean."""
+    per_expert = [int(t) for t in n["moe_expert_tokens"]]
+    mean = sum(per_expert) / len(per_expert)
+    picks = cfg.top_k * int(n["moe_tokens"])
+    return {
+        "expert_local_hit_pct": round(100.0 * int(n["moe_local_pairs"]) / picks, 4) if picks else None,
+        "expert_load_max_over_mean": round(max(per_expert) / mean, 4) if mean else None,
+    }
+
+
+def check_experts_held(cfg) -> None:
+    first, count = cfg.experts_held
+    if not (0 <= first and count >= 1 and first + count <= cfg.router_width):
+        raise ValueError(
+            f"experts_held {cfg.experts_held} outside the router's {cfg.router_width}"
+        )
+    if not 1 <= cfg.top_k <= cfg.router_width:
+        raise ValueError(f"top_k={cfg.top_k} outside [1, {cfg.router_width}]")

@@ -1064,7 +1064,9 @@ def serving_model(cfg: LlamaConfig):
             )
         )(key)
 
-    def prefill(params, cache, slot, tokens, positions):
+    def prefill(params, cache, slot, tokens, positions, n_real=None):
+        # ``n_real`` changes nothing here: a pad's keys and values are
+        # masked by position or overwritten (serving/engine.py).
         hidden, cache = decode_forward(
             prefill_model, params, cache, tokens, positions, return_hidden=True, slot=slot
         )

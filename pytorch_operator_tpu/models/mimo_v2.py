@@ -44,6 +44,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from . import layer_list
+from .layer_list import logits, rms_norm, write_positions
+
 Dtype = Any
 
 FULL, WINDOW = "full", "window"
@@ -83,13 +86,7 @@ class MiMoV2Config:
         for kind in self.layers:
             if kind[0] not in (FULL, WINDOW) or kind[1] not in (DENSE, MOE):
                 raise ValueError(f"layer kind {kind!r} is not (full|window, dense|moe)")
-        first, count = self.experts_held
-        if not (0 <= first and count >= 1 and first + count <= self.router_width):
-            raise ValueError(
-                f"experts_held {self.experts_held} outside the router's {self.router_width}"
-            )
-        if not 1 <= self.top_k <= self.router_width:
-            raise ValueError(f"top_k={self.top_k} outside [1, {self.router_width}]")
+        layer_list.check_experts_held(self)
         if self.rotary_dim % 2 or self.rotary_dim > self.qk_head_dim:
             raise ValueError(f"rotary_dim={self.rotary_dim} must be even and <= qk_head_dim")
 
@@ -114,9 +111,9 @@ class MiMoV2Config:
             prefill=functools.partial(_prefill, self),
             decode=functools.partial(_decode, self),
             logits=logits,
-            counts=zero_counts(self),
+            counts=layer_list.zero_moe_counts(self),
             gauges=cache_bytes,
-            derive=functools.partial(derived_stats, self),
+            derive=functools.partial(layer_list.derived_moe_stats, self),
         )
 
 
@@ -213,57 +210,22 @@ def layer_shapes(cfg: MiMoV2Config, kind: tuple[str, str]) -> dict:
     return out
 
 
-def outer_shapes(cfg: MiMoV2Config) -> dict:
-    D, V = cfg.d_model, cfg.vocab_size
-    return {
-        ("embed", "embedding"): ((V, D), 1, cfg.param_dtype),
-        ("final_norm", "scale"): ((D,), None, jnp.float32),
-        ("lm_head", "kernel"): ((D, V), D, cfg.param_dtype),
-    }
-
-
-def _draw(key, shapes: dict) -> dict:
-    tree: dict = {}
-    for i, (path, (shape, fan_in, dtype)) in enumerate(sorted(shapes.items())):
-        if fan_in is None:
-            leaf = jnp.ones(shape, dtype)
-        elif fan_in == 0:
-            leaf = jnp.zeros(shape, dtype)
-        else:
-            leaf = (
-                jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
-                * fan_in ** -0.5
-            ).astype(dtype)
-        node = tree
-        for name in path[:-1]:
-            node = node.setdefault(name, {})
-        node[path[-1]] = leaf
-    return tree
-
-
 def init_layer(cfg: MiMoV2Config, kind: tuple[str, str], key, layer) -> dict:
     """Layer ``layer``'s leaves (``layer`` may be traced: layers of one kind
     share a compiled program)."""
-    return _draw(jax.random.fold_in(key, layer), layer_shapes(cfg, kind))
+    return layer_list.draw(jax.random.fold_in(key, layer), layer_shapes(cfg, kind))
 
 
 def init_outer(cfg: MiMoV2Config, key) -> dict:
-    return _draw(jax.random.fold_in(key, 1 << 20), outer_shapes(cfg))
+    return layer_list.draw(jax.random.fold_in(key, 1 << 20), layer_list.outer_shapes(cfg))
 
 
 def init_params(cfg: MiMoV2Config, key) -> dict:
-    """The serving tree ``{embed, layers: [per-layer dict], final_norm,
-    lm_head}``, each layer made by a program of its own in the serving
-    dtype: no float32 copy of the whole tree ever sits on the device (the
-    largest transient is one leaf's float32 draw). ``init_layer`` and
-    ``init_outer`` are looked up at call time, so a caller that brings its
-    own seeded leaves (the benchmark) replaces those two."""
-    outer = jax.jit(lambda k: init_outer(cfg, k))(key)
-    make = jax.jit(
-        lambda kind, k, l: init_layer(cfg, kind, k, l), static_argnums=(0,)
-    )
-    layers = [make(kind, key, jnp.int32(l)) for l, kind in enumerate(cfg.layers)]
-    return {**outer, "layers": layers}
+    """The serving tree, a layer at a time (``layer_list.init_params``).
+    ``init_layer`` and ``init_outer`` are looked up at call time, so a
+    caller that brings its own seeded leaves (the benchmark) replaces those
+    two."""
+    return layer_list.init_params(cfg, key, cfg.layers, init_outer, init_layer)
 
 
 # ---- the cache: one state per layer, of the layer's own kind ----
@@ -308,12 +270,6 @@ def cache_bytes(cache: dict) -> dict:
 # ---- the forward ----
 
 
-def rms_norm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * scale).astype(x.dtype)
-
-
 def partial_rope(x, positions, theta: float, rotary_dim: int):
     """Rotate-half rotary embedding on the first ``rotary_dim`` components
     of x ``[B, S, heads, d]``; the rest pass through."""
@@ -325,22 +281,6 @@ def partial_rope(x, positions, theta: float, rotary_dim: int):
     x2 = x[..., half:rotary_dim].astype(jnp.float32)
     rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return jnp.concatenate([rot.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
-
-
-def _write(slab, vals, positions):
-    """Write ``vals [B, Hk, S, d]`` (or ``[B, S]`` for a ring's recorded
-    positions) at ``positions [B, S] % length`` of ``slab``'s position
-    axis. A single position a row (a decode step) is one update-slice a
-    row; a chunk is a scatter, since a ring may wrap inside it."""
-    T = slab.shape[-2] if slab.ndim == 4 else slab.shape[-1]
-    idx = positions % T
-    if slab.ndim == 2:
-        return jax.vmap(lambda c, u, i: c.at[i].set(u))(slab, vals, idx)
-    if idx.shape[1] == 1:
-        return jax.vmap(
-            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (0, i, 0))
-        )(slab, vals, idx[:, 0])
-    return jax.vmap(lambda c, u, i: c.at[:, i].set(u))(slab, vals, idx)
 
 
 def attention(cfg: MiMoV2Config, attn_kind: str, w: dict, state: dict, x, positions):
@@ -359,8 +299,8 @@ def attention(cfg: MiMoV2Config, attn_kind: str, w: dict, state: dict, x, positi
     k = partial_rope(k, positions, theta, cfg.rotary_dim)
 
     new = {
-        "k": _write(state["k"], k.swapaxes(1, 2).astype(cfg.dtype), positions),
-        "v": _write(state["v"], v.swapaxes(1, 2).astype(cfg.dtype), positions),
+        "k": write_positions(state["k"], k.swapaxes(1, 2).astype(cfg.dtype), positions),
+        "v": write_positions(state["v"], v.swapaxes(1, 2).astype(cfg.dtype), positions),
     }
     q = q.reshape(B, S, Hk, H // Hk, cfg.qk_head_dim)
     if attn_kind == FULL:
@@ -368,7 +308,7 @@ def attention(cfg: MiMoV2Config, attn_kind: str, w: dict, state: dict, x, positi
 
         out = cache_attention(q, positions, new["k"], new["v"])
     else:
-        new["pos"] = _write(state["pos"], positions, positions)
+        new["pos"] = write_positions(state["pos"], positions, positions)
         out = _ring_attend(cfg, q, positions, new, w["sink"])
     return out.reshape(B, S, H * cfg.v_head_dim) @ w["o_proj"], new
 
@@ -396,17 +336,6 @@ def dense_mlp(w: dict, x):
     return (jax.nn.silu(x @ w["gate_proj"]) * (x @ w["up_proj"])) @ w["down_proj"]
 
 
-def zero_counts(cfg: MiMoV2Config) -> dict:
-    """The counters a forward adds to, at zero (int32; the engine drains
-    them to the host at every ``stats()``)."""
-    return {
-        "moe_tokens": jnp.zeros((), jnp.int32),
-        "moe_local_pairs": jnp.zeros((), jnp.int32),
-        "moe_expert_tokens": jnp.zeros((cfg.experts_held[1],), jnp.int32),
-        "moe_experts_touched": jnp.zeros((), jnp.int32),
-    }
-
-
 def forward(cfg: MiMoV2Config, params: dict, cache: dict, tokens, positions):
     """Tokens ``[B, S]`` at ``positions [B, S]`` through every layer and its
     cache: a prefill chunk (one row, S = chunk) and a decode step (every
@@ -417,7 +346,7 @@ def forward(cfg: MiMoV2Config, params: dict, cache: dict, tokens, positions):
     B, S = tokens.shape
     with jax.named_scope("embed"):
         x = params["embed"]["embedding"][tokens].astype(cfg.dtype)
-    counts = zero_counts(cfg)
+    counts = layer_list.zero_moe_counts(cfg)
     new_cache = {}
     for i, (attn_kind, ff_kind) in enumerate(cfg.layers):
         w = params["layers"][i]
@@ -443,14 +372,10 @@ def forward(cfg: MiMoV2Config, params: dict, cache: dict, tokens, positions):
     return x, new_cache, counts
 
 
-def logits(params: dict, hidden):
-    """Float32 logits of ``hidden [..., D]``: the head's product accumulates
-    in float32 from the operands as they are held."""
-    return jnp.dot(hidden, params["lm_head"]["kernel"], preferred_element_type=jnp.float32)
-
-
-def _prefill(cfg, params, cache, slot, tokens, positions):
-    """A chunk of the one row that lives at ``slot`` of the cache. This
+def _prefill(cfg, params, cache, slot, tokens, positions, n_real=None):
+    """A chunk of the one row that lives at ``slot`` of the cache (``n_real``,
+    how many of its tokens are the prompt's, changes nothing here: a pad's
+    keys are masked by position or overwritten). This
     family takes its row out and writes it back: a ring wraps inside a
     chunk, so its writes are scatters over the row's own entries, and the
     whole row (two full slabs of 10.5 MB, five rings of 1.3 MB at the
@@ -468,17 +393,3 @@ def _decode(cfg, params, cache, tok, pos):
     hidden, cache, counts = forward(cfg, params, cache, tok, pos)
     with jax.named_scope("head"):
         return logits(params, hidden[:, -1]), cache, counts
-
-
-def derived_stats(cfg: MiMoV2Config, n: dict) -> dict:
-    """What ``ServingEngine.stats()`` adds from this model's counters: of
-    the experts the tokens selected, the share held here (100 x held /
-    router's width where the router really routes over all of them), and
-    the busiest held expert's tokens over the mean."""
-    per_expert = [int(t) for t in n["moe_expert_tokens"]]
-    mean = sum(per_expert) / len(per_expert)
-    picks = cfg.top_k * int(n["moe_tokens"])
-    return {
-        "expert_local_hit_pct": round(100.0 * int(n["moe_local_pairs"]) / picks, 4) if picks else None,
-        "expert_load_max_over_mean": round(max(per_expert) / mean, 4) if mean else None,
-    }

@@ -5,7 +5,7 @@
 :class:`ServingModel` alone: its config, its seeded init, its cache
 constructor and its two forwards. A config class states its family by
 having ``serving_model()`` (``models.llama.LlamaConfig``,
-``models.mimo_v2.MiMoV2Config``); a job's ``--config`` names a preset, and
+``models.mimo_v2.MiMoV2Config``, ``models.nemotron_h.NemotronHConfig``); a job's ``--config`` names a preset, and
 :func:`preset` finds the family that has it. Nothing else selects a path.
 """
 
@@ -29,7 +29,8 @@ class ServingModel:
     slot's leaves as they were (the llama family writes the chunk's keys
     and values in place and reads that row's filled prefix where it lies;
     the layer-pattern family slices its small row out and writes it back,
-    inside its own forward). ``counts`` are
+    inside its own forward; the hybrid family keeps a constant-size
+    recurrent state a row beside its slabs). ``counts`` are
     the model's own device counters at zero (a pytree of int32 arrays, ``{}``
     for none): each forward returns what one call adds, the engine's two
     programs sum them on the device, and ``ServingEngine.stats()`` brings
@@ -41,8 +42,14 @@ class ServingModel:
     # (slots, chunk) -> cache.
     init_cache: Callable
     # (params, cache, slot (a traced int32 scalar), tokens [1, chunk],
-    # positions [1, chunk]) -> (final-norm hidden [1, chunk, D], cache,
-    # counts): one call shape for every family.
+    # positions [1, chunk], n_real (a traced int32 scalar: the first
+    # ``n_real`` tokens are the prompt's, the rest the last chunk's pad))
+    # -> (final-norm hidden [1, chunk, D], cache, counts): one call shape
+    # for every family. State that grows with position (keys and values)
+    # may take the pads in: every later read masks them by position or
+    # overwrites them. State that is a recurrence must stop at the last
+    # real token, and start from zero where the chunk stands at position 0
+    # (the slot's last occupant left its own there).
     prefill: Callable
     # (params, cache, tokens [slots, 1], positions [slots, 1]), every row
     # at its own position -> (float32 logits [slots, V], cache, counts).
@@ -65,11 +72,12 @@ def families() -> dict:
     """``preset name -> (model module, name of the function that makes its
     config)``, read at call time: a family's table is a module dict that a
     caller may add a preset to (the benchmark's ``bench``)."""
-    from . import llama, mimo_v2
+    from . import llama, mimo_v2, nemotron_h
 
     return {
-        **{name: (llama, fn) for name, fn in llama.CONFIGS.items()},
-        **{name: (mimo_v2, fn) for name, fn in mimo_v2.CONFIGS.items()},
+        name: (module, fn)
+        for module in (llama, mimo_v2, nemotron_h)
+        for name, fn in module.CONFIGS.items()
     }
 
 

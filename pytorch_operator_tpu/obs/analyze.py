@@ -503,6 +503,15 @@ def analyze(
                     rec["programs_compiled"], rec.get("programs_from_cache", 0)
                 ]
 
+    # A model whose per-slot state is a recurrence starts a row from zero at
+    # each admission (models/nemotron_h.py): the engine's last metrics record
+    # has both counts, and they must agree.
+    state_resets = {
+        rec["replica"]: [rec["prefill_state_resets"], rec.get("admitted", 0)]
+        for rec in tl.records.get("metrics", [])
+        if "prefill_state_resets" in rec
+    }
+
     return {
         "job": key,
         "generated_at": _time.time() if now is None else now,
@@ -512,6 +521,7 @@ def analyze(
         "clock": {r: est.to_dict() for r, est in sorted(tl.clock.items())},
         "replicas": replicas,
         "programs_compiled": compiled,
+        "state_resets": state_resets,
         "events": len(tl.events),
         "spans": len(tl.spans),
         "exemplars": exemplars,
@@ -588,6 +598,11 @@ def render_report(report: dict) -> str:
                 f"{n} program(s) compiled and {hits} from the cache {at.replace('_', ' ')}"
                 for at, (n, hits) in counts.items()
             )
+        )
+    for replica, (resets, admitted) in sorted(report.get("state_resets", {}).items()):
+        lines.append(
+            f"state:    {replica} {resets} row(s) started from zero state for {admitted} admitted"
+            + ("" if resets == admitted else "  <-- these must be equal")
         )
     alerts = report.get("alerts", [])
     findings = report.get("findings", [])

@@ -305,14 +305,16 @@ def moe_mlp(
     )({"w_in": params["w_in"], "w_out": params["w_out"]}, gates, x)
 
 
-# ---- a chip's share of a sigmoid-routed SwiGLU expert layer ----
+# ---- a chip's share of a sigmoid-routed expert layer ----
 #
 # What expert parallelism asks of one chip, without its exchange: the
 # router keeps its published width (every expert of the layer), the chip
 # holds the weights of ``experts_held = (first id, count)`` and computes
 # their part of each token's result. Exact: no capacity, no dropped token,
 # whatever the imbalance. The parts of all the shares add up to the whole
-# layer (tests/test_mimo_v2.py pins that against the uncut reference).
+# layer (tests/test_mimo_v2.py and tests/test_nemotron_h.py pin that against
+# the uncut references). An expert is one of two forms: the gated SwiGLU
+# ``(silu(x Wg) * (x Wu)) Wd``, or the two-matrix ``relu(x Wu)^2 Wd``.
 
 
 def route_sigmoid_topk(router, e_bias, x, top_k: int):
@@ -332,23 +334,31 @@ def route_sigmoid_topk(router, e_bias, x, top_k: int):
     return idx, picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
-def moe_swiglu_held(params, x, *, top_k: int, experts_held: tuple[int, int]):
+SWIGLU, RELU2 = "swiglu", "relu2"
+
+
+def moe_held(
+    params, x, *, top_k: int, experts_held: tuple[int, int],
+    form: str = SWIGLU, weight_scale: float = 1.0,
+):
     """This chip's part of the expert layer for ``x [N, D]``: routing over
     all ``router_width`` experts, the sum over the selected experts that
     lie in ``experts_held``; the weights are renormalised over all
-    ``top_k`` selected, held or not, and a token none of whose experts is
-    held gets zero.
+    ``top_k`` selected, held or not, then multiplied by ``weight_scale``
+    (a model's ``routed_scaling_factor``), and a token none of whose
+    experts is held gets zero.
 
     ``params``: ``router [D, E]``, ``e_bias [E]``, and the held experts'
-    ``w_gate``/``w_up [n, D, F]``, ``w_down [n, F, D]``.
+    ``w_up [n, D, F]``, ``w_down [n, F, D]`` and, for ``form`` SwiGLU,
+    ``w_gate [n, D, F]``.
 
     Every held expert runs over all N tokens with the others' gates at
     zero, and the gates are folded in before the one down-projection that
     contracts over (expert, F). At the sizes the engine calls it with (64
-    rows a decode step, 128 a prefill chunk, 16 experts of 3 x 4096 x 2048)
-    the layer is bound by reading its weights, which this reads once each:
-    PERF.md section 6 (PR 28) has the chip's numbers beside a ragged
-    grouped product's.
+    or 128 rows a decode step, 128 a prefill chunk, 16 experts of 3 x 4096
+    x 2048 or 32 of 2 x 2688 x 1856) the layer is bound by reading its
+    weights, which this reads once each: PERF.md section 6 (PR 28) has the
+    chip's numbers beside a ragged grouped product's.
 
     Returns ``(y [N, D], counts)``; ``counts`` are int32 sums over this
     call: ``moe_tokens`` (N), ``moe_local_pairs`` (selected experts that
@@ -358,13 +368,17 @@ def moe_swiglu_held(params, x, *, top_k: int, experts_held: tuple[int, int]):
     import jax.numpy as jnp
 
     first, n = experts_held
-    if params["w_gate"].shape[0] != n:
+    if form not in (SWIGLU, RELU2):
+        raise ValueError(f"expert form {form!r} is not {SWIGLU} or {RELU2}")
+    if params["w_up"].shape[0] != n:
         raise ValueError(
             f"experts_held {experts_held} but weights of "
-            f"{params['w_gate'].shape[0]} experts"
+            f"{params['w_up'].shape[0]} experts"
         )
     with jax.named_scope("moe_router"):
         idx, w = route_sigmoid_topk(params["router"], params["e_bias"], x, top_k)
+        if weight_scale != 1.0:
+            w = w * weight_scale
         local = idx - first  # [N, k]; held where 0 <= local < n
         onehot = local[:, :, None] == jnp.arange(n)[None, None, :]  # [N, k, n]
         gates = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1)  # [N, n]
@@ -375,8 +389,20 @@ def moe_swiglu_held(params, x, *, top_k: int, experts_held: tuple[int, int]):
             "moe_expert_tokens": per_expert,
             "moe_experts_touched": jnp.sum(per_expert > 0, dtype=jnp.int32),
         }
-    h = jax.nn.silu(
-        jnp.einsum("nd,edf->nef", x, params["w_gate"])
-    ) * jnp.einsum("nd,edf->nef", x, params["w_up"])
-    h = h * gates.astype(h.dtype)[:, :, None]
+    if form == SWIGLU:
+        h = jax.nn.silu(
+            jnp.einsum("nd,edf->nef", x, params["w_gate"])
+        ) * jnp.einsum("nd,edf->nef", x, params["w_up"])
+        h = h * gates.astype(h.dtype)[:, :, None]
+    else:
+        # relu^2 and the gate in float32 between the two products, rounded
+        # once to the operand of the second.
+        up = jnp.einsum("nd,edf->nef", x, params["w_up"], preferred_element_type=jnp.float32)
+        h = (jnp.square(jax.nn.relu(up)) * gates[:, :, None]).astype(x.dtype)
     return jnp.einsum("nef,efd->nd", h, params["w_down"]), counts
+
+
+def moe_swiglu_held(params, x, *, top_k: int, experts_held: tuple[int, int]):
+    """:func:`moe_held` for SwiGLU experts with unscaled weights (the
+    ``mimo_v2`` family's layer)."""
+    return moe_held(params, x, top_k=top_k, experts_held=experts_held)

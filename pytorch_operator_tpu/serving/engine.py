@@ -36,7 +36,11 @@ TPU-first shape (every program's shapes static):
   sliced out or written back), last chunk padded — the pad tokens write
   cache slots past the prompt that every later read either masks
   (col <= row) or overwrites (the next decode token lands exactly on the
-  first padded slot before anything attends it). A chunk attends the
+  first padded slot before anything attends it). That holds for state
+  that grows with position; a model whose state is a recurrence has no
+  later mask, so the model is also told how many of the chunk's tokens
+  are real (a traced scalar: still one program) and what it does with
+  that is its own business (models/serving.py). A chunk attends the
   row's prefix up to its own last position, by the same bounded
   attention, and runs no head: a small program of its own,
   ``prefill_chunk_head``, takes the last chunk's hidden states to the
@@ -221,9 +225,10 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
     add = functools.partial(jax.tree.map, jnp.add)
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def prefill_chunk(params, cache, counts, slot, chunk_toks, start):
+    def prefill_chunk(params, cache, counts, slot, chunk_toks, start, n_real=chunk):
         """One [1, chunk] prefill chunk into row ``slot`` of the batch
-        cache (slot/start are traced scalars: one program). The model
+        cache, of which the first ``n_real`` tokens are the prompt's
+        (slot/start/n_real are traced scalars: one program). The model
         writes the chunk's keys and values into the donated slabs where
         they belong and reads the row's filled prefix where it lies:
         nothing row-sized is copied out or back. Returns the final-norm
@@ -232,7 +237,7 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         is for its last). ``counts`` are the model's counters so far, to
         which this call's are added."""
         pos = (start + jnp.arange(chunk, dtype=jnp.int32))[None, :]
-        hidden, cache, added = model.prefill(params, cache, slot, chunk_toks, pos)
+        hidden, cache, added = model.prefill(params, cache, slot, chunk_toks, pos, n_real)
         return hidden, cache, add(counts, added)
 
     @jax.jit
@@ -449,6 +454,9 @@ class ServingEngine:
                     jnp.int32(slot),
                     jnp.asarray(buf[None, start : start + self.chunk]),
                     jnp.int32(start),
+                    # A host scalar: a program that does not read it (the
+                    # families whose state is keys and values) never gets it.
+                    np.int32(min(self.chunk, p - start)),
                 )
                 if start == 0:
                     # Up to this dispatch the device waited for the host;

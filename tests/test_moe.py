@@ -392,3 +392,56 @@ class TestMoE:
         params = jax.tree.map(jnp.asarray, _params(4, 4, 8))
         with pytest.raises(ValueError, match="top_k"):
             moe_mlp(params, jnp.zeros((4, 4)), mesh=mesh, top_k=9)
+
+
+# ---- a chip's share of a sigmoid-routed layer: the expert's two forms ----
+
+
+def _held_params(form, n=4, d=16, f=8, e=8, seed=0):
+    rng = np.random.default_rng(seed)
+    w = {"router": rng.standard_normal((d, e)) / 4, "e_bias": 0.05 * rng.standard_normal(e),
+         "w_up": rng.standard_normal((n, d, f)) / 4, "w_down": rng.standard_normal((n, f, d)) / 3}
+    if form == "swiglu":
+        w["w_gate"] = rng.standard_normal((n, d, f)) / 4
+    return {k: np.asarray(v, np.float32) for k, v in w.items()}
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.5])
+@pytest.mark.parametrize("form", ["swiglu", "relu2"])
+def test_the_held_experts_layer_in_both_forms_equals_a_loop_over_tokens(form, scale):
+    """``moe_held`` against the definition, token by token and expert by
+    expert in numpy: routing over all 8 experts, weights renormalised over
+    the 3 selected and scaled, only experts 2-5 computed."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.parallel.moe import moe_held
+
+    w, first, n, k = _held_params(form), 2, 4, 3
+    x = np.random.default_rng(1).standard_normal((11, 16)).astype(np.float32)
+    y, counts = moe_held(jax.tree.map(jnp.asarray, w), jnp.asarray(x), top_k=k, experts_held=(first, n),
+                         form=form, weight_scale=scale)
+    scores = 1 / (1 + np.exp(-(x @ w["router"])))
+    want, pairs = np.zeros_like(x), 0
+    for t in range(len(x)):
+        picked = np.argsort(-(scores[t] + w["e_bias"]))[:k]
+        for e in picked:
+            if first <= e < first + n:
+                pairs += 1
+                up = x[t] @ w["w_up"][e - first]
+                h = np.maximum(up, 0) ** 2 if form == "relu2" else (
+                    (g := x[t] @ w["w_gate"][e - first]) / (1 + np.exp(-g)) * up)
+                want[t] += scale * scores[t, e] / scores[t, picked].sum() * (h @ w["w_down"][e - first])
+    assert np.abs(np.asarray(y) - want).max() <= 1e-4 and int(counts["moe_local_pairs"]) == pairs
+    assert int(counts["moe_tokens"]) == 11 and int(counts["moe_expert_tokens"].sum()) == pairs
+
+
+def test_the_held_experts_layer_refuses_weights_of_another_count():
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.parallel.moe import moe_held
+
+    w = jax.tree.map(jnp.asarray, _held_params("relu2"))
+    with pytest.raises(ValueError, match="experts_held"):
+        moe_held(w, jnp.zeros((2, 16)), top_k=2, experts_held=(0, 3), form="relu2")

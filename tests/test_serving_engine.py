@@ -164,6 +164,68 @@ def test_an_eos_row_is_harvested_at_the_next_boundary(parity_model):
     assert nxt.id == "e1" and nxt.tokens == want[2]
 
 
+# ---- what a prefill chunk is told: how many of its tokens are real ----
+
+
+def _family_model(family):
+    """(serving model, params) of a family's test-size preset."""
+    import jax
+
+    from pytorch_operator_tpu.models.serving import preset
+
+    name = {"llama": "tiny", "mimo_v2": "mimo-tiny", "nemotron_h": "nemotron-h-tiny"}[family]
+    model = preset(name, decode=True, max_decode_len=64).serving_model()
+    return model, model.init_params(jax.random.key(0))
+
+
+@pytest.mark.parametrize("family", ["llama", "mimo_v2", "nemotron_h"])
+def test_the_count_of_real_tokens_changes_nothing_where_state_is_keys_and_values(family):
+    """``prefill`` of a padded last chunk with the count of real tokens, and
+    with the whole chunk called real: the families whose state is keys and
+    values give the same bits (every later read masks a pad by position or
+    overwrites it); the one whose state is a recurrence gives the same bits
+    at the real positions and another state behind them."""
+    import jax
+    import jax.numpy as jnp
+
+    model, params = _family_model(family)
+    chunk, real = 8, 5
+    toks = jnp.asarray(np.random.default_rng(0).integers(1, 200, (1, chunk)), jnp.int32)
+    pos = jnp.arange(chunk, dtype=jnp.int32)[None]
+    told, cache_told, _ = model.prefill(params, model.init_cache(2, chunk), jnp.int32(1), toks, pos, jnp.int32(real))
+    whole, cache_whole, _ = model.prefill(params, model.init_cache(2, chunk), jnp.int32(1), toks, pos, jnp.int32(chunk))
+    assert np.array_equal(np.asarray(told[0, :real], np.float32), np.asarray(whole[0, :real], np.float32))
+    same = all(np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+               for a, b in zip(jax.tree.leaves(cache_told), jax.tree.leaves(cache_whole)))
+    assert same == (family != "nemotron_h")
+
+
+@pytest.mark.parametrize("family", ["llama", "mimo_v2", "nemotron_h"])
+def test_the_engine_tells_every_chunk_how_many_of_its_tokens_are_real(family, monkeypatch):
+    """A prompt of 19 in chunks of 8: the model's ``prefill`` is traced once
+    (one program) and the three dispatches carry 8, 8 and 3."""
+    from pytorch_operator_tpu.serving import engine as engine_lib
+
+    model, params = _family_model(family)
+    seen, real_programs = [], engine_lib.programs
+
+    def spying(model, **kw):
+        progs = real_programs(model, **kw)
+
+        def prefill_chunk(params, cache, counts, slot, toks, start, n_real):
+            seen.append((int(start), int(n_real)))
+            return progs.prefill_chunk(params, cache, counts, slot, toks, start, n_real)
+
+        return progs._replace(prefill_chunk=prefill_chunk)
+
+    monkeypatch.setattr(engine_lib, "programs", spying)
+    eng = ServingEngine(model.cfg, params, slots=2, chunk=8, block=4)
+    eng.submit(_req("a", np.arange(1, 20, dtype=np.int32), 3))
+    (res,) = eng.run_until_drained()
+    assert len(res.tokens) == 3 and seen == [(0, 8), (8, 8), (16, 3)]
+    assert eng.stats()["prefill_chunks"] == 3 and eng.stats()["prefill_pad_tokens"] == 5
+
+
 @pytest.mark.slow
 class TestEngineParity:
     def test_mixed_lengths_match_single_stream(self):
