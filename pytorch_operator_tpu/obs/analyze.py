@@ -512,6 +512,15 @@ def analyze(
         if "prefill_state_resets" in rec
     }
 
+    # A serving engine's admissions (serving/engine.py): the rounds that
+    # admitted, beside the decode dispatches queued behind one with its
+    # first token still unread. Equal whenever an admitted row decodes.
+    admit_rounds = {
+        rec["replica"]: [rec["admit_rounds"], rec.get("decode_behind_admit", 0), rec.get("admitted", 0)]
+        for rec in tl.records.get("metrics", [])
+        if "admit_rounds" in rec
+    }
+
     return {
         "job": key,
         "generated_at": _time.time() if now is None else now,
@@ -522,6 +531,7 @@ def analyze(
         "replicas": replicas,
         "programs_compiled": compiled,
         "state_resets": state_resets,
+        "admit_rounds": admit_rounds,
         "events": len(tl.events),
         "spans": len(tl.spans),
         "exemplars": exemplars,
@@ -603,6 +613,11 @@ def render_report(report: dict) -> str:
         lines.append(
             f"state:    {replica} {resets} row(s) started from zero state for {admitted} admitted"
             + ("" if resets == admitted else "  <-- these must be equal")
+        )
+    for replica, (rounds, behind, admitted) in sorted(report.get("admit_rounds", {}).items()):
+        lines.append(
+            f"admits:   {replica} {admitted} admitted in {rounds} round(s), "
+            f"the decode dispatch queued behind {behind} of them before a first token was read"
         )
     alerts = report.get("alerts", [])
     findings = report.get("findings", [])

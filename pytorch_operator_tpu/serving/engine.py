@@ -45,10 +45,19 @@ TPU-first shape (every program's shapes static):
   attention, and runs no head: a small program of its own,
   ``prefill_chunk_head``, takes the last chunk's hidden states to the
   logits of the prompt's last position, once a prompt
-  (``prefill_head_chunks`` counts it). Arbitrary prompt lengths
-  therefore hit exactly these compiled programs, and a prompt longer
-  than one program's activation budget prefills in bounded
-  O(chunk · L) score memory.
+  (``prefill_head_chunks`` counts it), samples the first token from them
+  with the sampler the decode steps use and writes the row's token and
+  position into the donated ``tok`` / ``pos`` that ``decode_block`` reads.
+  Arbitrary prompt lengths therefore hit exactly these compiled programs,
+  and a prompt longer than one program's activation budget prefills in
+  bounded O(chunk · L) score memory.
+- An admission costs the device no round trip to the host: a boundary
+  queues every admitted prompt's chunks and head, then ``decode_block``
+  behind them (what it needs of a new row is the request's: its position
+  and its budget), and only then reads each first token (4 bytes) and the
+  decode tokens. A first token that ends its request (``eos_token``) is
+  learnt one dispatch late: the row ran a dispatch whose tokens are
+  dropped (``decode_behind_admit`` counts the dispatches queued so).
 - Slot L-1 of every row is a parking slot: rows that exhaust their
   budget clamp there, so admission requires prompt + new <= L-1 and
   no live stream ever attends a parked write.
@@ -85,7 +94,7 @@ lists every name beside the metric that reads it):
   second of the serving thread to a segment
   (:data:`GAP_SEGMENTS` while the device waits for the host,
   :data:`FENCE_SEGMENTS` while the host waits for the device,
-  ``dispatch``, ``idle``).
+  ``dispatch``, ``overlapped``, ``idle``).
 """
 
 from __future__ import annotations
@@ -104,15 +113,18 @@ from ..obs.trace import serve_span
 # Segments of the serving thread's time (``ServingEngine.host_lap``).
 # From a fence's return to the next dispatch the device has nothing to do
 # and the host is what it waits for; those seconds are the host gap, by
-# what the host did (``admit_prep``: a prompt padded, the arguments moved
-# to the device; ``accept``: tokens taken, the row's state set):
+# what the host did (``admit_prep``: up to a boundary's first dispatch, a
+# prompt padded or the decode dispatch sized; ``accept``: the decode
+# dispatch's tokens taken, row by row):
 GAP_SEGMENTS = ("accept", "harvest", "respond", "report", "poll", "submit", "admit_prep")
 # ... while the host blocks on the device's result:
 FENCE_SEGMENTS = ("first_token", "decode_fence")
-# ... ``dispatch`` in the dispatch calls and while it queues a prompt's later
-# chunks behind the first, and ``idle`` while there is no request anywhere
-# (the serve loop's sleep).
-SEGMENTS = GAP_SEGMENTS + FENCE_SEGMENTS + ("dispatch", "idle")
+# ... ``dispatch`` in the dispatch calls and while it queues work behind work
+# (a prompt's later chunks, the next prompt, the decode dispatch behind an
+# admission), ``overlapped`` for the bookkeeping done while the decode
+# dispatch is in flight (first tokens taken, the dispatch counted), and
+# ``idle`` while there is no request anywhere (the serve loop's sleep).
+SEGMENTS = GAP_SEGMENTS + FENCE_SEGMENTS + ("dispatch", "overlapped", "idle")
 
 
 def host_key(segment: str) -> str:
@@ -128,7 +140,7 @@ _COUNTERS = (
     "decode_live_positions", "decode_attended_positions", "prefill_attended_positions",
     *(f"decode_sized_by_{reason}" for reason in SIZED_BY),
     "prefill_chunks", "prefill_head_chunks", "prefill_tokens", "prefill_pad_tokens",
-    "admit_rounds", "admitted",
+    "admit_rounds", "decode_behind_admit", "admitted",
 )
 SPAN_CAT = "engine"
 
@@ -209,7 +221,6 @@ class Programs(NamedTuple):
     prefill_chunk: Callable
     prefill_chunk_head: Callable
     decode_block: Callable
-    first_token: Callable
 
 
 def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
@@ -240,18 +251,28 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         hidden, cache, added = model.prefill(params, cache, slot, chunk_toks, pos, n_real)
         return hidden, cache, add(counts, added)
 
-    @jax.jit
-    def prefill_chunk_head(params, hidden, last_idx):
-        """The head on position ``last_idx`` ONLY of a prompt's last
-        chunk (the full [chunk, V] product costs as much as several
-        transformer layers): float32 logits [V]. A program of its own
-        and not a second form of the chunk's: a second copy of the whole
-        chunk program cost every run 2.3 s of set-up to load (PERF.md
-        section 6, PR 31). Its name keeps ``prefill_chunk`` in it, by
-        which the benchmark finds the prefill's programs."""
+    @functools.partial(jax.jit, donate_argnums=(2, 3))
+    def prefill_chunk_head(params, hidden, tok, pos, slot, p, key):
+        """The end of an admission, once a prompt: the head on the last
+        real position ONLY of the prompt's last chunk (the full [chunk, V]
+        product costs as much as several transformer layers), the first
+        token sampled from those float32 logits as a decode step samples
+        (greedy is ``sample`` at temperature 0), and row ``slot`` of the
+        donated ``tok`` / ``pos`` [slots] set to it and to the prompt's
+        length ``p``, where ``decode_block`` writes its keys and values
+        before attending (as ``make_generate``'s first scan step does).
+        Returns them, the token as a scalar (all the host reads of an
+        admission) and the next key. A program of its own and not a
+        second form of the chunk's: a second copy of the whole chunk
+        program cost every run 2.3 s of set-up to load (PERF.md section
+        6, PR 31). Its name keeps ``prefill_chunk`` in it, by which the
+        benchmark finds the prefill's programs."""
         with jax.named_scope("head"):
-            h = jax.lax.dynamic_slice_in_dim(hidden, last_idx, 1, axis=1)
-            return model.logits(params, h[:, 0])[0]
+            h = jax.lax.dynamic_slice_in_dim(hidden, (p - 1) % chunk, 1, axis=1)
+            logits = model.logits(params, h[:, 0])
+        key, sub = jax.random.split(key)
+        first = sample(logits, sub)[0]
+        return tok.at[slot].set(first), pos.at[slot].set(p), first, key
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def decode_block(params, cache, counts, tok, pos, active, rng, steps):
@@ -285,15 +306,7 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         )
         return toks.swapaxes(0, 1), cache, counts, tok, pos, rng
 
-    @jax.jit
-    def first_token(logits, key):
-        """First-token sampling as ONE compiled dispatch (eager
-        sort/softmax/categorical would each be a dispatch, billed to
-        every request's TTFT)."""
-        key, sub = jax.random.split(key)
-        return sample(logits[None, :], sub)[0], key
-
-    return Programs(prefill_chunk, prefill_chunk_head, decode_block, first_token)
+    return Programs(prefill_chunk, prefill_chunk_head, decode_block)
 
 
 class ServingEngine:
@@ -342,13 +355,10 @@ class ServingEngine:
         self.chunk = chunk
         self.block = block
         self.eos_token = eos_token
-        self._temperature = temperature
-        self._top_k, self._top_p = top_k, top_p
         self._params = params
         self._rng = jax.random.key(seed)
         self._first_key = jax.random.key(seed + 1)
-        (self._prefill_chunk, self._prefill_chunk_head, self._decode_block,
-         self._first_token) = programs(
+        self._prefill_chunk, self._prefill_chunk_head, self._decode_block = programs(
             model, slots=slots, chunk=chunk, block=block,
             sample=make_sampler(temperature, top_k, top_p),
         )
@@ -364,6 +374,8 @@ class ServingEngine:
         self._tok = jnp.zeros((slots,), jnp.int32)
         self._pos = jnp.zeros((slots,), jnp.int32)
         self._slots: list[Optional[_Slot]] = [None] * slots
+        # Admissions whose first token is still on the device, in order.
+        self._unread: list[tuple[_Slot, object]] = []
         self._queue: deque[Request] = deque()
         self.last_steps = 0  # steps of the newest decode dispatch
         # Latency/throughput accounting.
@@ -386,6 +398,13 @@ class ServingEngine:
         now = time.perf_counter()
         self._host_s[segment] += now - self._mark
         self._mark = now
+
+    def _lap_to_dispatch(self) -> None:
+        """Lap at a prompt's first chunk and at the decode dispatch: up to a
+        boundary's first dispatch the device waited for the host
+        (``admit_prep``); behind an admission the host queues work behind
+        work (``dispatch``)."""
+        self.host_lap("dispatch" if self._unread else "admit_prep")
 
     # ---- admission ----
 
@@ -418,28 +437,18 @@ class ServingEngine:
     def _free_slots(self) -> list[int]:
         return [i for i, s in enumerate(self._slots) if s is None]
 
-    def _sample_first(self, logits) -> int:
-        """Sample the request's first token from the prefill's [V]
-        logits: greedy on the host, else the one-dispatch compiled
-        sampler (same T/top-k/top-p semantics as the decode blocks)."""
-        with obs.span("engine.first_token", SPAN_CAT):
-            if self._temperature == 0.0:
-                tok = np.argmax(np.asarray(logits))
-            else:
-                tok, self._first_key = self._first_token(logits, self._first_key)
-            tok = int(tok)  # the fence: every chunk of the prompt has run
-        self.host_lap("first_token")
-        return tok
-
     def _admit(self, request: Request, slot: int) -> None:
-        jnp, L = self._jnp, self.cfg.max_decode_len
+        """Queue a prompt's chunks and its head into ``slot``; read nothing
+        back. The row's budget and position after its first token are the
+        request's; the token's value stays on the device (``self._unread``)
+        until the decode dispatch is queued behind it."""
+        L = self.cfg.max_decode_len
         admit_time = time.time()
         prompt = np.asarray(request.prompt, np.int32)
         p = prompt.shape[0]
         padded = -(-p // self.chunk) * self.chunk
         buf = np.zeros((padded,), np.int32)
         buf[:p] = prompt
-        last_valid = (p - 1) % self.chunk  # index within the FINAL chunk
         self._n["admitted"] += 1
         self._n["prefill_chunks"] += padded // self.chunk
         self._n["prefill_tokens"] += p
@@ -448,47 +457,50 @@ class ServingEngine:
         self._n["prefill_attended_positions"] += int(
             self._attended(np.arange(self.chunk, padded + 1, self.chunk), L).sum()
         )
+        # Host values throughout: the dispatch moves what its program reads
+        # (a family whose state is keys and values never gets ``n_real``).
+        slot_ = np.int32(slot)
         for start in range(0, padded, self.chunk):
             with obs.span("engine.prefill_dispatch", SPAN_CAT, start=start):
-                args = (
-                    jnp.int32(slot),
-                    jnp.asarray(buf[None, start : start + self.chunk]),
-                    jnp.int32(start),
-                    # A host scalar: a program that does not read it (the
-                    # families whose state is keys and values) never gets it.
+                if start == 0:
+                    self._lap_to_dispatch()
+                hidden, self._cache, self._counts["prefill"] = self._prefill_chunk(
+                    self._params, self._cache, self._counts["prefill"], slot_,
+                    buf[None, start : start + self.chunk], np.int32(start),
                     np.int32(min(self.chunk, p - start)),
                 )
-                if start == 0:
-                    # Up to this dispatch the device waited for the host;
-                    # from here the host queues chunks behind chunks.
-                    self.host_lap("admit_prep")
-                hidden, self._cache, self._counts["prefill"] = self._prefill_chunk(
-                    self._params, self._cache, self._counts["prefill"], *args
-                )
         # The last chunk's last VALID position (not the padded tail) feeds
-        # the first token: the head runs once a prompt.
-        logits = self._prefill_chunk_head(self._params, hidden, jnp.int32(last_valid))
+        # the first token, and the program that samples it sets the row's
+        # state: the head runs once a prompt.
+        self._tok, self._pos, first, self._first_key = self._prefill_chunk_head(
+            self._params, hidden, self._tok, self._pos, slot_, np.int32(p), self._first_key
+        )
         self._n["prefill_head_chunks"] += 1
         self.host_lap("dispatch")
-        first = self._sample_first(logits)
-        first_time = time.time()
+        # decode_block writes the first token's k/v at position p before
+        # attending, exactly as make_generate's first scan step does.
         st = _Slot(
             request=request,
             admit_time=admit_time,
-            first_token_time=first_time,
-            pos=p - 1,
-            remaining=request.max_new_tokens,
+            first_token_time=0.0,  # stamped as its fence returns (_take_first)
+            pos=p,
+            remaining=request.max_new_tokens - 1,
             tokens=[],
         )
-        self._accept_token(st, slot, first)
         self._slots[slot] = st
-        # Row state: the first sampled token has NOT been written to the
-        # cache yet — decode_block writes its k/v at position p (st.pos
-        # after the accept) before attending, exactly as make_generate's
-        # first scan step does.
-        self._tok = self._tok.at[slot].set(first)
-        self._pos = self._pos.at[slot].set(st.pos)
-        self.host_lap("accept")
+        self._unread.append((st, first))
+
+    def _take_first(self) -> None:
+        """Read each admission's first token, in admission order: the one
+        thing the host learns late is a token that ends its request."""
+        for st, first in self._unread:
+            with obs.span("engine.first_token", SPAN_CAT):
+                token = int(first)  # the fence: every chunk of the prompt has run
+            self.host_lap("first_token")
+            st.first_token_time = time.time()
+            st.tokens.append(token)
+            st.done = st.remaining <= 0 or token == self.eos_token
+        self._unread.clear()
 
     def _accept_token(self, st: _Slot, slot: int, token: int) -> None:
         st.tokens.append(int(token))
@@ -515,9 +527,7 @@ class ServingEngine:
             return self._step()
 
     def _step(self) -> list[RequestResult]:
-        jnp = self._jnp
-        # 1. Admission.
-        admitted = 0
+        # 1. Admission: every prompt's chunks and head are queued, none read.
         for slot in self._free_slots():
             if not self._queue:
                 break
@@ -528,23 +538,40 @@ class ServingEngine:
                 prompt_len=p, chunks=-(-p // self.chunk),
             ):
                 self._admit(request, slot)
-            admitted += 1
-        self._n["admit_rounds"] += bool(admitted)
-        # Harvest single-token requests that finished inside prefill.
-        finished = self._harvest()
+        self._n["admit_rounds"] += bool(self._unread)
+        # Rows with budget left (a request of one token is finished by its
+        # first and stays parked).
         active_rows = [
-            i for i, s in enumerate(self._slots) if s is not None
+            i for i, s in enumerate(self._slots) if s is not None and s.remaining > 0
         ]
         if not active_rows:
-            return finished
+            self._take_first()
+            return self._harvest()
         # 2. One decode dispatch over the full slot batch, as long as the
-        # rows' budgets and the free slots say (decode_steps).
+        # rows' budgets and the free slots say (decode_steps), queued
+        # behind this boundary's admissions.
         active = np.zeros((self.slots,), bool)
         active[active_rows] = True
         steps, sized_by = decode_steps(
             [self._slots[i].remaining for i in active_rows],
             self.slots - len(active_rows), self.block,
         )
+        t0 = time.time()
+        with obs.span(
+            "engine.decode_dispatch", SPAN_CAT, rows=len(active_rows), steps=steps
+        ):
+            self._lap_to_dispatch()
+            (toks, self._cache, self._counts["decode"], self._tok, self._pos,
+             self._rng) = self._decode_block(
+                self._params, self._cache, self._counts["decode"], self._tok,
+                self._pos, active, self._rng, np.int32(steps),
+            )
+        self.host_lap("dispatch")
+        # From here to the decode fence the device has the dispatch to run.
+        if self._unread:
+            self._n["decode_behind_admit"] += 1
+            self._take_first()
+            t0 = time.time()  # the dispatch starts where the last head ended
         # What each step's attention reads of every row: up to the deepest
         # active row, which the program finds from the same positions.
         L = self.cfg.max_decode_len
@@ -552,18 +579,13 @@ class ServingEngine:
         self._n["decode_attended_positions"] += len(active_rows) * int(
             self._attended(np.minimum(deepest + np.arange(steps), L - 1) + 1, L).sum()
         )
-        t0 = time.time()
-        with obs.span(
-            "engine.decode_dispatch", SPAN_CAT, rows=len(active_rows), steps=steps
-        ):
-            active = jnp.asarray(active)
-            self.host_lap("admit_prep")  # up to this dispatch the device waited
-            (toks, self._cache, self._counts["decode"], self._tok, self._pos,
-             self._rng) = self._decode_block(
-                self._params, self._cache, self._counts["decode"], self._tok,
-                self._pos, active, self._rng, np.int32(steps),
-            )
-        self.host_lap("dispatch")
+        self.last_steps = steps
+        self._n["decode_blocks"] += 1
+        self._n["decode_steps"] += steps
+        self._n[f"decode_sized_by_{sized_by}"] += 1
+        self._n["slot_blocks_occupied"] += len(active_rows)
+        self._n["decode_row_steps"] += len(active_rows) * steps
+        self.host_lap("overlapped")
         with obs.span("engine.decode_fence", SPAN_CAT):
             # Device fence: the dispatch is the unit.
             toks = np.asarray(toks)[:, :steps]
@@ -590,17 +612,11 @@ class ServingEngine:
                         accepted * live0 + accepted * (accepted - 1) // 2
                     )
                 live += accepted
-        self.last_steps = steps
-        self._n["decode_blocks"] += 1
-        self._n["decode_steps"] += steps
-        self._n[f"decode_sized_by_{sized_by}"] += 1
-        self._n["slot_blocks_occupied"] += len(active_rows)
-        self._n["decode_row_steps"] += len(active_rows) * steps
         if live:
             self._n["decode_tokens"] += live
             self._decode_wall += wall
         self.host_lap("accept")
-        return finished + self._harvest()
+        return self._harvest()
 
     def _harvest(self) -> list[RequestResult]:
         out = []
@@ -656,6 +672,7 @@ class ServingEngine:
             if st is not None:
                 aborted.append(st.request.id)
                 self._slots[i] = None
+        self._unread.clear()
         return aborted
 
     @property

@@ -27,7 +27,7 @@ from pytorch_operator_tpu.serving.engine import FENCE_SEGMENTS, GAP_SEGMENTS, SE
 SHAPES = [(5, 7), (13, 9), (8, 1), (21, 5), (3, 12)]  # (prompt, new tokens); one finishes inside prefill
 COUNTERS = ("decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
             "decode_sized_by_budget", "decode_sized_by_quantum", "decode_sized_by_ceiling", "prefill_chunks",
-            "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "admitted")
+            "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "decode_behind_admit", "admitted")
 
 
 @pytest.fixture(scope="module")
@@ -90,6 +90,8 @@ def test_counters_repeat_exactly_and_say_what_they_count(model):
     assert n["decode_sized_by_ceiling"] > 0 and n["decode_sized_by_budget"] > 0  # block 4 cuts; so do last tokens
     assert n["decode_blocks"] <= n["slot_blocks_occupied"] <= 3 * n["decode_blocks"]
     assert 1 <= n["admit_rounds"] <= n["admitted"]
+    # Every round here admits a row that decodes, so each one's decode dispatch was queued behind it, unread.
+    assert n["decode_behind_admit"] == n["admit_rounds"] < n["decode_blocks"]
     stats = eng.stats()
     assert stats["slot_occupancy_pct"] == pytest.approx(
         100 * n["slot_blocks_occupied"] / (3 * n["decode_blocks"]), abs=1e-3)
@@ -150,7 +152,10 @@ def test_every_second_of_the_serving_thread_goes_to_one_segment(model):
         f"host_gap_{k}_s" for k in GAP_SEGMENTS}
     assert host_key("accept") == "host_gap_accept_s" and host_key("idle") == "host_idle_s"
     assert segments["idle"] >= 0.02 and segments["poll"] == 0.0
-    assert all(segments[k] > 0 for k in FENCE_SEGMENTS + ("admit_prep", "accept", "harvest", "dispatch", "submit"))
+    assert all(segments[k] > 0 for k in FENCE_SEGMENTS + (
+        "admit_prep", "accept", "harvest", "dispatch", "overlapped", "submit"))
+    # What runs while a dispatch is in flight is no part of the gap.
+    assert "overlapped" not in GAP_SEGMENTS and host_key("overlapped") == "host_overlapped_s"
 
 
 @pytest.fixture
@@ -175,11 +180,24 @@ def test_engine_spans_nest_under_the_step_and_requests_keep_their_hops(model, tr
     assert {"engine.step", "engine.admit", "engine.prefill_dispatch", "engine.first_token",
             "engine.decode_dispatch", "engine.decode_fence", "engine.accept", "engine.harvest"} <= names
     for e in spans:
-        if e["name"] in ("engine.admit", "engine.decode_dispatch", "engine.decode_fence", "engine.accept",
-                         "engine.harvest"):
+        if e["name"] in ("engine.admit", "engine.decode_dispatch", "engine.first_token", "engine.decode_fence",
+                         "engine.accept", "engine.harvest"):
             assert by_id[e["parent"]]["name"] == "engine.step"
-        if e["name"] in ("engine.prefill_dispatch", "engine.first_token"):
+        if e["name"] == "engine.prefill_dispatch":
             assert by_id[e["parent"]]["name"] == "engine.admit"
+    # A step's order (PR 35): every admission dispatched, then the decode dispatch, and only then the fences:
+    # one on each admission's first token, in admission order, before the one on the decode tokens.
+    firsts = 0
+    for step in (e for e in spans if e["name"] == "engine.step"):
+        inside = sorted((e for e in spans if e.get("parent") == step["id"]), key=lambda e: e["ts"])
+        order = [e["name"] for e in inside if e["name"] != "engine.harvest"]
+        admits = order.count("engine.admit")
+        assert order == ["engine.admit"] * admits + ["engine.decode_dispatch"] + ["engine.first_token"] * admits + [
+            "engine.decode_fence", "engine.accept"], order
+        ends = [e["ts"] + e["dur"] for e in inside if e["name"] in ("engine.admit", "engine.decode_dispatch")]
+        assert all(e["ts"] >= max(ends) for e in inside if e["name"] == "engine.first_token")
+        firsts += admits
+    assert firsts == len(SHAPES)
     admits = [e for e in spans if e["name"] == "engine.admit"]
     assert sorted(e["args"]["rid"] for e in admits) == [f"r{i}" for i in range(len(SHAPES))]
     assert all(e["args"]["chunks"] == -(-e["args"]["prompt_len"] // 8) for e in admits)

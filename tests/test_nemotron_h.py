@@ -17,6 +17,7 @@ through it, a wrong decay, group or gate moves a logit by 0.05 or more.
 from __future__ import annotations
 
 import json
+import re
 import time
 from pathlib import Path
 
@@ -565,3 +566,7 @@ def test_tpujob_run_of_a_serve_job_with_the_preset_answers_requests(tmp_path):
     assert final["prefill_state_resets"] == final["admitted"] == 3 and final["moe_tokens"] % 3 == 0
     why = subprocess.run([*cli, "why", job["metadata"]["name"]], cwd=ROOT, capture_output=True, text=True, timeout=120)
     assert "3 row(s) started from zero state for 3 admitted" in why.stdout, why.stdout[-2000:]
+    # ... and the rounds that admitted beside the decode dispatches queued behind one (PR 35): every row decodes.
+    assert final["decode_behind_admit"] == final["admit_rounds"] >= 2 and "host_overlapped_s" in final
+    assert re.search(rf"admits: +\S+ 3 admitted in {final['admit_rounds']} round\(s\), the decode dispatch queued "
+                     rf"behind {final['decode_behind_admit']} of them", why.stdout), why.stdout[-2000:]

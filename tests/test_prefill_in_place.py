@@ -4,7 +4,8 @@
   slot's row of a many-slot cache and nothing else, and that row is what the
   row-at-a-time form gave (both families);
 - the engine's ``prefill_chunk`` runs no head, and ``prefill_chunk_head`` on
-  a prompt's last chunk gives the logits the one program gave;
+  a prompt's last chunk samples from the logits the one program gave and
+  (PR 35) sets the row's token and position in the donated row state;
 - an int8 weight's product with the scale behind it
   (``QuantizedTensor.project``) is ``dequantize()``-then-product to rounding,
   and no further from float32;
@@ -106,12 +107,16 @@ def test_prefill_writes_its_own_slot_and_no_other(family, slot):
                for g, w in zip(jax.tree.leaves(cache), jax.tree.leaves(before)))
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.9], ids=["greedy", "sampled"])
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_the_chunk_program_runs_no_head_and_the_head_program_gives_the_one_programs_logits(family):
+def test_the_chunk_program_runs_no_head_and_the_head_program_samples_from_the_one_programs_logits(family, temperature):
     """A chunk returns its hidden states and the cache the model's own
-    prefill gives; ``prefill_chunk_head`` on a prompt's last chunk returns
-    ``model.logits`` of the hidden state at ``last_idx``, which is what the
-    one program computed on every chunk."""
+    prefill gives; ``prefill_chunk_head`` on a prompt's last chunk samples
+    the first token from ``model.logits`` of the hidden state at the
+    prompt's last position (what the one program computed on every chunk)
+    with the key's second half, sets row ``slot`` of ``tok`` to it and of
+    ``pos`` to the prompt's length, touches no other row, and hands back
+    the key's first half."""
     import jax
     import jax.numpy as jnp
 
@@ -119,10 +124,11 @@ def test_the_chunk_program_runs_no_head_and_the_head_program_gives_the_one_progr
 
     cfg, params = FAMILIES[family]()
     model = cfg.serving_model()
-    progs = programs(model, slots=SLOTS, chunk=CHUNK, block=4, sample=make_sampler(0.0, 0, 1.0))
+    sample = make_sampler(temperature, 0, 1.0)
+    progs = programs(model, slots=SLOTS, chunk=CHUNK, block=4, sample=sample)
     toks = np.random.default_rng(2).integers(1, cfg.vocab_size, (2, 1, CHUNK)).astype(np.int32)
     zero = lambda: jax.tree.map(jnp.zeros_like, model.counts)
-    cache, counts, slot, last = model.init_cache(SLOTS, CHUNK), zero(), jnp.int32(1), 5
+    cache, counts, slot, p = model.init_cache(SLOTS, CHUNK), zero(), jnp.int32(1), CHUNK + 6
     _, cache, counts = progs.prefill_chunk(params, cache, counts, slot, jnp.asarray(toks[0]), jnp.int32(0))
     before = jax.tree.map(jnp.copy, cache)
     hidden, cache, counts = progs.prefill_chunk(params, cache, counts, slot, jnp.asarray(toks[1]), jnp.int32(CHUNK))
@@ -131,10 +137,19 @@ def test_the_chunk_program_runs_no_head_and_the_head_program_gives_the_one_progr
     np.testing.assert_array_equal(np.asarray(hidden, np.float32), np.asarray(want_hidden, np.float32))
     for got, w in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(w))
-    logits = progs.prefill_chunk_head(params, hidden, jnp.int32(last))
-    assert logits.shape == (cfg.vocab_size,) and logits.dtype == jnp.float32
-    np.testing.assert_allclose(
-        np.asarray(logits), np.asarray(model.logits(params, want_hidden[:, last])[0]), rtol=1e-5, atol=1e-5)
+    key = jax.random.key(11)
+    next_key, sub = jax.random.split(key)
+    logits = model.logits(params, want_hidden[:, (p - 1) % CHUNK])
+    assert logits.shape == (1, cfg.vocab_size) and logits.dtype == jnp.float32
+    want = int(sample(logits, sub)[0])
+    if temperature == 0.0:
+        assert want == int(np.argmax(np.asarray(logits[0])))  # what the host's argmax gave before PR 35
+    row_tok, row_pos = jnp.asarray([7, 8, 9], jnp.int32), jnp.asarray([70, 80, 90], jnp.int32)
+    row_tok, row_pos, first, got_key = progs.prefill_chunk_head(
+        params, hidden, row_tok, row_pos, np.int32(1), np.int32(p), key)
+    assert first.shape == () and int(first) == want
+    assert np.asarray(row_tok).tolist() == [7, want, 9] and np.asarray(row_pos).tolist() == [70, p, 90]
+    assert (jax.random.key_data(got_key) == jax.random.key_data(next_key)).all()
     # Both are the prefill's programs by name (the benchmark finds them so).
     assert "prefill_chunk" in progs.prefill_chunk_head.__name__ and "prefill_chunk" in progs.prefill_chunk.__name__
 

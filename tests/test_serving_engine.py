@@ -226,6 +226,230 @@ def test_the_engine_tells_every_chunk_how_many_of_its_tokens_are_real(family, mo
     assert eng.stats()["prefill_chunks"] == 3 and eng.stats()["prefill_pad_tokens"] == 5
 
 
+# ---- an admission is dispatched whole: nothing is read back until the decode dispatch is queued (PR 35) ----
+
+
+def _sampled_rollouts(model, params, prompts, news, *, chunk, seed, temperature, top_k):
+    """What the engine must give when every prompt is admitted in its first
+    round, one a slot, straight from the model's own forwards: each first
+    token from the chain of ``key(seed + 1)`` in admission order, then one
+    sample a step over all rows from the chain of ``key(seed)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.ops.sampling import make_sampler
+
+    sample = make_sampler(temperature, top_k, 1.0)
+    prefill, decode = jax.jit(model.prefill), jax.jit(model.decode)
+    cache = model.init_cache(len(prompts), chunk)
+    first_key, rng = jax.random.key(seed + 1), jax.random.key(seed)
+    tok = []
+    for slot, prompt in enumerate(prompts):
+        p = len(prompt)
+        buf = np.zeros((-(-p // chunk) * chunk,), np.int32)
+        buf[:p] = prompt
+        for start in range(0, len(buf), chunk):
+            pos = (start + jnp.arange(chunk, dtype=jnp.int32))[None]
+            hidden, cache, _ = prefill(params, cache, jnp.int32(slot), jnp.asarray(buf[None, start:start + chunk]),
+                                       pos, jnp.int32(min(chunk, p - start)))
+        first_key, sub = jax.random.split(first_key)
+        tok.append(sample(model.logits(params, hidden[:, (p - 1) % chunk]), sub)[0])
+    tok, pos = jnp.stack(tok), jnp.asarray([len(prompt) for prompt in prompts], jnp.int32)
+    out = [[int(t)] for t in tok]
+    for _ in range(max(news) - 1):
+        logits, cache, _ = decode(params, cache, tok[:, None], pos[:, None])
+        rng, k = jax.random.split(rng)
+        tok, pos = sample(logits, k), pos + 1
+        for row, t in zip(out, np.asarray(tok)):
+            row.append(int(t))
+    return [row[:n] for row, n in zip(out, news)]
+
+
+ROUND_SHAPES = [(5, 7), (19, 12), (8, 4)]  # (prompt, new tokens): one, three and one chunks of 8
+
+
+def _round_prompts():
+    rng = np.random.default_rng(11)
+    return [rng.integers(1, 250, (p,)).astype(np.int32) for p, _ in ROUND_SHAPES]
+
+
+@pytest.mark.parametrize("family", ["llama", "nemotron_h"])
+def test_seeded_sampling_with_several_prompts_admitted_in_one_round_is_the_models_own_rollout(family):
+    """Temperature 1, top-k 8, three prompts into three slots at one
+    boundary: the first tokens come from the first-token key split once an
+    admission, in admission order, and the rest from the decode key, as
+    before the head's program sampled them; a second engine with the same
+    seed repeats them and greedy gives others."""
+    model, params = _family_model(family)
+    prompts, news = _round_prompts(), [n for _, n in ROUND_SHAPES]
+    want = _sampled_rollouts(model, params, prompts, news, chunk=8, seed=3, temperature=1.0, top_k=8)
+
+    def served(**sampling):
+        eng = ServingEngine(model.cfg, params, slots=3, chunk=8, block=4, **sampling)
+        for i, (prompt, n) in enumerate(zip(prompts, news)):
+            eng.submit(_req(f"t{i}", prompt, n))
+        got = {r.id: r.tokens for r in eng.run_until_drained()}
+        assert eng.stats()["admit_rounds"] == eng.stats()["decode_behind_admit"] == 1
+        return [got[f"t{i}"] for i in range(len(prompts))]
+
+    assert served(temperature=1.0, top_k=8, seed=3) == want == served(temperature=1.0, top_k=8, seed=3)
+    assert served() != want and served(temperature=1.0, top_k=8, seed=4) != want
+
+
+class _Unread:
+    """A program's output the host has not read yet: the read is logged."""
+
+    def __init__(self, value, log, name):
+        self.value, self.log, self.name = value, log, name
+
+    def __int__(self):
+        self.log.append(f"read:{self.name}")
+        return int(self.value)
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(f"read:{self.name}")
+        return np.asarray(self.value, dtype)
+
+
+def _logging_programs(monkeypatch, log):
+    """The engine's own programs, each dispatch logged by its name, and the
+    two outputs the host reads (an admission's first token, a dispatch's
+    tokens) wrapped so that the read is logged too."""
+    from pytorch_operator_tpu.serving import engine as engine_lib
+
+    real_programs = engine_lib.programs
+
+    def logging(model, **kw):
+        progs = real_programs(model, **kw)
+
+        def prefill_chunk(*args):
+            log.append("prefill_chunk")
+            return progs.prefill_chunk(*args)
+
+        def prefill_chunk_head(*args):
+            log.append("prefill_chunk_head")
+            tok, pos, first, key = progs.prefill_chunk_head(*args)
+            return tok, pos, _Unread(first, log, "first_token"), key
+
+        def decode_block(*args):
+            log.append("decode_block")
+            toks, *rest = progs.decode_block(*args)
+            return (_Unread(toks, log, "decode_tokens"), *rest)
+
+        return engine_lib.Programs(prefill_chunk, prefill_chunk_head, decode_block)
+
+    monkeypatch.setattr(engine_lib, "programs", logging)
+
+
+def test_nothing_is_read_back_between_an_admissions_first_chunk_and_the_decode_dispatch_behind_it(parity_model, monkeypatch):
+    """Two prompts admitted at one boundary (one and two chunks of 8): both
+    prompts' chunks and heads are dispatched, then the decode block, and
+    only then the two first tokens are read, in admission order, before the
+    decode tokens; a boundary without an admission reads the decode tokens
+    alone. (The CPU backend does not honour
+    ``jax.transfer_guard_device_to_host``, so the order is recorded where
+    the engine dispatches and reads.) The tokens are ``make_generate``'s."""
+    cfg, params, prompts, want = parity_model
+    log = []
+    _logging_programs(monkeypatch, log)
+    eng = ServingEngine(cfg, params, slots=2, chunk=8, block=4)
+    eng.submit(_req("r0", prompts[0], 20))  # 5 tokens: one chunk
+    eng.submit(_req("r1", prompts[1], 30))  # 13 tokens: two chunks
+    assert eng.step() == []
+    assert log == ["prefill_chunk", "prefill_chunk_head", "prefill_chunk", "prefill_chunk", "prefill_chunk_head",
+                   "decode_block", "read:first_token", "read:first_token", "read:decode_tokens"]
+    del log[:]
+    eng.step()
+    assert log == ["decode_block", "read:decode_tokens"]
+    got = {r.id: r.tokens for r in eng.run_until_drained()}
+    assert got == {"r0": want[0], "r1": want[1]}
+    n = eng.stats()
+    assert n["admit_rounds"] == n["decode_behind_admit"] == 1 and n["admitted"] == 2
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0], ids=["greedy", "sampled"])
+def test_an_admission_compiles_no_program_beyond_the_engines_own(temperature):
+    """Five requests through three slots, admissions at several boundaries:
+    the process compiles (or takes from the compile cache) the engine's
+    three programs and nothing else: no eager scatter sets a row's state,
+    no sampler of its own takes the first token."""
+    from pytorch_operator_tpu.runtime.backend import compile_counts
+
+    model, params = _family_model("llama")
+    sampling = {"temperature": temperature, "top_k": 8, "seed": 1} if temperature else {}
+    eng = ServingEngine(model.cfg, params, slots=3, chunk=8, block=4, **sampling)
+    before = sum(compile_counts().values())
+    rng = np.random.default_rng(0)
+    for i, (p, n) in enumerate([(5, 7), (13, 9), (8, 1), (21, 5), (3, 12)]):
+        eng.submit(_req(f"c{i}", rng.integers(0, 256, (p,)).astype(np.int32), n))
+    assert len(eng.run_until_drained()) == 5
+    assert sum(compile_counts().values()) - before == 3
+    assert [f._cache_size() for f in (eng._prefill_chunk, eng._prefill_chunk_head, eng._decode_block)] == [1, 1, 1]
+    assert not hasattr(eng, "_first_token") and eng.stats()["admit_rounds"] > 1
+
+
+def test_a_first_token_that_ends_its_request_costs_one_dispatch_and_leaves_the_slot_clean(parity_model):
+    """The host learns an admission's first token after the decode dispatch
+    is queued: where it is ``eos_token`` the row ran that dispatch for
+    nothing, its tokens are dropped, the request ends with its one token and
+    the slot is free at the same boundary; the next occupant of the slot
+    gets its own rollout, token for token."""
+    cfg, params, prompts, want = parity_model
+    eos = want[1][0]
+    assert eos not in want[3]
+    eng = ServingEngine(cfg, params, slots=1, chunk=8, block=4, eos_token=eos)
+    eng.submit(_req("e0", prompts[1], 30))
+    eng.submit(_req("e1", prompts[3], 18))
+    (res,) = eng.step()
+    assert res.id == "e0" and res.tokens == [eos] and res.tpot_s is None
+    n = eng.stats()
+    assert n["decode_blocks"] == n["decode_behind_admit"] == 1 and n["decode_tokens"] == 0
+    assert n["decode_row_steps"] == eng.last_steps == 4 and eng.slots_free == 1 and eng.queued == 1
+    (nxt,) = eng.run_until_drained()
+    assert nxt.id == "e1" and nxt.tokens == want[3]
+    assert eng.stats()["decode_tokens"] == 17
+
+
+def test_a_request_of_one_token_never_enters_a_decode_dispatch(parity_model, monkeypatch):
+    """``max_new_tokens`` 1 is finished by its first token: alone it runs no
+    decode dispatch at all; beside a decoding row it stays parked (the
+    dispatch runs one row) and is answered at that boundary."""
+    cfg, params, prompts, want = parity_model
+    log = []
+    _logging_programs(monkeypatch, log)
+    eng = ServingEngine(cfg, params, slots=3, chunk=8, block=4)
+    eng.submit(_req("one", prompts[0], 1))
+    (res,) = eng.step()
+    assert res.tokens == want[0][:1] and not eng.busy
+    assert log == ["prefill_chunk", "prefill_chunk_head", "read:first_token"]
+    n = eng.stats()
+    assert n["decode_blocks"] == 0 and (n["admit_rounds"], n["decode_behind_admit"]) == (1, 0)
+    eng.submit(_req("long", prompts[1], 30))
+    eng.submit(_req("one-more", prompts[2], 1))
+    (res,) = eng.step()
+    assert res.id == "one-more" and res.tokens == want[2][:1]
+    n = eng.stats()
+    assert n["decode_blocks"] == 1 and n["slot_blocks_occupied"] == 1 and n["decode_row_steps"] == eng.last_steps
+    assert (n["admit_rounds"], n["decode_behind_admit"]) == (2, 1)
+    (res,) = eng.run_until_drained()
+    assert res.id == "long" and res.tokens == want[1]
+
+
+@pytest.mark.parametrize("slots", [1, 2, 4])
+def test_every_round_that_admits_a_decoding_row_queues_the_decode_dispatch_behind_it(parity_model, slots):
+    cfg, params, prompts, want = parity_model
+    eng = ServingEngine(cfg, params, slots=slots, chunk=8, block=4)
+    for i, (prompt, (_, n)) in enumerate(zip(prompts, PARITY_SHAPES)):
+        eng.submit(_req(f"r{i}", prompt, n))
+    got = {r.id: r.tokens for r in eng.run_until_drained()}
+    assert [got[f"r{i}"] for i in range(len(want))] == want
+    n = eng.stats()
+    assert n["decode_behind_admit"] == n["admit_rounds"] >= -(-len(want) // slots) and n["admitted"] == len(want)
+    assert n["decode_behind_admit"] < n["decode_blocks"]
+    eng.reset_stats()
+    assert eng.stats()["decode_behind_admit"] == 0 and "host_overlapped_s" in eng.stats()
+
+
 @pytest.mark.slow
 class TestEngineParity:
     def test_mixed_lengths_match_single_stream(self):
@@ -312,9 +536,9 @@ class TestEngineParity:
         assert res.tokens[-1] == eos
 
     def test_temperature_sampling_serves(self):
-        """T>0 exercises the one-dispatch first-token sampler and the
-        device sampler in the decode blocks; tokens must be in-range
-        and the full budget delivered."""
+        """T>0 exercises the sampler in the head's program and in the
+        decode blocks; tokens must be in-range and the full budget
+        delivered."""
         cfg, params = _cfg_params()
         eng = ServingEngine(
             cfg, params, slots=2, chunk=8, block=4,

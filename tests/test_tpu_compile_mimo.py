@@ -140,7 +140,9 @@ def llama_programs(one_chip):
                 params, cache, {}, ints(L_SLOTS), ints(L_SLOTS), active, key, ints()).compile()
         if name == "prefill_chunk_head":
             hidden = jax.ShapeDtypeStruct((1, L_CHUNK, cfg.d_model), cfg.dtype, sharding=one_chip)
-            return progs.prefill_chunk_head.lower(params, hidden, ints()).compile()
+            key = on(jax.eval_shape(lambda: jax.random.key(0)))
+            return progs.prefill_chunk_head.lower(
+                params, hidden, ints(L_SLOTS), ints(L_SLOTS), ints(), ints(), key).compile()
         return progs.prefill_chunk.lower(
             stacked if name == "prefill_chunk_stacked" else params, cache, {}, ints(), ints(1, L_CHUNK), ints()).compile()
 
@@ -214,6 +216,23 @@ def test_a_llama_chunk_moves_fewer_bytes_than_before(llama_programs):
     cost = llama_programs("prefill_chunk_stacked").cost_analysis()
     cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     assert cost["bytes accessed"] < 4.8e9 < PARENT_CHUNK_BYTES
+
+
+def test_the_llama_head_program_samples_the_first_token_and_writes_the_rows_state_in_place(llama_programs):
+    """PR 35: the head's program takes the donated ``tok`` and ``pos`` of all slots and returns them with the row
+    set, so an admission reads 4 bytes back and ``decode_block`` is queued behind it: both are aliased to their
+    outputs, and the sampler runs in the program (its scope is in the text)."""
+    head = llama_programs("prefill_chunk_head")
+    text = head.as_text()
+    assert "head/dot_general" in text and "jit(prefill_chunk_head)/sample" in text
+    assert donated_into_outputs(head) == 2  # tok and pos, int32 [slots] each
+
+
+def donated_into_outputs(compiled) -> int:
+    """How many of a compiled program's arguments are aliased to its outputs."""
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_computation_layout", compiled.as_text()).group(1)
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    return len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", aliases))
 
 
 @pytest.mark.parametrize("form", ["prefill_chunk", "decode_block"])

@@ -25,7 +25,7 @@ import time
 import pytest
 
 import tests.jaxenv  # noqa: F401
-from tests.test_tpu_compile_mimo import WRITES_NOTHING, _arrays, _top_level
+from tests.test_tpu_compile_mimo import WRITES_NOTHING, _arrays, _top_level, donated_into_outputs
 
 HBM = 16 * 1024**3
 SLOTS, CHUNK, BLOCK, LEN = 128, 128, 64, 4096
@@ -79,6 +79,10 @@ def compiled(one_chip):
             key = on(jax.eval_shape(lambda: jax.random.key(0)))
             active = jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one_chip)
             out = progs.decode_block.lower(params, cache, counts, ints(SLOTS), ints(SLOTS), active, key, ints()).compile()
+        elif name == "prefill_chunk_head":
+            key = on(jax.eval_shape(lambda: jax.random.key(0)))
+            hidden = jax.ShapeDtypeStruct((1, CHUNK, cfg.d_model), cfg.dtype, sharding=one_chip)
+            out = progs.prefill_chunk_head.lower(params, hidden, ints(SLOTS), ints(SLOTS), ints(), ints(), key).compile()
         else:
             out = progs.prefill_chunk.lower(params, cache, counts, ints(), ints(1, CHUNK), ints(), ints()).compile()
         print(f"{name} of 16 layer trees compiled for a described v5e in {time.time() - t0:.1f} s")
@@ -118,3 +122,14 @@ def test_a_prefill_chunk_writes_its_rows_state_back_in_place(compiled):
     # One a layer, each an update-slice of the donated leaf (fused with the row's own arithmetic), none a copy.
     assert len(writers) == MAMBA_LAYERS and all("dynamic_update_slice" in name for _, name in writers), writers
     assert "jit(prefill_chunk)/ssm/ssm_scan" in text and "head/dot_general" not in text
+
+
+def test_the_head_program_samples_the_first_token_and_writes_the_rows_state_in_place(compiled):
+    """PR 35: an admission's last program takes the donated ``tok`` and ``pos`` of all 128 slots and returns them
+    with the row set, beside the 4 bytes the host reads: both aliased, the head's product and the sampler in its text."""
+    head = compiled("prefill_chunk_head")
+    text = head.as_text()
+    assert "jit_prefill_chunk_head" in text and "head/dot_general" in text and "jit(prefill_chunk_head)/sample" in text
+    assert donated_into_outputs(head) == 2
+    mem = head.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
