@@ -5,14 +5,15 @@
 :class:`ServingModel` alone: its config, its seeded init, its cache
 constructor and its two forwards. A config class states its family by
 having ``serving_model()`` (``models.llama.LlamaConfig``,
-``models.mimo_v2.MiMoV2Config``, ``models.nemotron_h.NemotronHConfig``); a job's ``--config`` names a preset, and
+``models.mimo_v2.MiMoV2Config``, ``models.nemotron_h.NemotronHConfig``,
+``models.phi4_flash.Phi4FlashConfig``); a job's ``--config`` names a preset, and
 :func:`preset` finds the family that has it. Nothing else selects a path.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+from typing import Any, Callable, Optional
 
 
 def _nothing(*_):
@@ -30,7 +31,8 @@ class ServingModel:
     and values in place and reads that row's filled prefix where it lies;
     the layer-pattern family slices its small row out and writes it back,
     inside its own forward; the hybrid family keeps a constant-size
-    recurrent state a row beside its slabs). ``counts`` are
+    recurrent state a row beside its slabs; the decoder-hybrid-decoder
+    family has layers that own no leaf and read another layer's). ``counts`` are
     the model's own device counters at zero (a pytree of int32 arrays, ``{}``
     for none): each forward returns what one call adds, the engine's two
     programs sum them on the device, and ``ServingEngine.stats()`` brings
@@ -44,8 +46,10 @@ class ServingModel:
     # (params, cache, slot (a traced int32 scalar), tokens [1, chunk],
     # positions [1, chunk], n_real (a traced int32 scalar: the first
     # ``n_real`` tokens are the prompt's, the rest the last chunk's pad))
-    # -> (final-norm hidden [1, chunk, D], cache, counts): one call shape
-    # for every family. State that grows with position (keys and values)
+    # -> (hidden, cache, counts): one call shape for every family.
+    # ``hidden`` is the final-norm hidden [1, chunk, D], or any pytree of
+    # [1, chunk, ...] leaves that the family's ``finish`` takes one token
+    # of. State that grows with position (keys and values)
     # may take the pads in: every later read masks them by position or
     # overwrites them. State that is a recurrence must stop at the last
     # real token, and start from zero where the chunk stands at position 0
@@ -56,6 +60,19 @@ class ServingModel:
     decode: Callable
     # (params, hidden [n, D]) -> float32 logits [n, V].
     logits: Callable
+    # The end of an admission, once a prompt: (params, cache (read only),
+    # slot, h (``hidden`` at the prompt's last token: [1, ...] leaves),
+    # position (that token's)) -> float32 logits [1, V]. None = ``logits
+    # (params, h)``: every layer ran in ``prefill``. A family whose later
+    # layers write no state runs them here, for the one token whose logits
+    # anybody reads, against the slot's cache.
+    finish: Optional[Callable] = None
+    # What an admission's programs attend of the full-length slabs, for the
+    # engine's ``prefill_attended_positions``: (each chunk's last position
+    # + 1 (an integer array), the prompt's length) -> the positions each
+    # read needs, an integer array. None = each chunk reads up to its own
+    # end.
+    slab_reads: Optional[Callable] = None
     # a checkpoint's parameter tree (the trainer's form, host arrays) ->
     # the same leaves as ``init_params`` arranges them, which is how the
     # forwards read them fastest (``workloads.generate.load_params`` calls
@@ -72,11 +89,11 @@ def families() -> dict:
     """``preset name -> (model module, name of the function that makes its
     config)``, read at call time: a family's table is a module dict that a
     caller may add a preset to (the benchmark's ``bench``)."""
-    from . import llama, mimo_v2, nemotron_h
+    from . import llama, mimo_v2, nemotron_h, phi4_flash
 
     return {
         name: (module, fn)
-        for module in (llama, mimo_v2, nemotron_h)
+        for module in (llama, mimo_v2, nemotron_h, phi4_flash)
         for name, fn in module.CONFIGS.items()
     }
 

@@ -512,6 +512,15 @@ def analyze(
         if "prefill_state_resets" in rec
     }
 
+    # A model whose later layers write no state runs them on a prompt's last
+    # token only (models/phi4_flash.py): the tokens they ran on outside the
+    # decode dispatches, beside the prompt tokens prefilled.
+    cross_tokens = {
+        rec["replica"]: [rec["prefill_cross_tokens"], rec.get("prefill_tokens", 0)]
+        for rec in tl.records.get("metrics", [])
+        if "prefill_cross_tokens" in rec
+    }
+
     # A serving engine's admissions (serving/engine.py): the rounds that
     # admitted, beside the decode dispatches queued behind one with its
     # first token still unread. Equal whenever an admitted row decodes.
@@ -531,6 +540,7 @@ def analyze(
         "replicas": replicas,
         "programs_compiled": compiled,
         "state_resets": state_resets,
+        "cross_tokens": cross_tokens,
         "admit_rounds": admit_rounds,
         "events": len(tl.events),
         "spans": len(tl.spans),
@@ -614,6 +624,8 @@ def render_report(report: dict) -> str:
             f"state:    {replica} {resets} row(s) started from zero state for {admitted} admitted"
             + ("" if resets == admitted else "  <-- these must be equal")
         )
+    for replica, (ran, prefilled) in sorted(report.get("cross_tokens", {}).items()):
+        lines.append(f"prefill:  {replica} prefill_cross_tokens {ran} beside prefill_tokens {prefilled}")
     for replica, (rounds, behind, admitted) in sorted(report.get("admit_rounds", {}).items()):
         lines.append(
             f"admits:   {replica} {admitted} admitted in {rounds} round(s), "

@@ -45,7 +45,10 @@ TPU-first shape (every program's shapes static):
   attention, and runs no head: a small program of its own,
   ``prefill_chunk_head``, takes the last chunk's hidden states to the
   logits of the prompt's last position, once a prompt
-  (``prefill_head_chunks`` counts it), samples the first token from them
+  (``prefill_head_chunks`` counts it) -- through the model's ``finish``
+  where it has one (layers that write no state run there, for that one
+  token, reading the slot's cache), else the head's product alone --
+  samples the first token from them
   with the sampler the decode steps use and writes the row's token and
   position into the donated ``tok`` / ``pos`` that ``decode_block`` reads.
   Arbitrary prompt lengths therefore hit exactly these compiled programs,
@@ -58,6 +61,11 @@ TPU-first shape (every program's shapes static):
   decode tokens. A first token that ends its request (``eos_token``) is
   learnt one dispatch late: the row ran a dispatch whose tokens are
   dropped (``decode_behind_admit`` counts the dispatches queued so).
+- A boundary's admissions queue at most :data:`ADMIT_CHUNKS` prefill
+  chunks (and always one prompt) in front of the decode dispatch: the
+  rows that are decoding wait for a bounded stretch of prefill, and a
+  wave of long prompts into an empty engine is admitted a round at a
+  time, its first rows decoding meanwhile.
 - Slot L-1 of every row is a parking slot: rows that exhaust their
   budget clamp there, so admission requires prompt + new <= L-1 and
   no live stream ever attends a parked write.
@@ -153,12 +161,24 @@ SPAN_CAT = "engine"
 QUANTUM = 8
 
 
+# Most prefill chunks the admissions of ONE boundary may queue in front of
+# the decode dispatch behind them (a boundary always admits one prompt,
+# whatever its length). The rows that are decoding wait for every chunk of
+# a round, so an empty engine that meets a hundred long prompts (a closed
+# loop's first wave) would hold its first rows' second tokens back for the
+# whole wave; with the bound they wait a second or so, and the rest of the
+# wave is admitted a round at a time between dispatches of a quantum. No
+# cell's steady state comes near it (PERF.md section 6, PR 36).
+ADMIT_CHUNKS = 128
+
+
 def decode_steps(remaining, free_slots: int, block: int) -> tuple[int, str]:
     """How many steps the next decode dispatch runs, and which rule sized
     it (one of :data:`SIZED_BY`). ``remaining`` are the active rows'
     remaining budgets (each >= 1; an upper bound on the row's life where
     an EOS token can end it sooner), ``free_slots`` the slots that hold no
-    request — after admission, so a free slot means nothing is queued.
+    request — after admission, so a free slot means nothing is queued, or
+    that the boundary's admissions reached :data:`ADMIT_CHUNKS`.
 
     - a slot free: an arrival could be admitted at the next boundary, so
       at most a quantum;
@@ -251,8 +271,14 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         hidden, cache, added = model.prefill(params, cache, slot, chunk_toks, pos, n_real)
         return hidden, cache, add(counts, added)
 
-    @functools.partial(jax.jit, donate_argnums=(2, 3))
-    def prefill_chunk_head(params, hidden, tok, pos, slot, p, key):
+    def finish(params, cache, slot, h, position):
+        if model.finish is not None:
+            return model.finish(params, cache, slot, h, position)
+        with jax.named_scope("head"):
+            return model.logits(params, h)
+
+    @functools.partial(jax.jit, donate_argnums=(3, 4))
+    def prefill_chunk_head(params, cache, hidden, tok, pos, slot, p, key):
         """The end of an admission, once a prompt: the head on the last
         real position ONLY of the prompt's last chunk (the full [chunk, V]
         product costs as much as several transformer layers), the first
@@ -261,15 +287,21 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         donated ``tok`` / ``pos`` [slots] set to it and to the prompt's
         length ``p``, where ``decode_block`` writes its keys and values
         before attending (as ``make_generate``'s first scan step does).
-        Returns them, the token as a scalar (all the host reads of an
+        ``hidden`` is whatever pytree the model's prefill returned; the
+        model's ``finish`` takes that one position of it to the logits and
+        may read row ``slot`` of ``cache`` (not donated) to do so: a model
+        whose prefill ran every layer has none, the head's product is all
+        that is left, and the cache is no input of its program.
+        Returns ``tok``, ``pos``, the token as a scalar (all the host reads of an
         admission) and the next key. A program of its own and not a
         second form of the chunk's: a second copy of the whole chunk
         program cost every run 2.3 s of set-up to load (PERF.md section
         6, PR 31). Its name keeps ``prefill_chunk`` in it, by which the
         benchmark finds the prefill's programs."""
         with jax.named_scope("head"):
-            h = jax.lax.dynamic_slice_in_dim(hidden, (p - 1) % chunk, 1, axis=1)
-            logits = model.logits(params, h[:, 0])
+            at = (p - 1) % chunk
+            h = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, at, 1, axis=1)[:, 0], hidden)
+        logits = finish(params, cache, slot, h, p - 1)
         key, sub = jax.random.split(key)
         first = sample(logits, sub)[0]
         return tok.at[slot].set(first), pos.at[slot].set(p), first, key
@@ -453,10 +485,12 @@ class ServingEngine:
         self._n["prefill_chunks"] += padded // self.chunk
         self._n["prefill_tokens"] += p
         self._n["prefill_pad_tokens"] += padded - p
-        # What the chunks' attention reads of the row: each up to its own end.
-        self._n["prefill_attended_positions"] += int(
-            self._attended(np.arange(self.chunk, padded + 1, self.chunk), L).sum()
-        )
+        # What the admission's attention reads of the row's slabs: each
+        # chunk up to its own end, unless the model says otherwise.
+        reads = np.arange(self.chunk, padded + 1, self.chunk)
+        if self.model.slab_reads is not None:
+            reads = self.model.slab_reads(reads, p)
+        self._n["prefill_attended_positions"] += int(self._attended(reads, L).sum())
         # Host values throughout: the dispatch moves what its program reads
         # (a family whose state is keys and values never gets ``n_real``).
         slot_ = np.int32(slot)
@@ -473,7 +507,7 @@ class ServingEngine:
         # the first token, and the program that samples it sets the row's
         # state: the head runs once a prompt.
         self._tok, self._pos, first, self._first_key = self._prefill_chunk_head(
-            self._params, hidden, self._tok, self._pos, slot_, np.int32(p), self._first_key
+            self._params, self._cache, hidden, self._tok, self._pos, slot_, np.int32(p), self._first_key
         )
         self._n["prefill_head_chunks"] += 1
         self.host_lap("dispatch")
@@ -528,9 +562,13 @@ class ServingEngine:
 
     def _step(self) -> list[RequestResult]:
         # 1. Admission: every prompt's chunks and head are queued, none read.
+        room = ADMIT_CHUNKS
         for slot in self._free_slots():
             if not self._queue:
                 break
+            room -= -(-len(self._queue[0].prompt) // self.chunk)
+            if room < 0 and self._unread:
+                break  # the rest of the queue at the next boundary, behind a decode dispatch
             request = self._queue.popleft()
             p = len(request.prompt)
             with obs.span(

@@ -146,7 +146,7 @@ def test_the_chunk_program_runs_no_head_and_the_head_program_samples_from_the_on
         assert want == int(np.argmax(np.asarray(logits[0])))  # what the host's argmax gave before PR 35
     row_tok, row_pos = jnp.asarray([7, 8, 9], jnp.int32), jnp.asarray([70, 80, 90], jnp.int32)
     row_tok, row_pos, first, got_key = progs.prefill_chunk_head(
-        params, hidden, row_tok, row_pos, np.int32(1), np.int32(p), key)
+        params, cache, hidden, row_tok, row_pos, np.int32(1), np.int32(p), key)
     assert first.shape == () and int(first) == want
     assert np.asarray(row_tok).tolist() == [7, want, 9] and np.asarray(row_pos).tolist() == [70, p, 90]
     assert (jax.random.key_data(got_key) == jax.random.key_data(next_key)).all()

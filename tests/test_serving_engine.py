@@ -173,17 +173,21 @@ def _family_model(family):
 
     from pytorch_operator_tpu.models.serving import preset
 
-    name = {"llama": "tiny", "mimo_v2": "mimo-tiny", "nemotron_h": "nemotron-h-tiny"}[family]
+    name = {"llama": "tiny", "mimo_v2": "mimo-tiny", "nemotron_h": "nemotron-h-tiny", "phi4_flash": "phi4-flash-tiny"}[family]
     model = preset(name, decode=True, max_decode_len=64).serving_model()
     return model, model.init_params(jax.random.key(0))
 
 
-@pytest.mark.parametrize("family", ["llama", "mimo_v2", "nemotron_h"])
+FAMILIES = ["llama", "mimo_v2", "nemotron_h", "phi4_flash"]
+RECURRENT = ("nemotron_h", "phi4_flash")  # the families that keep a scan's state beside keys and values
+
+
+@pytest.mark.parametrize("family", FAMILIES)
 def test_the_count_of_real_tokens_changes_nothing_where_state_is_keys_and_values(family):
     """``prefill`` of a padded last chunk with the count of real tokens, and
     with the whole chunk called real: the families whose state is keys and
     values give the same bits (every later read masks a pad by position or
-    overwrites it); the one whose state is a recurrence gives the same bits
+    overwrites it); those whose state is a recurrence give the same bits
     at the real positions and another state behind them."""
     import jax
     import jax.numpy as jnp
@@ -194,13 +198,14 @@ def test_the_count_of_real_tokens_changes_nothing_where_state_is_keys_and_values
     pos = jnp.arange(chunk, dtype=jnp.int32)[None]
     told, cache_told, _ = model.prefill(params, model.init_cache(2, chunk), jnp.int32(1), toks, pos, jnp.int32(real))
     whole, cache_whole, _ = model.prefill(params, model.init_cache(2, chunk), jnp.int32(1), toks, pos, jnp.int32(chunk))
-    assert np.array_equal(np.asarray(told[0, :real], np.float32), np.asarray(whole[0, :real], np.float32))
+    for a, b in zip(jax.tree.leaves(told), jax.tree.leaves(whole)):  # ``hidden`` is the family's own pytree
+        assert np.array_equal(np.asarray(a[0, :real], np.float32), np.asarray(b[0, :real], np.float32))
     same = all(np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
                for a, b in zip(jax.tree.leaves(cache_told), jax.tree.leaves(cache_whole)))
-    assert same == (family != "nemotron_h")
+    assert same == (family not in RECURRENT)
 
 
-@pytest.mark.parametrize("family", ["llama", "mimo_v2", "nemotron_h"])
+@pytest.mark.parametrize("family", FAMILIES)
 def test_the_engine_tells_every_chunk_how_many_of_its_tokens_are_real(family, monkeypatch):
     """A prompt of 19 in chunks of 8: the model's ``prefill`` is traced once
     (one program) and the three dispatches carry 8, 8 and 3."""
@@ -253,7 +258,9 @@ def _sampled_rollouts(model, params, prompts, news, *, chunk, seed, temperature,
             hidden, cache, _ = prefill(params, cache, jnp.int32(slot), jnp.asarray(buf[None, start:start + chunk]),
                                        pos, jnp.int32(min(chunk, p - start)))
         first_key, sub = jax.random.split(first_key)
-        tok.append(sample(model.logits(params, hidden[:, (p - 1) % chunk]), sub)[0])
+        h = jax.tree.map(lambda a: a[:, (p - 1) % chunk], hidden)
+        logits = model.logits(params, h) if model.finish is None else model.finish(params, cache, jnp.int32(slot), h, jnp.int32(p - 1))
+        tok.append(sample(logits, sub)[0])
     tok, pos = jnp.stack(tok), jnp.asarray([len(prompt) for prompt in prompts], jnp.int32)
     out = [[int(t)] for t in tok]
     for _ in range(max(news) - 1):
@@ -273,7 +280,7 @@ def _round_prompts():
     return [rng.integers(1, 250, (p,)).astype(np.int32) for p, _ in ROUND_SHAPES]
 
 
-@pytest.mark.parametrize("family", ["llama", "nemotron_h"])
+@pytest.mark.parametrize("family", ["llama", "nemotron_h", "phi4_flash"])
 def test_seeded_sampling_with_several_prompts_admitted_in_one_round_is_the_models_own_rollout(family):
     """Temperature 1, top-k 8, three prompts into three slots at one
     boundary: the first tokens come from the first-token key split once an
@@ -368,14 +375,16 @@ def test_nothing_is_read_back_between_an_admissions_first_chunk_and_the_decode_d
 
 
 @pytest.mark.parametrize("temperature", [0.0, 1.0], ids=["greedy", "sampled"])
-def test_an_admission_compiles_no_program_beyond_the_engines_own(temperature):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_an_admission_compiles_no_program_beyond_the_engines_own(family, temperature):
     """Five requests through three slots, admissions at several boundaries:
     the process compiles (or takes from the compile cache) the engine's
     three programs and nothing else: no eager scatter sets a row's state,
-    no sampler of its own takes the first token."""
+    no sampler of its own takes the first token, and a model's ``finish``
+    (or its absence) adds no program."""
     from pytorch_operator_tpu.runtime.backend import compile_counts
 
-    model, params = _family_model("llama")
+    model, params = _family_model(family)
     sampling = {"temperature": temperature, "top_k": 8, "seed": 1} if temperature else {}
     eng = ServingEngine(model.cfg, params, slots=3, chunk=8, block=4, **sampling)
     before = sum(compile_counts().values())
@@ -386,6 +395,74 @@ def test_an_admission_compiles_no_program_beyond_the_engines_own(temperature):
     assert sum(compile_counts().values()) - before == 3
     assert [f._cache_size() for f in (eng._prefill_chunk, eng._prefill_chunk_head, eng._decode_block)] == [1, 1, 1]
     assert not hasattr(eng, "_first_token") and eng.stats()["admit_rounds"] > 1
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_head_program_takes_the_cache_only_where_the_model_finishes_an_admission_itself(family):
+    """``prefill_chunk_head`` is handed the cache (read, not donated), the
+    slot and the position for the model's ``finish``. The three families
+    whose prefill ran every layer have none: their first token is the head's
+    product on the hidden state, as before, and the compiled program has no
+    cache among its inputs (an unused argument is pruned). The
+    decoder-hybrid-decoder's reads its slab and its cross-decoder's weights."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.ops.sampling import make_sampler
+    from pytorch_operator_tpu.serving.engine import programs
+
+    model, params = _family_model(family)
+    chunk, p, slot = 8, 13, jnp.int32(2)
+    progs = programs(model, slots=3, chunk=chunk, block=4, sample=make_sampler(0.0, 0, 1.0))
+    zero = jax.tree.map(jnp.zeros_like, model.counts)
+    prompt = np.random.default_rng(5).integers(1, 250, (2 * chunk,)).astype(np.int32)
+    cache = model.init_cache(3, chunk)
+    for start in (0, chunk):
+        hidden, cache, zero = progs.prefill_chunk(params, cache, zero, slot, prompt[None, start:start + chunk],
+                                                  jnp.int32(start), jnp.int32(min(chunk, p - start)))
+    tok, pos, first, _ = progs.prefill_chunk_head(params, cache, hidden, jnp.zeros((3,), jnp.int32),
+                                                  jnp.zeros((3,), jnp.int32), slot, jnp.int32(p), jax.random.key(0))
+    h = jax.tree.map(lambda a: a[:, (p - 1) % chunk], hidden)
+    bytes_of = lambda tree: sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+    compiled = progs.prefill_chunk_head.lower(params, cache, hidden, tok, pos, slot, jnp.int32(p), jax.random.key(0)).compile()
+    inputs = compiled.memory_analysis().argument_size_in_bytes
+    if family == "phi4_flash":
+        assert model.finish is not None
+        want = model.finish(params, cache, slot, h, jnp.int32(p - 1))
+        slab = cache[f"layer_{model.cfg.full_layer}"]
+        assert inputs > bytes_of(slab) + bytes_of(params["layers"][model.cfg.full_layer:])
+    else:
+        assert model.finish is None and model.slab_reads is None
+        want = model.logits(params, h)
+        assert inputs < bytes_of(cache)  # the cache is not among the program's inputs
+    assert int(first) == int(np.argmax(np.asarray(want[0]))) == int(tok[2]) and int(pos[2]) == p
+
+
+def test_a_boundary_admits_a_bounded_stretch_of_prefill_and_always_one_prompt(parity_model, monkeypatch):
+    """Four prompts of 5 chunks into four free slots with the bound at 10
+    chunks: two a boundary, a decode dispatch of a quantum behind each
+    round (a slot is free, so no longer), the same greedy tokens as without
+    the bound; a prompt longer than the bound is still admitted, alone."""
+    from pytorch_operator_tpu.serving import engine as engine_lib
+
+    cfg, params, _, _ = parity_model
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(1, 250, (40,)).astype(np.int32) for _ in range(4)]
+
+    def served(bound):
+        monkeypatch.setattr(engine_lib, "ADMIT_CHUNKS", bound)
+        eng = ServingEngine(cfg, params, slots=4, chunk=8, block=16)
+        for i, prompt in enumerate(prompts):
+            eng.submit(_req(f"b{i}", prompt, 12))
+        got = {r.id: r.tokens for r in eng.run_until_drained()}
+        return [got[f"b{i}"] for i in range(4)], eng.stats()
+
+    whole, n = served(engine_lib.ADMIT_CHUNKS)
+    assert n["admit_rounds"] == 1 and n["prefill_chunks"] == 20
+    bounded, n = served(10)
+    assert bounded == whole and n["admit_rounds"] == n["decode_behind_admit"] == 2 and n["decode_sized_by_quantum"] >= 1
+    alone, n = served(3)
+    assert alone == whole and n["admit_rounds"] == 4 and n["admitted"] == 4
 
 
 def test_a_first_token_that_ends_its_request_costs_one_dispatch_and_leaves_the_slot_clean(parity_model):

@@ -142,7 +142,7 @@ def llama_programs(one_chip):
             hidden = jax.ShapeDtypeStruct((1, L_CHUNK, cfg.d_model), cfg.dtype, sharding=one_chip)
             key = on(jax.eval_shape(lambda: jax.random.key(0)))
             return progs.prefill_chunk_head.lower(
-                params, hidden, ints(L_SLOTS), ints(L_SLOTS), ints(), ints(), key).compile()
+                params, cache, hidden, ints(L_SLOTS), ints(L_SLOTS), ints(), ints(), key).compile()
         return progs.prefill_chunk.lower(
             stacked if name == "prefill_chunk_stacked" else params, cache, {}, ints(), ints(1, L_CHUNK), ints()).compile()
 

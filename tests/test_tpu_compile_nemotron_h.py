@@ -82,7 +82,7 @@ def compiled(one_chip):
         elif name == "prefill_chunk_head":
             key = on(jax.eval_shape(lambda: jax.random.key(0)))
             hidden = jax.ShapeDtypeStruct((1, CHUNK, cfg.d_model), cfg.dtype, sharding=one_chip)
-            out = progs.prefill_chunk_head.lower(params, hidden, ints(SLOTS), ints(SLOTS), ints(), ints(), key).compile()
+            out = progs.prefill_chunk_head.lower(params, cache, hidden, ints(SLOTS), ints(SLOTS), ints(), ints(), key).compile()
         else:
             out = progs.prefill_chunk.lower(params, cache, counts, ints(), ints(1, CHUNK), ints(), ints()).compile()
         print(f"{name} of 16 layer trees compiled for a described v5e in {time.time() - t0:.1f} s")
