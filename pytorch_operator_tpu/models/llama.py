@@ -554,8 +554,10 @@ class Attention(nn.Module):
     def _cache_attend(self, q, positions, ck, cv, ks, vs, slot=None):
         """q against the cache's filled prefix under a per-(row, token)
         position-validity mask (ops/cache_attention.py: the slab is read
-        in blocks up to the one that holds the deepest query's position,
-        found inside the program from ``positions``). Serves
+        in blocks found inside the program from ``positions``; an int8
+        cache and every chunk up to the one that holds the deepest query's
+        position, a decode step over a plain cache each row's up to that
+        row's own). Serves
         single-token decode steps (S=1, possibly at per-row depths) and
         chunked-prefill continuations (S>1, prefill_mode="cache"). An
         int8 cache's scales fold into the scores and the probabilities."""
@@ -1045,6 +1047,7 @@ def serving_model(cfg: LlamaConfig):
     seeded init is the training model's (float32, the whole tree in one
     program: ``workloads.generate.load_params`` quantises or commits it),
     with the layers a tree each, as ``arrange`` makes a checkpoint's."""
+    from ..ops.cache_attention import reads_per_row
     from .serving import ServingModel
 
     if not cfg.decode:
@@ -1089,6 +1092,7 @@ def serving_model(cfg: LlamaConfig):
         prefill=prefill,
         decode=decode,
         logits=logits,
+        decode_reads_per_row=reads_per_row(quantized=cfg.kv_quantize == "int8"),
         arrange=per_layer_params,
     )
 

@@ -25,7 +25,8 @@ The layer (pre-norm residual, RMSNorm, no biases, untied head):
 
 TPU-first shape: everything static. A full layer's cache is a
 ``[slots, Hk, max_decode_len, d]`` slab of which attention reads the filled
-prefix, in static blocks up to the deepest query (ops/cache_attention.py),
+prefix, in static blocks (ops/cache_attention.py: a decode step each row's
+up to that row's own position, a chunk up to its last),
 under a position mask; a window layer's is a ring of ``window + chunk`` positions
 addressed by ``position % ring`` that also records WHICH position each entry holds, so
 an entry is live iff its recorded position passes the same mask: a slot
@@ -99,6 +100,7 @@ class MiMoV2Config:
 
     def serving_model(self):
         """What the serving engine talks to (models/serving.py)."""
+        from ..ops.cache_attention import reads_per_row
         from .serving import ServingModel
 
         if not self.decode:
@@ -111,6 +113,7 @@ class MiMoV2Config:
             prefill=functools.partial(_prefill, self),
             decode=functools.partial(_decode, self),
             logits=logits,
+            decode_reads_per_row=reads_per_row(),
             counts=layer_list.zero_moe_counts(self),
             gauges=cache_bytes,
             derive=functools.partial(layer_list.derived_moe_stats, self),

@@ -133,6 +133,7 @@ class NemotronHConfig:
 
     def serving_model(self):
         """What the serving engine talks to (models/serving.py)."""
+        from ..ops.cache_attention import reads_per_row
         from .serving import ServingModel
 
         if not self.decode:
@@ -145,6 +146,7 @@ class NemotronHConfig:
             prefill=functools.partial(_prefill, self),
             decode=functools.partial(_decode, self),
             logits=logits,
+            decode_reads_per_row=reads_per_row(),
             counts=zero_counts(self),
             gauges=cache_bytes,
             derive=functools.partial(layer_list.derived_moe_stats, self),

@@ -158,6 +158,7 @@ class Phi4FlashConfig:
 
     def serving_model(self):
         """What the serving engine talks to (models/serving.py)."""
+        from ..ops.cache_attention import reads_per_row
         from .serving import ServingModel
 
         if not self.decode:
@@ -172,6 +173,7 @@ class Phi4FlashConfig:
             logits=logits,
             finish=functools.partial(finish, self),
             slab_reads=functools.partial(slab_reads, self),
+            decode_reads_per_row=reads_per_row(),
             counts=zero_counts(),
             gauges=functools.partial(cache_gauges, self),
         )

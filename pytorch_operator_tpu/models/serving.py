@@ -73,6 +73,10 @@ class ServingModel:
     # read needs, an integer array. None = each chunk reads up to its own
     # end.
     slab_reads: Optional[Callable] = None
+    # Whether a decode step reads each row's slabs to that row's own depth
+    # (``ops.cache_attention.reads_per_row``) or every row's to the deepest
+    # row's, for the engine's ``decode_attended_positions``.
+    decode_reads_per_row: bool = False
     # a checkpoint's parameter tree (the trainer's form, host arrays) ->
     # the same leaves as ``init_params`` arranges them, which is how the
     # forwards read them fastest (``workloads.generate.load_params`` calls

@@ -530,6 +530,16 @@ def analyze(
         if "admit_rounds" in rec
     }
 
+    # What a serving engine's decode steps read of the cache slabs beside
+    # what their rows had live (serving/engine.py, ops/cache_attention.py):
+    # near 1 where each row is read to its own depth, the deepest row's
+    # depth over the mean's where every row is read to the deepest.
+    slab_reads = {
+        rec["replica"]: [rec["decode_attended_positions"], rec["decode_live_positions"]]
+        for rec in tl.records.get("metrics", [])
+        if rec.get("decode_live_positions")
+    }
+
     return {
         "job": key,
         "generated_at": _time.time() if now is None else now,
@@ -542,6 +552,7 @@ def analyze(
         "state_resets": state_resets,
         "cross_tokens": cross_tokens,
         "admit_rounds": admit_rounds,
+        "slab_reads": slab_reads,
         "events": len(tl.events),
         "spans": len(tl.spans),
         "exemplars": exemplars,
@@ -630,6 +641,11 @@ def render_report(report: dict) -> str:
         lines.append(
             f"admits:   {replica} {admitted} admitted in {rounds} round(s), "
             f"the decode dispatch queued behind {behind} of them before a first token was read"
+        )
+    for replica, (attended, live) in sorted(report.get("slab_reads", {}).items()):
+        lines.append(
+            f"slabs:    {replica} decode_attended_positions {attended} over decode_live_positions {live} "
+            f"= {attended / live:.2f}"
         )
     alerts = report.get("alerts", [])
     findings = report.get("findings", [])

@@ -1,20 +1,33 @@
 """Attention of queries against a key/value cache, reading the cache's
 filled prefix and not the whole slab.
 
-Both served families keep a row's keys and values in a slab of
+Every served family keeps a row's keys and values in a slab of
 ``max_decode_len`` positions (models/llama.py ``Attention._cache_attend``,
-models/mimo_v2.py full layers) of which the traffic fills a part. A decode
-step or a prefill chunk needs positions ``[0, n)`` where ``n`` is one past
-the last position any of its queries stands at, so the program computes
-``n`` from ``positions`` and walks the slab in blocks (:func:`block`) up to
-the one that holds ``n``: a loop with a traced trip count and a running
-softmax, one compiled body whatever is filled. Inside the blocks read the
-mathematics is the whole slab's: the same dtypes, float32 scores, a float32
-softmax (carried as running maximum, sum and weighted values, normalised
-once at the end), every position ``col <= row`` attended, nothing
-approximated. Rows that hold no request must stand at position 0
-(serving/engine.py ``decode_block``), or their stale positions hold the
-bound up.
+the full layers of models/mimo_v2.py, models/nemotron_h.py and
+models/phi4_flash.py) of which the traffic fills a part. A query at
+position ``p`` needs positions ``[0, p]`` of its row, so the program finds
+from ``positions`` how many blocks (:func:`block`) of the slab hold them
+and reads no more, in one of two forms that share the block size and the
+rounding and nothing else (:func:`reads_per_row` says which a call is):
+
+- **a decode step over a plain slab** (one query a row, every row of the
+  slabs, keys and values in the queries' dtype) reads EACH ROW to that
+  row's own depth: a Pallas TPU kernel over a grid of (row, block)
+  (:func:`_decode_attention`) whose per-row block counts go in by scalar
+  prefetch. A row that holds no request stands at position 0 and costs
+  one block; a deep row costs its own blocks and nobody else's.
+- **every other call** reads to the DEEPEST query's position: a prefill
+  chunk (one row, ``slot`` given: its bound is the row's own anyway) and
+  an int8 slab with its per-position scales, decode step or chunk. A loop
+  with a traced trip count and a running softmax, one compiled body
+  whatever is filled. There, rows that hold no request must stand at
+  position 0 (serving/engine.py ``decode_block``), or their stale
+  positions hold the bound up.
+
+Inside the blocks read the mathematics is the whole slab's in both: the
+same dtypes, float32 scores, a float32 softmax (carried as running
+maximum, sum and weighted values, normalised once at the end), every
+position ``col <= row`` attended, nothing approximated.
 
 The engine counts what the bound saved with the same rounding
 (:func:`attended`), on the host, from the positions it already holds.
@@ -68,7 +81,11 @@ def cache_attention(q, positions, k, v, k_scale=None, v_scale=None, *, slot=None
     is cut out of that row where it lies, so the row is never an array of
     its own (a prefill chunk in the serving engine).
 
-    Only the blocks up to the deepest query's position are read."""
+    A decode step over a plain slab (:func:`reads_per_row`) reads each
+    row's blocks up to that row's own position; every other call reads
+    only the blocks up to the deepest query's."""
+    if reads_per_row(q.shape[1], k_scale is not None, slot is not None):
+        return _decode_attention(q, positions, k, v)
     B, S, K, G, dk = q.shape
     L, dv, dtype = k.shape[2], v.shape[-1], q.dtype
     T = block(L)
@@ -133,3 +150,119 @@ def cache_attention(q, positions, k, v, k_scale=None, v_scale=None, *, slot=None
     n = jnp.minimum(blocks_needed(jnp.max(positions) + 1, L), L // T)
     _, total, out = jax.lax.fori_loop(0, n, one_block, start)
     return (out / total[..., None]).astype(dtype).transpose(0, 3, 1, 2, 4)
+
+
+# ---- a decode step over a plain slab: each row to its own depth ----
+
+
+def reads_per_row(queries_a_row: int = 1, quantized: bool = False, in_slot: bool = False) -> bool:
+    """Whether :func:`cache_attention` reads each row's slab to that row's
+    own depth (the kernel below) or every row's to the deepest query's (the
+    loop above). Per row: a decode step (one query a row, every row of the
+    slabs) over a slab in the queries' dtype. The loop: an int8 slab (its
+    ``[.., L, 1]`` scales want a kernel layout of their own) and a prefill
+    chunk (one row, whose bound IS the batch's). A model tells the engine
+    which its decode step is by the same function."""
+    return queries_a_row == 1 and not quantized and not in_slot
+
+
+def _decode_attention(q, positions, k, v):
+    """:func:`cache_attention` for a decode step (``S == 1``) over plain
+    slabs, as a Pallas TPU kernel over a grid of (row, block): row ``b``'s
+    block count ``blocks_needed(positions[b] + 1, L)`` goes in by scalar
+    prefetch and the body runs for the blocks below it. The grid's second
+    bound is the deepest row's count (found on the device, like the loop's
+    trip count), and past a row's own count the index map of the key and
+    value blocks names the next row's first block, so a skipped grid step
+    fetches nothing and no row waits for its first block. The mathematics
+    inside a block is the loop's ``one_block``. On a TPU it is a Mosaic
+    kernel; where the default backend is the CPU, the same kernel under
+    the interpreter (as ops/flash_attention.py decides). A step's blocks
+    ``[K, T, dk]`` + ``[K, T, dv]`` lie in fast memory twice over: past
+    ~100 MiB of them (a slab of 32k positions with 32 key heads) the
+    chip's compiler refuses the kernel."""
+    # Imported here: the library takes about a second, and a process that
+    # serves an int8 cache or trains never needs it.
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, _, K, G, dk = q.shape
+    L, dv = k.shape[2], v.shape[-1]
+    T = block(L)
+    lowest = jnp.finfo(jnp.float32).min
+    pos = positions[:, 0].astype(jnp.int32)
+    n = jnp.minimum(blocks_needed(pos + 1, L), L // T)
+
+    def kernel(n_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, top_ref, total_ref, out_ref):
+        """One block of one row: ``one_block`` of the loop, on ``q [K, G,
+        dk]`` against ``k [K, T, dk]`` / ``v [K, T, dv]``, the running
+        softmax in float32 scratch over the row's grid steps."""
+        b, i = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(i == 0)
+        def _():
+            top_ref[...] = jnp.full_like(top_ref, lowest)
+            total_ref[...] = jnp.zeros_like(total_ref)
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        @pl.when(i < n_ref[b])
+        def _():
+            kb, vb = k_ref[0], v_ref[0]
+            scores = jax.lax.dot_general(
+                q_ref[0], kb, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+            ) / jnp.sqrt(jnp.float32(dk))  # [K, G, T]
+            col = i * T + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
+            scores = jnp.where(col <= pos_ref[b], scores, lowest)
+            top = top_ref[...]
+            new_top = jnp.maximum(top, scores.max(-1, keepdims=True))
+            weights = jnp.exp(scores - new_top)
+            keep = jnp.exp(top - new_top)
+            total_ref[...] = total_ref[...] * keep + weights.sum(-1, keepdims=True)
+            out_ref[...] = out_ref[...] * keep + jax.lax.dot_general(
+                weights.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )
+            top_ref[...] = new_top
+
+        @pl.when(i == n_ref[b] - 1)
+        def _():
+            o_ref[0] = (out_ref[...] / total_ref[...]).astype(o_ref.dtype)
+
+    at_row = lambda b, i, n, pos: (b, 0, 0, 0)  # noqa: E731
+
+    def at_block(b, i, n, pos):
+        # Past the row's last needed block the NEXT row's first: its fetch
+        # is issued while this row's last block is computed, and the steps
+        # that follow find it where it is.
+        more = i < n[b]
+        return (jnp.where(more, b, jnp.minimum(b + 1, B - 1)), 0, jnp.where(more, i, 0), 0)
+
+    # A grid step's key and value blocks, fetched while the step before
+    # computes: beyond the 16 MiB a kernel may use of fast memory unasked
+    # (many key heads, or a long slab's eighth) the kernel asks for more.
+    blocks = 2 * K * T * (dk * k.dtype.itemsize + dv * v.dtype.itemsize)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, jnp.max(n)),
+            in_specs=[
+                pl.BlockSpec((1, K, G, dk), at_row),
+                pl.BlockSpec((1, K, T, dk), at_block),
+                pl.BlockSpec((1, K, T, dv), at_block),
+            ],
+            out_specs=pl.BlockSpec((1, K, G, dv), at_row),
+            scratch_shapes=[
+                pltpu.VMEM((K, G, 1), jnp.float32),
+                pltpu.VMEM((K, G, 1), jnp.float32),
+                pltpu.VMEM((K, G, dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=max(16 << 20, blocks + (4 << 20))
+        ),
+        interpret=jax.default_backend() == "cpu",
+        name="cache_attention_decode",
+    )(n, pos, q[:, 0], k, v)
+    return out[:, None]

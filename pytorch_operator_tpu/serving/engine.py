@@ -15,13 +15,16 @@ TPU-first shape (every program's shapes static):
   steps (a traced count, so every length is the same compiled program)
   over the full [slots] batch through the model's per-row decode
   forward — every row at its own position. Attention reads each
-  layer's cache in blocks up to the one that holds the deepest row's
-  position, a trip count found inside the program
-  (ops/cache_attention.py), so a step costs what the batch holds and not
-  what the slabs reserve. Empty rows are parked at position 0 (they
+  layer's cache in blocks, found inside the program from the positions
+  (ops/cache_attention.py): each row's up to the block that holds that
+  row's own position where the slabs are plain (a kernel with per-row
+  lengths; ``ServingModel.decode_reads_per_row``), every row's up to the
+  deepest row's where they are int8 (a loop with a traced trip count).
+  Either way a step costs what the batch holds and not what the slabs
+  reserve. Empty rows are parked at position 0 (they
   re-write position 0 of their own empty row, which the next admission's
-  first chunk overwrites), so a slot's last occupant never holds the
-  bound up; rows never see each other's cache. Everything the engine
+  first chunk overwrites), so a slot's last occupant reads one block and
+  never holds the loop's bound up; rows never see each other's cache. Everything the engine
   decides (admit, harvest, and the serve loop's poll and responses)
   happens between dispatches, so the engine chooses each dispatch's
   length from what it holds on the host (:func:`decode_steps`): to the
@@ -93,9 +96,13 @@ lists every name beside the metric that reads it):
   blocks (dispatches), their steps and what sized them, occupied rows,
   row-steps and accepted tokens (slot occupancy, decode yield), the cache
   positions those tokens had live beside the positions attention read for
-  them (``decode_attended_positions`` over ``decode_row_steps`` x
-  ``max_decode_len`` is the share of the slabs still read; the same for
-  ``prefill_attended_positions`` over the chunks), prefill chunks, those
+  them (``decode_attended_positions`` charges an active row the whole
+  blocks up to its own position where the model's decode step reads per
+  row, up to the deepest active row's where it does not; over
+  ``decode_row_steps`` x ``max_decode_len`` it is the share of the slabs
+  still read, over ``decode_live_positions`` what was read for each
+  position live; ``prefill_attended_positions`` is the same over the
+  chunks, each read to its own end), prefill chunks, those
   of them that ran the head, and pad tokens,
   admissions, the model's own counters (summed on the device inside the
   two programs, brought back at ``stats()``), and one clock that charges every
@@ -610,12 +617,16 @@ class ServingEngine:
             self._n["decode_behind_admit"] += 1
             self._take_first()
             t0 = time.time()  # the dispatch starts where the last head ended
-        # What each step's attention reads of every row: up to the deepest
-        # active row, which the program finds from the same positions.
+        # What each step's attention reads of an active row: the blocks up
+        # to the row's own position where the model's decode step reads per
+        # row, else up to the deepest active row's; the program finds either
+        # from the same positions.
         L = self.cfg.max_decode_len
-        deepest = max(self._slots[i].pos for i in active_rows)
-        self._n["decode_attended_positions"] += len(active_rows) * int(
-            self._attended(np.minimum(deepest + np.arange(steps), L - 1) + 1, L).sum()
+        depths = np.array([self._slots[i].pos for i in active_rows])
+        if not self.model.decode_reads_per_row:
+            depths[:] = depths.max()
+        self._n["decode_attended_positions"] += int(
+            self._attended(np.minimum(depths[:, None] + np.arange(steps), L - 1) + 1, L).sum()
         )
         self.last_steps = steps
         self._n["decode_blocks"] += 1

@@ -22,8 +22,10 @@ optimizer state never touches host memory).
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import sys
+import threading
 import time
 
 from .. import faults, obs
@@ -84,6 +86,14 @@ def run(
         quantize=quantize,
         kv_quantize=kv_quantize,
     )
+    if cfg.serving_model().decode_reads_per_row:
+        # Its decode step is a Pallas kernel (ops/cache_attention.py), whose
+        # library takes about a second of Python to import: brought in on a
+        # thread beside the backend's start and the weights, which wait on
+        # the device and the compile cache, not in front of the first dispatch.
+        threading.Thread(
+            target=importlib.import_module, args=("jax.experimental.pallas.tpu",), daemon=True
+        ).start()
     log(
         f"[serve] config={config} slots={slots} chunk={chunk} "
         f"block={block} L={max_decode_len} spool={spool_dir} "

@@ -2,7 +2,9 @@
 and is the whole-slab masked product all the same: the op alone around every
 block's edge, through both families' call sites, and through the
 engine, where a slot whose last occupant stood deep must not hold the
-bound up.
+bound up. A decode step over a plain slab is a kernel that reads each row to
+that row's own depth (here under the Pallas interpreter); an int8 slab and a
+prefill chunk keep the loop to the deepest query.
 """
 
 from __future__ import annotations
@@ -145,6 +147,104 @@ def test_nothing_past_the_bound_is_read(site, length):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+# ---- a decode step over a plain slab: the kernel with per-row lengths ----
+
+# (kv heads, queries a kv head, key size, value size) of the three layer-list
+# families' cells: Phi-4-mini-flash's pairs, MiMo-V2.5's full layers,
+# Nemotron-3-Nano's attention layers.
+FAMILY_SHAPES = {"phi4_flash": (10, 4, 128, 128), "mimo_v2": (4, 16, 192, 128), "nemotron_h": (2, 16, 128, 128)}
+SLAB = 256  # positions of the slabs below: blocks of 32
+
+
+def _ragged(case):
+    T = ca.block(SLAB)
+    return {
+        "every_edge": [0, 1, T - 1, T, T + 1, SLAB - 1],
+        "one_deep_among_shallow": [3, 0, 5 * T + 7, 1, T - 1, 2],
+        "every_row_empty": [0] * 6,
+    }[case]
+
+
+def _plain_float32(q, positions, k, v):
+    """The whole slab, float32 throughout, one softmax: no blocks, no running sums."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    scores = jnp.einsum("bskgd,bktd->bkgst", q, k, precision="highest") / np.sqrt(q.shape[-1])
+    visible = jnp.arange(k.shape[2])[None, None, :] <= positions[:, :, None]
+    probs = jax.nn.softmax(jnp.where(visible[:, None, None], scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bkgst,bktd->bskgd", probs, v, precision="highest")
+
+
+def _loop(*args):
+    """The same call through the loop to the deepest query."""
+    import jax
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ca, "reads_per_row", lambda *a, **kw: False)
+        return jax.jit(lambda *a: ca.cache_attention(*a))(*args)  # a function of its own: traced anew
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", ["every_edge", "one_deep_among_shallow", "every_row_empty"])
+@pytest.mark.parametrize("family", FAMILY_SHAPES)
+def test_the_decode_kernel_equals_the_loop_and_the_plain_softmax_at_ragged_depths(family, case, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    K, G, dk, dv = FAMILY_SHAPES[family]
+    depths = _ragged(case)
+    rng = np.random.default_rng(len(family) + len(case))
+    q = jnp.asarray(rng.normal(size=(len(depths), 1, K, G, dk)), dtype)
+    k = jnp.asarray(rng.normal(size=(len(depths), K, SLAB, dk)), dtype)
+    v = jnp.asarray(rng.normal(size=(len(depths), K, SLAB, dv)), dtype)
+    positions = jnp.asarray(depths, jnp.int32)[:, None]
+    assert "pallas_call" in str(jax.make_jaxpr(ca.cache_attention)(q, positions, k, v))
+    got = jax.jit(ca.cache_attention)(q, positions, k, v)
+    assert got.shape == (len(depths), 1, K, G, dv) and got.dtype == q.dtype
+    got = np.asarray(got, np.float32)
+    # The loop's mathematics block for block; the backend may order a product's sums otherwise
+    # for a row alone than for the batch (float32), which a bfloat16 result rounds once more.
+    tol = 1e-2 if dtype == "bfloat16" else 2e-6
+    np.testing.assert_allclose(got, np.asarray(_loop(q, positions, k, v), np.float32), rtol=tol, atol=tol)
+    tol = 2e-2 if dtype == "bfloat16" else 2e-5
+    np.testing.assert_allclose(got, np.asarray(_plain_float32(q, positions, k, v)), rtol=tol, atol=tol)
+
+
+def test_a_decode_step_reads_nothing_past_each_rows_own_block():
+    """NaN past every row's own last needed block, inside what the deepest
+    row needs: the loop to the deepest query reads it (0 x NaN), the kernel
+    does not."""
+    import jax.numpy as jnp
+
+    depths = np.array([0, BLOCK - 1, BLOCK, 3 * BLOCK + 2, L - 1])
+    q, pos, k, v = _inputs("plain_f32", depths[:, None], seed=3)
+    own = ca.attended(depths + 1, L)[:, None, None, None]
+    poison = lambda a: jnp.where(jnp.arange(L)[None, None, :, None] >= own, jnp.nan, a)  # noqa: E731
+    op, whole = _programs()
+    got = np.asarray(op(q, pos, poison(k), poison(v)))
+    assert np.isfinite(got).all() and not np.isfinite(np.asarray(_loop(q, pos, poison(k), poison(v)))[:-1]).any()
+    np.testing.assert_allclose(got, np.asarray(whole(q, pos, k, v)), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("call", ["int8_decode", "int8_chunk", "plain_chunk_in_slot", "plain_chunk_no_slot", "plain_decode_in_slot"])
+def test_an_int8_slab_and_every_slot_call_still_trace_the_loop(call):
+    """Only a decode step over a plain slab is the kernel: the int8 family's
+    programs and every prefill chunk trace as they did."""
+    import jax
+    import jax.numpy as jnp
+
+    kind = "int8_scales" if call.startswith("int8") else "plain_bf16"
+    positions = _decode_positions(20) if call.endswith("decode") else _chunk_positions(20)
+    q, pos, *cache = _inputs(kind, positions if "slot" not in call else positions[:1, :1 if "decode" in call else None])
+    slot = jnp.int32(1) if "slot" in call else None
+    text = str(jax.make_jaxpr(lambda *a: ca.cache_attention(*a, slot=slot))(q, pos, *cache))
+    assert "pallas_call" not in text and "while" in text
+    assert ca.reads_per_row() and not ca.reads_per_row(quantized=True)
+    assert not ca.reads_per_row(in_slot=True) and not ca.reads_per_row(queries_a_row=4)
+
+
 def test_a_row_at_the_parking_position_reads_the_whole_slab():
     positions = _decode_positions(7)
     positions[2, 0] = L - 1  # a row clamped there lifts the bound to L
@@ -265,6 +365,25 @@ def test_decode_attended_positions_follow_the_rows_positions_step_by_step():
     want = sum(int(ca.attended(p + 1, L)) for p in range(6, 6 + 29))
     assert s["decode_row_steps"] == 29 and s["decode_attended_positions"] == want
     assert s["prefill_attended_positions"] == ca.attended(8, L)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_decode_attended_positions_round_as_the_program_reads(family):
+    """A deep row and a shallow one decode side by side: a family whose
+    decode step is the kernel is charged each row's own blocks, the int8
+    family the deepest row's for both."""
+    cfg, params = FAMILIES[family]()
+    eng = _engine(cfg, params, slots=2, chunk=8, block=64)
+    per_row = eng.model.decode_reads_per_row
+    assert per_row == (family != "llama_int8_cache")
+    _submit(eng, [(_prompt(41, 8), 9), (_prompt(3, 9), 9)])
+    eng.run_until_drained()
+    s = eng.stats()
+    # The first token comes from the prefill; 8 decode steps write positions 41 .. 48 and 3 .. 10.
+    deep = sum(int(ca.attended(p + 1, L)) for p in range(41, 49))
+    shallow = sum(int(ca.attended(p + 1, L)) for p in range(3, 11))
+    assert s["decode_row_steps"] == 16 and s["decode_live_positions"] < deep + shallow < 2 * deep
+    assert s["decode_attended_positions"] == (deep + shallow if per_row else 2 * deep)
 
 
 def test_the_final_metrics_record_carries_the_two_counters(tmp_path, monkeypatch):

@@ -570,3 +570,7 @@ def test_tpujob_run_of_a_serve_job_with_the_preset_answers_requests(tmp_path):
     assert final["decode_behind_admit"] == final["admit_rounds"] >= 2 and "host_overlapped_s" in final
     assert re.search(rf"admits: +\S+ 3 admitted in {final['admit_rounds']} round\(s\), the decode dispatch queued "
                      rf"behind {final['decode_behind_admit']} of them", why.stdout), why.stdout[-2000:]
+    # ... and what the decode steps read of the slabs over what was live (PR 38): each row to its own depth.
+    ratio = final["decode_attended_positions"] / final["decode_live_positions"]
+    assert re.search(rf"slabs: +\S+ decode_attended_positions {final['decode_attended_positions']} over "
+                     rf"decode_live_positions {final['decode_live_positions']} = {ratio:.2f}", why.stdout), why.stdout[-2000:]

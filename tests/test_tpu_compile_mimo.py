@@ -34,7 +34,13 @@ def one_chip():
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    # The decode kernel asks the default backend whether it runs under the interpreter (ops/cache_attention.py),
+    # and that is the CPU here: for a described chip the test answers for it.
+    import jax
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        yield SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -72,10 +78,12 @@ def test_a_decode_step_over_64_slots_compiles_and_fits(described, one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 10.7e9  # 9.05 GB of weights + 1.76 GB of cache
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
-    # Each of the two full layers' attention is one loop over the slab's blocks, its trip count traced
-    # (the per-row cache writes are loops of the compiler's own: scatters).
-    loops = [l for l in re.findall(r" while\(.*", compiled.as_text()) if 'attn_full/while"' in l]
-    assert len(loops) == 2 and not any("known_trip_count" in l for l in loops), loops
+    # Each of the two full layers' attention is the decode kernel with per-row lengths, lowered through Mosaic
+    # (dk 192, 4 key heads), and no loop over the slab's blocks (the per-row cache writes are loops of the
+    # compiler's own: scatters).
+    text = compiled.as_text()
+    assert len(decode_kernels(text, "attn_full")) == 2
+    assert not [l for l in re.findall(r" while\(.*", text) if 'attn_full/while"' in l]
 
 
 def test_a_prefill_chunk_into_one_slots_row_compiles_and_fits(described, one_chip):
@@ -148,6 +156,35 @@ def llama_programs(one_chip):
 
     yield compiled
     jax.config.update("jax_enable_compilation_cache", True)
+
+
+def decode_kernels(text, *scopes):
+    """The Mosaic kernels of ``ops.cache_attention`` (a decode step over a plain slab, each row to its own depth)
+    in a compiled program's text, under any of ``scopes``: the instructions' lines."""
+    return [l for l in text.splitlines() if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l
+            and "cache_attention_decode" in l and any(f"/{scope}/" in l for scope in scopes)]
+
+
+@pytest.mark.parametrize("rows, heads, group, dk, dv, length, dtype", [
+    (8, 32, 1, 128, 128, 4096, "bfloat16"),  # 32 key heads: a step's blocks pass the 16 MiB a kernel gets unasked
+    (8, 8, 4, 128, 128, 32768, "bfloat16"),  # a long slab's eighth: 4,096 positions a block
+    (3, 2, 2, 16, 16, 64, "float32"),  # the tiny shapes the CPU tests run under the interpreter
+    (8, 8, 4, 64, 64, 2048, "bfloat16"),  # a head size of half the lanes
+], ids=["many_heads", "long_slab", "tiny", "head_size_64"])
+def test_the_decode_kernel_lowers_through_mosaic_at_other_shapes(described, one_chip, rows, heads, group, dk, dv, length, dtype):
+    """The loop took any shape; what Mosaic's tiling or the kernel's fast memory would refuse of the kernel fails
+    here and not on the chip (the interpreter accepts any block). (``described`` keeps the compile cache off.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.ops.cache_attention import cache_attention
+
+    on = lambda *shape, dtype=dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)  # noqa: E731
+    # A function of its own: traced anew, whatever a CPU test of this process traced the op as.
+    text = jax.jit(lambda *a: cache_attention(*a)).lower(
+        on(rows, 1, heads, group, dk), on(rows, 1, dtype="int32"), on(rows, heads, length, dk), on(rows, heads, length, dv),
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and " while(" not in text
 
 
 def _top_level(text):
@@ -245,6 +282,17 @@ def test_a_llama_program_fits_and_copies_no_int8_weight_of_a_chunk(llama_program
     copies = sum(n for op, result, _ in _top_level(compiled.as_text()) if op in ("copy", "fusion")
                  for dtype, n, _ in _arrays(result) if dtype == "s8" and n >= WEIGHT and "4096" not in result)
     assert copies <= (0 if form != "decode_block" else 24 * 4 * WEIGHT), copies
+
+
+@pytest.mark.parametrize("form", ["decode_block", "prefill_chunk"])
+def test_the_int8_familys_programs_hold_no_kernel_and_keep_the_loop(llama_programs, form):
+    """Where PR 37's check fell (the first cell's traced run): an int8 slab with ``[slots, 8, 4096, 1]`` scales
+    and every prefill chunk walk their blocks in the loop to the deepest query, one a layer inside the scan over
+    layers or 24 of them, its trip count traced; nothing of theirs goes through Mosaic."""
+    text = llama_programs(form).as_text()
+    assert "tpu_custom_call" not in text and "cache_attention_decode" not in text
+    loops = [l for l in text.splitlines() if " while(" in l and re.search(r'attn\._cache_attend/while"', l)]
+    assert len(loops) == 24 and not any("known_trip_count" in l for l in loops), len(loops)
 
 
 def test_a_llama_decode_step_keeps_every_dequantisation_inside_its_product(llama_programs):

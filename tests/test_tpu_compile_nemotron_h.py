@@ -25,7 +25,7 @@ import time
 import pytest
 
 import tests.jaxenv  # noqa: F401
-from tests.test_tpu_compile_mimo import WRITES_NOTHING, _arrays, _top_level, donated_into_outputs
+from tests.test_tpu_compile_mimo import WRITES_NOTHING, _arrays, _top_level, decode_kernels, donated_into_outputs
 
 HBM = 16 * 1024**3
 SLOTS, CHUNK, BLOCK, LEN = 128, 128, 64, 4096
@@ -45,7 +45,13 @@ def one_chip():
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    # The decode kernel asks the default backend whether it runs under the interpreter (ops/cache_attention.py),
+    # and that is the CPU here: for a described chip the test answers for it.
+    import jax
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        yield SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -114,6 +120,17 @@ def test_a_decode_step_updates_each_layers_state_in_one_fusion(compiled):
     writers = _state_writers(compiled("decode_block").as_text())
     assert len(writers) == MAMBA_LAYERS and {op for op, _ in writers} == {"fusion"}, writers
     assert all("ssm/ssm_scan" in name for _, name in writers), writers
+
+
+def test_a_decode_step_walks_each_attention_layers_slab_in_the_kernel_and_a_chunk_in_the_loop(compiled):
+    """Two attention layers of 2 key heads: a decode step's are the decode kernel with per-row lengths, lowered
+    through Mosaic at [128, 2, 4096, 128] under ``attn_full``; a chunk (one row) keeps the loop."""
+    text = compiled("decode_block").as_text()
+    assert len(decode_kernels(text, "attn_full")) == 2
+    assert not [l for l in text.splitlines() if " while(" in l and 'attn_full/while"' in l]
+    chunk = compiled("prefill_chunk").as_text()
+    assert "tpu_custom_call" not in chunk
+    assert len([l for l in chunk.splitlines() if " while(" in l and 'attn_full/while"' in l]) == 2
 
 
 def test_a_prefill_chunk_writes_its_rows_state_back_in_place(compiled):
