@@ -31,8 +31,8 @@ TPU-first shape (every program's shapes static):
   step at which the next slot frees when all are taken, a quantum
   (:data:`QUANTUM`) while a slot is free for an arrival, never past the
   last row's budget, never more than ``block``, the most steps one
-  dispatch may run. The counters below say how often each rule sized a
-  dispatch (``BENCHMARK.json``'s chat and longprompt cells judge it).
+  dispatch may run. Each dispatch's span says which rule sized it
+  (``BENCHMARK.json``'s chat and longprompt cells judge it).
 - ONE prefill program, ``prefill_chunk``: fixed-size chunks through the
   model's chunked-prefill forward into one slot's row of the donated
   cache, in place (the model is told the slot; nothing row-sized is
@@ -87,13 +87,20 @@ lists every name beside the metric that reads it):
 - spans through ``obs.span`` (file records under ``TPUJOB_TRACE_DIR``,
   ``TraceAnnotation`` s on the device trace's clock while a
   ``jax.profiler`` session records): ``engine.step`` around
-  ``engine.admit`` (with ``engine.prefill_dispatch`` per chunk and
-  ``engine.first_token``, the fence on the logits),
-  ``engine.decode_dispatch``, ``engine.decode_fence``, ``engine.accept``
+  ``engine.admit`` (with ``engine.prefill_dispatch`` per chunk),
+  ``engine.decode_dispatch``, ``engine.first_token`` (the fence on the
+  boundary's first tokens), ``engine.decode_fence``, ``engine.accept``
   and ``engine.harvest``; the per-request hop spans ``slot_wait`` and
-  ``decode`` from the engine's own timestamps;
+  ``decode`` from the engine's own timestamps. The dispatch is the unit
+  of the trace: a dispatch's span carries what the dispatch was (a
+  chunk's slot, prompt tokens and whether the head ran behind it; a
+  decode dispatch's rows, steps and the rule that sized it; on its fence
+  also the positions live and attended), each number known before the
+  span opens, so a reader has the counters' increments of exactly the
+  traced stretch, on the device's clock, and can pair every run of
+  ``decode_block`` on the device with the dispatch that queued it;
 - counters (integers and ``perf_counter`` sums, O(1) per iteration):
-  blocks (dispatches), their steps and what sized them, occupied rows,
+  blocks (dispatches) and their steps, occupied rows,
   row-steps and accepted tokens (slot occupancy, decode yield), the cache
   positions those tokens had live beside the positions attention read for
   them (``decode_attended_positions`` charges an active row the whole
@@ -109,7 +116,11 @@ lists every name beside the metric that reads it):
   second of the serving thread to a segment
   (:data:`GAP_SEGMENTS` while the device waits for the host,
   :data:`FENCE_SEGMENTS` while the host waits for the device,
-  ``dispatch``, ``overlapped``, ``idle``).
+  ``dispatch``, ``overlapped``, ``idle``);
+- the record as it stood at the newest arrival (``submit`` copies the
+  counters and the clock's sums: two small dicts a request), which
+  ``stats()`` gives as ``fed_*`` beside the whole: the engine under
+  arrivals apart from the engine emptying.
 """
 
 from __future__ import annotations
@@ -147,13 +158,12 @@ def host_key(segment: str) -> str:
     return f"host_gap_{segment}_s" if segment in GAP_SEGMENTS else f"host_{segment}_s"
 
 
-# What sized a decode dispatch (:func:`decode_steps`), each a counter
-# ``decode_sized_by_<reason>`` in ``ServingEngine.stats()``.
+# What sized a decode dispatch (:func:`decode_steps`): ``sized_by`` on the
+# dispatch's span.
 SIZED_BY = ("budget", "quantum", "ceiling")
 _COUNTERS = (
     "decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
     "decode_live_positions", "decode_attended_positions", "prefill_attended_positions",
-    *(f"decode_sized_by_{reason}" for reason in SIZED_BY),
     "prefill_chunks", "prefill_head_chunks", "prefill_tokens", "prefill_pad_tokens",
     "admit_rounds", "decode_behind_admit", "admitted",
 )
@@ -420,10 +430,16 @@ class ServingEngine:
         # Latency/throughput accounting.
         self.completed: list[RequestResult] = []
         self._tpot_samples: list[float] = []
+        self._clear_record()
+
+    def _clear_record(self) -> None:
         self._decode_wall = 0.0
         self._n = dict.fromkeys(_COUNTERS, 0)
         self._host_s = dict.fromkeys(SEGMENTS, 0.0)
-        self._mark = time.perf_counter()
+        self._mark = self._reset_at = time.perf_counter()
+        # The record as it stood at the newest arrival (``submit``): the
+        # time, the counters, the clock's sums and the decode wall.
+        self._fed = (self._reset_at, dict(self._n), dict(self._host_s), 0.0)
 
     # ---- the serving thread's clock ----
 
@@ -472,6 +488,7 @@ class ServingEngine:
             self.host_lap("idle")  # work arrives: the idle stretch ends here
         request.claim_time = time.time()
         self._queue.append(request)
+        self._fed = (time.perf_counter(), dict(self._n), dict(self._host_s), self._decode_wall)
 
     def _free_slots(self) -> list[int]:
         return [i for i, s in enumerate(self._slots) if s is None]
@@ -502,20 +519,25 @@ class ServingEngine:
         # (a family whose state is keys and values never gets ``n_real``).
         slot_ = np.int32(slot)
         for start in range(0, padded, self.chunk):
-            with obs.span("engine.prefill_dispatch", SPAN_CAT, start=start):
+            n_real, head = min(self.chunk, p - start), start + self.chunk == padded
+            with obs.span(
+                "engine.prefill_dispatch", SPAN_CAT, start=start, slot=slot, n_real=n_real, head=head
+            ):
                 if start == 0:
                     self._lap_to_dispatch()
                 hidden, self._cache, self._counts["prefill"] = self._prefill_chunk(
                     self._params, self._cache, self._counts["prefill"], slot_,
-                    buf[None, start : start + self.chunk], np.int32(start),
-                    np.int32(min(self.chunk, p - start)),
+                    buf[None, start : start + self.chunk], np.int32(start), np.int32(n_real),
                 )
-        # The last chunk's last VALID position (not the padded tail) feeds
-        # the first token, and the program that samples it sets the row's
-        # state: the head runs once a prompt.
-        self._tok, self._pos, first, self._first_key = self._prefill_chunk_head(
-            self._params, self._cache, hidden, self._tok, self._pos, slot_, np.int32(p), self._first_key
-        )
+                if head:
+                    # The last chunk's last VALID position (not the padded
+                    # tail) feeds the first token, and the program that
+                    # samples it sets the row's state: the head runs once a
+                    # prompt, queued behind that chunk.
+                    self._tok, self._pos, first, self._first_key = self._prefill_chunk_head(
+                        self._params, self._cache, hidden, self._tok, self._pos, slot_,
+                        np.int32(p), self._first_key,
+                    )
         self._n["prefill_head_chunks"] += 1
         self.host_lap("dispatch")
         # decode_block writes the first token's k/v at position p before
@@ -534,13 +556,15 @@ class ServingEngine:
     def _take_first(self) -> None:
         """Read each admission's first token, in admission order: the one
         thing the host learns late is a token that ends its request."""
-        for st, first in self._unread:
-            with obs.span("engine.first_token", SPAN_CAT):
+        if not self._unread:
+            return
+        with obs.span("engine.first_token", SPAN_CAT, n=len(self._unread)):
+            for st, first in self._unread:
                 token = int(first)  # the fence: every chunk of the prompt has run
-            self.host_lap("first_token")
-            st.first_token_time = time.time()
-            st.tokens.append(token)
-            st.done = st.remaining <= 0 or token == self.eos_token
+                st.first_token_time = time.time()
+                st.tokens.append(token)
+                st.done = st.remaining <= 0 or token == self.eos_token
+        self.host_lap("first_token")
         self._unread.clear()
 
     def _accept_token(self, st: _Slot, slot: int, token: int) -> None:
@@ -602,8 +626,9 @@ class ServingEngine:
             self.slots - len(active_rows), self.block,
         )
         t0 = time.time()
+        rows = len(active_rows)
         with obs.span(
-            "engine.decode_dispatch", SPAN_CAT, rows=len(active_rows), steps=steps
+            "engine.decode_dispatch", SPAN_CAT, rows=rows, steps=steps, sized_by=sized_by
         ):
             self._lap_to_dispatch()
             (toks, self._cache, self._counts["decode"], self._tok, self._pos,
@@ -623,19 +648,22 @@ class ServingEngine:
         # from the same positions.
         L = self.cfg.max_decode_len
         depths = np.array([self._slots[i].pos for i in active_rows])
+        first_live = int(depths.sum()) + rows  # cache positions live at the dispatch's first step
         if not self.model.decode_reads_per_row:
             depths[:] = depths.max()
-        self._n["decode_attended_positions"] += int(
+        attended = int(
             self._attended(np.minimum(depths[:, None] + np.arange(steps), L - 1) + 1, L).sum()
         )
+        self._n["decode_attended_positions"] += attended
         self.last_steps = steps
         self._n["decode_blocks"] += 1
         self._n["decode_steps"] += steps
-        self._n[f"decode_sized_by_{sized_by}"] += 1
-        self._n["slot_blocks_occupied"] += len(active_rows)
-        self._n["decode_row_steps"] += len(active_rows) * steps
+        self._n["slot_blocks_occupied"] += rows
+        self._n["decode_row_steps"] += rows * steps
         self.host_lap("overlapped")
-        with obs.span("engine.decode_fence", SPAN_CAT):
+        with obs.span(
+            "engine.decode_fence", SPAN_CAT, rows=rows, steps=steps, live=first_live, attended=attended
+        ):
             # Device fence: the dispatch is the unit.
             toks = np.asarray(toks)[:, :steps]
         self.host_lap("decode_fence")
@@ -777,10 +805,35 @@ class ServingEngine:
         self._model_n = self._jax.tree.map(np.zeros_like, self._model_n)
         self.completed.clear()
         self._tpot_samples.clear()
-        self._decode_wall = 0.0
-        self._n = dict.fromkeys(_COUNTERS, 0)
-        self._host_s = dict.fromkeys(SEGMENTS, 0.0)
-        self._mark = time.perf_counter()
+        self._clear_record()
+
+    def _derived(self, n: dict, host: dict, decode_wall: float) -> dict:
+        """What ``stats()`` computes from a record's counters, its clock's
+        sums and its decode wall: of the whole record, and of the record as
+        it stood at the newest arrival."""
+
+        def share(part, whole):
+            return round(100.0 * part / whole, 3) if whole else None
+
+        return {
+            "decode_tokens_per_sec": round(n["decode_tokens"] / decode_wall, 1) if decode_wall else None,
+            # Rows that held a request, of the rows the dispatches ran;
+            # tokens accepted, of the steps those rows ran; and the mean
+            # length of a dispatch.
+            "slot_occupancy_pct": share(n["slot_blocks_occupied"], n["decode_blocks"] * self.slots),
+            "decode_yield_pct": share(n["decode_tokens"], n["decode_row_steps"]),
+            "decode_steps_per_block": round(n["decode_steps"] / n["decode_blocks"], 3)
+            if n["decode_blocks"]
+            else None,
+            # Pad positions of each prompt's last chunk, of the positions
+            # the prefill program ran.
+            "prefill_pad_pct": share(
+                n["prefill_pad_tokens"], n["prefill_tokens"] + n["prefill_pad_tokens"]
+            ),
+            # The host gap is the time the device waited for the host with
+            # work queued.
+            "host_gap_s": sum(host[k] for k in GAP_SEGMENTS),
+        }
 
     def stats(self) -> dict:
         """Aggregate latency/throughput record (the bench block)."""
@@ -798,18 +851,16 @@ class ServingEngine:
         self._drain_counts()
         plain = lambda v: int(v) if np.ndim(v) == 0 else [int(x) for x in v]
         model_n = self._jax.tree.map(np.add, self._model_n["prefill"], self._model_n["decode"])
-
-        def share(part, whole):
-            return round(100.0 * part / whole, 3) if whole else None
+        # The record as it stood at the newest arrival, by the same
+        # expressions: while requests arrive it trails the whole by one
+        # boundary; once they stop, the whole runs on through the emptying
+        # of the slots and this one does not.
+        fed_at, fed_n, fed_host, fed_wall = self._fed
+        fed = self._derived(fed_n, fed_host, fed_wall)
 
         return {
             "requests": len(done),
             "generated_tokens": sum(len(r.tokens) for r in done),
-            "decode_tokens_per_sec": round(
-                n["decode_tokens"] / self._decode_wall, 1
-            )
-            if self._decode_wall
-            else None,
             "ttft_ms_p50": pct(ttft, 0.50),
             "ttft_ms_p99": pct(ttft, 0.99),
             "tpot_ms_p50": pct(tpot, 0.50),
@@ -818,28 +869,15 @@ class ServingEngine:
             "block": self.block,
             "chunk": self.chunk,
             **n,
-            # Rows that held a request, of the rows the dispatches ran;
-            # tokens accepted, of the steps those rows ran; and the mean
-            # length of a dispatch.
-            "slot_occupancy_pct": share(
-                n["slot_blocks_occupied"], n["decode_blocks"] * self.slots
-            ),
-            "decode_yield_pct": share(n["decode_tokens"], n["decode_row_steps"]),
-            "decode_steps_per_block": round(n["decode_steps"] / n["decode_blocks"], 3)
-            if n["decode_blocks"]
-            else None,
-            # Pad positions of each prompt's last chunk, of the positions
-            # the prefill program ran.
-            "prefill_pad_pct": share(
-                n["prefill_pad_tokens"],
-                n["prefill_tokens"] + n["prefill_pad_tokens"],
-            ),
-            # The serving thread's seconds by segment; the host gap is
-            # the time the device waited for the host with work queued.
-            # ``host_gap_<segment>_s`` are its parts, so a reader finds
-            # them by the key and keeps no list of its own.
-            "host_gap_s": sum(host[k] for k in GAP_SEGMENTS),
+            **self._derived(n, host, self._decode_wall),
+            # The serving thread's seconds by segment: the gap's parts are
+            # ``host_gap_<segment>_s``, so a reader finds them by the key
+            # and keeps no list of its own.
             **{host_key(k): v for k, v in host.items()},
+            "fed_s": fed_at - self._reset_at,
+            **{f"fed_{k}": fed_n[k] for k in ("decode_blocks", "decode_steps", "decode_tokens")},
+            **{f"fed_{k}": fed[k] for k in (
+                "slot_occupancy_pct", "decode_yield_pct", "decode_tokens_per_sec", "host_gap_s")},
             # The model's own: its gauges, its counters over both programs
             # and over the decode program alone, and what it derives.
             **self._gauges,

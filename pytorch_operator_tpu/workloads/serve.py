@@ -22,6 +22,7 @@ optimizer state never touches host memory).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import importlib
 import json
 import sys
@@ -65,7 +66,10 @@ def run(
 
     The loop's phases are spans (``serve.poll``, ``serve.submit``,
     ``serve.respond``, ``serve.idle`` around the engine's own) and laps
-    of the engine's one clock (``ServingEngine.host_lap``); with
+    of the engine's one clock (``ServingEngine.host_lap``). While the
+    engine is busy, everything the loop does between two of its steps
+    lies in one span, ``serve.boundary``: the loop's side of the stretch
+    in which the device waits for the host, as one name; with
     ``profile_dir`` the whole loop runs under a ``jax.profiler`` trace,
     where those spans land beside the device's operations."""
     import jax
@@ -124,6 +128,9 @@ def run(
     rejected = 0
     last_activity = time.time()
     last_report = 0.0
+    # The open ``serve.boundary``, if any: from one ``engine.step``'s
+    # return to just before the next, across the loop's iterations.
+    boundary = contextlib.ExitStack()
 
     def to_request(rec: dict) -> Request:
         if rec.get("prompt") is not None:
@@ -195,9 +202,14 @@ def run(
 
     def step_and_respond() -> None:
         nonlocal rejected
+        boundary.close()
+        fault = None
         try:
             results = engine.step()
         except faults.InjectedFault as e:
+            fault, results = e, []
+        boundary.enter_context(obs.span("serve.boundary", SPAN_CAT))
+        if fault is not None:
             # Failure-path hardening: a faulted iteration must not
             # strand its in-flight requests (a client would block
             # its full timeout on a response nothing will write).
@@ -206,14 +218,13 @@ def run(
             # untouched, the engine keeps serving.
             aborted = engine.abort_in_flight()
             for rid in aborted:
-                spool.respond(rid, {"id": rid, "error": f"engine fault: {e}"})
+                spool.respond(rid, {"id": rid, "error": f"engine fault: {fault}"})
             rejected += len(aborted)
             log(
-                f"[serve] engine step fault ({e}); aborted "
+                f"[serve] engine step fault ({fault}); aborted "
                 f"{len(aborted)} in-flight request(s) with error "
                 "responses"
             )
-            results = []
         for res in results:
             finish(res)
         engine.host_lap("respond")
@@ -262,7 +273,7 @@ def run(
         )
         engine.host_lap("report")
 
-    with maybe_profile(profile_dir, log):
+    with maybe_profile(profile_dir, log), boundary:
         while True:
             # Admission feed: claim enough to keep the slots fed one
             # iteration ahead (ring tier first, then the file spool).
@@ -277,6 +288,7 @@ def run(
             if engine.busy:
                 step_and_respond()
             else:
+                boundary.close()
                 with obs.span("serve.idle", SPAN_CAT):
                     time.sleep(poll_interval)
                 engine.host_lap("idle")

@@ -22,11 +22,12 @@ import tests.jaxenv  # noqa: F401
 from pytorch_operator_tpu.models import llama as llama_lib
 from pytorch_operator_tpu.obs import trace as obs_trace
 from pytorch_operator_tpu.serving import Request, ServingEngine
+from pytorch_operator_tpu.serving import engine as engine_lib
 from pytorch_operator_tpu.serving.engine import FENCE_SEGMENTS, GAP_SEGMENTS, SEGMENTS, SIZED_BY, host_key
 
 SHAPES = [(5, 7), (13, 9), (8, 1), (21, 5), (3, 12)]  # (prompt, new tokens); one finishes inside prefill
 COUNTERS = ("decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
-            "decode_sized_by_budget", "decode_sized_by_quantum", "decode_sized_by_ceiling", "prefill_chunks",
+            "prefill_chunks",
             "prefill_tokens", "prefill_pad_tokens", "admit_rounds", "decode_behind_admit", "admitted")
 
 
@@ -59,7 +60,9 @@ def _counts(stats):
     return {k: stats[k] for k in COUNTERS}
 
 
-def test_counters_repeat_exactly_and_say_what_they_count(model):
+def test_counters_repeat_exactly_and_say_what_they_count(model, monkeypatch):
+    rule, sized = engine_lib.decode_steps, []  # what the rule returned for each dispatch: (steps, reason)
+    monkeypatch.setattr(engine_lib, "decode_steps", lambda *a: sized.append(rule(*a)) or sized[-1])
     runs = []
     for _ in range(2):
         eng = _engine(model)
@@ -86,8 +89,9 @@ def test_counters_repeat_exactly_and_say_what_they_count(model):
     # Row-steps are the sum over dispatches of rows x the steps that dispatch ran (not rows x block).
     assert n["decode_row_steps"] == row_steps <= 4 * n["slot_blocks_occupied"]
     assert n["decode_blocks"] <= n["decode_steps"] <= 4 * n["decode_blocks"]
-    assert sum(n[f"decode_sized_by_{reason}"] for reason in SIZED_BY) == n["decode_blocks"]
-    assert n["decode_sized_by_ceiling"] > 0 and n["decode_sized_by_budget"] > 0  # block 4 cuts; so do last tokens
+    assert len(sized) == 2 * n["decode_blocks"] and sized[: n["decode_blocks"]] == sized[n["decode_blocks"]:]
+    assert sum(steps for steps, _ in sized) == 2 * n["decode_steps"]
+    assert {"ceiling", "budget"} <= {reason for _, reason in sized} <= set(SIZED_BY)  # block 4 cuts; so do last tokens
     assert n["decode_blocks"] <= n["slot_blocks_occupied"] <= 3 * n["decode_blocks"]
     assert 1 <= n["admit_rounds"] <= n["admitted"]
     # Every round here admits a row that decodes, so each one's decode dispatch was queued behind it, unread.
@@ -186,17 +190,19 @@ def test_engine_spans_nest_under_the_step_and_requests_keep_their_hops(model, tr
         if e["name"] == "engine.prefill_dispatch":
             assert by_id[e["parent"]]["name"] == "engine.admit"
     # A step's order (PR 35): every admission dispatched, then the decode dispatch, and only then the fences:
-    # one on each admission's first token, in admission order, before the one on the decode tokens.
+    # on the admissions' first tokens, in admission order, before the one on the decode tokens.
     firsts = 0
     for step in (e for e in spans if e["name"] == "engine.step"):
         inside = sorted((e for e in spans if e.get("parent") == step["id"]), key=lambda e: e["ts"])
         order = [e["name"] for e in inside if e["name"] != "engine.harvest"]
         admits = order.count("engine.admit")
-        assert order == ["engine.admit"] * admits + ["engine.decode_dispatch"] + ["engine.first_token"] * admits + [
+        assert order == ["engine.admit"] * admits + ["engine.decode_dispatch"] + ["engine.first_token"] * bool(admits) + [
             "engine.decode_fence", "engine.accept"], order
         ends = [e["ts"] + e["dur"] for e in inside if e["name"] in ("engine.admit", "engine.decode_dispatch")]
         assert all(e["ts"] >= max(ends) for e in inside if e["name"] == "engine.first_token")
-        firsts += admits
+        # ONE fence span a boundary, which says how many first tokens it took.
+        firsts += sum(e["args"]["n"] for e in inside if e["name"] == "engine.first_token")
+        assert sum(e["args"]["n"] for e in inside if e["name"] == "engine.first_token") == admits
     assert firsts == len(SHAPES)
     admits = [e for e in spans if e["name"] == "engine.admit"]
     assert sorted(e["args"]["rid"] for e in admits) == [f"r{i}" for i in range(len(SHAPES))]

@@ -19,11 +19,30 @@ from pytorch_operator_tpu.workloads import llama_train
 
 @pytest.fixture(scope="module")
 def trace_dir(tmp_path_factory):
+    """A profiled run of the tiny trainer over the eight virtual devices,
+    compiled HERE and not taken from the persistent compile cache: an
+    XLA:CPU program whose devices meet in in-process collectives, once it
+    comes back from the cache, now and then runs two of its collectives at
+    once on the eight threads there are, seven devices wait in one and the
+    eighth in the other, and after 40 s the runtime aborts the process
+    (``rendezvous.cc``: "Termination timeout"; about one run in four here,
+    none in twelve compiled fresh). Under ``-n 6`` this module's tests go to
+    several workers, each runs this fixture, and every worker after the
+    first found the first one's program in the cache (PR 39)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
     d = tmp_path_factory.mktemp("prof")
-    llama_train.run(
-        config="tiny", batch_size=4, seq_len=32, steps=4, warmup=1,
-        profile_dir=str(d), log=lambda *_: None,
-    )
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        llama_train.run(
+            config="tiny", batch_size=4, seq_len=32, steps=4, warmup=1,
+            profile_dir=str(d), log=lambda *_: None,
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
     return d
 
 

@@ -17,6 +17,7 @@ import pytest
 
 import tests.jaxenv  # noqa: F401
 from pytorch_operator_tpu.models import llama as llama_lib
+from pytorch_operator_tpu.obs import trace as obs_trace
 from pytorch_operator_tpu.serving import Request, ServingEngine, Spool
 from pytorch_operator_tpu.serving.engine import QUANTUM, SIZED_BY, decode_steps
 
@@ -96,6 +97,24 @@ def test_decode_steps_rule(remaining, free_slots, block, want):
     assert 1 <= steps <= min(block, max(remaining))  # no step that no row can use
 
 
+@pytest.fixture
+def sized_by(tmp_path, monkeypatch):
+    """What sized each decode dispatch so far, in order, as the dispatches'
+    own spans say it (file records under ``TPUJOB_TRACE_DIR``)."""
+    monkeypatch.setenv(obs_trace.ENV_VAR, str(tmp_path / "trace"))
+    obs_trace.reset_tracer()
+
+    def read() -> list:
+        rec = obs_trace.tracer()
+        rec.flush()
+        return [e["args"]["sized_by"] for e in obs_trace.load_span_file(rec.path)
+                if e["name"] == "engine.decode_dispatch"]
+
+    yield read
+    monkeypatch.delenv(obs_trace.ENV_VAR)
+    obs_trace.reset_tracer()
+
+
 PARITY_SHAPES = [(5, 20), (13, 30), (8, 3), (9, 18)]  # (prompt, new tokens): budgets on both sides of a quantum
 
 
@@ -110,7 +129,7 @@ def parity_model():
 
 @pytest.mark.parametrize("block", [1, 4, 64])
 @pytest.mark.parametrize("slots, occupancy", [(5, "free"), (4, "full"), (2, "queued")])
-def test_greedy_tokens_do_not_depend_on_where_dispatches_are_cut(parity_model, block, slots, occupancy):
+def test_greedy_tokens_do_not_depend_on_where_dispatches_are_cut(parity_model, block, slots, occupancy, sized_by):
     """The same four requests with a slot always free, with every slot
     taken, and with a queue waiting for slots, under ceilings of 1, 4 and 64
     steps: token for token ``make_generate``'s single-stream rollout."""
@@ -128,22 +147,23 @@ def test_greedy_tokens_do_not_depend_on_where_dispatches_are_cut(parity_model, b
     n = eng.stats()
     assert all(1 <= steps <= block for steps, _ in sizes)
     assert n["decode_steps"] <= block * n["decode_blocks"]
-    assert sum(n[f"decode_sized_by_{reason}"] for reason in SIZED_BY) == n["decode_blocks"]
+    reasons = sized_by()  # one a dispatch, on its span
+    assert len(reasons) == n["decode_blocks"] and set(reasons) <= set(SIZED_BY)
     assert n["decode_tokens"] == sum(new - 1 for _, new in PARITY_SHAPES)
     if block < Q:
-        assert n["decode_sized_by_ceiling"] > 0 and n["decode_sized_by_quantum"] == 0
+        assert "ceiling" in reasons and "quantum" not in reasons
     elif occupancy == "free":
         # First dispatch: budgets 19, 29, 2, 17 and a slot free -> a quantum.
-        assert sizes[0][0] == Q and n["decode_sized_by_quantum"] > 0 and n["decode_sized_by_ceiling"] == 0
+        assert sizes[0][0] == Q and reasons[0] == "quantum" and "ceiling" not in reasons
     elif occupancy == "full":
         # All four slots taken, shortest budget 2 -> the floor of a quantum; nothing ever exceeds it here.
         assert sizes[0][0] == Q and max(steps for steps, _ in sizes) == Q
     else:
         # Two slots, two requests queued: 19 and 29 remain -> run to 19, when the next slot frees.
-        assert sizes[0] == (19, 2) and n["decode_sized_by_budget"] > 0
+        assert sizes[0] == (19, 2) and reasons[0] == "budget"
 
 
-def test_an_eos_row_is_harvested_at_the_next_boundary(parity_model):
+def test_an_eos_row_is_harvested_at_the_next_boundary(parity_model, sized_by):
     """A budget is an upper bound on a row's life where an EOS token can end
     it: the dispatch is sized by the budget, the row ends inside it, and the
     same ``step()`` hands the answer out and frees the slot."""
@@ -157,7 +177,7 @@ def test_an_eos_row_is_harvested_at_the_next_boundary(parity_model):
     (res,) = eng.step()
     assert res.id == "e0" and res.tokens == full[:cut]
     n = eng.stats()
-    assert eng.last_steps == 29 and n["decode_sized_by_budget"] == 1  # one slot, all taken: to its budget
+    assert eng.last_steps == 29 and sized_by() == ["budget"]  # one slot, all taken: to its budget
     assert n["decode_row_steps"] == 29 and n["decode_tokens"] == cut - 1
     assert eng.slots_free == 1 and eng.queued == 1  # the next boundary admits the one that waited
     (nxt,) = eng.run_until_drained()
@@ -438,7 +458,7 @@ def test_the_head_program_takes_the_cache_only_where_the_model_finishes_an_admis
     assert int(first) == int(np.argmax(np.asarray(want[0]))) == int(tok[2]) and int(pos[2]) == p
 
 
-def test_a_boundary_admits_a_bounded_stretch_of_prefill_and_always_one_prompt(parity_model, monkeypatch):
+def test_a_boundary_admits_a_bounded_stretch_of_prefill_and_always_one_prompt(parity_model, monkeypatch, sized_by):
     """Four prompts of 5 chunks into four free slots with the bound at 10
     chunks: two a boundary, a decode dispatch of a quantum behind each
     round (a slot is free, so no longer), the same greedy tokens as without
@@ -459,8 +479,9 @@ def test_a_boundary_admits_a_bounded_stretch_of_prefill_and_always_one_prompt(pa
 
     whole, n = served(engine_lib.ADMIT_CHUNKS)
     assert n["admit_rounds"] == 1 and n["prefill_chunks"] == 20
+    before = len(sized_by())
     bounded, n = served(10)
-    assert bounded == whole and n["admit_rounds"] == n["decode_behind_admit"] == 2 and n["decode_sized_by_quantum"] >= 1
+    assert bounded == whole and n["admit_rounds"] == n["decode_behind_admit"] == 2 and "quantum" in sized_by()[before:]
     alone, n = served(3)
     assert alone == whole and n["admit_rounds"] == 4 and n["admitted"] == 4
 
