@@ -1,9 +1,10 @@
 """What the families that serve an explicit LIST of layers share
-(``models/mimo_v2.py``, ``models/nemotron_h.py``): seeded leaves drawn a
-layer at a time in the serving dtype, RMSNorm, the write of a step's or a
-chunk's keys and values into a slab, the head's product, and the plumbing of
-the expert layers' device counters. What a layer computes, and what its
-cache holds, is each family's own.
+(``models/mimo_v2.py``, ``models/nemotron_h.py``, ``models/phi4_flash.py``):
+seeded leaves drawn a layer at a time in the serving dtype, RMSNorm, the
+write of a step's or a chunk's keys and values into a layer's slab or ring
+(a decode step's through the kernel of ``ops/cache_write.py``), the head's
+product, and the plumbing of the expert layers' device counters. What a
+layer computes, and what its cache holds, is each family's own.
 """
 
 from __future__ import annotations
@@ -74,20 +75,34 @@ def rms_norm(x, scale, eps):
     return (y * scale).astype(x.dtype)
 
 
-def write_positions(slab, vals, positions):
-    """Write ``vals [B, Hk, S, d]`` (or ``[B, S]`` for a ring's recorded
-    positions) at ``positions [B, S] % length`` of ``slab``'s position
-    axis. A single position a row (a decode step) is one update-slice a
-    row; a chunk is a scatter, since a ring may wrap inside it."""
-    T = slab.shape[-2] if slab.ndim == 4 else slab.shape[-1]
-    idx = positions % T
-    if slab.ndim == 2:
-        return jax.vmap(lambda c, u, i: c.at[i].set(u))(slab, vals, idx)
+def write_positions(cache: dict, k, v, positions) -> dict:
+    """A layer's cache ``{k, v[, pos]}`` with the incoming keys and values
+    ``[B, Hk, S, d]`` written at ``positions [B, S] % length`` of the ``k``
+    and ``v`` leaves' position axis; a ring (a cache with a ``pos`` leaf
+    ``[B, length]``) also records the positions there.
+
+    A decode step (one position a row, every row at a place of its own) is
+    ONE pass over the rows with no serial trip a row: both leaves through
+    the kernel of ops/cache_write.py, which moves each row's one tile and
+    nothing else of the donated leaves, and ``pos`` by a select over the
+    leaf, all under the scope ``cache_write`` inside the layer's own. A
+    chunk is a scatter, since a ring may wrap inside it."""
+    idx = positions % cache["k"].shape[2]
     if idx.shape[1] == 1:
-        return jax.vmap(
-            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (0, i, 0))
-        )(slab, vals, idx[:, 0])
-    return jax.vmap(lambda c, u, i: c.at[:, i].set(u))(slab, vals, idx)
+        from ..ops.cache_write import write_rows
+
+        with jax.named_scope("cache_write"):
+            new_k, new_v = write_rows((cache["k"], cache["v"]), (k, v), idx[:, 0])
+            new = {"k": new_k, "v": new_v}
+            if "pos" in cache:
+                col = jax.lax.broadcasted_iota(jnp.int32, cache["pos"].shape, 1)
+                new["pos"] = jnp.where(col == idx, positions, cache["pos"])
+        return new
+    scatter = lambda slab, vals: jax.vmap(lambda c, u, i: c.at[:, i].set(u))(slab, vals, idx)  # noqa: E731
+    new = {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v)}
+    if "pos" in cache:
+        new["pos"] = jax.vmap(lambda c, u, i: c.at[i].set(u))(cache["pos"], positions, idx)
+    return new
 
 
 def logits(params: dict, hidden):

@@ -301,17 +301,14 @@ def attention(cfg: MiMoV2Config, attn_kind: str, w: dict, state: dict, x, positi
     q = partial_rope(q, positions, theta, cfg.rotary_dim)
     k = partial_rope(k, positions, theta, cfg.rotary_dim)
 
-    new = {
-        "k": write_positions(state["k"], k.swapaxes(1, 2).astype(cfg.dtype), positions),
-        "v": write_positions(state["v"], v.swapaxes(1, 2).astype(cfg.dtype), positions),
-    }
+    k, v = k.swapaxes(1, 2).astype(cfg.dtype), v.swapaxes(1, 2).astype(cfg.dtype)
+    new = write_positions(state, k, v, positions)
     q = q.reshape(B, S, Hk, H // Hk, cfg.qk_head_dim)
     if attn_kind == FULL:
         from ..ops.cache_attention import cache_attention
 
         out = cache_attention(q, positions, new["k"], new["v"])
     else:
-        new["pos"] = write_positions(state["pos"], positions, positions)
         out = _ring_attend(cfg, q, positions, new, w["sink"])
     return out.reshape(B, S, H * cfg.v_head_dim) @ w["o_proj"], new
 

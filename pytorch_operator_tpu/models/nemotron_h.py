@@ -432,10 +432,7 @@ def attention(cfg: NemotronHConfig, w: dict, cache: dict, x, positions, *, slot=
     k = jnp.einsum("bsd,dke->bkse", x, w["k_proj"]).astype(cfg.dtype)
     v = jnp.einsum("bsd,dke->bkse", x, w["v_proj"]).astype(cfg.dtype)
     if slot is None:
-        new = {
-            "k": layer_list.write_positions(cache["k"], k, positions),
-            "v": layer_list.write_positions(cache["v"], v, positions),
-        }
+        new = layer_list.write_positions(cache, k, v, positions)
     else:
         at = (slot, 0, positions[0, 0], 0)
         new = {

@@ -515,16 +515,9 @@ def write_kv(cache: dict, k, v, positions, slot):
     """The incoming pairs written where they belong: a chunk at row ``slot``
     in place (it never wraps: :func:`ring_len`), a decode step a position a
     row; a ring also records the positions."""
-    T = cache["k"].shape[2]
     if slot is None:
-        new = {
-            "k": layer_list.write_positions(cache["k"], k, positions),
-            "v": layer_list.write_positions(cache["v"], v, positions),
-        }
-        if "pos" in cache:
-            new["pos"] = layer_list.write_positions(cache["pos"], positions, positions)
-        return new
-    at = positions[0, 0] % T
+        return layer_list.write_positions(cache, k, v, positions)
+    at = positions[0, 0] % cache["k"].shape[2]
     new = {
         "k": jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, at, 0)),
         "v": jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, at, 0)),

@@ -91,7 +91,8 @@ def run(
         kv_quantize=kv_quantize,
     )
     if cfg.serving_model().decode_reads_per_row:
-        # Its decode step is a Pallas kernel (ops/cache_attention.py), whose
+        # Its decode step is Pallas kernels (ops/cache_attention.py; the
+        # layer-list families' write too, ops/cache_write.py), whose
         # library takes about a second of Python to import: brought in on a
         # thread beside the backend's start and the weights, which wait on
         # the device and the compile cache, not in front of the first dispatch.

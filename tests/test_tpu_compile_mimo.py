@@ -79,11 +79,12 @@ def test_a_decode_step_over_64_slots_compiles_and_fits(described, one_chip):
     assert mem.argument_size_in_bytes > 10.7e9  # 9.05 GB of weights + 1.76 GB of cache
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
     # Each of the two full layers' attention is the decode kernel with per-row lengths, lowered through Mosaic
-    # (dk 192, 4 key heads), and no loop over the slab's blocks (the per-row cache writes are loops of the
-    # compiler's own: scatters).
+    # (dk 192, 4 key heads), and every layer's keys and values are written by the write's kernel, all 64 rows in
+    # one pass: a step holds no loop at all, over a slab's blocks or over the rows.
     text = compiled.as_text()
     assert len(decode_kernels(text, "attn_full")) == 2
-    assert not [l for l in re.findall(r" while\(.*", text) if 'attn_full/while"' in l]
+    assert len(write_kernels(text, "attn_full")) == 2 and len(write_kernels(text, "attn_window")) == 5
+    assert " while(" not in text
 
 
 def test_a_prefill_chunk_into_one_slots_row_compiles_and_fits(described, one_chip):
@@ -94,6 +95,46 @@ def test_a_prefill_chunk_into_one_slots_row_compiles_and_fits(described, one_chi
         params, cache, _ints((), one_chip), _ints((1, CHUNK), one_chip), _ints((1, CHUNK), one_chip)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM  # the cache of all 64 slots is an argument
+    # A chunk keeps the scatter (a ring may wrap inside it) and the loop over its row's blocks: no kernel is its.
+    assert "tpu_custom_call" not in compiled.as_text() and "/cache_write/" not in compiled.as_text()
+
+
+def test_the_engines_decode_block_writes_every_layers_keys_and_values_in_one_kernel_each(described, one_chip):
+    """``decode_block`` as the engine compiles it (64 slots, up to 64 steps a dispatch): the only loop is the
+    steps'; under ``attn_full/cache_write`` and ``attn_window/cache_write`` a layer's keys and values go through ONE
+    aliased Mosaic call (no loop of 64 trips a leaf, as the scatter was); the cache is donated whole; and inside
+    the steps' loop nothing else writes a slab or a ring. The ``[.., 192]`` key leaves lie position-minor at the
+    program's edge and are brought into the kernels' layout and back ONCE a dispatch, outside the loop: as before
+    the write's kernel, which asks for the layout the walk's kernel asks for."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.ops.sampling import make_sampler
+    from pytorch_operator_tpu.serving.engine import programs
+
+    model, params, cache = described
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)  # noqa: E731
+    progs = programs(model, slots=SLOTS, chunk=CHUNK, block=64, sample=make_sampler(0.0, 0, 1.0))
+    compiled = progs.decode_block.lower(
+        params, cache, on(jax.eval_shape(lambda: model.counts)), _ints((SLOTS,), one_chip), _ints((SLOTS,), one_chip),
+        jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one_chip), on(jax.eval_shape(lambda: jax.random.key(0))),
+        _ints((), one_chip)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert len(write_kernels(text, "attn_full")) == 2 and len(write_kernels(text, "attn_window")) == 5
+    assert len([l for l in text.splitlines() if " while(" in l]) == 1
+    cache_bytes = 1_342_177_280 + 419_758_080  # the slabs, the rings with their positions: the configuration's bytes
+    assert cache_bytes <= mem.alias_size_in_bytes < cache_bytes + 1e6
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
+    assert mem.temp_size_in_bytes < 1.5e9  # the parent's 1.474e9: the key leaves in the kernels' layout, the logits
+    leaves = {"keys": ("[64,4,4096,192]", "[64,8,256,192]"), "values": ("[64,4,4096,128]", "[64,8,256,128]")}
+    written = lambda shapes, entry: [op for op, _ in _writers(text, "bf16", shapes, entry)]  # noqa: E731
+    # Inside the loop a leaf is the result of a kernel or moves between the chip's memories whole (the compiler's
+    # own prefetch of a ring, as before): no fusion, copy or scatter makes a second one.
+    moves = {"custom-call", "copy-start", "copy-done", "slice-start", "slice-done"}
+    assert set(written(leaves["keys"] + leaves["values"], False)) <= moves
+    assert "copy" not in written(leaves["values"], True)
+    at_edge = [op for op in written(leaves["keys"], True) if op == "copy"]
+    assert len(at_edge) == 14  # 7 leaves in, 7 out
 
 
 # ---- the llama family's two serving programs at the InternLM2 cell's sizes (PR 31) ----
@@ -165,6 +206,16 @@ def decode_kernels(text, *scopes):
             and "cache_attention_decode" in l and any(f"/{scope}/" in l for scope in scopes)]
 
 
+def write_kernels(text, scope):
+    """The Mosaic kernels of ``ops.cache_write`` (a decode step's keys and values into a layer's leaves, every row
+    in one pass) in a compiled program's text, under ``scope``'s ``cache_write``: the instructions' lines, each
+    aliasing its leaves to its results."""
+    found = [l for l in text.splitlines() if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l
+             and f"/{scope}/cache_write/cache_write_rows/" in l]
+    assert all("output_to_operand_aliasing={{0}: (3, {}), {1}: (4, {})}" in l for l in found), found[:1]
+    return found
+
+
 @pytest.mark.parametrize("rows, heads, group, dk, dv, length, dtype", [
     (8, 32, 1, 128, 128, 4096, "bfloat16"),  # 32 key heads: a step's blocks pass the 16 MiB a kernel gets unasked
     (8, 8, 4, 128, 128, 32768, "bfloat16"),  # a long slab's eighth: 4,096 positions a block
@@ -187,15 +238,15 @@ def test_the_decode_kernel_lowers_through_mosaic_at_other_shapes(described, one_
     assert text.count('custom_call_target="tpu_custom_call"') == 1 and " while(" not in text
 
 
-def _top_level(text):
+def _top_level(text, entry=None):
     """(opcode, result type, op_name) of every instruction that is not inside a fusion: the entry computation and
-    the bodies of its loops."""
+    the bodies of its loops (``entry`` True: the entry computation's alone; False: the others')."""
     fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
     out, inside = [], None
     for line in text.splitlines():
-        header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        header = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
         if header:
-            inside = header.group(1)
+            inside = header.group(2) if entry in (None, bool(header.group(1))) else None
             continue
         if line.startswith("}"):
             inside = None
@@ -215,6 +266,12 @@ def _arrays(result_type):
 
 
 WRITES_NOTHING = ("parameter", "get-tuple-element", "tuple", "bitcast", "constant", "while", "dynamic-update-slice")
+
+
+def _writers(text, dtype, shapes, entry=None):
+    """(opcode, op_name) of the top-level instructions (outside fusions) that write an array of one of ``shapes``."""
+    return [(op, name) for op, result, name in _top_level(text, entry=entry) if op not in WRITES_NOTHING
+            and any(t == dtype and dims in shapes for t, _, dims in _arrays(result))]
 
 
 def _dequantised_weights(text):
@@ -293,6 +350,11 @@ def test_the_int8_familys_programs_hold_no_kernel_and_keep_the_loop(llama_progra
     assert "tpu_custom_call" not in text and "cache_attention_decode" not in text
     loops = [l for l in text.splitlines() if " while(" in l and re.search(r'attn\._cache_attend/while"', l)]
     assert len(loops) == 24 and not any("known_trip_count" in l for l in loops), len(loops)
+    # Its write is its own too (models/llama.py: int8 slabs and their float32 scales, a layout the layer-list
+    # families' kernel does not take): a decode step's 24 layers x 4 leaves are scatters, each a loop over the slots.
+    assert "/cache_write/" not in text
+    scatters = [l for l in text.splitlines() if " while(" in l and re.search(r'attn\._decode_attend/vmap\(vmap\(\)\)/scatter"', l)]
+    assert len(scatters) == (96 if form == "decode_block" else 0), len(scatters)
 
 
 def test_a_llama_decode_step_keeps_every_dequantisation_inside_its_product(llama_programs):

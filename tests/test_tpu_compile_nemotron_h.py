@@ -25,11 +25,12 @@ import time
 import pytest
 
 import tests.jaxenv  # noqa: F401
-from tests.test_tpu_compile_mimo import WRITES_NOTHING, _arrays, _top_level, decode_kernels, donated_into_outputs
+from tests.test_tpu_compile_mimo import _writers, decode_kernels, donated_into_outputs, write_kernels
 
 HBM = 16 * 1024**3
 SLOTS, CHUNK, BLOCK, LEN = 128, 128, 64, 4096
 STATE = "[128,64,64,128]"  # one Mamba layer's scan state over the slots, float32
+SLAB = "[128,2,4096,128]"  # an attention layer's keys (or values) over the slots
 MAMBA_LAYERS = 7
 
 
@@ -101,8 +102,7 @@ def compiled(one_chip):
 def _state_writers(text):
     """Top-level instructions (outside fusions) that write an array the size
     of one layer's scan state over all slots."""
-    return [(op, name) for op, result, name in _top_level(text) if op not in WRITES_NOTHING
-            and any(dtype == "f32" and dims == STATE for dtype, _, dims in _arrays(result))]
+    return _writers(text, "f32", (STATE,))
 
 
 @pytest.mark.parametrize("program", ["decode_block", "prefill_chunk"])
@@ -127,9 +127,16 @@ def test_a_decode_step_walks_each_attention_layers_slab_in_the_kernel_and_a_chun
     through Mosaic at [128, 2, 4096, 128] under ``attn_full``; a chunk (one row) keeps the loop."""
     text = compiled("decode_block").as_text()
     assert len(decode_kernels(text, "attn_full")) == 2
-    assert not [l for l in text.splitlines() if " while(" in l and 'attn_full/while"' in l]
+    # Before each walk the layer's keys and values of all 128 rows are written by ONE aliased kernel under
+    # ``attn_full/cache_write`` (not two scatters of 128 trips each): the steps' loop is the program's only loop, and
+    # nothing else writes an array the size of a slab (no copy of one).
+    assert len(write_kernels(text, "attn_full")) == 2
+    assert len([l for l in text.splitlines() if " while(" in l]) == 1
+    slab_writers = _writers(text, "bf16", (SLAB,))
+    assert len(slab_writers) == 2 and all(op == "custom-call" and "/cache_write/" in name for op, name in slab_writers), slab_writers
     chunk = compiled("prefill_chunk").as_text()
-    assert "tpu_custom_call" not in chunk
+    assert "tpu_custom_call" not in chunk and "/cache_write/" not in chunk
+    assert "tpu_custom_call" not in compiled("prefill_chunk_head").as_text()
     assert len([l for l in chunk.splitlines() if " while(" in l and 'attn_full/while"' in l]) == 2
 
 

@@ -29,7 +29,7 @@ import time
 import pytest
 
 import tests.jaxenv  # noqa: F401
-from tests.test_tpu_compile_mimo import WRITES_NOTHING, _arrays, _top_level, decode_kernels, donated_into_outputs
+from tests.test_tpu_compile_mimo import _writers, decode_kernels, donated_into_outputs, write_kernels
 
 HBM = 16 * 1024**3
 SLOTS, CHUNK, BLOCK, LEN = 96, 128, 64, 4096
@@ -109,12 +109,6 @@ def compiled(one_chip):
     jax.config.update("jax_enable_compilation_cache", True)
 
 
-def _writers(text, dtype, shapes):
-    """Top-level instructions (outside fusions) that write an array of one of ``shapes``."""
-    return [(op, name) for op, result, name in _top_level(text) if op not in WRITES_NOTHING
-            and any(t == dtype and dims in shapes for t, _, dims in _arrays(result))]
-
-
 @pytest.mark.parametrize("program", ["decode_block", "prefill_chunk"])
 def test_the_program_fits_and_its_cache_is_donated_whole(compiled, program):
     mem = compiled(program).memory_analysis()
@@ -131,7 +125,13 @@ def test_a_decode_step_updates_each_layers_state_in_one_fusion_and_copies_no_rin
     writers = _writers(text, "f32", (STATE,))
     assert len(writers) == MAMBA_LAYERS and {op for op, _ in writers} == {"fusion"}, writers
     assert all("ssm/ssm_scan" in name for _, name in writers), writers
-    assert not _writers(text, "bf16", (RING, SLAB)), _writers(text, "bf16", (RING, SLAB))[:4]
+    # The one slab's and the eight rings' keys and values are written by ONE aliased kernel a layer over the 96
+    # rows, under its layer's ``cache_write`` (18 scatters of 96 trips before); nothing else writes an array the
+    # size of a ring or of the slab (no copy of one), and the steps' loop is the program's only loop.
+    assert len(write_kernels(text, "attn_full")) == 1 and len(write_kernels(text, "attn_window")) == 8
+    leaf_writers = _writers(text, "bf16", (RING, SLAB))
+    assert len(leaf_writers) == 9 and all(op == "custom-call" and "/cache_write/" in name for op, name in leaf_writers), leaf_writers
+    assert len([l for l in text.splitlines() if " while(" in l]) == 1
     # the one slab is walked by eight layers a step, each the decode kernel with per-row lengths, lowered through
     # Mosaic at [96, 10, 4096, 128] under its layer's scope (what the benchmark's readers sum); no loop is left
     kernels = decode_kernels(text, "attn_full", "attn_cross")
@@ -166,4 +166,5 @@ def test_the_head_program_runs_the_cross_decoder_on_one_token_and_copies_no_cach
     assert mem.temp_size_in_bytes < 0.1e9 and not _writers(text, "bf16", (SLAB,) + ROWS)
     # one row (``slot``): the loop, whose bound is the row's own; so too the chunk's program
     assert "tpu_custom_call" not in text and "tpu_custom_call" not in compiled("prefill_chunk").as_text()
+    assert "/cache_write/" not in text and "/cache_write/" not in compiled("prefill_chunk").as_text()  # a decode step's alone
     assert len([l for l in text.splitlines() if " while(" in l and re.search(r'attn_(full|cross)/while"', l)]) == 8
