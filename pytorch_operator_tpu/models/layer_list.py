@@ -81,22 +81,38 @@ def write_positions(cache: dict, k, v, positions) -> dict:
     and ``v`` leaves' position axis; a ring (a cache with a ``pos`` leaf
     ``[B, length]``) also records the positions there.
 
-    A decode step (one position a row, every row at a place of its own) is
-    ONE pass over the rows with no serial trip a row: both leaves through
-    the kernel of ops/cache_write.py, which moves each row's one tile and
-    nothing else of the donated leaves, and ``pos`` by a select over the
-    leaf, all under the scope ``cache_write`` inside the layer's own. A
-    chunk is a scatter, since a ring may wrap inside it."""
+    A decode step (every row at a place of its own, a step's few positions a
+    row: one, or a verifying step's two, at most
+    ``ops.cache_attention.STEP_QUERIES``) is ONE pass over the rows a
+    position, in the positions' order, with no serial trip a row: both
+    leaves through the kernel of ops/cache_write.py, which moves each row's
+    one tile and nothing else of the donated leaves, and ``pos`` by a select
+    over the leaf, all under the scope ``cache_write`` inside the layer's
+    own. A chunk is a scatter, since a ring may wrap inside it.
+
+    **A position written and then given up** (a verifying step's second,
+    whose draft the main stack did not choose) needs no undoing: the row's
+    next step starts AT that position and writes it again before it attends
+    anything. In a slab that is all. In a ring the write at position ``p +
+    1`` also REPLACES the entry of ``p + 1 - length``, which the queries at
+    ``p`` and later cannot see as long as ``length > window`` (every ring
+    here keeps ``window + chunk`` positions), and the entry's recorded
+    position is rewritten with it, so no query ever meets keys under
+    another position's name."""
+    from ..ops.cache_attention import STEP_QUERIES
+
     idx = positions % cache["k"].shape[2]
-    if idx.shape[1] == 1:
+    if idx.shape[1] <= STEP_QUERIES:
         from ..ops.cache_write import write_rows
 
         with jax.named_scope("cache_write"):
-            new_k, new_v = write_rows((cache["k"], cache["v"]), (k, v), idx[:, 0])
-            new = {"k": new_k, "v": new_v}
-            if "pos" in cache:
-                col = jax.lax.broadcasted_iota(jnp.int32, cache["pos"].shape, 1)
-                new["pos"] = jnp.where(col == idx, positions, cache["pos"])
+            new = dict(cache)
+            col = jax.lax.broadcasted_iota(jnp.int32, cache["pos"].shape, 1) if "pos" in cache else None
+            for s in range(idx.shape[1]):
+                at = idx[:, s]
+                new["k"], new["v"] = write_rows((new["k"], new["v"]), (k[:, :, s : s + 1], v[:, :, s : s + 1]), at)
+                if col is not None:
+                    new["pos"] = jnp.where(col == at[:, None], positions[:, s : s + 1], new["pos"])
         return new
     scatter = lambda slab, vals: jax.vmap(lambda c, u, i: c.at[:, i].set(u))(slab, vals, idx)  # noqa: E731
     new = {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v)}

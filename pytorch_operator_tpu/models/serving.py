@@ -5,7 +5,7 @@
 :class:`ServingModel` alone: its config, its seeded init, its cache
 constructor and its two forwards. A config class states its family by
 having ``serving_model()`` (``models.llama.LlamaConfig``,
-``models.mimo_v2.MiMoV2Config``, ``models.nemotron_h.NemotronHConfig``,
+``models.mimo_v2.MiMoV2Config`` (MiMo-V2.5 and K-EXAONE), ``models.nemotron_h.NemotronHConfig``,
 ``models.phi4_flash.Phi4FlashConfig``); a job's ``--config`` names a preset, and
 :func:`preset` finds the family that has it. Nothing else selects a path.
 """
@@ -18,6 +18,36 @@ from typing import Any, Callable, Optional
 
 def _nothing(*_):
     return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class Drafter:
+    """What a model that DRAFTS gives the engine beside its forwards
+    (``ServingModel.drafter``): a decode step then runs TWO positions a row
+    through the main stack (the row's last accepted token and the draft of
+    the next), accepts the draft iff it is the main stack's own choice,
+    yields one or two tokens, and leaves the next draft in the row's state.
+    What is delivered is, token for token, what ``decode`` alone delivers;
+    greedy only. ``prefill`` of such a model gets each chunk's tokens with
+    the ONE that follows them (``[1, chunk + 1]``; past the prompt's end a
+    pad) and fills the drafter's own state from them; ``hidden`` is whatever
+    ``finish`` and ``first`` take one token of."""
+
+    # (params, cache, tokens [slots, 2], positions [slots, 2]) -> (float32
+    # logits [slots, 2, V], hidden (any pytree of [slots, 2, ...] leaves),
+    # cache, counts): the main stack, both positions written to its cache.
+    verify: Callable
+    # (params, cache, hidden, chosen [slots, 2] (the main stack's choice
+    # after each position), positions [slots, 2], accepted [slots] bool,
+    # live [slots] bool (rows that hold a request)) -> (float32 draft
+    # logits [slots, V]: the token after next at the LAST POSITION KEPT, the
+    # second where ``accepted``; cache; counts). A rejected position's state
+    # is overwritten by the next step's write before anything reads it.
+    draft: Callable
+    # The end of an admission: (params, cache, slot, h (``hidden`` at the
+    # prompt's last token), position, first [1] (the sampled first token))
+    # -> (float32 draft logits [1, V], cache): the row's first draft.
+    first: Callable
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,7 +76,8 @@ class ServingModel:
     # (params, cache, slot (a traced int32 scalar), tokens [1, chunk],
     # positions [1, chunk], n_real (a traced int32 scalar: the first
     # ``n_real`` tokens are the prompt's, the rest the last chunk's pad))
-    # -> (hidden, cache, counts): one call shape for every family.
+    # -> (hidden, cache, counts): one call shape for every family (a model
+    # that drafts gets one token more: :class:`Drafter`).
     # ``hidden`` is the final-norm hidden [1, chunk, D], or any pytree of
     # [1, chunk, ...] leaves that the family's ``finish`` takes one token
     # of. State that grows with position (keys and values)
@@ -67,15 +98,20 @@ class ServingModel:
     # layers write no state runs them here, for the one token whose logits
     # anybody reads, against the slot's cache.
     finish: Optional[Callable] = None
+    # None = a decode step yields one token a row. A model that drafts
+    # states how here (:class:`Drafter`); nothing else selects the path.
+    drafter: Optional[Drafter] = None
     # What an admission's programs attend of the full-length slabs, for the
     # engine's ``prefill_attended_positions``: (each chunk's last position
     # + 1 (an integer array), the prompt's length) -> the positions each
     # read needs, an integer array. None = each chunk reads up to its own
     # end.
     slab_reads: Optional[Callable] = None
-    # Whether a decode step reads each row's slabs to that row's own depth
-    # (``ops.cache_attention.reads_per_row``) or every row's to the deepest
-    # row's, for the engine's ``decode_attended_positions``.
+    # Whether a decode step (one query a row, or a verifying step's two)
+    # reads each row's slabs to that row's own depth or every row's to the
+    # deepest row's, for the engine's ``decode_attended_positions``: what
+    # ``ops.cache_attention.reads_per_row`` answers for the model's slabs
+    # (the one statement of the condition is there).
     decode_reads_per_row: bool = False
     # a checkpoint's parameter tree (the trainer's form, host arrays) ->
     # the same leaves as ``init_params`` arranges them, which is how the

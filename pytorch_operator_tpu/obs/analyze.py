@@ -540,6 +540,14 @@ def analyze(
         if rec.get("decode_live_positions")
     }
 
+    # A model that drafts (models/serving.py ``Drafter``): the row-steps that
+    # verified a draft, those whose draft was accepted, and the share.
+    drafts = {
+        rec["replica"]: [rec["mtp_drafts"], rec.get("mtp_accepted", 0), rec.get("mtp_accept_pct")]
+        for rec in tl.records.get("metrics", [])
+        if rec.get("mtp_drafts")
+    }
+
     return {
         "job": key,
         "generated_at": _time.time() if now is None else now,
@@ -553,6 +561,7 @@ def analyze(
         "cross_tokens": cross_tokens,
         "admit_rounds": admit_rounds,
         "slab_reads": slab_reads,
+        "drafts": drafts,
         "events": len(tl.events),
         "spans": len(tl.spans),
         "exemplars": exemplars,
@@ -646,6 +655,10 @@ def render_report(report: dict) -> str:
         lines.append(
             f"slabs:    {replica} decode_attended_positions {attended} over decode_live_positions {live} "
             f"= {attended / live:.2f}"
+        )
+    for replica, (drafted, accepted, pct) in sorted(report.get("drafts", {}).items()):
+        lines.append(
+            f"drafts:   {replica} mtp_accepted {accepted} of mtp_drafts {drafted} row-step(s): mtp_accept_pct {pct}"
         )
     alerts = report.get("alerts", [])
     findings = report.get("findings", [])

@@ -10,12 +10,15 @@ from ``positions`` how many blocks (:func:`block`) of the slab hold them
 and reads no more, in one of two forms that share the block size and the
 rounding and nothing else (:func:`reads_per_row` says which a call is):
 
-- **a decode step over a plain slab** (one query a row, every row of the
-  slabs, keys and values in the queries' dtype) reads EACH ROW to that
-  row's own depth: a Pallas TPU kernel over a grid of (row, block)
-  (:func:`_decode_attention`) whose per-row block counts go in by scalar
-  prefetch. A row that holds no request stands at position 0 and costs
-  one block; a deep row costs its own blocks and nobody else's.
+- **a decode step over a plain slab** (every row of the slabs, a step's
+  few queries a row: one, or a verifying step's two, at most
+  :data:`STEP_QUERIES`; keys and values in the queries' dtype) reads EACH
+  ROW to that row's own depth: a Pallas TPU kernel over a grid of (row,
+  block) (:func:`_decode_attention`) whose per-row block counts go in by
+  scalar prefetch, a row's queries folded beside the group axis and each
+  masked at its own position. A row that holds no request stands at
+  position 0 and costs one block; a deep row costs its own blocks and
+  nobody else's.
 - **every other call** reads to the DEEPEST query's position: a prefill
   chunk (one row, ``slot`` given: its bound is the row's own anyway) and
   an int8 slab with its per-position scales, decode step or chunk. A loop
@@ -46,6 +49,13 @@ from jax.experimental.layout import Layout, with_layout_constraint
 # filled up, and a static prefix under ``lax.switch`` (30-40 us a
 # conditional) up to 2048.
 BLOCKS = 8
+
+# The most queries a row brings in a decode step: its last accepted token
+# and, where the model drafts, the one draft behind it (models/serving.py
+# ``Drafter``). Up to here a call over every row is a step, and reads and
+# writes (models/layer_list.py ``write_positions``) a row at a time through
+# the kernels; anything longer is a chunk.
+STEP_QUERIES = 2
 
 
 def block(L: int) -> int:
@@ -82,8 +92,8 @@ def cache_attention(q, positions, k, v, k_scale=None, v_scale=None, *, slot=None
     its own (a prefill chunk in the serving engine).
 
     A decode step over a plain slab (:func:`reads_per_row`) reads each
-    row's blocks up to that row's own position; every other call reads
-    only the blocks up to the deepest query's."""
+    row's blocks up to that row's own deepest position; every other call
+    reads only the blocks up to the deepest query's."""
     if reads_per_row(q.shape[1], k_scale is not None, slot is not None):
         return _decode_attention(q, positions, k, v)
     B, S, K, G, dk = q.shape
@@ -156,21 +166,30 @@ def cache_attention(q, positions, k, v, k_scale=None, v_scale=None, *, slot=None
 
 
 def reads_per_row(queries_a_row: int = 1, quantized: bool = False, in_slot: bool = False) -> bool:
-    """Whether :func:`cache_attention` reads each row's slab to that row's
-    own depth (the kernel below) or every row's to the deepest query's (the
-    loop above). Per row: a decode step (one query a row, every row of the
-    slabs) over a slab in the queries' dtype. The loop: an int8 slab (its
-    ``[.., L, 1]`` scales want a kernel layout of their own) and a prefill
-    chunk (one row, whose bound IS the batch's). A model tells the engine
-    which its decode step is by the same function."""
-    return queries_a_row == 1 and not quantized and not in_slot
+    """THE statement of when :func:`cache_attention` reads each row's slab
+    to that row's own depth (the kernel below) and when every row's to the
+    deepest query's (the loop above). Per row: a call over every row of the
+    slabs (no ``slot``) that brings a step's few queries a row (at most
+    :data:`STEP_QUERIES`: a decode step's one, a verifying step's two) over
+    slabs in the queries' dtype. The loop: an int8 slab (its ``[.., L, 1]``
+    scales want a kernel layout of their own) and a prefill chunk (one row
+    at ``slot``, whose bound IS the batch's; or any call of more queries a
+    row). A model tells the engine which its decode step is by the same
+    function (``ServingModel.decode_reads_per_row``), and the engine charges
+    ``decode_attended_positions`` what the form it names reads: each row's
+    whole blocks up to the row's deepest query."""
+    return queries_a_row <= STEP_QUERIES and not quantized and not in_slot
 
 
 def _decode_attention(q, positions, k, v):
-    """:func:`cache_attention` for a decode step (``S == 1``) over plain
-    slabs, as a Pallas TPU kernel over a grid of (row, block): row ``b``'s
-    block count ``blocks_needed(positions[b] + 1, L)`` goes in by scalar
-    prefetch and the body runs for the blocks below it. The grid's second
+    """:func:`cache_attention` for a decode step (``S`` queries a row, ``S``
+    at most :data:`STEP_QUERIES`) over plain slabs, as a Pallas TPU kernel
+    over a grid of (row, block): row ``b``'s block count, ``blocks_needed``
+    of its deepest query's position + 1, goes in by scalar prefetch and the
+    body runs for the blocks below it. A row's ``S`` queries lie folded
+    beside the group axis (``[K, S G, dk]``: one product a block for all of
+    them) and each is masked at its own position, which goes in by scalar
+    prefetch too. The grid's second
     bound is the deepest row's count (found on the device, like the loop's
     trip count), and past a row's own count the index map of the key and
     value blocks names the next row's first block, so a skipped grid step
@@ -186,15 +205,15 @@ def _decode_attention(q, positions, k, v):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, _, K, G, dk = q.shape
+    B, S, K, G, dk = q.shape
     L, dv = k.shape[2], v.shape[-1]
     T = block(L)
     lowest = jnp.finfo(jnp.float32).min
-    pos = positions[:, 0].astype(jnp.int32)
-    n = jnp.minimum(blocks_needed(pos + 1, L), L // T)
+    pos = positions.astype(jnp.int32).reshape(B * S)  # query s of row b at [b * S + s]
+    n = jnp.minimum(blocks_needed(jnp.max(positions.astype(jnp.int32), axis=1) + 1, L), L // T)
 
     def kernel(n_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, top_ref, total_ref, out_ref):
-        """One block of one row: ``one_block`` of the loop, on ``q [K, G,
+        """One block of one row: ``one_block`` of the loop, on ``q [K, S G,
         dk]`` against ``k [K, T, dk]`` / ``v [K, T, dv]``, the running
         softmax in float32 scratch over the row's grid steps."""
         b, i = pl.program_id(0), pl.program_id(1)
@@ -210,9 +229,16 @@ def _decode_attention(q, positions, k, v):
             kb, vb = k_ref[0], v_ref[0]
             scores = jax.lax.dot_general(
                 q_ref[0], kb, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-            ) / jnp.sqrt(jnp.float32(dk))  # [K, G, T]
+            ) / jnp.sqrt(jnp.float32(dk))  # [K, S G, T]
             col = i * T + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
-            scores = jnp.where(col <= pos_ref[b], scores, lowest)
+            # Each query's own position: query s of the row is the folded
+            # axis' entries [s G, (s + 1) G).
+            at = pos_ref[b * S]
+            if S > 1:
+                folded = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                for s in range(1, S):
+                    at = jnp.where(folded >= s * G, pos_ref[b * S + s], at)
+            scores = jnp.where(col <= at, scores, lowest)
             top = top_ref[...]
             new_top = jnp.maximum(top, scores.max(-1, keepdims=True))
             weights = jnp.exp(scores - new_top)
@@ -241,28 +267,30 @@ def _decode_attention(q, positions, k, v):
     # computes: beyond the 16 MiB a kernel may use of fast memory unasked
     # (many key heads, or a long slab's eighth) the kernel asks for more.
     blocks = 2 * K * T * (dk * k.dtype.itemsize + dv * v.dtype.itemsize)
+    # [B, S, K, G, dk] -> [B, K, S G, dk]: a row's queries beside its groups.
+    folded = q[:, 0] if S == 1 else q.transpose(0, 2, 1, 3, 4).reshape(B, K, S * G, dk)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, jnp.max(n)),
             in_specs=[
-                pl.BlockSpec((1, K, G, dk), at_row),
+                pl.BlockSpec((1, K, S * G, dk), at_row),
                 pl.BlockSpec((1, K, T, dk), at_block),
                 pl.BlockSpec((1, K, T, dv), at_block),
             ],
-            out_specs=pl.BlockSpec((1, K, G, dv), at_row),
+            out_specs=pl.BlockSpec((1, K, S * G, dv), at_row),
             scratch_shapes=[
-                pltpu.VMEM((K, G, 1), jnp.float32),
-                pltpu.VMEM((K, G, 1), jnp.float32),
-                pltpu.VMEM((K, G, dv), jnp.float32),
+                pltpu.VMEM((K, S * G, 1), jnp.float32),
+                pltpu.VMEM((K, S * G, 1), jnp.float32),
+                pltpu.VMEM((K, S * G, dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, K, G, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, S * G, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=max(16 << 20, blocks + (4 << 20))
         ),
         interpret=jax.default_backend() == "cpu",
         name="cache_attention_decode",
-    )(n, pos, q[:, 0], k, v)
-    return out[:, None]
+    )(n, pos, folded, k, v)
+    return out[:, None] if S == 1 else out.reshape(B, K, S, G, dv).transpose(0, 2, 1, 3, 4)

@@ -1,4 +1,6 @@
-"""A decode step's write into a key/value cache: one new position a row.
+"""A decode step's write into a key/value cache: one new position a row a
+call (a verifying step's two positions are two calls:
+models/layer_list.py ``write_positions``).
 
 Every layer-list family (models/mimo_v2.py, models/nemotron_h.py,
 models/phi4_flash.py) keeps a layer's keys and values as leaves ``[rows,

@@ -72,6 +72,29 @@ TPU-first shape (every program's shapes static):
 - Slot L-1 of every row is a parking slot: rows that exhaust their
   budget clamp there, so admission requires prompt + new <= L-1 and
   no live stream ever attends a parked write.
+- A model that DRAFTS (``ServingModel.drafter``, models/serving.py) gets
+  a VERIFYING step in ``decode_block`` (:func:`_drafting_programs`): the
+  row's state is its last accepted token ``tok`` at ``pos`` and a
+  ``draft`` of the token after it; a step runs both through the main
+  stack at ``pos`` and ``pos + 1``, takes the stack's own choice after
+  the first, accepts the draft iff it IS that choice (then the choice
+  after the second is a token too), lets the drafter leave the next
+  draft, and advances the row by one or two -- all on the device inside
+  the dispatch's loop, no host round trip a step. What is delivered is
+  what the model delivers without its drafter, token for token; a
+  position written under a rejected draft is written again by the row's
+  next step before anything attends it. The dispatch returns each step's
+  two tokens and how many of them it delivered; ``_accept_token`` is
+  called once a delivered token and a row's surplus token past its budget
+  is dropped; ``decode_steps`` sizes a dispatch for rows that need between
+  half and all of their budget in steps; the counters that depend on
+  where a row stood (``decode_live_positions``,
+  ``decode_attended_positions``) take the dispatch's own tallies, at
+  every row-step, and ``decode_yield_pct`` = tokens over row-steps reads
+  up to 200. The head's program of such a model also runs the drafter on
+  the prompt's last position (it writes the drafter's state, so it takes
+  the cache donated) and leaves the first draft. Greedy only: a
+  temperature is refused.
 
 Latency accounting: TTFT per request (submit -> first sampled token,
 measured on the host around the real dispatches) and its three parts
@@ -95,7 +118,10 @@ lists every name beside the metric that reads it):
   of the trace: a dispatch's span carries what the dispatch was (a
   chunk's slot, prompt tokens and whether the head ran behind it; a
   decode dispatch's rows, steps and the rule that sized it; on its fence
-  also the positions live and attended), each number known before the
+  also the positions live and attended -- where the model drafts, the
+  FLOOR of attended, as if no draft were accepted, and the dispatch's own
+  ``attended`` with ``drafted`` and ``accepted`` on ``engine.accept``,
+  which opens after the fence), each number known before the
   span opens, so a reader has the counters' increments of exactly the
   traced stretch, on the device's clock, and can pair every run of
   ``decode_block`` on the device with the dispatch that queued it;
@@ -189,13 +215,19 @@ QUANTUM = 8
 ADMIT_CHUNKS = 128
 
 
-def decode_steps(remaining, free_slots: int, block: int) -> tuple[int, str]:
+def decode_steps(remaining, free_slots: int, block: int, per_step: int = 1) -> tuple[int, str]:
     """How many steps the next decode dispatch runs, and which rule sized
     it (one of :data:`SIZED_BY`). ``remaining`` are the active rows'
     remaining budgets (each >= 1; an upper bound on the row's life where
     an EOS token can end it sooner), ``free_slots`` the slots that hold no
     request — after admission, so a free slot means nothing is queued, or
     that the boundary's admissions reached :data:`ADMIT_CHUNKS`.
+    ``per_step`` is the most tokens a step yields a row (2 where the model
+    drafts): a row with ``r`` tokens left then needs between ``ceil(r /
+    per_step)`` and ``r`` steps, and the rules below take the FEWEST for
+    "the step at which the next slot frees" (no slot can free sooner, and a
+    dispatch that ends before the row does is followed by another, sized
+    again from what is left) and the MOST for "a step no row can use".
 
     - a slot free: an arrival could be admitted at the next boundary, so
       at most a quantum;
@@ -206,7 +238,7 @@ def decode_steps(remaining, free_slots: int, block: int) -> tuple[int, str]:
     - never past the longest budget (a step no row can use), never more
       than ``block``.
     """
-    shortest, longest = min(remaining), max(remaining)
+    shortest, longest = -(-min(remaining) // per_step), max(remaining)
     steps, sized_by = QUANTUM, "quantum"
     if not free_slots and shortest > QUANTUM:
         steps, sized_by = shortest, "budget"
@@ -355,7 +387,80 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         )
         return toks.swapaxes(0, 1), cache, counts, tok, pos, rng
 
-    return Programs(prefill_chunk, prefill_chunk_head, decode_block)
+    if model.drafter is None:
+        return Programs(prefill_chunk, prefill_chunk_head, decode_block)
+    return Programs(prefill_chunk, *_drafting_programs(model, finish, slots=slots, chunk=chunk, block=block, sample=sample))
+
+
+def _drafting_programs(model, finish, *, slots: int, chunk: int, block: int, sample):
+    """``prefill_chunk_head`` and ``decode_block`` for a model that drafts
+    (``ServingModel.drafter``; the same names, by which the benchmark finds
+    the programs in a trace). The row's state gains ``draft [slots]``, the
+    drafter's token for the position after the row's; ``sample`` is greedy
+    (the engine refuses a drafting model any other)."""
+    import jax
+    import jax.numpy as jnp
+
+    L, drafter = model.cfg.max_decode_len, model.drafter
+    add = functools.partial(jax.tree.map, jnp.add)
+
+    @functools.partial(jax.jit, donate_argnums=(1, 3, 4, 5))
+    def prefill_chunk_head(params, cache, hidden, tok, pos, draft, slot, p, key):
+        """The end of an admission as the other models' program has it (the
+        head on the prompt's last position, the first token sampled, the
+        row's ``tok`` / ``pos`` set), and then the drafter on that position
+        with the first token: it writes its own state there, so this program
+        takes the cache donated, and leaves the row's first draft in the
+        donated ``draft``."""
+        with jax.named_scope("head"):
+            at = (p - 1) % chunk
+            h = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, at, 1, axis=1)[:, 0], hidden)
+        logits = finish(params, cache, slot, h, p - 1)
+        key, sub, sub2 = jax.random.split(key, 3)
+        first = sample(logits, sub)
+        draft_logits, cache = drafter.first(params, cache, slot, h, p - 1, first)
+        first = first[0]
+        return (cache, tok.at[slot].set(first), pos.at[slot].set(p),
+                draft.at[slot].set(sample(draft_logits, sub2)[0]), first, key)
+
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def decode_block(params, cache, counts, tok, pos, draft, active, rng, steps):
+        """``steps`` verifying steps over all slots, on the device from end
+        to end: each runs the row's last accepted token and its draft (at
+        ``pos`` and ``pos + 1``) through the main stack, takes the stack's
+        own choice after the first, accepts the draft iff it IS that choice
+        and then takes the choice after the second too, lets the drafter
+        leave the next draft, and advances the row by one or two. Parked
+        rows stand at position 0 as in the other models' program. Returns
+        the tokens ``[slots, block, 2]`` with how many of a step's two were
+        delivered ``[slots, block]`` (1 or 2; 0 for a parked row), of which
+        the first ``steps`` steps are written."""
+        pos = jnp.where(active, pos, 0)
+
+        def step(i, carry):
+            cache, counts, tok, pos, draft, rng, toks, took = carry
+            at = jnp.minimum(jnp.stack([pos, pos + 1], axis=1), L - 1)
+            logits, hidden, cache, added = drafter.verify(params, cache, jnp.stack([tok, draft], axis=1), at)
+            rng, k, k2 = jax.random.split(rng, 3)
+            chosen = sample(logits.reshape(2 * slots, -1), k).reshape(slots, 2).astype(tok.dtype)
+            accepted = active & (chosen[:, 0] == draft)
+            draft_logits, cache, more = drafter.draft(params, cache, hidden, chosen, at, accepted, active)
+            n = jnp.where(active, 1 + accepted.astype(jnp.int32), 0)
+            tok = jnp.where(active, jnp.where(accepted, chosen[:, 1], chosen[:, 0]), tok)
+            draft = jnp.where(active, sample(draft_logits, k2).astype(tok.dtype), draft)
+            pos = jnp.where(active, jnp.minimum(pos + n, L - 1), pos)
+            toks = jax.lax.dynamic_update_index_in_dim(toks, chosen, i, 0)
+            took = jax.lax.dynamic_update_index_in_dim(took, n, i, 0)
+            return cache, add(add(counts, added), more), tok, pos, draft, rng, toks, took
+
+        toks = jnp.zeros((block, slots, 2), tok.dtype)
+        took = jnp.zeros((block, slots), jnp.int32)
+        cache, counts, tok, pos, draft, rng, toks, took = jax.lax.fori_loop(
+            0, steps, step, (cache, counts, tok, pos, draft, rng, toks, took)
+        )
+        return toks.swapaxes(0, 1), took.swapaxes(0, 1), cache, counts, tok, pos, draft, rng
+
+    return prefill_chunk_head, decode_block
 
 
 class ServingEngine:
@@ -398,6 +503,11 @@ class ServingEngine:
                 f"chunk {chunk} (+1 parking slot)"
             )
         validate_sampling(temperature, top_k, top_p)
+        if model.drafter is not None and temperature > 0:
+            raise ValueError(
+                "this model drafts (models.serving.Drafter) and the engine verifies a draft greedily: "
+                f"temperature {temperature} is not served (speculative sampling is not implemented); use 0"
+            )
         self.model = model
         self.cfg = model.cfg
         self.slots = slots
@@ -422,6 +532,8 @@ class ServingEngine:
         self._model_n = jax.tree.map(lambda a: np.zeros(a.shape, np.int64), self._counts)
         self._tok = jnp.zeros((slots,), jnp.int32)
         self._pos = jnp.zeros((slots,), jnp.int32)
+        # A model that drafts: each row's draft of the token after ``tok``.
+        self._draft = jnp.zeros((slots,), jnp.int32) if model.drafter is not None else None
         self._slots: list[Optional[_Slot]] = [None] * slots
         # Admissions whose first token is still on the device, in order.
         self._unread: list[tuple[_Slot, object]] = []
@@ -503,7 +615,9 @@ class ServingEngine:
         prompt = np.asarray(request.prompt, np.int32)
         p = prompt.shape[0]
         padded = -(-p // self.chunk) * self.chunk
-        buf = np.zeros((padded,), np.int32)
+        # A model that drafts gets each chunk with the token that follows it.
+        ahead = 0 if self._draft is None else 1
+        buf = np.zeros((padded + ahead,), np.int32)
         buf[:p] = prompt
         self._n["admitted"] += 1
         self._n["prefill_chunks"] += padded // self.chunk
@@ -527,15 +641,24 @@ class ServingEngine:
                     self._lap_to_dispatch()
                 hidden, self._cache, self._counts["prefill"] = self._prefill_chunk(
                     self._params, self._cache, self._counts["prefill"], slot_,
-                    buf[None, start : start + self.chunk], np.int32(start), np.int32(n_real),
+                    buf[None, start : start + self.chunk + ahead], np.int32(start), np.int32(n_real),
                 )
-                if head:
+                if head and self._draft is None:
                     # The last chunk's last VALID position (not the padded
                     # tail) feeds the first token, and the program that
                     # samples it sets the row's state: the head runs once a
                     # prompt, queued behind that chunk.
                     self._tok, self._pos, first, self._first_key = self._prefill_chunk_head(
                         self._params, self._cache, hidden, self._tok, self._pos, slot_,
+                        np.int32(p), self._first_key,
+                    )
+                elif head:
+                    # ... and, where the model drafts, leaves the row's first
+                    # draft there too (the drafter writes its state: the
+                    # cache goes in donated).
+                    (self._cache, self._tok, self._pos, self._draft, first,
+                     self._first_key) = self._prefill_chunk_head(
+                        self._params, self._cache, hidden, self._tok, self._pos, self._draft, slot_,
                         np.int32(p), self._first_key,
                     )
         self._n["prefill_head_chunks"] += 1
@@ -552,6 +675,17 @@ class ServingEngine:
         )
         self._slots[slot] = st
         self._unread.append((st, first))
+
+    def _attended_over(self, deepest) -> int:
+        """Slab positions a dispatch's walks read, from ``deepest [rows,
+        steps]``, each active row's deepest query at each step: whole blocks
+        up to it where the model's decode step reads per row, up to the
+        deepest row's where it does not."""
+        L = self.cfg.max_decode_len
+        deepest = np.minimum(deepest, L - 1)
+        if not self.model.decode_reads_per_row:
+            deepest = np.broadcast_to(deepest.max(axis=0), deepest.shape)
+        return int(self._attended(deepest + 1, L).sum())
 
     def _take_first(self) -> None:
         """Read each admission's first token, in admission order: the one
@@ -621,9 +755,10 @@ class ServingEngine:
         # behind this boundary's admissions.
         active = np.zeros((self.slots,), bool)
         active[active_rows] = True
+        drafting = self._draft is not None
         steps, sized_by = decode_steps(
             [self._slots[i].remaining for i in active_rows],
-            self.slots - len(active_rows), self.block,
+            self.slots - len(active_rows), self.block, 2 if drafting else 1,
         )
         t0 = time.time()
         rows = len(active_rows)
@@ -631,11 +766,18 @@ class ServingEngine:
             "engine.decode_dispatch", SPAN_CAT, rows=rows, steps=steps, sized_by=sized_by
         ):
             self._lap_to_dispatch()
-            (toks, self._cache, self._counts["decode"], self._tok, self._pos,
-             self._rng) = self._decode_block(
-                self._params, self._cache, self._counts["decode"], self._tok,
-                self._pos, active, self._rng, np.int32(steps),
-            )
+            if drafting:
+                (toks, took, self._cache, self._counts["decode"], self._tok, self._pos, self._draft,
+                 self._rng) = self._decode_block(
+                    self._params, self._cache, self._counts["decode"], self._tok,
+                    self._pos, self._draft, active, self._rng, np.int32(steps),
+                )
+            else:
+                (toks, self._cache, self._counts["decode"], self._tok, self._pos,
+                 self._rng) = self._decode_block(
+                    self._params, self._cache, self._counts["decode"], self._tok,
+                    self._pos, active, self._rng, np.int32(steps),
+                )
         self.host_lap("dispatch")
         # From here to the decode fence the device has the dispatch to run.
         if self._unread:
@@ -645,16 +787,17 @@ class ServingEngine:
         # What each step's attention reads of an active row: the blocks up
         # to the row's own position where the model's decode step reads per
         # row, else up to the deepest active row's; the program finds either
-        # from the same positions.
+        # from the same positions. Where the model drafts, a step's deepest
+        # query stands one position further and the rows advance on the
+        # device by one or two: what the host can say before the fence is
+        # the FLOOR (no draft accepted), which is what the fence's span
+        # carries; the counters take what the device did, after the fence.
         L = self.cfg.max_decode_len
         depths = np.array([self._slots[i].pos for i in active_rows])
         first_live = int(depths.sum()) + rows  # cache positions live at the dispatch's first step
-        if not self.model.decode_reads_per_row:
-            depths[:] = depths.max()
-        attended = int(
-            self._attended(np.minimum(depths[:, None] + np.arange(steps), L - 1) + 1, L).sum()
-        )
-        self._n["decode_attended_positions"] += attended
+        attended = self._attended_over(depths[:, None] + np.arange(steps) + drafting)
+        if not drafting:
+            self._n["decode_attended_positions"] += attended
         self.last_steps = steps
         self._n["decode_blocks"] += 1
         self._n["decode_steps"] += steps
@@ -669,13 +812,27 @@ class ServingEngine:
         self.host_lap("decode_fence")
         wall = time.time() - t0
         live = 0
-        with obs.span("engine.accept", SPAN_CAT):
-            for i in active_rows:
+        said = {}
+        if drafting:
+            # From the dispatch's own tallies: where each row stood at each
+            # step, so what its two queries had live and what the walk read.
+            took = np.asarray(took)[active_rows, :steps]
+            deepest = np.minimum(depths[:, None] + np.cumsum(took, axis=1) - took + 1, L - 1)
+            attended = self._attended_over(deepest)
+            self._n["decode_attended_positions"] += attended
+            self._n["decode_live_positions"] += int(deepest.sum()) + rows * steps
+            said = dict(drafted=rows * steps, accepted=int((took == 2).sum()), attended=attended)
+            # A step's tokens in order, those it delivered: [steps, 2] -> the row's stream.
+            toks = [toks[i][np.arange(2) < took[r][:, None]] for r, i in enumerate(active_rows)]
+        else:
+            toks = toks[active_rows]
+        with obs.span("engine.accept", SPAN_CAT, **said):
+            for i, stream in zip(active_rows, toks):
                 st = self._slots[i]
                 accepted, live0 = 0, st.pos + 1  # cache positions live at the row's first step
-                for t in toks[i]:
+                for t in stream:
                     if st.done:
-                        break
+                        break  # a token past the row's budget (a step's second) is dropped
                     self._accept_token(st, i, t)
                     accepted += 1
                 if accepted:
@@ -685,9 +842,10 @@ class ServingEngine:
                     # wait — aggregating wall/total_tokens would understate
                     # tpot by the concurrency factor).
                     self._tpot_samples.append(wall / accepted)
-                    self._n["decode_live_positions"] += (
-                        accepted * live0 + accepted * (accepted - 1) // 2
-                    )
+                    if not drafting:
+                        self._n["decode_live_positions"] += (
+                            accepted * live0 + accepted * (accepted - 1) // 2
+                        )
                 live += accepted
         if live:
             self._n["decode_tokens"] += live

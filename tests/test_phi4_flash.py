@@ -625,7 +625,8 @@ def test_the_manifest_declares_the_cell_and_its_metrics_as_the_issue_names_them(
     manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
     cell = next(w for w in manifest["workloads"] if w["name"] == "serve-phi4-mini-flash-reasoning")
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("phi4-mini-flash-serve", "reasoning-ctx1k-closed-120", 1)
-    assert manifest["workloads"][-1] is cell and len(manifest["workloads"]) == 6 and all(w["chips"] == 1 for w in manifest["workloads"])
+    # the sixth cell (later PRs append theirs behind it), and every cell one chip
+    assert manifest["workloads"][5] is cell and all(w["chips"] == 1 for w in manifest["workloads"])
     config = next(c for c in manifest["configs"] if c["name"] == cell["config"])
     assert config["reduced"] == [] and config["source"] == CELL["source"] and config["file"].endswith("phi4-mini-flash-serve.json")
     new = {"attn_cross_share_pct", "shared_kv_roofline_pct", "decode_step_hbm_roofline_pct", "prefill_cross_skip_pct"}
