@@ -67,6 +67,17 @@ def generator_stall_ms_per_s(ctx):
     return 1e3 * sum(stalls) / ctx["seconds"]
 
 
+def generator_supply_used_pct(ctx):
+    """The share of a closed loop's supply (the traffic file's ``supply``) that
+    the generator sent, window and all: at 100 the run fails
+    (``run.SupplyRanOut``), and the file's rule keeps it at 50 or under when it
+    is written (``traffic.schedule``). None for an open loop, which has none."""
+    sent, supply = (ctx.get("load") or {}).get("sent"), (ctx.get("traffic") or {}).get("supply")
+    if sent is None or not supply:
+        return None
+    return 100.0 * len(sent) / int(supply)
+
+
 def engine_decode_tok_s(ctx):
     return ctx["final"].get("decode_tokens_per_sec")
 

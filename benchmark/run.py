@@ -51,6 +51,12 @@ class BenchFailure(Exception):
     """The run cannot give a result; exit non-zero and print none."""
 
 
+class SupplyRanOut(BenchFailure):
+    """A closed loop asked for more requests than its traffic file holds: the
+    benchmark's fault, not the replica's. A window that was not fed to its end
+    has no honest rate, so this too is a run without a result."""
+
+
 def say(msg: str) -> None:
     print(msg, flush=True)
 
@@ -231,7 +237,11 @@ def drive_serve(spool: Path, job: subprocess.Popen, mix: dict, schedule: list,
             while len(in_flight) < int(mix["clients"]):
                 rec = next(supply, None)
                 if rec is None:
-                    raise BenchFailure("closed loop: the supply of requests ran out inside the window")
+                    raise SupplyRanOut(
+                        f"closed loop: traffic file {mix.get('name')!r} holds a supply of {len(schedule)} requests and "
+                        f"the last was drawn {time.time() - t0:.1f} s into a window of {seconds:g} s, with "
+                        f"{sum(r['id'] in answers for r in sent)} answers read: the program outran the traffic file. "
+                        "Raise its `supply` (to twice what a window sends, traffic.py) in a `benchmark` PR")
                 send(rec, time.time())
                 sent.append(rec)
                 in_flight.add(rec["id"])
@@ -358,6 +368,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             proc.wait(timeout=STARTUP_S + seconds if role == "train" else 240)
         except subprocess.TimeoutExpired:
             raise BenchFailure("the job did not end") from None
+    except SupplyRanOut:
+        raise  # the traffic file's fault: the replica's log has nothing to say about it
     except BenchFailure as e:
         raise BenchFailure(f"{e} (no accelerator, or the replica failed):\n{log_tails(state)}") from None
     finally:
@@ -397,6 +409,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         # A window in which the whole machine stood still reads as a slow server.
         say(f"generator sleeps that overran by {1e3 * STALL_S:g} ms or more: {len(load['stalls'])}, "
             f"{sum(load['stalls']):.3f} s in all, the longest {max(load['stalls'], default=0.0):.3f} s")
+        if mix["loop"] == "closed":  # how near the window came to the traffic file's end (traffic.py: the rule)
+            say(f"generator sent {attempted} of the traffic file's supply of {len(schedule)} requests: "
+                f"{100.0 * attempted / len(schedule):.1f}%")
         ttft = [a["ttft_ms"] for a in good]
         e2e["ttft_mean_ms"] = M.mean(ttft)
         say(f"time to first token ms over {len(ttft)} requests: mean {e2e['ttft_mean_ms']} "

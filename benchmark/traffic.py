@@ -70,7 +70,13 @@ def prompt_tokens(seed: int, index: int, length: int, vocab: int) -> list:
 def schedule(mix: dict, seed: int, seconds: float, vocab: int) -> list:
     """The requests of one run. Open loop: one per arrival, with its due
     time. Closed loop: an ordered supply the clients draw from (due None),
-    long enough that no window exhausts it."""
+    all of it made before the job starts. The rule for its length: the file's
+    ``supply`` is at least twice its ``sent_a_window`` (what one window of the
+    cell sends today, a chip run recorded in the file) and a multiple of the
+    table's length, so that a longer supply begins with the shorter one's
+    requests. A window that asks for more fails the run (``run.SupplyRanOut``):
+    no cell fails before its program is twice as fast. The per-layer metric
+    ``generator_supply_used_pct.serve_tps`` shows how near a run came."""
     if mix["loop"] == "open":
         due = arrival_times(mix, seed, seconds)
     elif mix["loop"] == "closed":

@@ -27,11 +27,11 @@ def test_declared_beside_the_yield_of_the_same_cell(name):
     suffix = name.rsplit(".", 1)[-1]
     (beside,) = [m for m in MANIFEST["per_layer"] if m["name"] == {
         "ttft": "claim_wait_mean_ms", "serve_tps": "decode_yield_pct.serve_tps"}[suffix]]
-    assert entry["workloads"] == [CELL_OF[name]] == beside["workloads"]
+    # The cell PR 25 gave it, among those later PRs appended (PR 28 on), and the yield's too.
+    assert CELL_OF[name] in entry["workloads"] and CELL_OF[name] in beside["workloads"]
     assert (entry["moves"], entry["layer"], entry["source"]) == (beside["moves"], beside["layer"], "program_counter")
-    # Appended by PR 25, and nothing moved since: only what later PRs appended follows (PR 26: the load generator's).
-    later = [m["name"] for m in MANIFEST["per_layer"][MANIFEST["per_layer"].index(entry) + 1:]]
-    assert all(n in CELL_OF or n.startswith("generator_") for n in later), later
+    # Appended after PR 24's; what later PRs appended follows, and is theirs to pin.
+    assert MANIFEST["per_layer"].index(entry) > MANIFEST["per_layer"].index(beside)
 
 
 @pytest.mark.parametrize("name", sorted(CELL_OF))
