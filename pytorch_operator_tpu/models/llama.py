@@ -487,30 +487,6 @@ class Attention(nn.Module):
             # The incoming S tokens sit at contiguous positions starting
             # at positions[:, 0] (prefill: the prompt or a chunk of it;
             # decode: one token at the current index).
-            if cfg.decode_per_row and slot is None:
-                # Per-row write offsets: a batched update-slice (XLA
-                # lowers the vmapped DUS to a scatter). Only the serving
-                # engine's mixed-depth batches pay this; the uniform
-                # path below stays a single DUS.
-                starts = positions[:, 0]
-
-                def write(slab, vals):
-                    return jax.vmap(
-                        lambda c, u, s: jax.lax.dynamic_update_slice(
-                            c, u, (0, s, 0)
-                        )
-                    )(slab, vals, starts)
-
-            else:
-                # One update-slice: over rows [0, B), or at row ``slot``
-                # of slabs that hold every slot.
-                row, start = 0 if slot is None else slot, positions[0, 0]
-
-                def write(slab, vals):
-                    return jax.lax.dynamic_update_slice(
-                        slab, vals, (row, 0, start, 0)
-                    )
-
             k_in = k.swapaxes(1, 2)  # [B, K, S, D]
             v_in = v.swapaxes(1, 2)
             if kv8:
@@ -518,13 +494,44 @@ class Attention(nn.Module):
 
                 with jax.named_scope("kv_quantize"):
                     kq, vq = quantize(k_in, axis=-1), quantize(v_in, axis=-1)
-                ck.value = write(ck.value, kq.q)
-                ks.value = write(ks.value, kq.scale)
-                cv.value = write(cv.value, vq.q)
-                vs.value = write(vs.value, vq.scale)
+                leaves, vals = (ck, ks, cv, vs), (kq.q, kq.scale, vq.q, vq.scale)
             else:
-                ck.value = write(ck.value, k_in.astype(cfg.dtype))
-                cv.value = write(cv.value, v_in.astype(cfg.dtype))
+                leaves, vals = (ck, cv), (k_in.astype(cfg.dtype), v_in.astype(cfg.dtype))
+            per_row = cfg.decode_per_row and slot is None
+            if per_row and S == 1:
+                # A serving decode step: every row's one position at the
+                # row's own place, the layer's leaves (an int8 cache's
+                # scales with them) in ONE pass over the rows
+                # (ops/cache_write.py), not a scatter loop a leaf. As a
+                # function of the program: the layers' calls are alike,
+                # so the kernel is traced and lowered once a program and
+                # not once a layer (0.2 s of start-up each).
+                from ..ops.cache_write import write_rows
+
+                with jax.named_scope("cache_write"):
+                    new = jax.jit(write_rows)(
+                        [leaf.value for leaf in leaves], vals, positions[:, 0]
+                    )
+            elif per_row:
+                # Several positions a row at per-row offsets (a prompt
+                # through the per-row model): a batched update-slice,
+                # which XLA lowers to a scatter.
+                new = [
+                    jax.vmap(
+                        lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s, 0))
+                    )(leaf.value, val, positions[:, 0])
+                    for leaf, val in zip(leaves, vals)
+                ]
+            else:
+                # One update-slice: over rows [0, B), or at row ``slot``
+                # of slabs that hold every slot.
+                at = (0 if slot is None else slot, 0, positions[0, 0], 0)
+                new = [
+                    jax.lax.dynamic_update_slice(leaf.value, val, at)
+                    for leaf, val in zip(leaves, vals)
+                ]
+            for leaf, value in zip(leaves, new):
+                leaf.value = value
         if S > 1 and cfg.prefill_mode == "self":
             # PREFILL (mode "self"): the prompt lands at positions
             # [0, S) of a fresh cache, so causal attention over the

@@ -201,7 +201,8 @@ def _decode_attention(q, positions, k, v):
     ~100 MiB of them (a slab of 32k positions with 32 key heads) the
     chip's compiler refuses the kernel."""
     # Imported here: the library takes about a second, and a process that
-    # serves an int8 cache or trains never needs it.
+    # trains never needs it (one that serves has it from workloads/serve.py's
+    # thread).
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

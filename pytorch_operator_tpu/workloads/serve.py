@@ -90,15 +90,15 @@ def run(
         quantize=quantize,
         kv_quantize=kv_quantize,
     )
-    if cfg.serving_model().decode_reads_per_row:
-        # Its decode step is Pallas kernels (ops/cache_attention.py; the
-        # layer-list families' write too, ops/cache_write.py), whose
-        # library takes about a second of Python to import: brought in on a
-        # thread beside the backend's start and the weights, which wait on
-        # the device and the compile cache, not in front of the first dispatch.
-        threading.Thread(
-            target=importlib.import_module, args=("jax.experimental.pallas.tpu",), daemon=True
-        ).start()
+    # Every served family's decode step holds Pallas kernels (the write,
+    # ops/cache_write.py; over a plain slab the walk too,
+    # ops/cache_attention.py), whose library takes about a second of Python
+    # to import: brought in on a thread beside the backend's start and the
+    # weights, which wait on the device and the compile cache, not in front
+    # of the first dispatch.
+    threading.Thread(
+        target=importlib.import_module, args=("jax.experimental.pallas.tpu",), daemon=True
+    ).start()
     log(
         f"[serve] config={config} slots={slots} chunk={chunk} "
         f"block={block} L={max_decode_len} spool={spool_dir} "

@@ -1,10 +1,11 @@
 """A decode step's cache write (ops/cache_write.py behind
-``layer_list.write_positions``) puts the same values at the same places as
-the per-row update-slices it replaced and touches nothing else: the whole
-leaf compared bit for bit at the three layer-list families' leaf shapes
-(here under the Pallas interpreter), through a leaf of NaNs, for a ring's
-``pos``, and through each family's ``decode_block``. A chunk keeps the
-scatter and traces no kernel.
+``layer_list.write_positions`` and the llama family's ``_decode_attend``) puts
+the same values at the same places as the per-row update-slices it replaced
+and touches nothing else: the whole leaf compared bit for bit at the three
+layer-list families' leaf shapes and at an int8 layer's four (keys, values
+and their float32 scales; here under the Pallas interpreter), through a leaf
+of NaNs, for a ring's ``pos``, and through each family's ``decode_block``. A
+chunk keeps the scatter and traces no kernel.
 """
 
 from __future__ import annotations
@@ -13,7 +14,7 @@ import numpy as np
 import pytest
 
 import tests.jaxenv  # noqa: F401
-from pytorch_operator_tpu.models import layer_list, mimo_v2, nemotron_h, phi4_flash
+from pytorch_operator_tpu.models import layer_list, llama, mimo_v2, nemotron_h, phi4_flash
 from pytorch_operator_tpu.ops import cache_write
 
 # (key heads, positions, key size, value size, ring?) of a layer's leaves at the cells' sizes; the rows are few.
@@ -27,6 +28,13 @@ LEAVES = {
 }
 
 
+def update_slices(slabs, vals, idx):
+    """``write_rows`` as a decode step stood before it (the llama family's own form): an update-slice a row, a leaf at a time."""
+    import jax
+
+    return [jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (0, i, 0)))(slab, val, idx) for slab, val in zip(slabs, vals)]
+
+
 def parent_form(cache: dict, k, v, positions) -> dict:
     """``write_positions`` as it stood before the kernel: an update-slice a
     row for a decode step (a scatter for a chunk), ``pos`` set a row."""
@@ -34,12 +42,10 @@ def parent_form(cache: dict, k, v, positions) -> dict:
 
     idx = positions % cache["k"].shape[2]
 
-    def leaf(slab, vals):
-        if idx.shape[1] == 1:
-            return jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (0, i, 0)))(slab, vals, idx[:, 0])
-        return jax.vmap(lambda c, u, i: c.at[:, i].set(u))(slab, vals, idx)
-
-    new = {"k": leaf(cache["k"], k), "v": leaf(cache["v"], v)}
+    if idx.shape[1] == 1:
+        new = dict(zip("kv", update_slices((cache["k"], cache["v"]), (k, v), idx[:, 0])))
+    else:
+        new = {leaf: jax.vmap(lambda c, u, i: c.at[:, i].set(u))(cache[leaf], vals, idx) for leaf, vals in (("k", k), ("v", v))}
     if "pos" in cache:
         new["pos"] = jax.vmap(lambda c, u, i: c.at[i].set(u))(cache["pos"], positions, idx)
     return new
@@ -57,7 +63,7 @@ def ragged(T: int, ring: bool):
 
 def bits(a):
     a = np.asarray(a)
-    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+    return a.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
 
 
 def layer_cache(name, dtype, rng, fill=None):
@@ -142,10 +148,85 @@ def test_a_decode_step_is_the_kernel_under_its_scope_and_a_chunk_traces_none():
     assert all(np.array_equal(bits(got[leaf]), bits(want[leaf])) for leaf in want)
 
 
+# Positions of an int8 layer (the llama family's ``--kv-quantize int8``): the InternLM2 cells' 4,096 (a scale leaf
+# seen as [.., 32, 128], blocks of 1,024 positions), lengths 1,024 divides once and twice, one that 128 divides and
+# 1,024 does not and the tiny caches' (the scale's block is its whole axis; at 40 the int8 tile of 32 is too).
+INT8_LENGTHS = [4096, 2048, 1024, 1152, 64, 40]
+
+
+def int8_places(L: int):
+    """A row each at: position 0, a tile of 32's last and the next one's first, a row of 128 lanes' last and the
+    next one's first, a block of 1,024's last and the next one's first, a second row inside that block, the last."""
+    return np.asarray(sorted({p for p in (0, 31, 32, 127, 128, 1023, 1024, 1500, L - 1) if p < L}), np.int32)
+
+
+def int8_layer(L: int, rng, poisoned: bool = False, heads: int = 2, size: int = 128):
+    """An int8 layer's four leaves in ``_decode_attend``'s order (keys, their scales, values, theirs) with every
+    position filled (``poisoned``: a sentinel in the payloads, NaN in the scales), a step's values, the rows' places."""
+    import jax.numpy as jnp
+
+    at = int8_places(L)
+    B = len(at)
+    payload = lambda n: jnp.asarray(np.full((B, heads, n, size), -77) if poisoned and n == L else rng.integers(-127, 128, (B, heads, n, size)), jnp.int8)  # noqa: E731
+    scale = lambda n: jnp.asarray(np.full((B, heads, n, 1), np.nan) if poisoned and n == L else rng.uniform(1e-3, 1.0, (B, heads, n, 1)), jnp.float32)  # noqa: E731
+    return [payload(L), scale(L), payload(L), scale(L)], [payload(1), scale(1), payload(1), scale(1)], jnp.asarray(at)
+
+
+@pytest.mark.parametrize("L", INT8_LENGTHS)
+def test_an_int8_layers_write_is_the_update_slices_bit_for_bit(L):
+    import jax
+
+    slabs, vals, idx = int8_layer(L, np.random.default_rng(5))
+    got = jax.jit(cache_write.write_rows)(slabs, vals, idx)
+    want = jax.jit(update_slices)(slabs, vals, idx)
+    assert len(got) == len(want) == 4
+    for leaf, (a, b, new) in enumerate(zip(got, want, vals)):
+        assert a.dtype == b.dtype and a.shape == b.shape == slabs[leaf].shape
+        assert np.array_equal(bits(a), bits(b)), leaf
+        # and the places are the ones meant
+        for row, p in enumerate(np.asarray(idx)):
+            assert np.array_equal(bits(a[row, :, p]), bits(new[row, :, 0])), (leaf, row, p)
+
+
+@pytest.mark.parametrize("L", [4096, 1152, 40])
+def test_an_int8_layers_other_positions_keep_their_bits(L):
+    """Scale leaves of NaNs and payloads of a sentinel: outside the rows' own places every bit is what it was
+    (whichever view of the scales the kernel took), and what was written is the new value alone."""
+    import jax
+
+    slabs, vals, idx = int8_layer(L, np.random.default_rng(6), poisoned=True)
+    got = jax.jit(cache_write.write_rows)(slabs, vals, idx)
+    at = np.asarray(idx)
+    for leaf, (out, before, new) in enumerate(zip(got, slabs, vals)):
+        out, before = np.asarray(out), np.asarray(before)
+        written = np.zeros(out.shape, bool)
+        written[np.arange(len(at)), :, at] = True
+        assert np.array_equal(bits(out)[~written], bits(before)[~written]), leaf
+        assert np.array_equal(bits(out[np.arange(len(at)), :, at]), bits(np.asarray(new)[:, :, 0])), leaf
+        if out.dtype == np.float32:
+            assert np.isfinite(out[written]).all() and np.isnan(out[~written]).all()
+
+
+def test_an_int8_layer_goes_through_one_call_whichever_view_its_scales_take():
+    import jax
+
+    for L in (4096, 40):
+        slabs, vals, idx = int8_layer(L, np.random.default_rng(7), heads=1, size=8)
+        step = jax.make_jaxpr(cache_write.write_rows)(slabs, vals, idx)
+        kernels = [e for e in step.eqns if e.primitive.name == "pallas_call"]
+        assert len(kernels) == 1 and "scatter" not in str(step)
+        # the scales go in as rows of 128 lanes where blocks of 1,024 positions divide them, else as their one row
+        seen = [v.aval.shape for v in kernels[0].invars if v.aval.shape[:1] == (len(idx),) and v.aval.dtype == np.float32 and v.aval.shape[2:] != (1, 1)]
+        assert seen == [(len(idx), 1, *((L // 128, 128) if L % 1024 == 0 else (1, L)))] * 2, seen
+
+
 FAMILIES = {
     "mimo": lambda: mimo_v2.mimo_v2_tiny(decode=True, max_decode_len=64),
     "nemotron": lambda: nemotron_h.nemotron_h_tiny(decode=True, max_decode_len=64),
     "phi4": lambda: phi4_flash.phi4_flash_tiny(decode=True, max_decode_len=64),
+    # the llama family writes through ``write_rows`` itself: a plain cache's two leaves, an int8 one's four
+    "llama": lambda: llama.llama_tiny(decode=True, max_decode_len=64),
+    "llama_int8": lambda: llama.llama_tiny(decode=True, max_decode_len=64, kv_quantize="int8"),
 }
 
 
@@ -169,7 +250,9 @@ def test_a_whole_decode_block_leaves_the_cache_the_parent_form_leaves(family, mo
     def filled():
         # Every position holds something: a write that strayed would show.
         return jax.tree.map(
-            lambda a: jnp.asarray(rng.integers(0, 40, a.shape) if a.dtype == jnp.int32 else rng.normal(size=a.shape) * 0.1, a.dtype),
+            lambda a: jnp.asarray(
+                rng.integers(0, 40, a.shape) if a.dtype == jnp.int32 else rng.integers(-127, 128, a.shape) if a.dtype == jnp.int8
+                else rng.normal(size=a.shape) * 0.1, a.dtype),
             model.init_cache(SLOTS, CHUNK),
         )
 
@@ -186,6 +269,7 @@ def test_a_whole_decode_block_leaves_the_cache_the_parent_form_leaves(family, mo
 
     toks, new = run()
     monkeypatch.setattr(layer_list, "write_positions", parent_form)
+    monkeypatch.setattr(cache_write, "write_rows", update_slices)  # the llama family's: looked up at each trace
     if family == "mimo":
         monkeypatch.setattr(mimo_v2, "write_positions", parent_form)
     want_toks, want = run()
