@@ -3,11 +3,13 @@ file holds (``supply`` at least twice what a window sends today, a multiple of
 the table's length), that a longer supply begins with the shorter one's
 requests, the failure of a run whose supply does run out (its own message, its
 own exception, never "no accelerator"), and the per-layer metric that shows how
-near a run came. The load generator runs here against a made-up spool: a
+near a run came. The rules on the files hold for the real tree and for a copy
+with cells appended (``rules.py``). The load generator runs here against a made-up spool: a
 thread that answers each request file at once. No JAX, no replica."""
 
 from __future__ import annotations
 
+import copy
 import functools
 import json
 import os
@@ -20,22 +22,18 @@ import pytest
 from benchmark import run
 from benchmark import traffic as T
 from tests.zz_benchmark.benchcells import make_copy
+from tests.zz_benchmark.rules import REAL, Tree, appended, copy_cases, over  # noqa: F401
 
-ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).resolve().parent / "data"
-MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
-MIXES = sorted(p.stem for p in (ROOT / "benchmark" / "traffic").glob("*.json"))
-CLOSED = [m for m in MIXES if T.load(m).get("loop") == "closed"]
-CLOSED_CELLS = [w["name"] for w in MANIFEST["workloads"] if w["traffic"] in CLOSED]
 METRIC = "generator_supply_used_pct.serve_tps"
 
 
 # ---- the rule, on the files ----
 
 
-@pytest.mark.parametrize("mix_name", CLOSED)
-def test_supply_is_twice_what_a_window_sends_and_whole_tables(mix_name):
-    mix = T.load(mix_name)
+@over("mix_name", lambda tree: tree.closed)
+def test_supply_is_twice_what_a_window_sends_and_whole_tables(mix_name, tree=REAL):
+    mix = tree.mixes[mix_name]
     sent = mix["sent_a_window"]
     assert isinstance(sent["requests"], int) and sent["requests"] > int(mix["clients"])  # more than the first wave
     assert mix["supply"] >= 2 * sent["requests"], "no cell fails before its program is twice as fast"
@@ -44,32 +42,35 @@ def test_supply_is_twice_what_a_window_sends_and_whole_tables(mix_name):
     assert "supply_note" in mix and "2 x" in mix["supply_note"]
 
 
-def test_every_closed_cell_reports_the_metric_and_no_open_one():
-    (entry,) = [m for m in MANIFEST["per_layer"] if m["name"] == METRIC]
-    assert sorted(entry["workloads"]) == sorted(CLOSED_CELLS) and len(CLOSED_CELLS) == len(CLOSED) == 5
+def test_every_closed_cell_reports_the_metric_and_no_open_one(tree=REAL):
+    (entry,) = [m for m in tree.manifest["per_layer"] if m["name"] == METRIC]
+    assert tree.closed_cells and sorted(entry["workloads"]) == sorted(tree.closed_cells)
+    sent = {w["traffic"] for w in tree.manifest["workloads"] if w["name"] in tree.closed_cells}
+    assert sent == set(tree.closed), "a closed mix that no cell sends: no chip run holds its supply to the rule"
     assert (entry["layer"], entry["moves"], entry["source"], entry["better"], entry["unit"]) == (
         "load generator", "serve_tokens_per_s", "host_clock", "lower", "%")
 
 
-@pytest.mark.parametrize("mix_name", CLOSED)
-def test_a_longer_supply_begins_with_the_shorter_ones_requests(mix_name):
-    mix = T.load(mix_name)
+@over("mix_name", lambda tree: tree.closed)
+def test_a_longer_supply_begins_with_the_shorter_ones_requests(mix_name, tree=REAL):
+    mix = tree.mixes[mix_name]
     rows = len(mix["lengths"])
     whole = T.schedule(mix, 2**31 + 4321, 50.0, 1000)
     assert len(whole) == mix["supply"]
-    for supply in (rows, mix["supply"] // 2):
+    for supply in (rows, rows * (mix["supply"] // rows // 2)):  # one table, and half the supply's tables
         assert T.schedule({**mix, "supply": supply}, 2**31 + 4321, 50.0, 1000) == whole[:supply]
 
 
 @pytest.mark.parametrize("seed", [7, 2147484941, 2**31 + 2**20 + 63])
-def test_longprompts_first_512_requests_are_those_of_the_supply_of_512(seed):
-    """The file's history stays comparable: PR 23 to PR 42 ran it with ``supply`` 512, and the same seed still
-    gets those requests first (ids, lengths, token values), whatever row of the table it enters at."""
-    mix = T.load("longprompt-closed")
-    assert mix["supply"] == 1024 and "cycle_entry" not in mix
-    before = T.schedule({**mix, "supply": 512}, seed, 50.0, 92544)
+def test_longprompts_first_512_requests_are_those_of_the_supply_of_512(seed, tree=REAL):
+    """The file's history stays comparable: PR 23 to PR 42 ran it with ``supply`` 512 and PR 43 to PR 44 with
+    1,024, and the same seed still gets those requests first (ids, lengths, token values), whatever row of the
+    table it enters at."""
+    mix = tree.mixes["longprompt-closed"]
+    assert mix["supply"] > 1024 and "cycle_entry" not in mix
     now = T.schedule(mix, seed, 50.0, 92544)
-    assert now[:512] == before and len(now) == 1024
+    for supply in (512, 1024):
+        assert now[:supply] == T.schedule({**mix, "supply": supply}, seed, 50.0, 92544)
     table = [tuple(p) for p in mix["lengths"]]
     k = seed % len(table)
     assert [(r["prompt_len"], r["max_new_tokens"]) for r in now[:3]] == (table[k:] + table[:k])[:3]
@@ -206,9 +207,10 @@ def recorded(cell: str) -> dict:
 
 def test_the_reader_on_a_recorded_context_is_sent_over_supply():
     ctx = recorded("serve-internlm2-longprompt")
-    assert run.read_layer_metric(METRIC, ctx) == pytest.approx(100.0 * 477 / 1024) and ctx["traffic"]["supply"] == 1024
+    supply = ctx["traffic"]["supply"]
+    assert run.read_layer_metric(METRIC, ctx) == pytest.approx(100.0 * 477 / supply) and supply >= 2 * 477
     ctx["load"]["sent"] = ctx["load"]["sent"][:16]
-    assert run.read_layer_metric(METRIC, ctx) == pytest.approx(100.0 * 16 / 1024)  # never 0 where a window ran
+    assert run.read_layer_metric(METRIC, ctx) == pytest.approx(100.0 * 16 / supply)  # never 0 where a window ran
 
 
 @pytest.mark.parametrize("ctx", [
@@ -218,3 +220,23 @@ def test_the_reader_on_a_recorded_context_is_sent_over_supply():
 def test_nothing_to_read_is_none(ctx):
     ctx = recorded("serve-internlm2-chat") if ctx is None else ctx
     assert run.read_layer_metric(METRIC, ctx) is None
+
+
+# ---- the rules on the files, on a copy with cells appended ----
+
+
+@pytest.mark.parametrize("rule, item", copy_cases(globals()))
+def test_the_rule_holds_on_a_copy_with_cells_appended(rule, item, appended):
+    rule(*item, tree=appended)
+
+
+def test_a_closed_mix_that_no_cell_sends_fails_the_rule(appended):
+    """The copy's ``tiny-closed-b`` is one cell's alone: without that cell its file would lie under
+    ``benchmark/traffic/`` with a supply that no chip run reads."""
+    manifest = copy.deepcopy(appended.manifest)
+    manifest["workloads"] = [w for w in manifest["workloads"] if w["name"] != "tiny-long-b"]
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        if "tiny-long-b" in m.get("workloads", []):
+            m["workloads"].remove("tiny-long-b")
+    with pytest.raises(AssertionError, match="a closed mix that no cell sends"):
+        test_every_closed_cell_reports_the_metric_and_no_open_one(tree=Tree(appended.root, manifest))

@@ -1,24 +1,21 @@
 """The traffic generator: the same seed gives the same schedule, every seed
 the same work (lengths and gaps as multisets) in another order, and the
-stated lengths and rate hold."""
+stated lengths and rate hold: of every serving mix of the real tree, and of
+a copy with cells appended (``rules.py``)."""
 
 from __future__ import annotations
 
-import json
-from pathlib import Path
+import copy
 
 import pytest
 
 from benchmark import traffic as T
-
-ROOT = Path(__file__).resolve().parents[2]
-MIXES = sorted(p.stem for p in (ROOT / "benchmark" / "traffic").glob("*.json"))
-SERVE_MIXES = [m for m in MIXES if "loop" in T.load(m)]
+from tests.zz_benchmark.rules import REAL, Tree, appended, copy_cases, over  # noqa: F401
 
 
-@pytest.mark.parametrize("mix_name", SERVE_MIXES)
-def test_same_seed_same_schedule_other_seed_same_work(mix_name):
-    mix = T.load(mix_name)
+@over("mix_name", lambda tree: tree.serve_mixes)
+def test_same_seed_same_schedule_other_seed_same_work(mix_name, tree=REAL):
+    mix = tree.mixes[mix_name]
     a, b = T.schedule(mix, 12345, 20.0, 1000), T.schedule(mix, 12345, 20.0, 1000)
     assert a == b
     c = T.schedule(mix, 2**31 + 77, 20.0, 1000)  # the driver's seeds are large
@@ -28,14 +25,22 @@ def test_same_seed_same_schedule_other_seed_same_work(mix_name):
     assert all(len(r["prompt"]) == r["prompt_len"] and all(0 <= t < 1000 for t in r["prompt"]) for r in c)
 
 
-@pytest.mark.parametrize("mix_name", SERVE_MIXES)
-def test_lengths_are_the_tables_and_within_the_stated_range(mix_name):
-    mix = T.load(mix_name)
+@over("mix_name", lambda tree: tree.serve_mixes)
+def test_lengths_are_the_tables_and_within_the_stated_range(mix_name, tree=REAL):
+    """The longest prompt + answer fits the check's padding, and the cache of every cell that sends the mix
+    (``bench.engine.max_decode_len`` of its configuration, less the margin 4,095 kept against 4,096); a mix no
+    cell sends, the shallowest cache any served configuration states."""
+    mix = tree.mixes[mix_name]
     table = {tuple(p) for p in mix["lengths"]}
     sched = T.schedule(mix, 3, 30.0, 50)
     assert {(r["prompt_len"], r["max_new_tokens"]) for r in sched} <= table
     longest = max(p + a for p, a in table)
-    assert longest <= mix["check_pad_to"] and longest < 4095  # fits the engine's cache and the check's padding
+    assert longest <= mix["check_pad_to"]
+    depth = {w["name"]: bench["engine"]["max_decode_len"] for w in tree.manifest["workloads"]
+             for bench in [tree.config_of(w)["bench"]] if "engine" in bench}  # of every served cell
+    sent_by = [w["name"] for w in tree.manifest["workloads"] if w["traffic"] == mix_name]
+    caches = {cell: depth[cell] for cell in sent_by or depth}
+    assert caches and longest < min(caches.values()) - 1, f"{mix_name}: {longest} positions against max_decode_len {caches}"
 
 
 def test_open_loop_rate_and_gaps():
@@ -65,8 +70,8 @@ def test_closed_loop_supply_and_unknown_loop():
         T.load("no-such-mix")
 
 
-def test_chat_file_records_its_sweep():
-    mix = json.loads((ROOT / "benchmark" / "traffic" / "chat-poisson.json").read_text())
+def test_chat_file_records_its_sweep(tree=REAL):
+    mix = tree.mixes["chat-poisson"]
     assert mix["swept"], "the swept rates and what each gave belong in the traffic file"
     assert any(abs(row["rate_per_s"] * 0.8 - mix["rate_per_s"]) < 0.26 for row in mix["swept"] if row.get("knee"))
 
@@ -95,3 +100,27 @@ def test_the_seed_enters_one_fixed_cycle(entry):
 def test_the_chat_cell_enters_its_cycle_at_one_row():
     """PR 26: its seeds lay apart and its repeats together (PERF.md), so the seed chooses token values alone."""
     assert T.load("chat-poisson")["cycle_entry"] == 0 and "cycle_entry" not in T.load("longprompt-closed")
+
+
+# ---- the same rules, on a copy with cells appended ----
+
+
+@pytest.mark.parametrize("rule, item", copy_cases(globals()))
+def test_the_rule_holds_on_a_copy_with_cells_appended(rule, item, appended):
+    rule(*item, tree=appended)
+
+
+@pytest.mark.parametrize("config", [
+    pytest.param("internlm2-1.8b-serve", id="under-a-cache-of-4096"), pytest.param(None, id="sent-by-no-cell")])
+def test_a_mix_deeper_than_the_cache_it_meets_fails_the_rule(appended, config):
+    """The copy's deep mix (6,000 positions) holds the rule under its own configuration (8192, the test above).
+    Under a configuration whose cache holds 4,096 it does not, nor where no cell sends it and the copy's
+    shallowest cache judges it."""
+    manifest = copy.deepcopy(appended.manifest)
+    (deep,) = [w for w in manifest["workloads"] if w["name"] == "tiny-deep"]
+    if config:
+        deep["config"] = config
+    else:
+        manifest["workloads"].remove(deep)
+    with pytest.raises(AssertionError, match="tiny-deep-closed: 6000 positions against max_decode_len"):
+        test_lengths_are_the_tables_and_within_the_stated_range("tiny-deep-closed", tree=Tree(appended.root, manifest))
