@@ -1,5 +1,6 @@
 """What the families that serve an explicit LIST of layers share
-(``models/mimo_v2.py``, ``models/nemotron_h.py``, ``models/phi4_flash.py``):
+(``models/mimo_v2.py``, ``models/nemotron_h.py``, ``models/phi4_flash.py``,
+``models/jamba.py``):
 seeded leaves drawn a layer at a time in the serving dtype, RMSNorm, the
 write of a step's or a chunk's keys and values into a layer's slab or ring
 (a decode step's through the kernel of ``ops/cache_write.py``), the head's
@@ -121,10 +122,72 @@ def write_positions(cache: dict, k, v, positions) -> dict:
     return new
 
 
+def slab_attention(w: dict, cache: dict, x, positions, *, dtype, slot=None, hold=None):
+    """Grouped-query attention without a position embedding or bias for ``x
+    [B, S, D]`` at ``positions [B, S]`` (contiguous in a row) against a layer's
+    full-length slabs ``{k, v} [slots, Hk, L, d]``; the heads' counts and size
+    are the leaves' (``q_proj [D, H, d]``, ``k_proj`` / ``v_proj [D, Hk, d]``,
+    ``o_proj [H d, D]``). The incoming keys and values are written into the
+    slabs first (a chunk at row ``slot``, in place; a decode step a position
+    a row), then the queries attend the filled prefix
+    (ops/cache_attention.py). A decode step's rows that ``hold [B]`` (None =
+    none does) write at the parking position ``L - 1`` that no live stream
+    attends, and attend one block, whose result nobody reads
+    (``ServingModel.holds``). Returns (out [B, S, D], new cache)."""
+    from ..ops.cache_attention import cache_attention
+
+    B, S, _ = x.shape
+    (_, H, d), Hk = w["q_proj"].shape, w["k_proj"].shape[1]
+    q = jnp.einsum("bsd,dhe->bshe", x, w["q_proj"]).reshape(B, S, Hk, H // Hk, d)
+    k = jnp.einsum("bsd,dke->bkse", x, w["k_proj"]).astype(dtype)
+    v = jnp.einsum("bsd,dke->bkse", x, w["v_proj"]).astype(dtype)
+    if slot is not None:
+        at = (slot, 0, positions[0, 0], 0)
+        new = {
+            "k": jax.lax.dynamic_update_slice(cache["k"], k, at),
+            "v": jax.lax.dynamic_update_slice(cache["v"], v, at),
+        }
+    elif hold is None:
+        new = write_positions(cache, k, v, positions)
+    else:
+        new = write_positions(cache, k, v, jnp.where(hold[:, None], cache["k"].shape[2] - 1, positions))
+        positions = jnp.where(hold[:, None], 0, positions)
+    out = cache_attention(q, positions, new["k"], new["v"], slot=slot)
+    return out.reshape(B, S, H * d) @ w["o_proj"], new
+
+
 def logits(params: dict, hidden):
     """Float32 logits of ``hidden [..., D]``: the head's product accumulates
     in float32 from the operands as they are held."""
     return jnp.dot(hidden, params["lm_head"]["kernel"], preferred_element_type=jnp.float32)
+
+
+def tied_logits(params: dict, hidden):
+    """Float32 logits of ``hidden [..., D]`` against the embedding (a tied
+    head; no head bias), accumulated in float32 from the operands as they are
+    held."""
+    return jax.lax.dot_general(
+        hidden, params["embed"]["embedding"], (((hidden.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def gated_mlp(w: dict, x):
+    """SwiGLU without bias, the gate and up projections fused: ``(u *
+    silu(g)) W_down`` with ``[g | u] = x W_gate_up``."""
+    F = w["down"].shape[0]
+    gu = x @ w["gate_up"]
+    return (gu[..., F:] * jax.nn.silu(gu[..., :F])) @ w["down"]
+
+
+def state_cache_bytes(cache: dict) -> dict:
+    """The two gauges of a hybrid's cache: bytes held by the attention
+    layers' full-length slabs, and by the state-space layers' constant state."""
+    size = lambda state: sum(a.size * a.dtype.itemsize for a in state.values())
+    return {
+        "cache_full_bytes": sum(size(s) for s in cache.values() if "k" in s and "pos" not in s),
+        "cache_state_bytes": sum(size(s) for s in cache.values() if "state" in s),
+    }
 
 
 # ---- the expert layers' device counters (parallel/moe.py makes them) ----

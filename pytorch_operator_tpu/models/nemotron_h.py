@@ -288,14 +288,7 @@ def init_cache(cfg: NemotronHConfig, slots: int, chunk: int) -> dict:
     return cache
 
 
-def cache_bytes(cache: dict) -> dict:
-    """The two gauges: bytes held by the attention layers' slabs, and by the
-    Mamba layers' constant state."""
-    size = lambda state: sum(a.size * a.dtype.itemsize for a in state.values())
-    return {
-        "cache_full_bytes": sum(size(s) for s in cache.values() if "k" in s),
-        "cache_state_bytes": sum(size(s) for s in cache.values() if "state" in s),
-    }
+cache_bytes = layer_list.state_cache_bytes
 
 
 def zero_counts(cfg: NemotronHConfig) -> dict:
@@ -419,28 +412,10 @@ def ssm_mixer(cfg: NemotronHConfig, w: dict, cache: dict, x, *, slot=None, fresh
 
 
 def attention(cfg: NemotronHConfig, w: dict, cache: dict, x, positions, *, slot=None):
-    """Grouped-query attention without a position embedding for ``x [B, S,
-    D]`` at ``positions [B, S]`` (contiguous in a row): the incoming keys
-    and values are written into the slabs first (a chunk at row ``slot``, in
-    place; a decode step a position a row), then the queries attend the
-    filled prefix (ops/cache_attention.py). Returns (out, new cache)."""
-    from ..ops.cache_attention import cache_attention
-
-    B, S, _ = x.shape
-    H, Hk, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = jnp.einsum("bsd,dhe->bshe", x, w["q_proj"]).reshape(B, S, Hk, H // Hk, d)
-    k = jnp.einsum("bsd,dke->bkse", x, w["k_proj"]).astype(cfg.dtype)
-    v = jnp.einsum("bsd,dke->bkse", x, w["v_proj"]).astype(cfg.dtype)
-    if slot is None:
-        new = layer_list.write_positions(cache, k, v, positions)
-    else:
-        at = (slot, 0, positions[0, 0], 0)
-        new = {
-            "k": jax.lax.dynamic_update_slice(cache["k"], k, at),
-            "v": jax.lax.dynamic_update_slice(cache["v"], v, at),
-        }
-    out = cache_attention(q, positions, new["k"], new["v"], slot=slot)
-    return out.reshape(B, S, H * d) @ w["o_proj"], new
+    """Grouped-query attention with NO position embedding, through the
+    layer's full slab: ``layer_list.slab_attention``, which reads the heads
+    off the leaves. Returns (out, new cache)."""
+    return layer_list.slab_attention(w, cache, x, positions, dtype=cfg.dtype, slot=slot)
 
 
 def shared_expert(w: dict, x):

@@ -39,9 +39,10 @@ The equations, for layer ``l`` (``x <- x + mixer(LN(x))``, ``x <- x + W2 (u
   S_{t-1}[n, c] + dt_t[c] B_t[n] u_t[c]``, ``y_t[c] = sum_n S_t[n, c] C_t[n] +
   D[c] u_t[c]``; ``out = (y * silu(z)) W_out``. The decay is per channel AND
   per state index, so ``nemotron_h.scan_chunk``'s decay-masked ``C B^T``
-  product (one scalar decay a head) does not apply. The scan has two forms
-  that agree (:func:`scan_step`, :func:`scan_chunk`); which runs is decided
-  by the call's shape.
+  product (one scalar decay a head) does not apply. The mixer and its scan
+  (two forms that agree, ``scan_step`` and ``scan_chunk``; which runs is
+  decided by the call's shape) are ``models/ssm.py``'s: the tree's one
+  Mamba-1 mixer, which ``models/jamba.py`` runs too.
 - differential attention: ``q = x W_q + b_q`` as pairs ``(q1_i, q2_i)`` of
   adjacent heads, ``[k | v] = x W_kv + b_kv`` as pairs ``(k1_g, k2_g)`` and
   ``v_g = [v1_g | v2_g]`` of twice the head size; pair ``i`` uses ``g = i //
@@ -77,7 +78,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import layer_list
+from . import layer_list, ssm
+from .ssm import scan_chunk, scan_step  # noqa: F401 (the family's tests and tools find the scan by these names)
 
 Dtype = Any
 
@@ -85,11 +87,6 @@ MAMBA, MAMBA_MEMORY, WINDOW, FULL, GMU, CROSS = (
     "mamba", "mamba_memory", "attn_window", "attn_full", "gmu", "attn_cross",
 )
 F32 = jnp.float32
-# Steps of the chunk's scan an iteration of its loop runs (:func:`scan_chunk`):
-# chosen on the chip among 1, 8, 16, 32 (0.175, 0.062, 0.074, 0.083 ms a
-# layer's chunk of 128 tokens; 128 took 17 minutes to compile).
-UNROLL = 8
-
 
 @dataclasses.dataclass(frozen=True)
 class Phi4FlashConfig:
@@ -235,14 +232,8 @@ def layer_shapes(cfg: Phi4FlashConfig, kind: str) -> dict:
         ("mlp", "gate_up"): ((D, 2 * F), D, w), ("mlp", "down"): ((F, D), F * res, w),
     }
     if kind in (MAMBA, MAMBA_MEMORY):
-        di, N, K, R = cfg.d_inner, cfg.d_state, cfg.d_conv, cfg.dt_rank
-        out.update({
-            ("ssm", "in_proj"): ((D, 2 * di), D, w),
-            ("ssm", "conv_w"): ((K, di), K, F32),
-            ("ssm", "conv_b"): ((di,), K, F32),
-            ("ssm", "x_proj"): ((di, R + 2 * N), di, w),
-            ("ssm", "out_proj"): ((di, D), di * res, w),
-        })
+        shapes = ssm.mixer_shapes(D, cfg.d_inner, cfg.d_state, cfg.d_conv, cfg.dt_rank, w, cfg.d_inner * res)
+        out.update({("ssm", name): leaf for name, leaf in shapes.items()})
     elif kind == GMU:
         di = cfg.d_inner
         out.update({("gmu", "in_proj"): ((D, di), D, w), ("gmu", "out_proj"): ((di, D), di * res, w)})
@@ -269,14 +260,8 @@ def init_layer(cfg: Phi4FlashConfig, kind: str, key, layer) -> dict:
     key = jax.random.fold_in(key, layer)
     tree = layer_list.draw(key, layer_shapes(cfg, kind))
     if kind in (MAMBA, MAMBA_MEMORY):
-        di, N, R = cfg.d_inner, cfg.d_state, cfg.dt_rank
-        kd, kp = jax.random.split(jax.random.fold_in(key, 1 << 10))
-        dt = jnp.exp(jax.random.uniform(kd, (di,), F32, math.log(1e-3), math.log(1e-1)))
         tree["ssm"].update(
-            A_log=jnp.broadcast_to(jnp.log(jnp.arange(1, N + 1, dtype=F32))[:, None], (N, di)),
-            dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
-            dt_proj=jax.random.uniform(kp, (R, di), F32, -(R ** -0.5), R ** -0.5).astype(cfg.param_dtype),
-            D=jnp.ones((di,), F32),
+            ssm.published_init(jax.random.fold_in(key, 1 << 10), cfg.d_inner, cfg.d_state, cfg.dt_rank, cfg.param_dtype)
         )
     return tree
 
@@ -328,10 +313,7 @@ def init_cache(cfg: Phi4FlashConfig, slots: int, chunk: int) -> dict:
     cache = {}
     for i, kind in enumerate(cfg.layers):
         if kind in (MAMBA, MAMBA_MEMORY):
-            cache[f"layer_{i}"] = {
-                "conv": jnp.zeros((slots, cfg.d_conv - 1, cfg.d_inner), cfg.dtype),
-                "state": jnp.zeros((slots, cfg.d_state, cfg.d_inner), F32),
-            }
+            cache[f"layer_{i}"] = ssm.init_state(slots, cfg.d_inner, cfg.d_state, cfg.d_conv, cfg.dtype)
         elif kind == WINDOW:
             cache[f"layer_{i}"] = {**kv(R), "pos": jnp.full((slots, R), -1, jnp.int32)}
         elif kind == FULL:
@@ -365,41 +347,6 @@ def slab_reads(cfg: Phi4FlashConfig, chunk_ends, p: int):
     return np.full((cfg.full_readers,), p)
 
 
-# ---- the scan, in two forms that agree ----
-
-
-def scan_step(u, Bm, Cm, dt, A, state):
-    """The one-step recurrence over rows: ``u``, ``dt [B, C]`` (softplus
-    applied), ``Bm``/``Cm [B, N]``, ``A [N, C]``, ``state [B, N, C]``; all
-    float32. Returns ``(y [B, C], new state)``: ``S' = exp(dt A) S + dt u (x)
-    B``, ``y = sum_n S' C``. One elementwise pass over the state and a sum
-    over its state axis: what a row costs is its state read and written once."""
-    S = jnp.exp(dt[:, None, :] * A) * state + (dt * u)[:, None, :] * Bm[:, :, None]
-    return jnp.sum(S * Cm[:, :, None], axis=1), S
-
-
-def scan_chunk(u, Bm, Cm, dt, A, state):
-    """A chunk of one row from its entry state: ``u``, ``dt [S, C]`` (softplus
-    applied; zero where the state must not move), ``Bm``/``Cm [S, N]``, ``A
-    [N, C]``, entry ``state [N, C]``; all float32. Returns ``(y [S, C], exit
-    state)``: :func:`scan_step` a token, as a loop unrolled :data:`UNROLL`
-    steps an iteration. The state is 80 vector registers' worth, so a step
-    is an elementwise pass that never leaves the chip's fast memory: on the
-    v5e it costs 0.5 us, a layer's chunk of 128 tokens 0.06 ms, where
-    sub-chunks of 16 tokens by an associative scan over (decay, input) pairs,
-    which was built first, cost 0.26-0.52 ms: every level of such a scan
-    writes and reads the chunk's ``[S, N, C]`` states (42 MB) through device
-    memory (PERF.md section 6, PR 36, has the table)."""
-
-    def step(state, t):
-        u_t, B_t, C_t, dt_t = t
-        y_t, state = scan_step(u_t[None], B_t[None], C_t[None], dt_t[None], A, state[None])
-        return state[0], y_t[0]
-
-    state, y = jax.lax.scan(step, state, (u, Bm, Cm, dt), unroll=min(UNROLL, u.shape[0]))
-    return y, state
-
-
 # ---- the mixers ----
 
 
@@ -410,59 +357,19 @@ def layer_norm(x, w: dict, eps: float):
     return ((x32 - mean) * jax.lax.rsqrt(var + eps) * w["scale"] + w["bias"]).astype(x.dtype)
 
 
-def dense_mlp(w: dict, x):
-    F = w["down"].shape[0]
-    gu = x @ w["gate_up"]
-    return (gu[..., F:] * jax.nn.silu(gu[..., :F])) @ w["down"]
+dense_mlp = layer_list.gated_mlp
 
 
 def ssm_mixer(cfg: Phi4FlashConfig, w: dict, cache: dict, x, *, slot=None, fresh=None, n_real=None):
-    """A Mamba-1 layer for ``x [B, S, D]``. With ``slot`` (a prefill chunk:
-    ``B == 1``) the row's state is cut out of ``cache``'s leaves, zeroed
-    where ``fresh`` (the chunk stands at position 0), run through the chunk
-    form of the scan in which only the first ``n_real`` tokens move it, and
-    put back; without (a decode step: ``S == 1``) every row takes one step
-    of the recurrence. Returns ``(out [B, S, D], m [B, S, d_inner], new
-    cache)``; ``m`` is the scan's output with the ``D`` term, before the
-    gate: what the gated memory units of the same token read."""
-    B, S, _ = x.shape
-    di, N, K, R = cfg.d_inner, cfg.d_state, cfg.d_conv, cfg.dt_rank
-    proj = x @ w["in_proj"]
-    u, z = proj[..., :di], proj[..., di:]
-    if slot is None:
-        tail, state = cache["conv"], cache["state"]
-    else:
-        # Whatever the slot's last occupant (or a parked row's idle steps) left there is dropped, not multiplied away.
-        start = lambda leaf: jnp.where(fresh, jnp.zeros_like(leaf), leaf)
-        tail = start(jax.lax.dynamic_slice_in_dim(cache["conv"], slot, 1, 0))
-        state = start(jax.lax.dynamic_slice_in_dim(cache["state"], slot, 1, 0)[0])
-    with jax.named_scope("ssm_conv"):
-        window = jnp.concatenate([tail, u], axis=1)  # [B, K - 1 + S, di]
-        taps = window.astype(F32)
-        u = jax.nn.silu(w["conv_b"] + sum(w["conv_w"][j] * taps[:, j : j + S] for j in range(K)))
-    low = jnp.dot(u.astype(x.dtype), w["x_proj"], preferred_element_type=F32)
-    Bm, Cm = low[..., R : R + N], low[..., R + N :]
-    dt = jnp.dot(low[..., :R].astype(x.dtype), w["dt_proj"], preferred_element_type=F32)
-    dt = jax.nn.softplus(dt + w["dt_bias"])  # [B, S, di]
-    A = -jnp.exp(w["A_log"])
-    with jax.named_scope("ssm_scan"):
-        if slot is None:
-            y, state = scan_step(u[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], A, state)
-            y = y[:, None]
-            new = {"conv": window[:, 1:], "state": state}
-        else:
-            real = jnp.arange(S) < n_real
-            y, state = scan_chunk(u[0], Bm[0], Cm[0], jnp.where(real[:, None], dt[0], 0.0), A, state)
-            y = y[None]
-            # The inputs before the first token that is not real: what the next chunk, or the first decode step, convolves with.
-            tail = jax.lax.dynamic_slice_in_dim(window, n_real, K - 1, 1)
-            new = {
-                "conv": jax.lax.dynamic_update_slice_in_dim(cache["conv"], tail, slot, 0),
-                "state": jax.lax.dynamic_update_slice_in_dim(cache["state"], state[None], slot, 0),
-            }
-    y = y + w["D"] * u
-    out = (y * jax.nn.silu(z.astype(F32))).astype(x.dtype) @ w["out_proj"]
-    return out, y.astype(x.dtype), new
+    """A Mamba-1 layer for ``x [B, S, D]``: the tree's one Mamba-1 mixer
+    (``models/ssm.py``: a chunk of row ``slot`` whose first ``n_real`` tokens
+    move the state, from zero where ``fresh``; or a decode step over every
+    row), which reads its sizes off the leaves. This family's layers have no
+    inner norms. Returns ``(out [B, S, D], m [B, S, d_inner], new cache)``;
+    ``m`` is the scan's output with the ``D`` term, before the gate: what the
+    gated memory units of the same token read. The forward calls it by this
+    name, which is where a test replaces it."""
+    return ssm.mamba1_mixer(w, cache, x, slot=slot, fresh=fresh, n_real=n_real)
 
 
 def gated_memory(w: dict, x, m):
@@ -607,12 +514,7 @@ def cross_decoder(cfg: Phi4FlashConfig, params: dict, slab: dict, x, m, position
     return layer_norm(x, params["final_norm"], cfg.ln_eps)
 
 
-def logits(params: dict, hidden):
-    """Float32 logits of ``hidden [..., D]`` against the embedding (tied; no
-    head bias), accumulated in float32 from the operands as they are held."""
-    return jax.lax.dot_general(
-        hidden, params["embed"]["embedding"], (((hidden.ndim - 1,), (1,)), ((), ())), preferred_element_type=F32
-    )
+logits = layer_list.tied_logits
 
 
 def forward(cfg: Phi4FlashConfig, params: dict, cache: dict, tokens, positions, *, slot=None, n_real=None):

@@ -6,7 +6,7 @@
 constructor and its two forwards. A config class states its family by
 having ``serving_model()`` (``models.llama.LlamaConfig``,
 ``models.mimo_v2.MiMoV2Config`` (MiMo-V2.5 and K-EXAONE), ``models.nemotron_h.NemotronHConfig``,
-``models.phi4_flash.Phi4FlashConfig``); a job's ``--config`` names a preset, and
+``models.phi4_flash.Phi4FlashConfig``, ``models.jamba.JambaConfig``); a job's ``--config`` names a preset, and
 :func:`preset` finds the family that has it. Nothing else selects a path.
 """
 
@@ -113,6 +113,14 @@ class ServingModel:
     # ``ops.cache_attention.reads_per_row`` answers for the model's slabs
     # (the one statement of the condition is there).
     decode_reads_per_row: bool = False
+    # Whether ``decode`` leaves a row whose position is NEGATIVE untouched:
+    # no leaf of its cache moves (a recurrence's state and tail stay; keys
+    # and values go to the parking position ``max_decode_len - 1``). Only
+    # for such a model does the engine stop a prompt's prefill at a chunk's
+    # end and go on at the next boundary, its row held at -1 through the
+    # decode dispatches between (``serving/engine.py``); for every other a
+    # boundary admits a prompt whole, whatever its length.
+    holds: bool = False
     # a checkpoint's parameter tree (the trainer's form, host arrays) ->
     # the same leaves as ``init_params`` arranges them, which is how the
     # forwards read them fastest (``workloads.generate.load_params`` calls
@@ -129,11 +137,11 @@ def families() -> dict:
     """``preset name -> (model module, name of the function that makes its
     config)``, read at call time: a family's table is a module dict that a
     caller may add a preset to (the benchmark's ``bench``)."""
-    from . import llama, mimo_v2, nemotron_h, phi4_flash
+    from . import jamba, llama, mimo_v2, nemotron_h, phi4_flash
 
     return {
         name: (module, fn)
-        for module in (llama, mimo_v2, nemotron_h, phi4_flash)
+        for module in (llama, mimo_v2, nemotron_h, phi4_flash, jamba)
         for name, fn in module.CONFIGS.items()
     }
 

@@ -530,6 +530,15 @@ def analyze(
         if "admit_rounds" in rec
     }
 
+    # The boundaries that queued chunks of a prompt, beside the prompts: 1 a
+    # prompt until one is longer than a boundary's budget and its prefill is
+    # spread over several (serving/engine.py ``ADMIT_TOKENS``).
+    prefill_rounds = {
+        rec["replica"]: [rec["prefill_rounds"], rec.get("admitted", 0)]
+        for rec in tl.records.get("metrics", [])
+        if "prefill_rounds" in rec
+    }
+
     # What a serving engine's decode steps read of the cache slabs beside
     # what their rows had live (serving/engine.py, ops/cache_attention.py):
     # near 1 where each row is read to its own depth, the deepest row's
@@ -560,6 +569,7 @@ def analyze(
         "state_resets": state_resets,
         "cross_tokens": cross_tokens,
         "admit_rounds": admit_rounds,
+        "prefill_rounds": prefill_rounds,
         "slab_reads": slab_reads,
         "drafts": drafts,
         "events": len(tl.events),
@@ -651,6 +661,12 @@ def render_report(report: dict) -> str:
             f"admits:   {replica} {admitted} admitted in {rounds} round(s), "
             f"the decode dispatch queued behind {behind} of them before a first token was read"
         )
+    for replica, (rounds, admitted) in sorted(report.get("prefill_rounds", {}).items()):
+        if admitted:
+            lines.append(
+                f"rounds:   {replica} prefill_rounds {rounds} for {admitted} admitted = {rounds / admitted:.2f} a prompt"
+                + ("" if rounds == admitted else " (a prompt longer than a boundary's budget is prefilled over several)")
+            )
     for replica, (attended, live) in sorted(report.get("slab_reads", {}).items()):
         lines.append(
             f"slabs:    {replica} decode_attended_positions {attended} over decode_live_positions {live} "

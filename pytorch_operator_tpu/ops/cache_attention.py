@@ -43,12 +43,22 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 
-# Blocks a slab is read in. Chosen on the chip at 4096 positions (PERF.md
+# Blocks a slab is read in: an eighth of the slab, and never more positions
+# than :data:`BLOCK_MAX`. Chosen on the chip at 4096 positions (PERF.md
 # section 6, PR 29): an iteration costs about 7 us beside its reads, so
 # 512 positions a block beat 256 at both families' decode shapes from 512
 # filled up, and a static prefix under ``lax.switch`` (30-40 us a
 # conditional) up to 2048.
 BLOCKS = 8
+# The most positions a block holds, whatever the slab's length: a row reads
+# whole blocks up to its own depth, so a block that grew with the slab (an
+# eighth of 32,768 is 4,096) would charge a row 5,000 deep for 8,192, put a
+# ``[K, 4096, d]`` key and value block twice over into fast memory a grid
+# step, and make the chunk loop's score block ``[.., chunk, 4096]`` float32.
+# At 4,096 positions it is the eighth that every served cell read before
+# there was a cap; at 32,768, chosen on the chip among 512 / 1,024 / 2,048
+# (PERF.md section 6, PR 46).
+BLOCK_MAX = 512
 
 # The most queries a row brings in a decode step: its last accepted token
 # and, where the model drafts, the one draft behind it (models/serving.py
@@ -59,9 +69,13 @@ STEP_QUERIES = 2
 
 
 def block(L: int) -> int:
-    """Positions a block of a slab of ``L`` holds: an eighth of the slab,
-    or all of it where eighths do not divide it."""
-    return L // BLOCKS if L % BLOCKS == 0 else L
+    """Positions a block of a slab of ``L`` holds: an eighth of the slab but
+    at most :data:`BLOCK_MAX` (where that divides the slab), or all of it
+    where eighths do not divide it."""
+    if L % BLOCKS:
+        return L
+    eighth = L // BLOCKS
+    return BLOCK_MAX if eighth > BLOCK_MAX and L % BLOCK_MAX == 0 else eighth
 
 
 def blocks_needed(needed, L: int):
@@ -197,9 +211,10 @@ def _decode_attention(q, positions, k, v):
     inside a block is the loop's ``one_block``. On a TPU it is a Mosaic
     kernel; where the default backend is the CPU, the same kernel under
     the interpreter (as ops/flash_attention.py decides). A step's blocks
-    ``[K, T, dk]`` + ``[K, T, dv]`` lie in fast memory twice over: past
-    ~100 MiB of them (a slab of 32k positions with 32 key heads) the
-    chip's compiler refuses the kernel."""
+    ``[K, T, dk]`` + ``[K, T, dv]`` lie in fast memory twice over; ``T`` is
+    at most :data:`BLOCK_MAX` positions whatever the slab's length, so what
+    the kernel reserves grows with the key heads alone (32 heads of 128 at
+    512 positions: 16 MiB)."""
     # Imported here: the library takes about a second, and a process that
     # trains never needs it (one that serves has it from workloads/serve.py's
     # thread).
@@ -266,7 +281,7 @@ def _decode_attention(q, positions, k, v):
 
     # A grid step's key and value blocks, fetched while the step before
     # computes: beyond the 16 MiB a kernel may use of fast memory unasked
-    # (many key heads, or a long slab's eighth) the kernel asks for more.
+    # (many key heads) the kernel asks for more.
     blocks = 2 * K * T * (dk * k.dtype.itemsize + dv * v.dtype.itemsize)
     # [B, S, K, G, dk] -> [B, K, S G, dk]: a row's queries beside its groups.
     folded = q[:, 0] if S == 1 else q.transpose(0, 2, 1, 3, 4).reshape(B, K, S * G, dk)

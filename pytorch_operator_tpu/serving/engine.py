@@ -64,11 +64,29 @@ TPU-first shape (every program's shapes static):
   decode tokens. A first token that ends its request (``eos_token``) is
   learnt one dispatch late: the row ran a dispatch whose tokens are
   dropped (``decode_behind_admit`` counts the dispatches queued so).
-- A boundary's admissions queue at most :data:`ADMIT_CHUNKS` prefill
-  chunks (and always one prompt) in front of the decode dispatch: the
-  rows that are decoding wait for a bounded stretch of prefill, and a
-  wave of long prompts into an empty engine is admitted a round at a
-  time, its first rows decoding meanwhile.
+- A boundary queues at most :data:`ADMIT_TOKENS` prompt tokens of prefill
+  (as whole chunks) in front of the decode dispatch: the rows that are
+  decoding wait for a bounded stretch of prefill, and a wave of long
+  prompts into an empty engine is admitted a round at a time, its first
+  rows decoding meanwhile. A prompt LONGER than the budget: where the
+  model's decode step can leave a row untouched
+  (``ServingModel.holds``), its prefill stops at a chunk's end and goes
+  on at the next boundary (``prefill_rounds`` counts the boundaries that
+  queued chunks of a prompt: over ``admitted`` it is 1 until a prompt is
+  split), so the budget bounds what stands in front of a decode dispatch
+  whatever the prompt's length; the row's state, convolution tail and
+  slabs live in its slot meanwhile, the row HELD at position -1 through
+  the decode dispatches between, and it joins ``decode_block`` once its
+  head has run. For every other model such a prompt is admitted whole,
+  alone. A row part-way through its prompt is neither free nor active:
+  no request is admitted into its slot; it is no row of a decode
+  dispatch (``slot_blocks_occupied``, ``decode_row_steps`` and so
+  ``slot_occupancy_pct`` count the rows that decode, and
+  ``decode_attended_positions`` charges it nothing: like a parked row it
+  costs the walk one block a step); :func:`decode_steps` sees its slot
+  among the free ones, so the dispatch behind its part is a quantum and
+  the next part is queued soon; ``abort_in_flight`` evicts it with the
+  rest and returns its id.
 - Slot L-1 of every row is a parking slot: rows that exhaust their
   budget clamp there, so admission requires prompt + new <= L-1 and
   no live stream ever attends a parked write.
@@ -191,7 +209,7 @@ _COUNTERS = (
     "decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
     "decode_live_positions", "decode_attended_positions", "prefill_attended_positions",
     "prefill_chunks", "prefill_head_chunks", "prefill_tokens", "prefill_pad_tokens",
-    "admit_rounds", "decode_behind_admit", "admitted",
+    "admit_rounds", "decode_behind_admit", "admitted", "prefill_rounds",
 )
 SPAN_CAT = "engine"
 
@@ -204,15 +222,19 @@ SPAN_CAT = "engine"
 QUANTUM = 8
 
 
-# Most prefill chunks the admissions of ONE boundary may queue in front of
-# the decode dispatch behind them (a boundary always admits one prompt,
-# whatever its length). The rows that are decoding wait for every chunk of
-# a round, so an empty engine that meets a hundred long prompts (a closed
-# loop's first wave) would hold its first rows' second tokens back for the
-# whole wave; with the bound they wait a second or so, and the rest of the
-# wave is admitted a round at a time between dispatches of a quantum. No
-# cell's steady state comes near it (PERF.md section 6, PR 36).
-ADMIT_CHUNKS = 128
+# Most prompt tokens (as whole chunks, pads counted) the admissions of ONE
+# boundary may queue in front of the decode dispatch behind them; stated in
+# tokens so that it means the same at any chunk size (128 chunks of 128, as
+# it was first set: PERF.md section 6, PR 36). The rows that are decoding
+# wait for every chunk of a round, so an empty engine that meets a hundred
+# long prompts (a closed loop's first wave) would hold its first rows'
+# second tokens back for the whole wave; with the bound they wait a second
+# or so, and the rest of the wave is admitted a round at a time between
+# dispatches of a quantum. A round's first prompt is always begun: whole
+# where it fits or the model cannot hold a row (``ServingModel.holds``),
+# else as many whole chunks of it as the budget takes, the rest at the next
+# boundaries (PERF.md section 6, PR 46).
+ADMIT_TOKENS = 16_384
 
 
 def decode_steps(remaining, free_slots: int, block: int, per_step: int = 1) -> tuple[int, str]:
@@ -221,7 +243,9 @@ def decode_steps(remaining, free_slots: int, block: int, per_step: int = 1) -> t
     remaining budgets (each >= 1; an upper bound on the row's life where
     an EOS token can end it sooner), ``free_slots`` the slots that hold no
     request — after admission, so a free slot means nothing is queued, or
-    that the boundary's admissions reached :data:`ADMIT_CHUNKS`.
+    that the boundary's admissions reached :data:`ADMIT_TOKENS`, or that the
+    slot's row is part-way through its prompt (its next part is queued at
+    the next boundary).
     ``per_step`` is the most tokens a step yields a row (2 where the model
     drafts): a row with ``r`` tokens left then needs between ``ceil(r /
     per_step)`` and ``r`` steps, and the rules below take the FEWEST for
@@ -282,6 +306,10 @@ class _Slot:
     remaining: int
     tokens: list[int]
     done: bool = False
+    # A row part-way through its prompt (ServingModel.holds): the padded
+    # prompt and where its next chunk starts; None once its head is queued.
+    buf: Optional[np.ndarray] = None
+    next_start: int = 0
 
 
 class Programs(NamedTuple):
@@ -356,16 +384,20 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         return tok.at[slot].set(first), pos.at[slot].set(p), first, key
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def decode_block(params, cache, counts, tok, pos, active, rng, steps):
+    def decode_block(params, cache, counts, tok, pos, active, rng, steps, held=None):
         """``steps`` (a traced int32, at most ``block``) decode steps
         over all slots: tok/pos [slots] are each row's last accepted
         token and its position; parked rows (active=False) stand at
         position 0 whatever their last occupant left, so they do not
         hold up the bound of the model's cache attention, and
-        re-write position 0 of their own empty row. Returns the
+        re-write position 0 of their own empty row. ``held`` [slots]
+        (given only for a model that ``holds``: every other model's
+        program is as it was) are the rows part-way through their
+        prompt: they stand at position -1, where the model's step
+        moves nothing of theirs. Returns the
         sampled tokens [slots, block], of which the first ``steps``
         columns are written."""
-        pos = jnp.where(active, pos, 0)
+        pos = jnp.where(active, pos, 0 if held is None else jnp.where(held, -1, 0))
 
         def step(i, carry):
             cache, counts, tok, pos, rng, toks = carry
@@ -434,7 +466,8 @@ def _drafting_programs(model, finish, *, slots: int, chunk: int, block: int, sam
         rows stand at position 0 as in the other models' program. Returns
         the tokens ``[slots, block, 2]`` with how many of a step's two were
         delivered ``[slots, block]`` (1 or 2; 0 for a parked row), of which
-        the first ``steps`` steps are written."""
+        the first ``steps`` steps are written. (No ``held`` rows: no model
+        that drafts holds a row, and the engine refuses one that says so.)"""
         pos = jnp.where(active, pos, 0)
 
         def step(i, carry):
@@ -508,6 +541,8 @@ class ServingEngine:
                 "this model drafts (models.serving.Drafter) and the engine verifies a draft greedily: "
                 f"temperature {temperature} is not served (speculative sampling is not implemented); use 0"
             )
+        if model.drafter is not None and model.holds:
+            raise ValueError("this model drafts and says it holds a row: the verifying step takes no held rows")
         self.model = model
         self.cfg = model.cfg
         self.slots = slots
@@ -537,6 +572,7 @@ class ServingEngine:
         self._slots: list[Optional[_Slot]] = [None] * slots
         # Admissions whose first token is still on the device, in order.
         self._unread: list[tuple[_Slot, object]] = []
+        self._round_queued = False  # something is queued at this boundary already
         self._queue: deque[Request] = deque()
         self.last_steps = 0  # steps of the newest decode dispatch
         # Latency/throughput accounting.
@@ -571,7 +607,8 @@ class ServingEngine:
         boundary's first dispatch the device waited for the host
         (``admit_prep``); behind an admission the host queues work behind
         work (``dispatch``)."""
-        self.host_lap("dispatch" if self._unread else "admit_prep")
+        self.host_lap("dispatch" if self._round_queued else "admit_prep")
+        self._round_queued = True
 
     # ---- admission ----
 
@@ -605,19 +642,16 @@ class ServingEngine:
     def _free_slots(self) -> list[int]:
         return [i for i, s in enumerate(self._slots) if s is None]
 
-    def _admit(self, request: Request, slot: int) -> None:
-        """Queue a prompt's chunks and its head into ``slot``; read nothing
-        back. The row's budget and position after its first token are the
-        request's; the token's value stays on the device (``self._unread``)
-        until the decode dispatch is queued behind it."""
+    def _begin(self, request: Request, slot: int) -> _Slot:
+        """A prompt's row in ``slot``, none of its chunks queued yet: the
+        row's budget and position after its first token are the request's,
+        and the admission's counters are the whole prompt's."""
         L = self.cfg.max_decode_len
-        admit_time = time.time()
         prompt = np.asarray(request.prompt, np.int32)
         p = prompt.shape[0]
         padded = -(-p // self.chunk) * self.chunk
         # A model that drafts gets each chunk with the token that follows it.
-        ahead = 0 if self._draft is None else 1
-        buf = np.zeros((padded + ahead,), np.int32)
+        buf = np.zeros((padded + (self._draft is not None),), np.int32)
         buf[:p] = prompt
         self._n["admitted"] += 1
         self._n["prefill_chunks"] += padded // self.chunk
@@ -629,52 +663,87 @@ class ServingEngine:
         if self.model.slab_reads is not None:
             reads = self.model.slab_reads(reads, p)
         self._n["prefill_attended_positions"] += int(self._attended(reads, L).sum())
-        # Host values throughout: the dispatch moves what its program reads
-        # (a family whose state is keys and values never gets ``n_real``).
-        slot_ = np.int32(slot)
-        for start in range(0, padded, self.chunk):
-            n_real, head = min(self.chunk, p - start), start + self.chunk == padded
-            with obs.span(
-                "engine.prefill_dispatch", SPAN_CAT, start=start, slot=slot, n_real=n_real, head=head
-            ):
-                if start == 0:
-                    self._lap_to_dispatch()
-                hidden, self._cache, self._counts["prefill"] = self._prefill_chunk(
-                    self._params, self._cache, self._counts["prefill"], slot_,
-                    buf[None, start : start + self.chunk + ahead], np.int32(start), np.int32(n_real),
-                )
-                if head and self._draft is None:
-                    # The last chunk's last VALID position (not the padded
-                    # tail) feeds the first token, and the program that
-                    # samples it sets the row's state: the head runs once a
-                    # prompt, queued behind that chunk.
-                    self._tok, self._pos, first, self._first_key = self._prefill_chunk_head(
-                        self._params, self._cache, hidden, self._tok, self._pos, slot_,
-                        np.int32(p), self._first_key,
-                    )
-                elif head:
-                    # ... and, where the model drafts, leaves the row's first
-                    # draft there too (the drafter writes its state: the
-                    # cache goes in donated).
-                    (self._cache, self._tok, self._pos, self._draft, first,
-                     self._first_key) = self._prefill_chunk_head(
-                        self._params, self._cache, hidden, self._tok, self._pos, self._draft, slot_,
-                        np.int32(p), self._first_key,
-                    )
-        self._n["prefill_head_chunks"] += 1
-        self.host_lap("dispatch")
         # decode_block writes the first token's k/v at position p before
         # attending, exactly as make_generate's first scan step does.
         st = _Slot(
             request=request,
-            admit_time=admit_time,
+            admit_time=time.time(),
             first_token_time=0.0,  # stamped as its fence returns (_take_first)
             pos=p,
             remaining=request.max_new_tokens - 1,
             tokens=[],
+            buf=buf,
         )
         self._slots[slot] = st
-        self._unread.append((st, first))
+        return st
+
+    def _admit(self, st: _Slot, slot: int, room: int) -> int:
+        """Queue chunks of the prompt in ``slot`` from where it stands, and
+        its head behind the last; read nothing back. All that is left of it,
+        or, where that is more than ``room`` tokens and the model's decode
+        step can hold the row meanwhile (``ServingModel.holds``), the whole
+        chunks that fit ``room`` (at least one): the rest goes on at the
+        next boundary. The first token's value stays on the device
+        (``self._unread``) until the decode dispatch is queued behind it.
+        Returns the tokens queued, pads counted."""
+        p = len(st.request.prompt)
+        ahead = 0 if self._draft is None else 1
+        padded, begin = st.buf.shape[0] - ahead, st.next_start
+        end = padded
+        if padded - begin > room and self.model.holds:
+            end = begin + max(1, room // self.chunk) * self.chunk
+        self._n["prefill_rounds"] += 1
+        # Host values throughout: the dispatch moves what its program reads
+        # (a family whose state is keys and values never gets ``n_real``).
+        slot_ = np.int32(slot)
+        with obs.span(
+            "engine.admit", SPAN_CAT, rid=st.request.id, slot=slot, prompt_len=p, chunks=-(-p // self.chunk),
+            resumed=begin > 0,
+        ):
+            for start in range(begin, end, self.chunk):
+                first = self._chunk(st, slot_, start, p, head=start + self.chunk == padded, lap=start == begin)
+        self.host_lap("dispatch")
+        st.next_start = end
+        if end == padded:
+            self._n["prefill_head_chunks"] += 1
+            st.buf = None  # the row joins the decode dispatches
+            self._unread.append((st, first))
+        return end - begin
+
+    def _chunk(self, st: _Slot, slot_, start: int, p: int, *, head: bool, lap: bool):
+        """One chunk's dispatch, and behind a prompt's last chunk its head's:
+        returns the first token (on the device) where the head ran."""
+        ahead, first = 0 if self._draft is None else 1, None
+        n_real = min(self.chunk, p - start)
+        with obs.span(
+            "engine.prefill_dispatch", SPAN_CAT, start=start, slot=int(slot_), n_real=n_real, head=head,
+            resumed=st.next_start > 0,
+        ):
+            if lap:
+                self._lap_to_dispatch()
+            hidden, self._cache, self._counts["prefill"] = self._prefill_chunk(
+                self._params, self._cache, self._counts["prefill"], slot_,
+                st.buf[None, start : start + self.chunk + ahead], np.int32(start), np.int32(n_real),
+            )
+            if head and self._draft is None:
+                # The last chunk's last VALID position (not the padded
+                # tail) feeds the first token, and the program that
+                # samples it sets the row's state: the head runs once a
+                # prompt, queued behind that chunk.
+                self._tok, self._pos, first, self._first_key = self._prefill_chunk_head(
+                    self._params, self._cache, hidden, self._tok, self._pos, slot_,
+                    np.int32(p), self._first_key,
+                )
+            elif head:
+                # ... and, where the model drafts, leaves the row's first
+                # draft there too (the drafter writes its state: the
+                # cache goes in donated).
+                (self._cache, self._tok, self._pos, self._draft, first,
+                 self._first_key) = self._prefill_chunk_head(
+                    self._params, self._cache, hidden, self._tok, self._pos, self._draft, slot_,
+                    np.int32(p), self._first_key,
+                )
+        return first
 
     def _attended_over(self, deepest) -> int:
         """Slab positions a dispatch's walks read, from ``deepest [rows,
@@ -727,25 +796,25 @@ class ServingEngine:
 
     def _step(self) -> list[RequestResult]:
         # 1. Admission: every prompt's chunks and head are queued, none read.
-        room = ADMIT_CHUNKS
+        # A prompt begun at an earlier boundary goes on first (at most one
+        # row is part-way at a time: only a round's first prompt is split).
+        room = ADMIT_TOKENS
+        self._round_queued = False
+        for slot, st in enumerate(self._slots):
+            if st is not None and st.buf is not None:
+                room -= self._admit(st, slot, room)
         for slot in self._free_slots():
             if not self._queue:
                 break
-            room -= -(-len(self._queue[0].prompt) // self.chunk)
-            if room < 0 and self._unread:
+            p = len(self._queue[0].prompt)
+            if -(-p // self.chunk) * self.chunk > room and self._round_queued:
                 break  # the rest of the queue at the next boundary, behind a decode dispatch
-            request = self._queue.popleft()
-            p = len(request.prompt)
-            with obs.span(
-                "engine.admit", SPAN_CAT, rid=request.id, slot=slot,
-                prompt_len=p, chunks=-(-p // self.chunk),
-            ):
-                self._admit(request, slot)
+            room -= self._admit(self._begin(self._queue.popleft(), slot), slot, room)
         self._n["admit_rounds"] += bool(self._unread)
         # Rows with budget left (a request of one token is finished by its
         # first and stays parked).
         active_rows = [
-            i for i, s in enumerate(self._slots) if s is not None and s.remaining > 0
+            i for i, s in enumerate(self._slots) if s is not None and s.remaining > 0 and s.buf is None
         ]
         if not active_rows:
             self._take_first()
@@ -756,6 +825,11 @@ class ServingEngine:
         active = np.zeros((self.slots,), bool)
         active[active_rows] = True
         drafting = self._draft is not None
+        # Rows part-way through their prompt, for a model that can hold one
+        # (no other model's program has the argument).
+        held = ()
+        if self.model.holds:
+            held = (np.array([s is not None and s.buf is not None for s in self._slots]),)
         steps, sized_by = decode_steps(
             [self._slots[i].remaining for i in active_rows],
             self.slots - len(active_rows), self.block, 2 if drafting else 1,
@@ -776,7 +850,7 @@ class ServingEngine:
                 (toks, self._cache, self._counts["decode"], self._tok, self._pos,
                  self._rng) = self._decode_block(
                     self._params, self._cache, self._counts["decode"], self._tok,
-                    self._pos, active, self._rng, np.int32(steps),
+                    self._pos, active, self._rng, np.int32(steps), *held,
                 )
         self.host_lap("dispatch")
         # From here to the decode fence the device has the dispatch to run.
@@ -901,7 +975,10 @@ class ServingEngine:
         error response — exactly-once, never a silent drop). Queued
         requests stay queued. Safe without cache surgery: admission
         prefills a row in full before any decode reads it, so a freed
-        slot's stale k/v can never leak into a later request."""
+        slot's stale k/v can never leak into a later request. A row
+        part-way through its prompt goes with the rest: the chunks it
+        has queued run for nothing, and the slot's next occupant starts
+        at position 0, from zero state."""
         aborted = []
         for i, st in enumerate(self._slots):
             if st is not None:

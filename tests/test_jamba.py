@@ -1,0 +1,582 @@
+"""The Mamba-1 / attention hybrid serving model (models/jamba.py) against the
+benchmark's plain reference (benchmark/families/jamba/reference.py), at a small
+size with the real structure: six layers, attention of ONE key/value head
+under four queries at layers 1 and 4, Mamba-1 with the three inner RMSNorms
+elsewhere, a dense SwiGLU in every layer, a tied head, no positions.
+
+Program and reference start from the same seeded leaves, matrices rounded to
+bfloat16 as the configuration states them, and both compute in float32 here:
+what is left between them is the order of float32 sums (the blocked softmax
+against the whole one, the products' accumulation, the scan unrolled against
+the scan stepped), so the tolerance is 5e-4 on logits of unit size (``TOL``).
+A state that is not reset or not held, a reference without the inner norms, a
+stale slab moves a logit by 0.05 or more (``BROKEN``).
+
+The logits are the ENGINE's: ``ServingEngine`` is built over a sampler that
+keeps every logits array it is given (the head program's ``[1, V]`` after a
+prompt, a decode step's ``[slots, V]``) and then chooses greedily, so what is
+compared went through ``prefill_chunk``, ``prefill_chunk_head`` and
+``decode_block`` as a served token does.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+import random
+import subprocess
+import sys
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import tests.jaxenv  # noqa: F401
+from benchmark import family
+from pytorch_operator_tpu.models import jamba, ssm
+from pytorch_operator_tpu.models.serving import families, preset
+from pytorch_operator_tpu.serving import Request, ServingEngine
+from pytorch_operator_tpu.serving import engine as engine_lib
+
+ROOT = Path(__file__).resolve().parents[1]
+TINY = json.loads((ROOT / "tests/zz_benchmark/data/cells/config.tiny-jamba.json").read_text())
+CELL = json.loads((ROOT / "benchmark/configs/jamba2-3b-serve.json").read_text())
+TOL, BROKEN = 5e-4, 0.05
+CHUNK = 16
+
+W = family.load("jamba", "weights")
+R = family.load("jamba", "reference")
+INSTALL = family.load("jamba", "install")
+FLOPS = family.load("jamba", "flops")
+
+
+def _setup(model=TINY, seed=0, **over):
+    """(dims, program config, seeded params, key): float32 compute over
+    bfloat16-rounded matrices on both sides."""
+    import jax
+    import jax.numpy as jnp
+
+    d = W.dims(model)
+    cfg = jamba.make_config(
+        INSTALL.config_base(model, d),
+        {"decode": True, "max_decode_len": 128, "dtype": jnp.float32, "param_dtype": jnp.bfloat16, **over},
+    )
+    key = jax.random.key(seed)
+    return d, cfg, W.make_params(d, key, jnp.bfloat16), key
+
+
+def _reference_logits(d, key, tokens, **kw):
+    import jax.numpy as jnp
+
+    with R.highest():
+        return np.asarray(R.make_forward(d, **kw)(key, jnp.asarray(tokens, jnp.int32)))
+
+
+def _prompt(n, seed=1):
+    return np.random.default_rng(seed).integers(0, TINY["vocab_size"], (n,)).astype(np.int32)
+
+
+@pytest.fixture
+def kept(monkeypatch):
+    """Every logits array the engine's programs sample from, in the order the
+    device ran them: a list of float32 arrays ``[rows, V]``."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.ops import sampling
+
+    seen = []
+
+    def make_sampler(*_):
+        def sample(logits, key):
+            jax.debug.callback(lambda a: seen.append(np.asarray(a)), logits, ordered=True)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return sample
+
+    monkeypatch.setattr(sampling, "make_sampler", make_sampler)
+    return seen
+
+
+def _serve(cfg, params, jobs, **engine):
+    eng = ServingEngine(cfg, params, **{"slots": 3, "chunk": CHUNK, "block": 4, **engine})
+    for i, (prompt, new) in enumerate(jobs):
+        eng.submit(Request(id=f"r{i}", prompt=prompt, max_new_tokens=new, submit_time=time.time()))
+    done = {r.id: r.tokens for r in eng.run_until_drained()}
+    return [done[f"r{i}"] for i in range(len(jobs))], eng
+
+
+# ---- (a) prefill, then decode, through the engine, against the reference's full forward ----
+
+
+@pytest.mark.parametrize("prompt_len", [1, CHUNK - 1, CHUNK, CHUNK + 1, 3 * CHUNK + 5])
+def test_the_engines_logits_after_prefill_and_at_every_decode_step_equal_the_references_full_forward(kept, prompt_len):
+    """One request alone in the engine: the first token's logits (every
+    chunk, then the head's program) and each decode step's, at the row's own
+    slot, against the plain reference's forward over prompt + answer."""
+    d, cfg, params, key = _setup()
+    prompt, new = _prompt(prompt_len), 9
+    (tokens,), eng = _serve(cfg, params, [(prompt, new)], slots=2)
+    import jax
+
+    jax.effects_barrier()
+    want = _reference_logits(d, key, np.concatenate([prompt, tokens]))[prompt_len - 1 : prompt_len - 1 + new]
+    heads = [a for a in kept if a.shape[0] == 1]
+    steps = [a for a in kept if a.shape[0] == 2]
+    assert len(heads) == 1 and len(steps) >= new - 1
+    got = np.stack([heads[0][0]] + [a[0] for a in steps[: new - 1]])  # the one request took slot 0
+    assert np.abs(got - want).max() < TOL  # the order of float32 sums alone (the module's text)
+    assert tokens == [int(t) for t in np.argmax(want, axis=-1)]
+    assert eng.stats()["prefill_state_resets"] == eng.stats()["admitted"] == 1
+
+
+# ---- (b) a prompt longer than one boundary's budget ----
+
+
+def _two_rows(cfg, params, budget, monkeypatch, kept):
+    """A short request decodes; a long prompt (70 tokens = 5 chunks) arrives
+    behind it; returns (tokens of both, the long row's first-token logits and
+    its decode logits, the engine's record)."""
+    import jax
+
+    monkeypatch.setattr(engine_lib, "ADMIT_TOKENS", budget)
+    del kept[:]
+    eng = ServingEngine(cfg, params, slots=3, chunk=CHUNK, block=4)
+    eng.submit(Request(id="short", prompt=_prompt(9, 3), max_new_tokens=40, submit_time=time.time()))
+    eng.step()
+    eng.submit(Request(id="long", prompt=_prompt(70, 4), max_new_tokens=8, submit_time=time.time()))
+    seen = []
+    while eng.busy:
+        eng.step()
+        seen.append([None if s is None else (s.buf is not None) for s in eng._slots])
+    jax.effects_barrier()
+    done = {r.id: r.tokens for r in eng.completed}
+    heads = [a[0] for a in kept if a.shape[0] == 1]
+    first = next(h for h in heads if int(np.argmax(h)) == done["long"][0] and not np.array_equal(h, heads[0]))
+    return done, first, [a.copy() for a in kept if a.shape[0] == 3], eng.stats(), seen
+
+
+def test_a_prompt_split_over_three_boundaries_serves_the_logits_of_the_prompt_admitted_whole(kept, monkeypatch):
+    """The budget at 32 tokens: the 80 padded tokens of the long prompt are
+    queued at three boundaries (2 + 2 + 1 chunks), the short row running a
+    decode dispatch between the parts, the long row HELD through them. Its
+    first token's logits and every later step's are those of the same prompt
+    admitted whole, and those of the reference's full forward."""
+    d, cfg, params, key = _setup()
+    whole, first_w, steps_w, n_w, _ = _two_rows(cfg, params, 16_384, monkeypatch, kept)
+    split, first_s, steps_s, n_s, seen = _two_rows(cfg, params, 32, monkeypatch, kept)
+    assert whole == split and len(split["long"]) == 8 and len(split["short"]) == 40
+    assert (n_w["prefill_rounds"], n_s["prefill_rounds"]) == (2, 4) and n_w["admitted"] == n_s["admitted"] == 2
+    assert n_s["prefill_chunks"] == n_w["prefill_chunks"] == 1 + 5 and n_s["prefill_state_resets"] == 2
+    # part-way through its prompt at two boundaries, the short row decoding meanwhile
+    assert sum(row[1] is True for row in seen) == 2 and n_s["decode_blocks"] >= n_w["decode_blocks"]
+    assert np.abs(first_s - first_w).max() < TOL
+    want = _reference_logits(d, key, np.concatenate([_prompt(70, 4), split["long"]]))[69:77]
+    assert np.abs(first_s - want[0]).max() < TOL
+    # the long row's decode steps (slot 1), from the first dispatch it was active in
+    tail = np.stack([a[1] for a in steps_s])
+    hits = [i for i in range(len(tail) - 6) if np.abs(tail[i : i + 7] - want[1:]).max() < TOL]
+    assert hits, "the split row's decode logits are not the reference's"
+
+
+def test_a_row_part_way_through_its_prompt_is_neither_free_nor_active(monkeypatch):
+    """Two long prompts and a free slot under a budget of two chunks: the
+    second prompt is not begun while the first is part-way (only a round's
+    first prompt is split), no request is admitted into the part-way row's
+    slot, the decode dispatches count the rows that decode and not the held
+    one, and ``abort_in_flight`` evicts it with the rest."""
+    d, cfg, params, key = _setup()
+    monkeypatch.setattr(engine_lib, "ADMIT_TOKENS", 2 * CHUNK)
+    eng = ServingEngine(cfg, params, slots=2, chunk=CHUNK, block=4)
+    eng.submit(Request(id="a", prompt=_prompt(9, 3), max_new_tokens=30, submit_time=time.time()))
+    eng.step()
+    for rid, seed in (("b", 4), ("c", 5)):
+        eng.submit(Request(id=rid, prompt=_prompt(70, seed), max_new_tokens=4, submit_time=time.time()))
+    eng.step()
+    n = eng.stats()
+    assert eng._slots[1].buf is not None and eng._slots[1].next_start == 2 * CHUNK and eng.queued == 1
+    assert eng.slots_free == 0 and n["admitted"] == 2 and n["prefill_rounds"] == 2 and n["prefill_head_chunks"] == 1
+    assert n["decode_blocks"] == 2 and n["slot_blocks_occupied"] == 2  # one row decodes in each; the held row is no row of a dispatch
+    assert n["slot_occupancy_pct"] == 50.0 and n["decode_steps"] == 2 * 4  # each the engine's ``block`` of 4
+    assert sorted(eng.abort_in_flight()) == ["a", "b"] and eng.slots_free == 2 and eng.queued == 1
+    got = {r.id: r.tokens for r in eng.run_until_drained()}
+    want, _ = _serve(cfg, params, [(_prompt(70, 5), 4)])
+    assert got == {"c": want[0]}  # the slot's next occupant starts from zero state, whatever the evicted row left
+
+
+def test_a_model_that_cannot_hold_a_row_is_admitted_whole_whatever_the_budget(monkeypatch):
+    d, cfg, params, key = _setup()
+    monkeypatch.setattr(engine_lib, "ADMIT_TOKENS", CHUNK)
+    model = cfg.serving_model()
+    monkeypatch.setattr(jamba.JambaConfig, "serving_model", lambda self: dataclasses.replace(model, holds=False))
+    (tokens,), eng = _serve(cfg, params, [(_prompt(70, 4), 5)])
+    assert eng.stats()["prefill_rounds"] == eng.stats()["admit_rounds"] == 1 and len(tokens) == 5
+
+
+def test_a_held_row_is_left_bit_identical_by_a_decode_step():
+    """A decode step over three rows of which row 1 stands at -1: no leaf of
+    row 1 moves but the parking position of its slabs, and the other rows'
+    logits are those of a step in which row 1 is parked."""
+    import jax
+    import jax.numpy as jnp
+
+    d, cfg, params, key = _setup()
+    model = cfg.serving_model()
+    cache = jax.tree.map(lambda a: jax.random.normal(jax.random.key(7), a.shape, jnp.float32).astype(a.dtype),
+                         model.init_cache(3, CHUNK))
+    tok = jnp.asarray([[5], [6], [7]], jnp.int32)
+    decode = jax.jit(model.decode)
+    held, after, _ = decode(params, cache, tok, jnp.asarray([[9], [-1], [30]], jnp.int32))
+    parked, _, _ = decode(params, cache, tok, jnp.asarray([[9], [0], [30]], jnp.int32))
+    L = cfg.max_decode_len
+    for name, leaves in cache.items():
+        for leaf, was in leaves.items():
+            now = after[name][leaf]
+            keep = slice(None, L - 1) if leaf in ("k", "v") else slice(None)
+            moved = np.asarray(now[1] != was[1])
+            assert not (moved[:, keep] if leaf in ("k", "v") else moved).any(), (name, leaf)
+            assert np.asarray(now[0] != was[0]).any(), (name, leaf)  # a row that decodes does move
+    assert np.array_equal(np.asarray(held)[[0, 2]], np.asarray(parked)[[0, 2]])
+
+
+def test_the_chunk_plus_one_contract_of_a_model_that_drafts_holds_across_a_split(monkeypatch):
+    """At the admission's level (no model that drafts holds a row, so the
+    verifying step takes no held rows and the engine refuses a model that
+    says both): K-EXAONE's tiny preset (a drafter: each chunk comes with the
+    token that follows it) made to say it holds AFTER the engine is built,
+    one long prompt alone under a budget of one chunk: every chunk is queued
+    at a boundary of its own, each with its token ahead, and what the
+    admission leaves (the first token, the first draft, every leaf of the
+    cache: the drafter's own slab too) is what the prompt admitted whole
+    leaves, bit for bit. The request wants one token, so no verifying step
+    runs."""
+    import jax
+
+    from pytorch_operator_tpu.models import mimo_v2
+
+    cfg = preset("k-exaone-tiny", decode=True, max_decode_len=64)
+    params = mimo_v2.init_params(cfg, jax.random.key(0))
+    prompt = np.random.default_rng(0).integers(0, 256, (29,)).astype(np.int32)
+
+    def admitted(budget, holds):
+        monkeypatch.setattr(engine_lib, "ADMIT_TOKENS", budget)
+        eng = ServingEngine(cfg, params, slots=2, chunk=8, block=4)
+        eng.model = dataclasses.replace(eng.model, holds=holds)
+        eng.submit(Request(id="d", prompt=prompt, max_new_tokens=1, submit_time=time.time()))
+        (done,) = eng.run_until_drained()
+        n = eng.stats()
+        assert n["decode_blocks"] == 0
+        return done.tokens, np.asarray(eng._draft), jax.tree.map(np.asarray, eng._cache), n
+
+    first, draft, cache, n = admitted(16_384, False)
+    first2, draft2, cache2, m = admitted(8, True)
+    assert first2 == first and np.array_equal(draft2, draft)
+    assert all(jax.tree.leaves(jax.tree.map(np.array_equal, cache2, cache)))
+    assert (n["prefill_rounds"], m["prefill_rounds"]) == (1, 4) and m["prefill_chunks"] == 4
+    model = cfg.serving_model()
+    monkeypatch.setattr(type(cfg), "serving_model", lambda self: dataclasses.replace(model, holds=True))
+    with pytest.raises(ValueError, match="holds a row"):
+        ServingEngine(cfg, params, slots=2, chunk=8, block=4)
+
+
+# ---- (c) one key/value head under twenty queries ----
+
+
+@pytest.mark.parametrize("queries", [1, 2, CHUNK], ids=["decode-step", "verifying-step", "chunk"])
+def test_one_key_head_under_twenty_queries_through_cache_attention_equals_whole_score_matrices(queries):
+    """``K`` = 1, ``G`` = 20 (the published shape at head size 16 here): the
+    per-row kernel (a step's one or two queries a row) and the loop (a chunk
+    of one row at ``slot``) against a dense softmax over each row's prefix."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.ops.cache_attention import cache_attention
+
+    B, K, G, d, L = 3, 1, 20, 16, 128
+    k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
+    k = jax.random.normal(k1, (B, K, L, d), jnp.float32)
+    v = jax.random.normal(k2, (B, K, L, d), jnp.float32)
+    chunk = queries == CHUNK
+    rows = 1 if chunk else B
+    q = jax.random.normal(k3, (rows, queries, K, G, d), jnp.float32)
+    first = np.array([40]) if chunk else np.array([5, 77, 126 - queries])
+    positions = jnp.asarray(first[:, None] + np.arange(queries)[None, :], jnp.int32)
+    slot = jnp.int32(2) if chunk else None
+    got = np.asarray(jax.jit(lambda *a: cache_attention(*a, slot=slot))(q, positions, k, v))
+    for r in range(rows):
+        kr, vr = (k[2], v[2]) if chunk else (k[r], v[r])
+        for s in range(queries):
+            p = int(positions[r, s])
+            scores = np.einsum("gd,td->gt", np.asarray(q[r, s, 0]), np.asarray(kr[0, : p + 1])) / math.sqrt(d)
+            probs = np.exp(scores - scores.max(-1, keepdims=True))
+            want = (probs / probs.sum(-1, keepdims=True)) @ np.asarray(vr[0, : p + 1])
+            assert np.abs(got[r, s, 0] - want).max() < 1e-5
+
+
+def test_one_key_heads_step_is_written_by_the_rows_kernel_where_it_belongs():
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.models.layer_list import write_positions
+
+    B, K, L, d = 3, 1, 128, 16
+    cache = {"k": jnp.zeros((B, K, L, d), jnp.bfloat16), "v": jnp.ones((B, K, L, d), jnp.bfloat16)}
+    k = jax.random.normal(jax.random.key(1), (B, K, 1, d), jnp.float32).astype(jnp.bfloat16)
+    at = np.array([[0], [127], [50]])
+    new = jax.jit(write_positions)(cache, k, -k, jnp.asarray(at, jnp.int32))
+    for r in range(B):
+        assert np.array_equal(np.asarray(new["k"][r, 0, at[r, 0]]), np.asarray(k[r, 0, 0]))
+        assert np.array_equal(np.asarray(new["v"][r, 0, at[r, 0]]), np.asarray(-k[r, 0, 0]))
+        untouched = np.delete(np.arange(L), at[r, 0])
+        assert not np.asarray(new["k"][r, 0, untouched]).any() and (np.asarray(new["v"][r, 0, untouched]) == 1).all()
+
+
+# ---- (d) the inner norms, the shared mixer ----
+
+
+def test_a_reference_without_the_inner_norms_fails_the_tolerance(kept):
+    d, cfg, params, key = _setup()
+    prompt = _prompt(2 * CHUNK + 3)
+    (tokens,), _ = _serve(cfg, params, [(prompt, 4)], slots=2)
+    import jax
+
+    jax.effects_barrier()
+    got = next(a for a in kept if a.shape[0] == 1)[0]
+    seq = np.concatenate([prompt, tokens])
+    assert np.abs(got - _reference_logits(d, key, seq)[len(prompt) - 1]).max() < TOL
+    assert np.abs(got - _reference_logits(d, key, seq, inner_norms=False)[len(prompt) - 1]).max() > BROKEN
+
+
+def test_both_families_run_the_one_mamba1_mixer():
+    """``models/phi4_flash.py`` and ``models/jamba.py`` hold no scan and no
+    mixer of their own: the names the decoder-hybrid-decoder's tests find are
+    ``models/ssm.py``'s, and a layer's tree decides whether the inner norms run."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_operator_tpu.models import phi4_flash
+
+    assert phi4_flash.scan_step is ssm.scan_step and phi4_flash.scan_chunk is ssm.scan_chunk
+    for module in (phi4_flash, jamba):
+        text = Path(module.__file__).read_text()
+        assert "lax.scan" not in text and "def scan_" not in text and "ssm.mamba1_mixer(" in text
+    d, cfg, params, key = _setup()
+    w = params["layers"][0]["ssm"]
+    cache = ssm.init_state(2, cfg.d_inner, cfg.d_state, cfg.d_conv, jnp.float32)
+    x = jax.random.normal(jax.random.key(3), (2, 1, cfg.d_model), jnp.float32)
+    with_norms, _, _ = ssm.mamba1_mixer(w, cache, x, norm_eps=cfg.rms_eps)
+    bare = {name: leaf for name, leaf in w.items() if name not in ssm.INNER_NORMS}
+    without, _, _ = ssm.mamba1_mixer(bare, cache, x)
+    assert set(ssm.INNER_NORMS) <= set(w) and np.abs(np.asarray(with_norms - without)).max() > 1e-3
+    assert all(float(jnp.abs(w[name] - 1).max()) > 0.01 for name in ssm.INNER_NORMS)  # drawn near 1, not at it
+
+
+# ---- (e) sizes, presets, the blocks a walk reads ----
+
+
+def test_parameters_count_the_programs_tree_and_the_benchmarks():
+    import jax
+    import jax.numpy as jnp
+
+    cfg = preset("jamba2-3b", decode=True)
+    count = lambda tree: sum(math.prod(a.shape) for a in jax.tree.leaves(tree))
+    program = jax.eval_shape(lambda k: jamba.init_params(cfg, k), jax.random.key(0))
+    d = W.dims(CELL)
+    bench = jax.eval_shape(lambda k: W.make_params(d, k, jnp.bfloat16), jax.random.key(0))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), program) == jax.tree.map(lambda a: (a.shape, a.dtype), bench)
+    # 3,029.3 M: ISSUE 46 wrote 3,027.8 M from a Mamba layer rounded to 104.1 M; the layer is 104.16 M
+    assert FLOPS.parameters(CELL) == count(program) == CELL["bytes"]["parameters"] == 3_029_337_472
+    assert cfg.layers == d["kinds"] and [i for i, kind in enumerate(cfg.layers) if kind == jamba.FULL] == [7, 21]
+    assert INSTALL.config_base(CELL, d) == {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)
+                                            if f.name in INSTALL.config_base(CELL, d)}
+    engine = CELL["bench"]["engine"]
+    cache = jax.eval_shape(lambda: jamba.init_cache(dataclasses.replace(cfg, max_decode_len=engine["max_decode_len"]),
+                                                    engine["slots"], engine["chunk"]))
+    gauges = cfg.serving_model().gauges(cache)
+    assert gauges == {"cache_full_bytes": CELL["bytes"]["cache_full_bytes"], "cache_state_bytes": CELL["bytes"]["cache_state_bytes"]}
+    assert gauges["cache_full_bytes"] // engine["slots"] == 33_554_432 and FLOPS.kv_bytes_per_position(d) * 2 == 1024
+    assert 26 * FLOPS.state_bytes_per_row(d) == gauges["cache_state_bytes"] / engine["slots"] == 9_318_400
+
+
+def test_the_server_finds_the_family_by_its_presets():
+    table = families()
+    assert table["jamba2-3b"][0] is jamba and table["jamba-tiny"][0] is jamba
+    cfg = preset("jamba-tiny", decode=True, max_decode_len=64)
+    model = cfg.serving_model()
+    assert model.holds and model.finish is None and model.drafter is None and model.decode_reads_per_row
+    with pytest.raises(ValueError, match="unquantised"):
+        preset("jamba-tiny", quantize=True)
+
+
+def test_a_block_does_not_grow_with_the_slab():
+    from pytorch_operator_tpu.ops import cache_attention as ca
+
+    assert [ca.block(L) for L in (128, 1001, 4096, 8192, 32_768)] == [16, 1001, 512, 512, 512]
+    assert ca.attended(5000, 32_768) == 5120 and ca.attended(3000, 4096) == 3072 and ca.attended(1, 32_768) == 512
+    assert ca.blocks_needed(32_768, 32_768) == 64
+
+
+def test_least_bytes_and_operations_count_the_issues_arithmetic():
+    d = W.dims(CELL)
+    assert FLOPS.layer_params(d, "mamba")["ssm"] == 41_241_792 and FLOPS.layer_params(d, "attn_full")["attn"] == 13_762_560
+    assert FLOPS.mamba_layers(d) == 26 and FLOPS.attention_layers(d) == 2
+    # a row 10,000 deep: 2 slabs x 10,000 x 512 B
+    assert FLOPS.walk_step_bytes_min(CELL, slots=16, mean_positions=10_000) == 16 * 2 * 10_000 * 512
+    step = FLOPS.decode_step_bytes_min(CELL, slots=16, mean_positions=10_000)
+    assert step == 2 * 3_029_337_472 + 26 * 2 * 16 * 358_400 + 163_840_000
+    chunk = FLOPS.forward_flops_per_token(CELL, 0, head=False)
+    assert FLOPS.forward_flops_per_token(CELL, 0) - chunk == FLOPS.head_flops(CELL) == 2 * 2560 * 65_536
+    assert 5.5e9 < chunk < 5.8e9  # 2 x the 2.86 G matrix parameters + 26 scans' 0.49 M x 6
+    assert FLOPS.forward_flops_per_token(CELL, 9999, head=False) - chunk == pytest.approx(2 * 2 * 20 * 2 * 128 * 9999)
+
+
+# ---- (f) the readers and the manifest ----
+
+
+def test_the_prefill_reducer_sums_programs_scopes_spans_and_the_walks_kernel():
+    from benchmark import prefill_reduce as P
+
+    ms = 1_000_000
+    ops = [("%a = fusion()", 0, 4 * ms), ("%b = fusion()", 4 * ms, 6 * ms), ("%c = fusion()", 10 * ms, 11 * ms),
+           ("%walk = custom-call()", 12 * ms, 13 * ms), ("%walk = custom-call()", 14 * ms, 15 * ms), ("%while.1 = while()", 0, 6 * ms)]
+    mods = [("jit_prefill_chunk(1)", 0, 6 * ms), ("jit_prefill_chunk_head(2)", 10 * ms, 11 * ms), ("jit_decode_block(3)", 12 * ms, 15 * ms)]
+    paths = {"%a = fusion()": "jit(prefill_chunk)/ssm/ssm_scan/while/body/mul", "%b = fusion()": "jit(prefill_chunk)/dense_mlp/dot_general",
+             "%c = fusion()": "jit(prefill_chunk_head)/head/dot_general",
+             "%walk = custom-call()": "jit(decode_block)/while/body/attn_full/cache_attention_decode",
+             "%while.1 = while()": "jit(prefill_chunk)/ssm/ssm_scan/while"}
+    spans = [(1 * ms, {"start": 512, "slot": 0, "n_real": 512, "head": 0, "resumed": 1}),
+             (5 * ms, {"start": 1024, "slot": 0, "n_real": 100, "head": 1, "resumed": 1}), (99 * ms, {"start": 0, "n_real": 5, "head": 1})]
+    red = P.reduce_events([(ops, mods)], spans, paths)
+    assert red["prefill_s"] == pytest.approx(0.007) and (red["chunk_runs"], red["head_runs"]) == (1, 1)
+    assert red["prefill_scope_s"]["ssm_scan"] == red["prefill_scope_s"]["ssm"] == pytest.approx(0.004)
+    assert red["prefill_scope_s"]["dense_mlp"] == pytest.approx(0.002)
+    assert (red["decode_walk_s"], red["decode_walk_events"]) == (pytest.approx(0.002), 2)
+    assert red["spans"] == {"chunks": 2, "n_real": 612, "position_sum": 512 * 767.5 + 100 * 1073.5, "heads": 1, "resumed": 2}
+    tokens, position, heads = P.prefill_tokens(red)
+    assert tokens == 306 and heads == 1 and position == pytest.approx((512 * 767.5 + 100 * 1073.5) / 612)
+    bare = P.reduce_events([(ops, mods)], [(1 * ms, {"start": 0})], paths)  # the parent's spans say less
+    assert "spans" not in bare and P.prefill_tokens(bare) is None and P.prefill_tokens({}) is None
+
+
+def test_the_four_new_readers_count_a_record_and_import_no_jax():
+    code = """
+import json, sys
+from pathlib import Path
+sys.path.insert(0, %r)
+from benchmark import run, dispatch_reduce, prefill_reduce, scope_reduce
+red = {"busy_s": 3.9, "prefill_s": 3.0, "chunk_runs": 100.0, "head_runs": 4.0,
+       "prefill_scope_s": {"ssm": 1.2, "ssm_conv": 0.1, "ssm_scan": 0.6, "attn_full": 0.3, "dense_mlp": 1.0},
+       "decode_walk_s": 0.02, "decode_walk_events": 200.0,
+       "spans": {"chunks": 50, "n_real": 25000, "position_sum": 25000 * 6000.0, "heads": 2, "resumed": 20}}
+prefill_reduce.reduction = lambda ctx: red
+scope_reduce.reduction = lambda ctx: {"decode_steps": 100.0}
+# the window's paired dispatches: 80 steps of 11 rows 7,000 deep (NOT the whole run's 12 rows 9,000 deep)
+dispatch_reduce.reduction = lambda ctx: {"steps": 80, "row_steps": 880, "live_steps": 880 * 7000, "attended": 880 * 7200} if red else {}
+final = {"decode_steps": 1000, "decode_tokens": 12000, "decode_row_steps": 12500, "decode_live_positions": 12000 * 9000,
+         "decode_attended_positions": 12000 * 9300, "prefill_rounds": 66, "admitted": 60, "prefill_chunks": 1200, "admit_rounds": 40}
+ctx = {"cell": {"name": "serve-jamba2-3b-longdoc"}, "bench": Path("benchmark").resolve(), "device": {"device_kind": "TPU v5 lite"},
+       "config": json.load(open("benchmark/configs/jamba2-3b-serve.json")), "reports": [{}], "final": final}
+names = ["prefill_mfu_pct.serve_tps", "prefill_scan_share_pct.serve_tps", "slab_walk_roofline_pct.serve_tps",
+         "prefill_rounds_per_prompt.serve_tps"]
+got = [run.read_layer_metric(n, ctx) for n in names]
+red = {}
+ctx["final"] = {k: v for k, v in final.items() if k != "prefill_rounds"}
+got += [run.read_layer_metric(n, ctx) for n in names]
+print("GOT", json.dumps(got))
+assert "jax" not in sys.modules, "the harness imported JAX"
+"""
+    done = subprocess.run([sys.executable, "-c", code % str(ROOT)], cwd=ROOT, capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+    mfu, scan, walk, rounds, *nothing = json.loads(done.stdout.strip().splitlines()[-1].removeprefix("GOT "))
+    tokens = 500 * 100
+    flops = tokens * FLOPS.forward_flops_per_token(CELL, 6000.0, head=False) + 4 * FLOPS.head_flops(CELL)
+    assert mfu == pytest.approx(100 * flops / 3.0 / 197e12, rel=1e-6) and scan == pytest.approx(20.0)
+    # rows and depth are the traced window's own, over the device's 100 steps there
+    assert walk == pytest.approx(100 * (11 * 2 * 7000 * 512) * 100 / 0.02 / 819e9, rel=1e-6) and rounds == pytest.approx(1.1)
+    assert nothing == [None] * 4  # a trace that does not reduce, a record without the counter
+
+
+def test_the_manifest_declares_the_cell_its_traffic_and_its_metrics_as_the_issue_names_them():
+    manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cell = next(w for w in manifest["workloads"] if w["name"] == "serve-jamba2-3b-longdoc")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("jamba2-3b-serve", "longdoc-closed-20", 1)
+    config = next(c for c in manifest["configs"] if c["name"] == cell["config"])
+    assert config["reduced"] == [] and config["source"] == CELL["source"] and config["file"].endswith("jamba2-3b-serve.json")
+    catalog = {"attn_layer_offset": 7, "attn_layer_period": 14, "expert_layer_offset": 1, "expert_layer_period": 2, "hidden_size": 2560,
+               "intermediate_size": 8192, "mamba_d_conv": 4, "mamba_d_state": 16, "mamba_dt_rank": 160, "mamba_expand": 2,
+               "max_position_embeddings": 262144, "num_attention_heads": 20, "num_experts": 1, "num_experts_per_tok": 1,
+               "num_hidden_layers": 28, "num_key_value_heads": 1, "num_logits_to_keep": 1, "rms_norm_eps": 1e-06, "vocab_size": 65536}
+    assert {k: CELL[k] for k in catalog} == catalog and CELL["reduced"] == {}
+    new = {"prefill_mfu_pct", "prefill_scan_share_pct", "slab_walk_roofline_pct", "prefill_rounds_per_prompt"}
+    for m in manifest["per_layer"]:
+        stem, _, suffix = m["name"].partition(".")
+        if stem in new:
+            assert suffix == "serve_tps" and cell["name"] in m["workloads"] and m["moves"] == "serve_tokens_per_s"
+            assert (ROOT / "benchmark/layer_metrics" / f"{m['name']}.py").is_file()
+    reported = {m["name"] for m in manifest["per_layer"] if cell["name"] in m.get("workloads", [])}
+    assert {f"{n}.serve_tps" for n in new} <= reported
+    assert {"attn_full_share_pct.serve_tps", "decode_step_hbm_roofline_pct.serve_tps", "prefill_share_pct.serve_tps",
+            "decode_step_ms.serve_tps", "generator_supply_used_pct.serve_tps", "ssm_share_pct.serve_tps"} <= reported
+    # NOT ``ssm_state_roofline_pct``, which ISSUE 46 listed: on the chip it read 118% here, because its reader divides
+    # by the ``ssm`` scope's time inside decode_block alone (PERF.md section 6 and 7, PR 46, say what the trace shows)
+    assert not {"ssm_state_roofline_pct.serve_tps", "decode_hbm_roofline_pct.serve_tps",
+                "attn_window_share_pct.serve_tps", "spec_walk_roofline_pct.serve_tps"} & reported
+    mix = json.loads((ROOT / "benchmark/traffic" / f"{cell['traffic']}.json").read_text())
+    rng, table = random.Random(mix["drawn_from"]["table_seed"]), []
+    for _ in range(128):
+        p, a = rng.lognormvariate(math.log(8192), 0.6), rng.lognormvariate(math.log(256), 0.5)
+        table.append([min(max(round(p), 2048), 24576), min(max(round(a), 64), 512)])
+    assert mix["drawn_from"]["table_seed"] == 20261003 and mix["lengths"] == table
+    assert mix["clients"] == 20 == CELL["bench"]["engine"]["slots"] + 4 and mix["loop"] == "closed" and mix["cycle_entry"] == 0
+    assert mix["check_pad_to"] == max(p + a for p, a in table) == 25_029 < CELL["bench"]["engine"]["max_decode_len"] - 1
+    assert max(p for p, _ in table) > engine_lib.ADMIT_TOKENS  # the cell sends prompts that outlast a boundary
+
+
+# ---- the normal path: tpujob run -> supervisor -> workloads/serve.py -> ServingEngine ----
+
+
+def test_tpujob_run_of_a_serve_job_with_the_preset_answers_requests_and_why_prints_the_rounds(tmp_path):
+    """``examples/serve-hybrid-longdoc.yaml`` with the test-size preset on a
+    CPU device: the job answers its requests, its final record carries the
+    model's counter and gauges beside the engine's ``prefill_rounds``, and
+    ``tpujob why`` prints the rounds beside the admissions."""
+    import re
+    import threading
+
+    import yaml
+
+    from pytorch_operator_tpu.serving import Spool
+
+    job = yaml.safe_load((ROOT / "examples/serve-hybrid-longdoc.yaml").read_text())
+    template = job["spec"]["replica_specs"]["Master"]["template"]
+    assert template["module"] == "pytorch_operator_tpu.workloads.serve" and "jamba2-3b" in template["args"]
+    spool_dir = tmp_path / "spool"
+    template["args"] = ["--config", "jamba-tiny", "--spool", str(spool_dir), "--slots", "2", "--chunk", "16",
+                        "--block", "4", "--max-decode-len", "128", "--max-requests", "3", "--idle-timeout", "120",
+                        "--json"]
+    template["resources"] = {"cpu_devices": 1}
+    (tmp_path / "job.yaml").write_text(yaml.safe_dump(job))
+    sp, got = Spool(spool_dir), {}
+
+    def client():
+        rids = [sp.submit(prompt_len=21, max_new_tokens=9), sp.submit(prompt=[3, 1, 4, 1, 5], max_new_tokens=12),
+                sp.submit(prompt_len=40, max_new_tokens=5)]
+        for rid in rids:
+            got[rid] = sp.wait_response(rid, timeout=240)
+
+    t = threading.Thread(target=client)
+    t.start()
+    cli = [sys.executable, "-m", "pytorch_operator_tpu.client.cli", "--state-dir", str(tmp_path / "state")]
+    done = subprocess.run([*cli, "run", str(tmp_path / "job.yaml"), "--timeout", "240"],
+                          cwd=ROOT, capture_output=True, text=True, timeout=300)
+    t.join(timeout=60)
+    log = "\n".join(p.read_text() for p in (tmp_path / "state" / "logs").glob("*.log"))
+    assert done.returncode == 0 and not t.is_alive(), done.stdout[-1500:] + log[-3000:]
+    assert sorted(len(r["tokens"]) for r in got.values()) == [5, 9, 12]
+    final = json.loads(log[log.index("[serve] done: ") + len("[serve] done: "):].splitlines()[0])
+    assert final["config"] == "jamba-tiny" and min(final[k] for k in ("cache_state_bytes", "cache_full_bytes")) > 0
+    assert final["prefill_state_resets"] == final["admitted"] == final["prefill_rounds"] == 3 and final["prefill_tokens"] == 66
+    why = subprocess.run([*cli, "why", job["metadata"]["name"]], cwd=ROOT, capture_output=True, text=True, timeout=120)
+    assert "3 row(s) started from zero state for 3 admitted" in why.stdout, why.stdout[-2000:]
+    assert re.search(r"rounds: +\S+ prefill_rounds 3 for 3 admitted = 1.00 a prompt", why.stdout), why.stdout[-2000:]

@@ -470,20 +470,21 @@ def test_a_boundary_admits_a_bounded_stretch_of_prefill_and_always_one_prompt(pa
     prompts = [rng.integers(1, 250, (40,)).astype(np.int32) for _ in range(4)]
 
     def served(bound):
-        monkeypatch.setattr(engine_lib, "ADMIT_CHUNKS", bound)
+        monkeypatch.setattr(engine_lib, "ADMIT_TOKENS", bound * 8)  # stated in tokens: ``bound`` chunks of 8
         eng = ServingEngine(cfg, params, slots=4, chunk=8, block=16)
         for i, prompt in enumerate(prompts):
             eng.submit(_req(f"b{i}", prompt, 12))
         got = {r.id: r.tokens for r in eng.run_until_drained()}
         return [got[f"b{i}"] for i in range(4)], eng.stats()
 
-    whole, n = served(engine_lib.ADMIT_CHUNKS)
+    whole, n = served(engine_lib.ADMIT_TOKENS // 8)
     assert n["admit_rounds"] == 1 and n["prefill_chunks"] == 20
     before = len(sized_by())
     bounded, n = served(10)
     assert bounded == whole and n["admit_rounds"] == n["decode_behind_admit"] == 2 and "quantum" in sized_by()[before:]
     alone, n = served(3)
     assert alone == whole and n["admit_rounds"] == 4 and n["admitted"] == 4
+    assert n["prefill_rounds"] == 4  # this family's decode step cannot hold a row: a prompt is never split
 
 
 def test_a_first_token_that_ends_its_request_costs_one_dispatch_and_leaves_the_slot_clean(parity_model):

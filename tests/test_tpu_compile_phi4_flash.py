@@ -139,11 +139,20 @@ def test_a_decode_step_updates_each_layers_state_in_one_fusion_and_copies_no_rin
     assert not [l for l in text.splitlines() if " while(" in l and ('attn_full/while"' in l or 'attn_cross/while"' in l)]
 
 
+def scan_kernels(text):
+    """The Mosaic kernels of ``models.ssm.scan_chunk`` (a layer's chunk of the recurrence in one call) in a compiled
+    program's text: the instructions' lines."""
+    return [l for l in text.splitlines() if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l
+            and "/ssm/ssm_scan/ssm_scan_chunk/" in l]
+
+
 def test_a_prefill_chunk_runs_the_self_decoder_only_and_writes_in_place(compiled):
     text = compiled("prefill_chunk").as_text()
     writers = _writers(text, "f32", (STATE,))
-    # One a layer, each an update-slice of the donated leaf (fused with the row's own arithmetic), none a copy.
-    assert len(writers) == MAMBA_LAYERS and all("dynamic_update_slice" in name for _, name in writers), writers
+    # One a layer, each an update-slice of the donated leaf (most fused into the chunk's scan kernel's call), none a copy.
+    assert len(writers) == MAMBA_LAYERS, writers
+    assert all("ssm/ssm_scan/" in name and ("ssm_scan_chunk" in name or "dynamic_update_slice" in name) for _, name in writers), writers
+    assert len(scan_kernels(text)) == MAMBA_LAYERS and not [l for l in text.splitlines() if " while(" in l and "ssm_scan" in l]
     assert not _writers(text, "bf16", (RING, SLAB) + ROWS)  # keys and values go where they lie; no row is cut out
     assert "jit(prefill_chunk)/ssm/ssm_scan" in text and "jit(prefill_chunk)/attn_window" in text
     assert "jit(prefill_chunk)/attn_full" in text  # the slab's write
@@ -164,7 +173,8 @@ def test_the_head_program_runs_the_cross_decoder_on_one_token_and_copies_no_cach
     mem = head.memory_analysis()
     assert 5.8e9 < mem.argument_size_in_bytes < 6.1e9  # 2.01 of slab + 2.94 of layers 17-31 + 1.02 of embedding
     assert mem.temp_size_in_bytes < 0.1e9 and not _writers(text, "bf16", (SLAB,) + ROWS)
-    # one row (``slot``): the loop, whose bound is the row's own; so too the chunk's program
-    assert "tpu_custom_call" not in text and "tpu_custom_call" not in compiled("prefill_chunk").as_text()
+    # one row (``slot``): the loop, whose bound is the row's own; so too the chunk's program, whose only kernels are its scans
+    chunk = compiled("prefill_chunk").as_text()
+    assert "tpu_custom_call" not in text and chunk.count('custom_call_target="tpu_custom_call"') == len(scan_kernels(chunk))
     assert "/cache_write/" not in text and "/cache_write/" not in compiled("prefill_chunk").as_text()  # a decode step's alone
     assert len([l for l in text.splitlines() if " while(" in l and re.search(r'attn_(full|cross)/while"', l)]) == 8
