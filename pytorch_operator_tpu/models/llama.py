@@ -999,6 +999,20 @@ def decode_forward(
             x = table.astype(cfg.dtype)[tokens]
 
     block = Block(cfg, model.mesh)
+
+    # ONE function for every layer: the layers have one shape, so the second
+    # to the last call find the first one's trace and the program holds the
+    # layer once, called ``n_layers`` times (the compiler inlines the calls:
+    # what runs is the unrolled loop's program). Traced a layer at a time, 24
+    # layers were ~1.4 s of every program's start (PERF.md section 6, PR 47).
+    @jax.jit
+    def layer(lp, layer_cache, x, positions, slot):
+        with nn.logical_axis_rules(()):
+            ((x, _pos), _), upd = block.apply(
+                {"params": lp, "cache": layer_cache}, (x, positions), slot, mutable=["cache"]
+            )
+        return x, upd["cache"]
+
     layers = p["layers"]
     new_cache = {}
     for i in range(cfg.n_layers):
@@ -1007,14 +1021,7 @@ def decode_forward(
         # slice of the scan-stacked leaves (QuantizedTensor is a pytree
         # node, so its q/scale fields are sliced like any other leaf).
         lp = layers[i] if isinstance(layers, list) else jax.tree.map(lambda a: a[i], layers)
-        with nn.logical_axis_rules(()):
-            ((x, _pos), _), upd = block.apply(
-                {"params": lp, "cache": cache[f"layer_{i}"]},
-                (x, positions),
-                slot,
-                mutable=["cache"],
-            )
-        new_cache[f"layer_{i}"] = upd["cache"]
+        x, new_cache[f"layer_{i}"] = layer(lp, cache[f"layer_{i}"], x, positions, slot)
 
     x = RMSNorm(cfg.rms_eps).apply(
         {"params": dequantize_tree(p["final_norm"])}, x
@@ -1100,6 +1107,7 @@ def serving_model(cfg: LlamaConfig):
         decode=decode,
         logits=logits,
         decode_reads_per_row=reads_per_row(quantized=cfg.kv_quantize == "int8"),
+        prefill_any_width=True,
         arrange=per_layer_params,
     )
 

@@ -121,6 +121,25 @@ class ServingModel:
     # decode dispatches between (``serving/engine.py``); for every other a
     # boundary admits a prompt whole, whatever its length.
     holds: bool = False
+    # Whether ``prefill`` takes a chunk of ANY width up to the engine's wide
+    # width (``serving/engine.py:wide_chunk``: the cache attention's block)
+    # at the cost a token it has at the configuration's ``chunk``, and
+    # ``init_cache`` makes the same cache whatever ``chunk`` it is given.
+    # Only for such a model does the engine build a second chunk program and
+    # prefill a long prompt's body through it, the weights read once per
+    # wide chunk; every other model's programs and schedules are what they
+    # were. The llama family says so (its cache is slabs alone and its chunk
+    # attends through ``cache_attention(..., slot=)``, general in the
+    # chunk's width). What the others would need first: the ring families
+    # (``mimo_v2``, ``phi4_flash``) size a window layer's ring ``window +
+    # chunk``, so rings sized for the wide width (more memory, and a decode
+    # step reads rings whole) or a chunk that wraps its ring in sub-chunks;
+    # ``nemotron_h``'s ``scan_chunk`` is quadratic in the chunk (a ``[G, Hg,
+    # S, S]`` decay in float32 products), so a scan that walks a wide chunk
+    # in sub-chunks; ``jamba``'s cells state 512 already; a model that
+    # drafts gets each chunk with the token that follows it, and the engine
+    # builds no wide program for one (``wide_chunk``) until one asks.
+    prefill_any_width: bool = False
     # a checkpoint's parameter tree (the trainer's form, host arrays) ->
     # the same leaves as ``init_params`` arranges them, which is how the
     # forwards read them fastest (``workloads.generate.load_params`` calls

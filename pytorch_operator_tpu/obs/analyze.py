@@ -524,8 +524,11 @@ def analyze(
     # A serving engine's admissions (serving/engine.py): the rounds that
     # admitted, beside the decode dispatches queued behind one with its
     # first token still unread. Equal whenever an admitted row decodes.
+    # Then the prompts' chunks, and those that went through the wide program
+    # (a long prompt's body, for a model that takes a wide chunk).
     admit_rounds = {
-        rec["replica"]: [rec["admit_rounds"], rec.get("decode_behind_admit", 0), rec.get("admitted", 0)]
+        rec["replica"]: [rec["admit_rounds"], rec.get("decode_behind_admit", 0), rec.get("admitted", 0),
+                         rec.get("prefill_chunks", 0), rec.get("prefill_wide_chunks", 0)]
         for rec in tl.records.get("metrics", [])
         if "admit_rounds" in rec
     }
@@ -656,10 +659,11 @@ def render_report(report: dict) -> str:
         )
     for replica, (ran, prefilled) in sorted(report.get("cross_tokens", {}).items()):
         lines.append(f"prefill:  {replica} prefill_cross_tokens {ran} beside prefill_tokens {prefilled}")
-    for replica, (rounds, behind, admitted) in sorted(report.get("admit_rounds", {}).items()):
+    for replica, (rounds, behind, admitted, chunks, wide) in sorted(report.get("admit_rounds", {}).items()):
         lines.append(
             f"admits:   {replica} {admitted} admitted in {rounds} round(s), "
-            f"the decode dispatch queued behind {behind} of them before a first token was read"
+            f"the decode dispatch queued behind {behind} of them before a first token was read; "
+            f"{wide} of {chunks} prefill chunk(s) wide"
         )
     for replica, (rounds, admitted) in sorted(report.get("prefill_rounds", {}).items()):
         if admitted:

@@ -33,7 +33,7 @@ TPU-first shape (every program's shapes static):
   last row's budget, never more than ``block``, the most steps one
   dispatch may run. Each dispatch's span says which rule sized it
   (``BENCHMARK.json``'s chat and longprompt cells judge it).
-- ONE prefill program, ``prefill_chunk``: fixed-size chunks through the
+- The prefill program, ``prefill_chunk``: fixed-size chunks through the
   model's chunked-prefill forward into one slot's row of the donated
   cache, in place (the model is told the slot; nothing row-sized is
   sliced out or written back), last chunk padded — the pad tokens write
@@ -57,6 +57,28 @@ TPU-first shape (every program's shapes static):
   Arbitrary prompt lengths therefore hit exactly these compiled programs,
   and a prompt longer than one program's activation budget prefills in
   bounded O(chunk · L) score memory.
+- TWO widths of it where the model takes them (PR 47). A chunk pays a cost
+  that does not grow with its tokens: its weights are read once a chunk,
+  and at the configuration's ``chunk`` (the width that suits its SHORT
+  prompts: a prompt pads to whole chunks) that read is not hidden behind
+  the tokens' operations. So a model that says its ``prefill`` takes any
+  width and its cache does not depend on it
+  (``ServingModel.prefill_any_width``: the llama family; nothing else
+  selects the path, no option and no model's name) also gets
+  ``prefill_chunk_wide``, the same function at :func:`wide_chunk` tokens (the
+  cache attention's block, 512), and each prompt's chunks are chosen from
+  its length (:func:`chunk_schedule`): wide from position 0 while a wide
+  chunk's worth of tokens remains, the rest narrow, or as one more wide
+  chunk where the narrow ones would pad to its length anyway; a short
+  prompt keeps the narrow schedule exactly, and every prompt pads to what
+  it padded to. The wide program hands the head's ONE program the narrow
+  window of its hidden states that holds the last real token. The wide
+  program is compiled from shapes (or loaded from the compile cache) when
+  the engine is made, in line: no warm-up request need reach it, and
+  nothing compiles or loads inside a long prompt's admission.
+  ``prefill_wide_chunks`` counts its dispatches beside ``prefill_chunks``
+  (every chunk); a dispatch's span says its ``width``. Every other model
+  has one width, its programs and schedule as before.
 - An admission costs the device no round trip to the host: a boundary
   queues every admitted prompt's chunks and head, then ``decode_block``
   behind them (what it needs of a new row is the request's: its position
@@ -208,7 +230,7 @@ SIZED_BY = ("budget", "quantum", "ceiling")
 _COUNTERS = (
     "decode_blocks", "decode_steps", "slot_blocks_occupied", "decode_row_steps", "decode_tokens",
     "decode_live_positions", "decode_attended_positions", "prefill_attended_positions",
-    "prefill_chunks", "prefill_head_chunks", "prefill_tokens", "prefill_pad_tokens",
+    "prefill_chunks", "prefill_wide_chunks", "prefill_head_chunks", "prefill_tokens", "prefill_pad_tokens",
     "admit_rounds", "decode_behind_admit", "admitted", "prefill_rounds",
 )
 SPAN_CAT = "engine"
@@ -273,6 +295,51 @@ def decode_steps(remaining, free_slots: int, block: int, per_step: int = 1) -> t
     return steps, sized_by
 
 
+def wide_chunk(model, chunk: int) -> Optional[int]:
+    """The width of the wide prefill chunk, or None where there is none. The
+    model says whether it takes one (``ServingModel.prefill_any_width``);
+    the width is the cache attention's block: a chunk's scores against one
+    block of its row's slab are ``[1, K, G, width, block]`` float32, and a
+    chunk wider than that block was 3.2 x slower on the chip (PERF.md
+    section 6, PR 46), so the one constant is the attention's own. None
+    where the configuration's chunk is already that wide, does not divide
+    it (the narrow chunks behind the wide ones start on a multiple of
+    theirs), or the slab is no longer than it; and for a model that drafts
+    (its chunks come with the token that follows them: no such model takes
+    a wide chunk yet)."""
+    from ..ops.cache_attention import BLOCK_MAX as wide
+
+    fits = chunk < wide < model.cfg.max_decode_len and wide % chunk == 0
+    return wide if model.prefill_any_width and model.drafter is None and fits else None
+
+
+def chunk_schedule(p: int, chunk: int, wide: Optional[int]) -> list[tuple[int, int]]:
+    """The chunks a prompt of ``p`` tokens is prefilled in, as ``(start,
+    width)``: in order, covering ``[0, p)`` once, only the last padded.
+    Without a wide width (:func:`wide_chunk`) every chunk is ``chunk`` wide.
+    With one, wide chunks from position 0 while at least ``wide`` tokens
+    remain; what is left after them in narrow chunks, unless those would pad
+    to a whole wide chunk's length: then one more wide chunk runs the same
+    padded tokens with one read of the weights for several (on the chip a
+    chunk of 512 took 12.81 ms and four of 128 took 4 x 4.10: PERF.md
+    section 6, PR 47). So a prompt pads to the length it padded to with
+    narrow chunks alone, and one shorter than that tail keeps the narrow
+    schedule exactly."""
+    if wide is None:
+        return [(start, chunk) for start in range(0, p, chunk)]
+    body = p - p % wide
+    out, tail = [(start, wide) for start in range(0, body, wide)], range(body, p, chunk)
+    if len(tail) * chunk == wide:
+        return out + [(body, wide)]
+    return out + [(start, chunk) for start in tail]
+
+
+def padded_len(chunks: list) -> int:
+    """Where a schedule's last chunk ends: the prompt's length with its pad."""
+    start, width = chunks[-1]
+    return start + width
+
+
 @dataclasses.dataclass
 class Request:
     id: str
@@ -306,10 +373,13 @@ class _Slot:
     remaining: int
     tokens: list[int]
     done: bool = False
-    # A row part-way through its prompt (ServingModel.holds): the padded
-    # prompt and where its next chunk starts; None once its head is queued.
+    # A row whose prompt is not all queued yet: the padded prompt, its chunks
+    # (:func:`chunk_schedule`) and how many of them are queued (more than
+    # none and fewer than all only for a row part-way through its prompt,
+    # ``ServingModel.holds``); ``buf`` is None once its head is queued.
     buf: Optional[np.ndarray] = None
-    next_start: int = 0
+    chunks: list = dataclasses.field(default_factory=list)
+    queued: int = 0
 
 
 class Programs(NamedTuple):
@@ -318,6 +388,9 @@ class Programs(NamedTuple):
     prefill_chunk: Callable
     prefill_chunk_head: Callable
     decode_block: Callable
+    # The chunk's program at the wide width, where the model takes one
+    # (:func:`wide_chunk`); None for every other model.
+    prefill_chunk_wide: Optional[Callable] = None
 
 
 def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
@@ -332,6 +405,11 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
     L = model.cfg.max_decode_len
     add = functools.partial(jax.tree.map, jnp.add)
 
+    def chunk_forward(width, params, cache, counts, slot, chunk_toks, start, n_real):
+        pos = (start + jnp.arange(width, dtype=jnp.int32))[None, :]
+        hidden, cache, added = model.prefill(params, cache, slot, chunk_toks, pos, n_real)
+        return hidden, cache, add(counts, added)
+
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def prefill_chunk(params, cache, counts, slot, chunk_toks, start, n_real=chunk):
         """One [1, chunk] prefill chunk into row ``slot`` of the batch
@@ -344,9 +422,24 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         logits of a prompt's earlier chunks (:func:`prefill_chunk_head`
         is for its last). ``counts`` are the model's counters so far, to
         which this call's are added."""
-        pos = (start + jnp.arange(chunk, dtype=jnp.int32))[None, :]
-        hidden, cache, added = model.prefill(params, cache, slot, chunk_toks, pos, n_real)
-        return hidden, cache, add(counts, added)
+        return chunk_forward(chunk, params, cache, counts, slot, chunk_toks, start, n_real)
+
+    wide, prefill_chunk_wide = wide_chunk(model, chunk), None
+    if wide is not None:
+
+        @functools.partial(jax.jit, donate_argnums=(1, 2))
+        def prefill_chunk_wide(params, cache, counts, slot, chunk_toks, start, n_real=wide):
+            """:func:`prefill_chunk` at ``[1, wide]`` (:func:`wide_chunk`),
+            for the body of a long prompt: the weights are read once for
+            ``wide`` tokens. Of the hidden states it returns the ``chunk``
+            of them that hold the last real token (``wide`` is a multiple
+            of ``chunk``, so they start on a multiple of ``chunk`` as a
+            narrow chunk does): whichever program ran a prompt's last
+            chunk, the head's one program takes ``[1, chunk, D]`` and finds
+            the prompt's last position at ``(p - 1) % chunk``."""
+            hidden, cache, counts = chunk_forward(wide, params, cache, counts, slot, chunk_toks, start, n_real)
+            at = (n_real - 1) // chunk * chunk
+            return jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, at, chunk, axis=1), hidden), cache, counts
 
     def finish(params, cache, slot, h, position):
         if model.finish is not None:
@@ -373,8 +466,13 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         admission) and the next key. A program of its own and not a
         second form of the chunk's: a second copy of the whole chunk
         program cost every run 2.3 s of set-up to load (PERF.md section
-        6, PR 31). Its name keeps ``prefill_chunk`` in it, by which the
-        benchmark finds the prefill's programs."""
+        6, PR 31), for one product a prompt. (``prefill_chunk_wide`` IS a
+        second copy, and pays for itself: a long prompt's body runs a
+        quarter more tokens a second through it, and its load is paid
+        for by what the llama family's programs no longer spend on
+        tracing their layers one by one: PERF.md section 6, PR 47.) Its
+        name keeps ``prefill_chunk`` in it, by which the benchmark finds
+        the prefill's programs."""
         with jax.named_scope("head"):
             at = (p - 1) % chunk
             h = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, at, 1, axis=1)[:, 0], hidden)
@@ -420,7 +518,7 @@ def programs(model, *, slots: int, chunk: int, block: int, sample) -> Programs:
         return toks.swapaxes(0, 1), cache, counts, tok, pos, rng
 
     if model.drafter is None:
-        return Programs(prefill_chunk, prefill_chunk_head, decode_block)
+        return Programs(prefill_chunk, prefill_chunk_head, decode_block, prefill_chunk_wide)
     return Programs(prefill_chunk, *_drafting_programs(model, finish, slots=slots, chunk=chunk, block=block, sample=sample))
 
 
@@ -552,10 +650,11 @@ class ServingEngine:
         self._params = params
         self._rng = jax.random.key(seed)
         self._first_key = jax.random.key(seed + 1)
-        self._prefill_chunk, self._prefill_chunk_head, self._decode_block = programs(
+        self._prefill_chunk, self._prefill_chunk_head, self._decode_block, prefill_chunk_wide = programs(
             model, slots=slots, chunk=chunk, block=block,
             sample=make_sampler(temperature, top_k, top_p),
         )
+        self.wide = wide_chunk(model, chunk)
         self._jnp = jnp
         self._jax = jax
         self._attended = attended  # the cache attention's own rounding, for the counters
@@ -573,12 +672,36 @@ class ServingEngine:
         # Admissions whose first token is still on the device, in order.
         self._unread: list[tuple[_Slot, object]] = []
         self._round_queued = False  # something is queued at this boundary already
-        self._queue: deque[Request] = deque()
+        self._queue: deque[tuple[Request, list]] = deque()  # each with its prompt's chunk_schedule
         self.last_steps = 0  # steps of the newest decode dispatch
         # Latency/throughput accounting.
         self.completed: list[RequestResult] = []
         self._tpot_samples: list[float] = []
         self._clear_record()
+        self._prefill_chunk_wide = None
+        if self.wide is not None:
+            # No request that warms an engine up need be long enough to
+            # reach the wide program, and it must not be traced or loaded
+            # inside a long prompt's admission, so it is made here, in line,
+            # from shapes: lowered (~0.2 s: the model's layers are one
+            # traced function) and compiled, or loaded from the compile
+            # cache (~1.4 s for 24 layers). In line because every way of
+            # doing it beside the serving thread was measured and was worse
+            # (PERF.md section 6, PR 47). The shapes carry an array's place
+            # only where it is committed to it, as ``jit`` takes it: the
+            # compiled program's results are then committed or not as the
+            # jitted narrow one's are, and the head's and the decode program
+            # see one kind of argument, not a second kind and a second
+            # compile later. What is called is the compiled program itself
+            # (a jitted function called after its ``lower().compile()``
+            # traces and loads again).
+            shaped = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=a.sharding if getattr(a, "committed", False) else None))
+            scalar = jax.ShapeDtypeStruct((), jnp.int32)
+            self._prefill_chunk_wide = prefill_chunk_wide.lower(
+                *shaped((params, self._cache, self._counts["prefill"])), scalar,
+                jax.ShapeDtypeStruct((1, self.wide), jnp.int32), scalar, scalar,
+            ).compile()
 
     def _clear_record(self) -> None:
         self._decode_wall = 0.0
@@ -626,8 +749,8 @@ class ServingEngine:
             )
         # Valid stream cap (L-1 reserves the parking slot) AND the
         # padded prefill tail must stay inside the cache.
-        padded = -(-p // self.chunk) * self.chunk
-        if p + request.max_new_tokens > L - 1 or padded > L:
+        chunks = chunk_schedule(p, self.chunk, self.wide)
+        if p + request.max_new_tokens > L - 1 or padded_len(chunks) > L:
             raise ValueError(
                 f"{request.id}: prompt {p} + max_new "
                 f"{request.max_new_tokens} exceeds the cache budget "
@@ -636,30 +759,32 @@ class ServingEngine:
         if not self.busy:
             self.host_lap("idle")  # work arrives: the idle stretch ends here
         request.claim_time = time.time()
-        self._queue.append(request)
+        self._queue.append((request, chunks))
         self._fed = (time.perf_counter(), dict(self._n), dict(self._host_s), self._decode_wall)
 
     def _free_slots(self) -> list[int]:
         return [i for i, s in enumerate(self._slots) if s is None]
 
-    def _begin(self, request: Request, slot: int) -> _Slot:
-        """A prompt's row in ``slot``, none of its chunks queued yet: the
-        row's budget and position after its first token are the request's,
-        and the admission's counters are the whole prompt's."""
+    def _begin(self, request: Request, chunks: list, slot: int) -> _Slot:
+        """A prompt's row in ``slot``, none of its ``chunks`` (its
+        :func:`chunk_schedule`) queued yet: the row's budget and position
+        after its first token are the request's, and the admission's
+        counters are the whole prompt's."""
         L = self.cfg.max_decode_len
         prompt = np.asarray(request.prompt, np.int32)
         p = prompt.shape[0]
-        padded = -(-p // self.chunk) * self.chunk
+        padded = padded_len(chunks)
         # A model that drafts gets each chunk with the token that follows it.
         buf = np.zeros((padded + (self._draft is not None),), np.int32)
         buf[:p] = prompt
         self._n["admitted"] += 1
-        self._n["prefill_chunks"] += padded // self.chunk
+        self._n["prefill_chunks"] += len(chunks)
+        self._n["prefill_wide_chunks"] += sum(width != self.chunk for _, width in chunks)
         self._n["prefill_tokens"] += p
         self._n["prefill_pad_tokens"] += padded - p
         # What the admission's attention reads of the row's slabs: each
         # chunk up to its own end, unless the model says otherwise.
-        reads = np.arange(self.chunk, padded + 1, self.chunk)
+        reads = np.array([start + width for start, width in chunks])
         if self.model.slab_reads is not None:
             reads = self.model.slab_reads(reads, p)
         self._n["prefill_attended_positions"] += int(self._attended(reads, L).sum())
@@ -673,6 +798,7 @@ class ServingEngine:
             remaining=request.max_new_tokens - 1,
             tokens=[],
             buf=buf,
+            chunks=chunks,
         )
         self._slots[slot] = st
         return st
@@ -687,43 +813,45 @@ class ServingEngine:
         (``self._unread``) until the decode dispatch is queued behind it.
         Returns the tokens queued, pads counted."""
         p = len(st.request.prompt)
-        ahead = 0 if self._draft is None else 1
-        padded, begin = st.buf.shape[0] - ahead, st.next_start
-        end = padded
-        if padded - begin > room and self.model.holds:
-            end = begin + max(1, room // self.chunk) * self.chunk
+        part = st.chunks[st.queued:]
+        if self.model.holds:
+            # The whole chunks that fit the room, from the next on.
+            fits = int(np.searchsorted(np.cumsum([width for _, width in part]), room, side="right"))
+            part = part[: max(1, fits)]
+        last = st.queued + len(part) == len(st.chunks)
         self._n["prefill_rounds"] += 1
         # Host values throughout: the dispatch moves what its program reads
         # (a family whose state is keys and values never gets ``n_real``).
         slot_ = np.int32(slot)
         with obs.span(
-            "engine.admit", SPAN_CAT, rid=st.request.id, slot=slot, prompt_len=p, chunks=-(-p // self.chunk),
-            resumed=begin > 0,
+            "engine.admit", SPAN_CAT, rid=st.request.id, slot=slot, prompt_len=p, chunks=len(st.chunks),
+            resumed=st.queued > 0,
         ):
-            for start in range(begin, end, self.chunk):
-                first = self._chunk(st, slot_, start, p, head=start + self.chunk == padded, lap=start == begin)
+            for i, (start, width) in enumerate(part):
+                first = self._chunk(st, slot_, start, width, p, head=last and i == len(part) - 1, lap=i == 0)
         self.host_lap("dispatch")
-        st.next_start = end
-        if end == padded:
+        st.queued += len(part)
+        if last:
             self._n["prefill_head_chunks"] += 1
             st.buf = None  # the row joins the decode dispatches
             self._unread.append((st, first))
-        return end - begin
+        return sum(width for _, width in part)
 
-    def _chunk(self, st: _Slot, slot_, start: int, p: int, *, head: bool, lap: bool):
+    def _chunk(self, st: _Slot, slot_, start: int, width: int, p: int, *, head: bool, lap: bool):
         """One chunk's dispatch, and behind a prompt's last chunk its head's:
         returns the first token (on the device) where the head ran."""
         ahead, first = 0 if self._draft is None else 1, None
-        n_real = min(self.chunk, p - start)
+        n_real = min(width, p - start)
         with obs.span(
-            "engine.prefill_dispatch", SPAN_CAT, start=start, slot=int(slot_), n_real=n_real, head=head,
-            resumed=st.next_start > 0,
+            "engine.prefill_dispatch", SPAN_CAT, start=start, slot=int(slot_), n_real=n_real, width=width, head=head,
+            resumed=st.queued > 0,
         ):
             if lap:
                 self._lap_to_dispatch()
-            hidden, self._cache, self._counts["prefill"] = self._prefill_chunk(
+            run = self._prefill_chunk if width == self.chunk else self._prefill_chunk_wide
+            hidden, self._cache, self._counts["prefill"] = run(
                 self._params, self._cache, self._counts["prefill"], slot_,
-                st.buf[None, start : start + self.chunk + ahead], np.int32(start), np.int32(n_real),
+                st.buf[None, start : start + width + ahead], np.int32(start), np.int32(n_real),
             )
             if head and self._draft is None:
                 # The last chunk's last VALID position (not the padded
@@ -806,10 +934,9 @@ class ServingEngine:
         for slot in self._free_slots():
             if not self._queue:
                 break
-            p = len(self._queue[0].prompt)
-            if -(-p // self.chunk) * self.chunk > room and self._round_queued:
+            if padded_len(self._queue[0][1]) > room and self._round_queued:
                 break  # the rest of the queue at the next boundary, behind a decode dispatch
-            room -= self._admit(self._begin(self._queue.popleft(), slot), slot, room)
+            room -= self._admit(self._begin(*self._queue.popleft(), slot), slot, room)
         self._n["admit_rounds"] += bool(self._unread)
         # Rows with budget left (a request of one token is finished by its
         # first and stays parked).

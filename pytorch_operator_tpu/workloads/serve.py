@@ -354,7 +354,10 @@ def main(argv=None) -> int:
     p.add_argument("--slots", type=int, default=8,
                    help="concurrent cache slots (the serving batch)")
     p.add_argument("--chunk", type=int, default=64,
-                   help="prefill chunk length (bounds prefill memory)")
+                   help="prefill chunk length: what a short prompt pads to, "
+                   "and what a window layer's ring allows for; a model that "
+                   "takes a wider chunk runs a long prompt's body through "
+                   "chunks of 512 (serving/engine.py:chunk_schedule)")
     p.add_argument("--block", type=int, default=16,
                    help="the most decode steps one dispatch may run; the "
                    "engine picks each dispatch's length (to the next slot "

@@ -91,7 +91,9 @@ def test_the_spans_of_the_dispatches_sum_to_the_counters(model, traced_dir, shap
     chunks = _args(spans, "engine.prefill_dispatch")
     assert len(chunks) == n["prefill_chunks"]
     assert sum(c["n_real"] for c in chunks) == n["prefill_tokens"]
-    assert sum(CHUNK - c["n_real"] for c in chunks) == n["prefill_pad_tokens"]
+    # Each says how wide it was (PR 47: a model that takes a wide chunk gets two widths; this slab of 48 has one).
+    assert {c["width"] for c in chunks} == {CHUNK} and n["prefill_wide_chunks"] == 0
+    assert sum(c["width"] - c["n_real"] for c in chunks) == n["prefill_pad_tokens"]
     assert sum(c["head"] for c in chunks) == n["prefill_head_chunks"] == n["admitted"]
     by_admit = {}
     for e in spans:

@@ -195,7 +195,7 @@ def test_a_row_part_way_through_its_prompt_is_neither_free_nor_active(monkeypatc
         eng.submit(Request(id=rid, prompt=_prompt(70, seed), max_new_tokens=4, submit_time=time.time()))
     eng.step()
     n = eng.stats()
-    assert eng._slots[1].buf is not None and eng._slots[1].next_start == 2 * CHUNK and eng.queued == 1
+    assert eng._slots[1].buf is not None and eng._slots[1].queued == 2 and eng.queued == 1
     assert eng.slots_free == 0 and n["admitted"] == 2 and n["prefill_rounds"] == 2 and n["prefill_head_chunks"] == 1
     assert n["decode_blocks"] == 2 and n["slot_blocks_occupied"] == 2  # one row decodes in each; the held row is no row of a dispatch
     assert n["slot_occupancy_pct"] == 50.0 and n["decode_steps"] == 2 * 4  # each the engine's ``block`` of 4

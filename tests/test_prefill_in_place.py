@@ -204,12 +204,16 @@ def test_a_quantized_layer_reaches_its_projections_as_int8():
     jaxpr = jax.make_jaxpr(model.prefill)(params, cache, jnp.int32(0), jnp.zeros((1, CHUNK), jnp.int32), pos)
     kernels = {leaf.q.shape[1:] for leaf in jax.tree.leaves(
         params["layers"], is_leaf=lambda t: isinstance(t, QuantizedTensor)) if isinstance(leaf, QuantizedTensor)}
-    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    # The layers are ONE traced function (PR 47): the program calls it once a layer, and its equations are a layer's.
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "jit" and e.params["name"] == "layer"]
+    assert len(calls) == cfg.n_layers and len({id(e.params["jaxpr"]) for e in calls}) == 1
+    layer = calls[0].params["jaxpr"].jaxpr.eqns
+    dots = [e for e in layer if e.primitive.name == "dot_general"]
     weight_dots = [e for e in dots if e.invars[1].aval.shape in kernels]
-    assert len(weight_dots) == 7 * cfg.n_layers
+    assert len(weight_dots) == 7
     assert all(e.outvars[0].aval.dtype == jnp.float32 for e in weight_dots)
     # ... and nothing multiplies a kernel-shaped array by its scale before the product.
-    muls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "mul" and e.outvars[0].aval.shape in kernels]
+    muls = [e for e in [*layer, *jaxpr.jaxpr.eqns] if e.primitive.name == "mul" and e.outvars[0].aval.shape in kernels]
     assert not muls
 
 

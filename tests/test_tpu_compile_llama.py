@@ -31,6 +31,7 @@ from tests.test_tpu_compile_mimo import WRITES_NOTHING, _arrays, _ints, _top_lev
 HBM = 16 * 1024**3
 
 L_SLOTS, L_CHUNK, L_BLOCK, L_LEN = 8, 128, 64, 4096
+L_WIDE = 512  # serving/engine.py:wide_chunk at these sizes (the cache attention's block)
 PARENT_CHUNK_BYTES = 5.239e9  # `bytes accessed` of the chunk program before PR 31 (scan-stacked parameters)
 WEIGHT = 2048 * 8 * 128  # elements of the smallest matrix of a layer (k_proj, v_proj)
 ROW_SHAPES = (f"[1,8,{L_LEN},128]", f"[1,8,{L_LEN},1]")  # one slot's row of a layer's slabs and scales
@@ -80,6 +81,8 @@ def llama_programs(one_chip):
             key = on(jax.eval_shape(lambda: jax.random.key(0)))
             return progs.prefill_chunk_head.lower(
                 params, cache, hidden, ints(L_SLOTS), ints(L_SLOTS), ints(), ints(), key).compile()
+        if name == "prefill_chunk_wide":  # PR 47: the body of a long prompt, 512 tokens a call; n_real places the window it returns
+            return progs.prefill_chunk_wide.lower(params, cache, {}, ints(), ints(1, L_WIDE), ints(), ints()).compile()
         return progs.prefill_chunk.lower(
             stacked if name == "prefill_chunk_stacked" else params, cache, {}, ints(), ints(1, L_CHUNK), ints()).compile()
 
@@ -115,14 +118,17 @@ def _scatters(text):
 
 
 def _dequantised_weights(text):
-    """Top-level instructions that write a weight-sized bfloat16 or float32 array."""
+    """Top-level instructions that write a weight-sized bfloat16 or float32 array. (Of the wide chunk's 512 tokens the
+    activations ``[1, 512, 8192]`` and a block's scores ``[1, 8, 2, 512, 512]`` are that large too: no weight has an
+    axis of 512, so an array that has one is not counted.)"""
     return [
         (op, name) for op, result, name in _top_level(text) if op not in WRITES_NOTHING
-        and any(dtype in ("bf16", "f32") and n >= WEIGHT for dtype, n, _ in _arrays(result))
+        and any(dtype in ("bf16", "f32") and n >= WEIGHT and str(L_WIDE) not in dims[1:-1].split(",")
+                for dtype, n, dims in _arrays(result))
     ]
 
 
-@pytest.mark.parametrize("form", ["prefill_chunk", "prefill_chunk_stacked"])
+@pytest.mark.parametrize("form", ["prefill_chunk", "prefill_chunk_stacked", "prefill_chunk_wide"])
 def test_a_llama_chunk_writes_no_dequantised_weight_and_no_cache_row(llama_programs, form):
     text = llama_programs(form).as_text()
     assert not _dequantised_weights(text)
@@ -162,7 +168,7 @@ def test_the_llama_head_program_samples_the_first_token_and_writes_the_rows_stat
     assert donated_into_outputs(head) == 2  # tok and pos, int32 [slots] each
 
 
-@pytest.mark.parametrize("form", ["prefill_chunk", "decode_block"])
+@pytest.mark.parametrize("form", ["prefill_chunk", "decode_block", "prefill_chunk_wide"])
 def test_a_llama_program_fits_and_copies_no_int8_weight_of_a_chunk(llama_programs, form):
     compiled = llama_programs(form)
     mem = compiled.memory_analysis()
@@ -245,20 +251,40 @@ def test_the_write_kernel_lowers_through_mosaic_at_other_shapes(one_chip, llama_
     assert size % 128 or compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
-def test_a_prefill_chunk_holds_no_kernel_and_keeps_the_loop(llama_programs):
+@pytest.mark.parametrize("form", ["prefill_chunk", "prefill_chunk_wide"])
+def test_a_prefill_chunk_holds_no_kernel_and_keeps_the_loop(llama_programs, form):
     """Where PR 37's check fell (the first cell's traced run): every prefill chunk walks its row's blocks in the loop
     to the deepest query, one a layer, its trip count traced; its write is one update-slice a leaf at ``slot``;
     nothing of a chunk's goes through Mosaic, and the head's program holds no kernel either."""
-    text = llama_programs("prefill_chunk").as_text()
+    text = llama_programs(form).as_text()
     assert "tpu_custom_call" not in text and "/cache_write/" not in text and not _scatters(text)
     loops = [l for l in text.splitlines() if " while(" in l and re.search(r'attn\._cache_attend/while"', l)]
     assert len(loops) == LAYERS and not any("known_trip_count" in l for l in loops), len(loops)
     assert "tpu_custom_call" not in llama_programs("prefill_chunk_head").as_text()
 
 
+@pytest.mark.parametrize("form", ["prefill_chunk", "prefill_chunk_wide"])
+def test_a_chunk_updates_the_donated_cache_in_place_and_copies_no_leaf(llama_programs, form):
+    """PR 47: the wide chunk program (``serving/engine.py:wide_chunk``, 512 tokens a call) holds what the narrow one
+    does. Every leaf of the donated cache is aliased to its result (24 layers x keys, values and their scales); no
+    ``copy`` anywhere in the program makes a second slab or scale leaf, and nothing row-sized is cut out (the first
+    test above, by form); the program is named so that the benchmark's ``prefill_chunk`` finds it; and of the wide
+    chunk's hidden states only the narrow window that holds the last real token leaves the program."""
+    compiled = llama_programs(form)
+    text = compiled.as_text()
+    assert donated_into_outputs(compiled) == 4 * LAYERS and f"jit_{form}" in text
+    cache_bytes = 2 * LAYERS * L_SLOTS * 8 * L_LEN * (128 + 4)
+    assert cache_bytes <= compiled.memory_analysis().alias_size_in_bytes < cache_bytes + 1e6
+    for op, result, _ in _top_level(text):
+        assert not (op == "copy" and any(dims in (SCALES, SLAB) for _, _, dims in _arrays(result))), result[:80]
+    out = re.search(r"entry_computation_layout=\{\(.*?\)->\((.*?)\)\}", text).group(1)
+    assert f"bf16[1,{L_CHUNK},2048]" in out and f"bf16[1,{L_WIDE},2048]" not in out
+
+
 def test_a_llama_decode_step_keeps_every_dequantisation_inside_its_product(llama_programs):
     text = llama_programs("decode_block").as_text()
     assert not _dequantised_weights(text)
-    # All seven products of a layer are there, under the loop, by their modules' names.
+    # All seven products of a layer are there, under the loop, by their modules' names (PR 47: the layers are one
+    # traced function, ``models/llama.py:decode_forward``; the compiler inlines its calls and keeps the call's name).
     for proj in ("q_proj", "k_proj", "v_proj", "o_proj", "gate_proj", "up_proj", "down_proj"):
-        assert re.search(rf"while/body/Block/\w+/(\w+\.\w+/)*{proj}/dot_general", text), proj
+        assert re.search(rf"while/body/jit\(layer\)/Block/\w+/(\w+\.\w+/)*{proj}/dot_general", text), proj
